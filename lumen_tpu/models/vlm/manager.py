@@ -752,7 +752,7 @@ class VLMManager:
             self._page_size = env_int(
                 "LUMEN_VLM_PAGE_SIZE", DEFAULT_PAGE_SIZE, minimum=8, maximum=256
             )
-            self._pool_pages = resolve_pool_pages(
+            self._pool_pages, self.pool_source = resolve_pool_pages(
                 self.cfg, self._page_size, self.gen_slots, self.max_seq,
                 dtype_bytes=jnp.dtype(compute).itemsize,
             )

@@ -338,44 +338,60 @@ def resolve_pool_pages(
     slots: int,
     max_seq: int,
     dtype_bytes: int = 2,
-) -> int:
-    """Pool size in pages: ``LUMEN_VLM_KV_PAGES`` pins it; otherwise size
-    against live HBM headroom from ``metrics.device_memory()`` (the PR 9
-    telemetry surface), claiming ``LUMEN_VLM_KV_HEADROOM`` of the free
-    bytes on the tightest device. Backends without memory stats (CPU
-    tier-1) fall back to the slot-era footprint — ``slots`` full-length
-    rows — so tests and laptops behave exactly as the contiguous pool did
-    memory-wise while still getting page sharing."""
+) -> tuple[int, str]:
+    """Pool size in pages, and where it came from (``"pinned"``,
+    ``"device_memory"`` or ``"no_device_stats"``). ``LUMEN_VLM_KV_PAGES``
+    pins it; otherwise it is sized against live HBM headroom as the
+    devices report it (``memory_stats()``), claiming
+    ``LUMEN_VLM_KV_HEADROOM`` of the free bytes on the tightest device. A
+    backend without memory stats (the CPU, tier-1) gets the slot-era
+    footprint — ``slots`` full-length rows — so tests and laptops behave
+    exactly as the contiguous pool did memory-wise while still getting
+    page sharing. A TPU without them is an error: a pool sized by guess
+    either wastes the chip or runs it out of memory."""
+    import jax
+
     from ...utils.env import env_float, env_int
-    from ...utils.metrics import metrics
 
     maxp = -(-max_seq // page_size)
     # Floor: every slot can hold at least one modest row (1/4 max_seq)
     # concurrently; below that the pool thrashes on preemption.
     floor = slots * max(1, maxp // 4) + 1
-    fallback = slots * maxp + 1
+    # Cap at what block tables can even address (slots x max_pages) — a
+    # bigger pool than addressable is pure waste.
+    cap = slots * maxp + 1
     explicit = env_int("LUMEN_VLM_KV_PAGES", None, minimum=2)
     if explicit is not None:
-        return max(explicit, 2)
+        return max(explicit, 2), "pinned"
     frac = env_float(
         "LUMEN_VLM_KV_HEADROOM", DEFAULT_HEADROOM_FRACTION, minimum=0.05, maximum=0.95
     )
     per_page = page_bytes(cfg, page_size, dtype_bytes)
     headroom = None
-    for stats in metrics.device_memory().values():
-        limit, in_use = stats.get("bytes_limit"), stats.get("bytes_in_use")
-        if limit:
-            free = max(0, int(limit) - int(in_use or 0))
-            headroom = free if headroom is None else min(headroom, free)
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            if dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev} reports no memory_stats()['bytes_limit']: the paged "
+                    "KV pool cannot be sized from HBM headroom (pin it with "
+                    "LUMEN_VLM_KV_PAGES to go on)"
+                )
+            continue
+        free = max(0, int(limit) - int(stats.get("bytes_in_use") or 0))
+        headroom = free if headroom is None else min(headroom, free)
     if headroom is None:
-        return fallback
+        logger.info(
+            "VLM paged-KV pool: %d pages x %d tokens (slot-era footprint: the %s "
+            "backend reports no device memory stats)",
+            cap, page_size, jax.default_backend(),
+        )
+        return cap, "no_device_stats"
     pages = int(headroom * frac) // max(per_page, 1)
-    # Cap at what block tables can even address (slots x max_pages) — a
-    # bigger pool than addressable is pure waste.
-    cap = slots * maxp + 1
     sized = max(floor, min(pages, cap))
     logger.info(
         "VLM paged-KV pool: %d pages x %d tokens (%.1f MB of %.1f MB headroom, cap %d)",
         sized, page_size, sized * per_page / 1e6, headroom / 1e6, cap,
     )
-    return sized
+    return sized, "device_memory"

@@ -4,9 +4,12 @@ The C core covers the host half of the serving hot loops — letterbox/resize,
 NMS, CTC collapse — GIL-free so the ingest pipeline's preprocess workers
 scale across cores. Loading policy:
 
-1. use ``native/build/liblumen_host_ops.so`` if present and ABI-compatible;
+1. use the library keyed by this source's digest if it was built before;
 2. else, if a C++ toolchain is available, build it once (quiet, ~1s);
-3. else mark the library unavailable — every caller has a numpy/cv2
+3. only where there is no compiler, take an un-keyed
+   ``native/build/liblumen_host_ops.so`` that ``make -C native`` left
+   (nothing says which source it was built from);
+4. else mark the library unavailable — every caller has a numpy/cv2
    fallback, so the framework stays pure-Python-runnable.
 
 ``LUMEN_TPU_NO_NATIVE=1`` skips native entirely (debugging/benchmark A/B).
@@ -17,6 +20,7 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
+import shutil
 import subprocess
 import threading
 
@@ -63,14 +67,18 @@ def _src_digest() -> str:
 _LIB_PATH = os.path.join(
     _build_dir(), f"liblumen_host_ops-{ABI_VERSION}-{_src_digest()}.so"
 )
-# A `make -C native` prebuild lands at the unkeyed Makefile name; accept it
-# as a fallback (the ABI gate in load() still applies) so prebuilding for a
-# g++-less runtime keeps working alongside the digest-keyed self-build.
+# A `make -C native` prebuild lands at the unkeyed Makefile name; taken
+# only on a g++-less runtime (the ABI gate in load() still applies).
 _PREBUILT_PATH = os.path.join(_build_dir(), "liblumen_host_ops.so")
+_CXX = "g++"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+#: where the bound library came from: its path, and whether this process
+#: compiled it (``chip_smoke.py`` reports both).
+_loaded_path: str | None = None
+_built_here = False
 
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 _f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
@@ -87,13 +95,15 @@ def _build() -> bool:
     # concurrent processes racing the first build must never dlopen a
     # half-written .so, and a killed compiler must not leave a corrupt final.
     tmp = f"{_LIB_PATH}.tmp{os.getpid()}"
-    cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-o", tmp, src]
+    cmd = [_CXX, "-O2", "-std=c++17", "-fPIC", "-shared", "-o", tmp, src]
+    global _built_here
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if proc.returncode != 0:
             logger.warning("native host-ops build failed:\n%s", proc.stderr[-2000:])
             return False
         os.replace(tmp, _LIB_PATH)
+        _built_here = True
         return True
     except (OSError, subprocess.TimeoutExpired) as e:
         logger.info("native host-ops build skipped: %s", e)
@@ -123,9 +133,27 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
+def _try_load(candidate: str) -> ctypes.CDLL | None:
+    """Bind ``candidate`` if it exists, loads and speaks this ABI; a stale
+    or corrupt artifact is removed so a rebuild gets a clean slate."""
+    if not os.path.exists(candidate):
+        return None
+    try:
+        lib = _bind(ctypes.CDLL(candidate))
+        if lib.lumen_host_ops_abi_version() == ABI_VERSION:
+            return lib
+        logger.info("native host-ops ABI mismatch: %s", candidate)
+    except (OSError, AttributeError) as e:
+        # OSError: unloadable; AttributeError: loadable but missing a
+        # symbol, e.g. built from older sources.
+        logger.warning("native host-ops load failed: %s", e)
+    _unlink_quiet(candidate)
+    return None
+
+
 def load() -> ctypes.CDLL | None:
     """The bound library, building it on first use; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _loaded_path
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -134,28 +162,29 @@ def load() -> ctypes.CDLL | None:
         _tried = True
         if os.environ.get("LUMEN_TPU_NO_NATIVE") == "1":
             return None
-        for attempt in range(2):
-            for candidate in (_LIB_PATH, _PREBUILT_PATH):
-                if not os.path.exists(candidate):
-                    continue
-                try:
-                    lib = _bind(ctypes.CDLL(candidate))
-                    if lib.lumen_host_ops_abi_version() == ABI_VERSION:
-                        _lib = lib
-                        logger.info("native host-ops loaded: %s", candidate)
-                        return _lib
-                    logger.info("native host-ops ABI mismatch; rebuilding")
-                    _unlink_quiet(candidate)
-                except (OSError, AttributeError) as e:
-                    # Stale/corrupt artifact (OSError: unloadable;
-                    # AttributeError: loadable but missing a symbol, e.g.
-                    # built from older sources): remove it so the rebuild
-                    # below gets a clean slate.
-                    logger.warning("native host-ops load failed: %s", e)
-                    _unlink_quiet(candidate)
-            if attempt == 0 and not _build():
-                break
-        return None
+        path = _LIB_PATH
+        lib = _try_load(path)
+        if lib is None and _build():
+            lib = _try_load(path)
+        if lib is None and shutil.which(_CXX) is None:
+            path = _PREBUILT_PATH
+            lib = _try_load(path)
+        if lib is not None:
+            _lib, _loaded_path = lib, path
+            logger.info("native host-ops loaded: %s", path)
+        return _lib
+
+
+def provenance() -> dict:
+    """What :func:`load` bound: the path, whether it is the library keyed
+    by the committed source's digest, and whether this process built it."""
+    load()
+    return {
+        "path": _loaded_path,
+        "digest_keyed": _loaded_path == _LIB_PATH,
+        "built_this_run": _built_here,
+        "compiler": shutil.which(_CXX),
+    }
 
 
 def _unlink_quiet(path: str) -> None:

@@ -29,7 +29,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..runtime.mesh import EXPERT_AXIS

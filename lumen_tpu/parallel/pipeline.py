@@ -31,7 +31,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..runtime.mesh import STAGE_AXIS

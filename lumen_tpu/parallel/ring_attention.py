@@ -18,7 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 from ..runtime.mesh import SEQ_AXIS
 

@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 from ..ops.attention import attention
 from ..runtime.mesh import SEQ_AXIS

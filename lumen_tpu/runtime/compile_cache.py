@@ -2,14 +2,17 @@
 
 The reference pays "model load time" once per process (onnxruntime session
 build, ``onnxrt_backend.py:228``); our equivalent startup cost is XLA
-compilation — tens of seconds per shape bucket on TPU, worse through a
-remote-compile tunnel. JAX can persist compiled executables to disk keyed
-by (HLO, backend, flags); enabling it turns every warm restart, bench
-subprocess, and supervised-server respawn into a cache hit instead of a
-recompile.
+compilation — tens of seconds per shape bucket on TPU. JAX can persist
+compiled executables to disk keyed by (HLO, backend, flags); enabling it
+turns every warm restart, bench subprocess, and supervised-server respawn
+into a cache hit instead of a recompile.
 
-Opt-out via ``LUMEN_COMPILE_CACHE=0``; cache location override via
-``LUMEN_COMPILE_CACHE_DIR`` (default ``~/.cache/lumen_tpu/xla``).
+Where the cache lives is the deployment's call, made the way JAX itself
+takes it: ``JAX_COMPILATION_CACHE_DIR``. With it set, this module
+configures no directory at all. Without it the cache goes to one fixed
+path inside the checkout, ``<repo>/.jax_cache`` — the path is part of
+what makes an entry findable again, so it is never a home directory, a
+temporary name, a pid or a time. Opt out with ``LUMEN_COMPILE_CACHE=0``.
 """
 
 from __future__ import annotations
@@ -20,8 +23,11 @@ import threading
 
 logger = logging.getLogger(__name__)
 
-_DEFAULT_DIR = os.path.join(
-    os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")), "lumen_tpu", "xla"
+#: the cache's place when the environment names none: ``<repo>/.jax_cache``
+#: (git-ignored), next to the ``lumen_tpu`` package directory.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
 )
 
 _listener_lock = threading.Lock()
@@ -50,29 +56,26 @@ def _on_jax_event(name: str, secs: float, **kwargs) -> None:  # noqa: ARG001
 def install_compile_listener() -> bool:
     """Register the XLA compile-event hook (idempotent; returns whether
     the hook is live). Called from :func:`enable_persistent_cache` — the
-    one place this repo configures JAX's compilation machinery — and
-    safe on jax versions without ``jax.monitoring`` (degrades to off)."""
+    one place this repo configures JAX's compilation machinery."""
     global _listener_installed
+    from jax import monitoring
+
     with _listener_lock:
         if _listener_installed:
             return True
-        try:
-            from jax import monitoring
-
-            monitoring.register_event_duration_secs_listener(_on_jax_event)
-        except Exception as e:  # noqa: BLE001 - telemetry hook is never fatal
-            logger.warning("XLA compile-event listener unavailable: %s", e)
-            return False
+        monitoring.register_event_duration_secs_listener(_on_jax_event)
         _listener_installed = True
     logger.info("XLA compile events feeding capacity telemetry")
     return True
 
 
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Point JAX's compilation cache at a persistent directory.
+def enable_persistent_cache() -> str | None:
+    """Turn JAX's persistent compilation cache on (see the module
+    docstring for where it lives).
 
     Idempotent; safe to call before or after backend init (the cache is
-    consulted per compile). Returns the cache dir, or None when disabled.
+    consulted per compile). Returns the directory in force, or None when
+    disabled.
     """
     # Compile events feed telemetry whether or not the disk cache is on:
     # the recompile-storm detector must not vanish with LUMEN_COMPILE_CACHE=0.
@@ -81,14 +84,13 @@ def enable_persistent_cache(path: str | None = None) -> str | None:
         return None
     import jax
 
-    cache_dir = path or os.environ.get("LUMEN_COMPILE_CACHE_DIR") or _DEFAULT_DIR
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # JAX's own gating (min compile time 1s by default) keeps ms-scale
-        # programs out of the cache; every real model bucket qualifies.
-    except Exception as e:  # noqa: BLE001 - cache is an optimization, never fatal
-        logger.warning("persistent compile cache unavailable: %s", e)
-        return None
-    logger.info("persistent XLA compile cache at %s", cache_dir)
-    return cache_dir
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_dir:
+        # JAX read the variable itself at import; leave its setting alone.
+        logger.info("persistent XLA compile cache at %s (JAX_COMPILATION_CACHE_DIR)", cache_dir)
+        return cache_dir
+    # JAX's own gating (min compile time 1s by default) keeps ms-scale
+    # programs out of the cache; every real model bucket qualifies.
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    logger.info("persistent XLA compile cache at %s", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR

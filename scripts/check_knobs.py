@@ -31,9 +31,7 @@ KNOB_RE = re.compile(r"LUMEN_[A-Z][A-Z0-9_]*")
 #: toggles (documented where they are used) and internal plumbing that is
 #: not an operator surface. Keep this SHORT — the point of the check is
 #: that the default for a new knob is "document it".
-ALLOWLIST = {
-    "LUMEN_TPU_TESTS",  # tests/conftest.py on-chip toggle, documented there
-}
+ALLOWLIST: set[str] = set()
 
 
 def _scan(paths: list[str], exts: tuple[str, ...]) -> set[str]:

@@ -8,14 +8,11 @@ TPU hardware, mirroring the strategy described in SURVEY.md §4.
 import os
 import sys
 
-# Must be set before jax initializes a backend. LUMEN_TPU_TESTS=1 opts out
-# of the CPU override so the @pytest.mark.tpu subset runs on the real chip
-# (e.g. `LUMEN_TPU_TESTS=1 pytest -m tpu tests/test_ops.py`).
-_ON_CHIP = os.environ.get("LUMEN_TPU_TESTS") == "1"
-if not _ON_CHIP:
-    os.environ["JAX_PLATFORMS"] = "cpu"
+# Must be set before jax initializes a backend. The suite runs on the CPU
+# only; what needs the chip is checked by ``chip_smoke.py``.
+os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
-if not _ON_CHIP and "xla_force_host_platform_device_count" not in _flags:
+if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
@@ -26,23 +23,7 @@ if not _ON_CHIP and "xla_force_host_platform_device_count" not in _flags:
 # initialized lazily, so this sticks as long as no devices were touched yet.
 import jax  # noqa: E402
 
-if not _ON_CHIP:
-    jax.config.update("jax_platforms", "cpu")
-
-# Persistent XLA compile cache shared across the whole suite and across
-# runs (round-4 verdict item 8: >10 min of repeated CPU compiles).
-# XLA:CPU AOT-loads cached executables; the loader logs noisy E-level
-# warnings about the two `prefer-no-*` pseudo-features not appearing in
-# host detection — same machine, benign. Opt out with
-# LUMEN_TEST_NO_COMPILE_CACHE=1 if a cache entry is ever suspect.
-if not os.environ.get("LUMEN_TEST_NO_COMPILE_CACHE"):
-    _cache_dir = os.environ.get(
-        "LUMEN_TEST_COMPILE_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "lumen_tpu_test_xla"),
-    )
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+jax.config.update("jax_platforms", "cpu")
 
 # Repo root on sys.path so `import lumen_tpu` works without installation.
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -120,6 +101,21 @@ os.environ["LUMEN_DECODE_PROCS"] = "0"
 # args or a monkeypatched env (tests/test_fault_containment.py).
 os.environ["LUMEN_BREAKER_FAILURES"] = "0"
 
+# Persistent XLA compile cache shared across the whole suite and across
+# runs (round-4 verdict item 8: >10 min of repeated CPU compiles), placed
+# by the program's own helper: JAX_COMPILATION_CACHE_DIR, else
+# <repo>/.jax_cache. XLA:CPU AOT-loads cached executables; the loader logs
+# noisy E-level warnings about the two `prefer-no-*` pseudo-features not
+# appearing in host detection — same machine, benign. Opt out with
+# LUMEN_TEST_NO_COMPILE_CACHE=1 if a cache entry is ever suspect. (Last of
+# the environment set-up: importing the package reads some of the above.)
+if not os.environ.get("LUMEN_TEST_NO_COMPILE_CACHE"):
+    from lumen_tpu.runtime.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
 
 # Compile-heavy tests (>~15s each on this 1-core host, measured full-suite
 # run 2026-08-01: 511 tests, 13:47 hot-cache) are auto-marked ``slow`` so
@@ -194,19 +190,10 @@ def pytest_configure(config):
 
 
 def pytest_collection_modifyitems(config, items):
-    """Two jobs: (1) on-chip sessions run ONLY the @pytest.mark.tpu subset
-    — everything else was recorded/toleranced for CPU numerics (golden
-    fixtures, exact NMS masks) and would fail spuriously on TPU matmul
-    precision; (2) off-chip, auto-mark the ``_SLOW`` list so the default
-    tier (``-m "not slow"``) stays fast."""
+    """Auto-mark the ``_SLOW`` list so the default tier (``-m "not slow"``)
+    stays fast."""
     import pytest
 
-    if _ON_CHIP:
-        skip = pytest.mark.skip(reason="LUMEN_TPU_TESTS=1 runs only -m tpu tests")
-        for item in items:
-            if "tpu" not in item.keywords:
-                item.add_marker(skip)
-        return
     slow = pytest.mark.slow
     matched = set()
     for item in items:
@@ -249,8 +236,8 @@ def multidevice(request):
 
     The tier-1 suite already forces an 8-device CPU backend at the top of
     this conftest, so the common case is a no-op that returns the live
-    device count. When the current backend CANNOT provide them — an
-    on-chip session, a dev shell with its own XLA_FLAGS — the test is
+    device count. When the current backend CANNOT provide them — a dev
+    shell with its own XLA_FLAGS — the test is
     re-run in a subprocess under ``JAX_PLATFORMS=cpu`` +
     ``--xla_force_host_platform_device_count=8`` and this invocation
     reports the subprocess verdict (skip on pass, fail on fail) instead
@@ -271,7 +258,6 @@ def multidevice(request):
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     }
-    env.pop("LUMEN_TPU_TESTS", None)
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
          request.node.nodeid],

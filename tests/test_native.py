@@ -148,3 +148,35 @@ class TestLoader:
         lib = native.load()
         assert lib is not None
         assert lib.lumen_host_ops_abi_version() == native.ABI_VERSION
+
+    @pytest.fixture()
+    def fresh_loader(self, tmp_path, monkeypatch):
+        """The loader with nothing bound yet, pointed at an empty build
+        directory that holds one un-keyed prebuilt copy of the real
+        library (what ``make -C native`` leaves)."""
+        import shutil
+
+        keyed = tmp_path / "liblumen_host_ops-keyed.so"
+        prebuilt = tmp_path / "liblumen_host_ops.so"
+        shutil.copy(native.provenance()["path"], prebuilt)
+        monkeypatch.setattr(native, "_LIB_PATH", str(keyed))
+        monkeypatch.setattr(native, "_PREBUILT_PATH", str(prebuilt))
+        for name, value in (
+            ("_lib", None), ("_tried", False), ("_loaded_path", None), ("_built_here", False)
+        ):
+            monkeypatch.setattr(native, name, value)
+        return keyed, prebuilt
+
+    def test_builds_from_source_rather_than_take_unkeyed_prebuilt(self, fresh_loader):
+        """With a compiler, the library is the one keyed by the committed
+        source's digest — an un-keyed prebuilt one is not taken on trust."""
+        keyed, _ = fresh_loader
+        got = native.provenance()
+        assert got["path"] == str(keyed) and got["digest_keyed"] and got["built_this_run"]
+
+    def test_unkeyed_prebuilt_only_without_compiler(self, fresh_loader, monkeypatch):
+        _, prebuilt = fresh_loader
+        monkeypatch.setattr(native, "_CXX", "no-such-compiler-xyz")
+        got = native.provenance()
+        assert got["path"] == str(prebuilt) and not got["digest_keyed"]
+        assert got["compiler"] is None and not got["built_this_run"]

@@ -61,7 +61,7 @@ class TestMesh:
         # Sanity: a shard_map psum over the data axis actually reduces.
         from jax.sharding import PartitionSpec as P
 
-        from lumen_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         mesh = build_mesh({"data": -1})
         x = np.arange(8, dtype=np.float32)
@@ -291,40 +291,73 @@ class TestMeshBatching:
 
 
 class TestCompileCache:
-    def test_enable_points_jax_at_dir(self, tmp_path, monkeypatch):
-        import jax
+    """Where the cache lives: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX
+    reads it itself; the helper configures nothing), else the one fixed
+    path inside the checkout."""
 
+    @pytest.fixture()
+    def restore_cache_dir(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield prev
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_unset_env_points_jax_at_checkout_dir(self, monkeypatch, restore_cache_dir):
         from lumen_tpu.runtime import enable_persistent_cache
+        from lumen_tpu.runtime.compile_cache import DEFAULT_CACHE_DIR
 
         monkeypatch.delenv("LUMEN_COMPILE_CACHE", raising=False)
-        monkeypatch.delenv("LUMEN_COMPILE_CACHE_DIR", raising=False)
-        target = str(tmp_path / "xla")
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            got = enable_persistent_cache(target)
-            assert got == target
-            assert os.path.isdir(target)
-            assert jax.config.jax_compilation_cache_dir == target
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        assert enable_persistent_cache() == DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_CACHE_DIR
 
-    def test_env_opt_out(self, tmp_path, monkeypatch):
+    def test_env_opt_out(self, monkeypatch, restore_cache_dir):
         from lumen_tpu.runtime import enable_persistent_cache
 
         monkeypatch.setenv("LUMEN_COMPILE_CACHE", "0")
-        assert enable_persistent_cache(str(tmp_path / "x")) is None
-        assert not os.path.exists(str(tmp_path / "x"))
+        jax.config.update("jax_compilation_cache_dir", "/untouched")
+        assert enable_persistent_cache() is None
+        assert jax.config.jax_compilation_cache_dir == "/untouched"
 
-    def test_env_dir_override(self, tmp_path, monkeypatch):
-        import jax
-
+    def test_env_dir_is_left_to_jax(self, tmp_path, monkeypatch, restore_cache_dir):
+        """With JAX_COMPILATION_CACHE_DIR set the helper reports that
+        directory and calls no ``jax.config.update`` for it: whatever JAX
+        holds (it read the variable at import) stays as it is."""
         from lumen_tpu.runtime import enable_persistent_cache
 
         monkeypatch.delenv("LUMEN_COMPILE_CACHE", raising=False)
         target = str(tmp_path / "envdir")
-        prev = jax.config.jax_compilation_cache_dir
-        monkeypatch.setenv("LUMEN_COMPILE_CACHE_DIR", target)
-        try:
-            assert enable_persistent_cache() == target
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+        jax.config.update("jax_compilation_cache_dir", "/untouched")
+        assert enable_persistent_cache() == target
+        assert jax.config.jax_compilation_cache_dir == "/untouched"
+        assert not os.path.exists(target)  # JAX makes it on first write, not us
+
+    def test_two_calls_agree(self, monkeypatch, restore_cache_dir):
+        """The path is part of what makes an entry findable: it may not
+        depend on the call, the pid or the clock."""
+        from lumen_tpu.runtime import enable_persistent_cache
+
+        monkeypatch.delenv("LUMEN_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first = enable_persistent_cache()
+        assert enable_persistent_cache() == first
+        assert str(os.getpid()) not in first and not first.startswith(
+            (os.path.expanduser("~") + os.sep + ".cache", "/tmp")
+        )
+
+    def test_jax_reads_the_variable_itself(self, tmp_path):
+        """The rule above rests on JAX taking the directory from the
+        environment at import — checked in a fresh interpreter."""
+        import subprocess
+        import sys
+
+        target = str(tmp_path / "from-env")
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import jax; print(jax.config.jax_compilation_cache_dir)"],
+            env={**os.environ, "JAX_COMPILATION_CACHE_DIR": target, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.stdout.strip() == target, out.stderr[-500:]

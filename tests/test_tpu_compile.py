@@ -1,0 +1,103 @@
+"""Every Pallas kernel of the serving path, compiled for a TPU v5e that is
+described and not attached (``/opt/skills/guides/on-chip-measurement`` §2).
+
+Interpret mode checks a kernel's arithmetic; only the chip's compiler
+checks that Mosaic accepts its layouts (tile-aligned slices, VMEM budget).
+Both paged-attention kernels passed every interpret-mode test while the
+compiler refused them outright — these cases are the ones that would
+have caught that, at no chip time: shapes, not arrays, at Qwen2-0.5B
+widths (14 query / 2 KV heads, head_dim 64, hidden 896, MLP 4864,
+16-token pages), ``interpret=False`` passed explicitly because
+``jax.default_backend()`` is the CPU here.
+"""
+
+from __future__ import annotations
+
+import importlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# ``lumen_tpu.ops`` re-exports the ``attention`` FUNCTION over the submodule.
+att = importlib.import_module("lumen_tpu.ops.attention")
+from lumen_tpu.ops import quant_matmul
+
+B, HEADS, KV_HEADS, HEAD_DIM, PAGE = 8, 14, 2, 64, 16
+HIDDEN, MLP = 896, 4864
+BF16, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described ``v5e:2x2`` host; skips where
+    the installed runtime cannot describe it."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # An AOT executable for an absent chip is written to the persistent
+    # cache but cannot be read back: the next run would warn and recompile.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _paged(fn, q_shape, maxp):
+    pages = (B * maxp + 1, KV_HEADS, PAGE, HEAD_DIM)
+    return (
+        lambda q, k, v, bt, kl: fn(q, k, v, bt, kl, interpret=False),
+        [(q_shape, BF16), (pages, BF16), (pages, BF16), ((B, maxp), I32), ((B,), I32)],
+    )
+
+
+def _flash_cache(sq, sk):
+    return (
+        lambda q, k, v, off, valid: att.flash_attention_cache(
+            q, k, v, off, valid, interpret=False
+        ),
+        [
+            ((B, HEADS, sq, HEAD_DIM), BF16),
+            ((B, HEADS, sk, HEAD_DIM), BF16),
+            ((B, HEADS, sk, HEAD_DIM), BF16),
+            ((B,), I32),
+            ((B,), I32),
+        ],
+    )
+
+
+CASES = {
+    # 128 pages a row is the serving default (max_seq 2048); 512 pages is
+    # the 8,192-token row the old kernel capped at.
+    "paged_decode": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 128),
+    "paged_decode_8k_row": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 512),
+    "paged_varq_w5": _paged(att.paged_attention_varq_kernel, (B, 5, HEADS, HEAD_DIM), 128),
+    "flash_prefill": (
+        lambda q, k, v: att.flash_attention(q, k, v, causal=True, interpret=False),
+        [((B, HEADS, 512, HEAD_DIM), BF16)] * 3,
+    ),
+    "flash_cache_sq1": _flash_cache(1, 2048),
+    "flash_cache_sq256": _flash_cache(256, 2048),
+    "w8a16": (
+        lambda x, q, s: quant_matmul._w8a16_2d(x, q, s, block_n=256, interpret=False),
+        [((B, HIDDEN), BF16), ((HIDDEN, MLP), jnp.int8), ((MLP,), jnp.float32)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(v5e, name):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    assert "tpu_custom_call" in compiled.as_text()
