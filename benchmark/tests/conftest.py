@@ -1,0 +1,10 @@
+"""The benchmark's own tests: CPU, tiny sizes, never a measurement.
+Run them with ``JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q``."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
