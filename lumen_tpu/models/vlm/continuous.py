@@ -66,7 +66,7 @@ from ...utils import telemetry
 from ...utils.metrics import metrics
 from ...utils.shm_arena import ShmArena
 from ...utils.telemetry import record_event
-from ...utils.trace import current_trace
+from ...utils.trace import current_trace, phase
 from . import migration
 from .manager import _PendingGen
 from .paged_kv import DEFAULT_PAGE_SIZE, PagedKVPool, PoolExhausted, page_bytes
@@ -131,6 +131,18 @@ class _Request(_PendingGen):
     #: decode-host side of a migration: ``(manifest_keys, n_shared)``
     #: pending prefix-cache resolution at resume. None otherwise.
     migrate_in: "tuple | None" = None
+    #: scheduler-local request number, given at submit: the feeder
+    #: thread's phases name requests by it, and the request's own
+    #: ``batch.device`` spans carry it, so the two join.
+    rid: int = 0
+    #: ``perf_counter`` instants behind the cumulative wait gauges: first
+    #: submit; start of the current wait in the queue (re-stamped when a
+    #: preemption sends the request back); taken out of the queue for an
+    #: admission unit, a resume or the lane; first token handed out.
+    t_submit: float = 0.0
+    t_queued: float = 0.0
+    t_taken: float = 0.0
+    t_first_token: float = 0.0
 
 
 @dataclass
@@ -354,6 +366,19 @@ class ContinuousScheduler:
         # (every step in a block shares the block-start row count).
         self._occ_rows = 0
         self._occ_blocks = 0
+        # Cumulative waits, written by the loop thread alone (a window's
+        # mean is a ratio of deltas): queue wait of every admission
+        # (submit, or the preemption that sent it back -> taken for an
+        # admission unit, a resume or the lane), time in the chunked
+        # prefill lane (lane entry -> row installed, first token sampled),
+        # and submit -> first token handed to the stream.
+        self._submit_seq = 0
+        self.pending_ms_sum = 0.0
+        self.pending_count = 0
+        self.lane_ms_sum = 0.0
+        self.lane_jobs = 0
+        self.first_token_ms_sum = 0.0
+        self.first_token_count = 0
         self._thread = threading.Thread(target=self._loop, name="vlm-continuous", daemon=True)
         self._thread.start()
         ref = weakref.ref(self)  # registry must not pin the pool/params
@@ -365,7 +390,14 @@ class ContinuousScheduler:
             stats = s.kv.stats()
             out = {
                 "blocks_run": s.blocks_run,
+                "rows_stepped": s._occ_rows,
                 "admitted": s.admitted,
+                "pending_ms_sum": round(s.pending_ms_sum, 3),
+                "pending_count": s.pending_count,
+                "lane_ms_sum": round(s.lane_ms_sum, 3),
+                "lane_jobs": s.lane_jobs,
+                "first_token_ms_sum": round(s.first_token_ms_sum, 3),
+                "first_token_count": s.first_token_count,
                 "preempted": s.preemptions,
                 "prefill_chunks_run": s.chunks_run,
                 "prefill_lane_depth": len(s._prefill_jobs),
@@ -460,6 +492,7 @@ class ContinuousScheduler:
         with self._cond:
             if self._closed:
                 raise RuntimeError("continuous scheduler is closed")
+            self._stamp_submit(req)
             self._pending.append(req)
             self._cond.notify()
         # Arrival counter under the batcher's ``batch_items:{name}`` key:
@@ -467,6 +500,21 @@ class ContinuousScheduler:
         # engine families share the MicroBatcher sensor vocabulary.
         telemetry.count(f"batch_items:{self.name}")
         return req.future
+
+    def _stamp_submit(self, req: _Request) -> None:
+        """Number the request and start its clocks (under ``_cond``)."""
+        self._submit_seq += 1
+        req.rid = self._submit_seq
+        req.t_submit = req.t_queued = time.perf_counter()
+
+    def _count_admitted(self, req: _Request) -> None:
+        """A row was installed: book the queue wait that ended when the
+        loop took the request (``t_taken``), so ``pending_count`` is
+        ``admitted`` by construction."""
+        self.admitted += 1
+        taken = req.t_taken or time.perf_counter()
+        self.pending_ms_sum += max(0.0, taken - req.t_queued) * 1e3
+        self.pending_count += 1
 
     def load(self) -> int:
         """Dispatch weight for the manager's least-loaded engine pick."""
@@ -519,18 +567,18 @@ class ContinuousScheduler:
 
     # -- scheduler loop ----------------------------------------------------
 
+    def _idle(self) -> bool:
+        return not (self._closed or self._pending or self._slots or self._prefill_jobs)
+
     def _take_work(self) -> list[_Request]:
         """Block until there is something to do; drain admissible requests.
         Chunk-lane jobs hold a slot reservation, so the drain never takes
         more requests than slots that will actually be free."""
         with self._cond:
-            while (
-                not self._closed
-                and not self._pending
-                and not self._slots
-                and not self._prefill_jobs
-            ):
-                self._cond.wait()
+            if self._idle():
+                with phase("vlm.wait_work"):
+                    while self._idle():
+                        self._cond.wait()
             if self._closed:
                 return []
             free = self.n_slots - len(self._slots) - len(self._prefill_jobs)
@@ -559,58 +607,21 @@ class ContinuousScheduler:
                     for req in admit:
                         _fail(req, err)
                     return
-                live = []
-                for req in admit:
-                    if req.cancelled:
-                        # Stream consumer disconnected while queued: retire
-                        # without wasting a prefill dispatch on a dead row.
-                        # A parked spill record's tokens are what the row
-                        # produced — deliver them, and free the lease.
-                        rec = self._drop_spill(req)
-                        _retire(req, list(rec.tokens) if rec else [], eos=False)
-                    else:
-                        live.append(req)
-                # Page gating: take requests in arrival order while the
-                # free list covers their prompts; the rest go back to the
-                # queue head and wait for retires to free pages. A
-                # finished chunk-lane job waiting on pages gets its need
-                # RESERVED out of the budget first — without that, a
-                # sustained stream of short arrivals re-grants every
-                # freed page each turn and starves the long prompt
-                # forever.
-                placeable, deferred = [], []
-                budget = self.kv.pages_free - self._lane_reserved_pages()
-                for req in live:
-                    if req.spill is not None:
-                        # A parked victim resumes into exactly its exported
-                        # grant; growth past it is _ensure_growth's job.
-                        need = req.spill.n_pages
-                    else:
-                        n = int(np.asarray(req.length)[0])
-                        # A cached prefix needs no fresh grant — coverage
-                        # is re-checked at admission (eviction between the
-                        # peek and the attach degrades to a requeue).
-                        covered = len(self._prefix_lookup(req, n))
-                        need = self.kv.pages_for(n + 1) - covered
-                    if need > budget and self.prefix is not None and not deferred:
-                        # Cached history yields to live admissions before
-                        # any request waits on retires.
-                        budget += self.prefix.reclaim(need - budget)
-                    if deferred or need > budget:
-                        deferred.append(req)
-                    else:
-                        budget -= need
-                        placeable.append(req)
-                self._requeue_front(deferred)
+                with phase("vlm.gate", step=self.blocks_run + 1, taken=len(admit)):
+                    placeable = self._gate(admit)
+                taken = time.perf_counter()
                 direct, hits = [], []
                 for req in placeable:
+                    req.t_taken = taken
                     if req.spill is not None:
                         # Re-admission scatters the spilled pages back in —
                         # no prefill group, no chunk lane, no device work
                         # proportional to the prompt.
-                        self._resume_row(req)
+                        with phase("vlm.admit", kind="resume", rows=1, rids=str(req.rid)):
+                            self._resume_row(req)
                     elif req.embeds.shape[1] > self.prefill_chunk:
-                        self._prefill_jobs.append(self._start_chunk_job(req))
+                        with phase("vlm.admit", kind="lane", rows=1, rids=str(req.rid)):
+                            self._prefill_jobs.append(self._start_chunk_job(req))
                     elif self._prefix_lookup(req, int(np.asarray(req.length)[0])):
                         hits.append(req)
                     else:
@@ -619,11 +630,17 @@ class ContinuousScheduler:
                 # coverage), misses keep the batched-prefill groups. Both
                 # fail like a group: the unit's requests on error, the
                 # whole engine if the donation consumed the pool.
-                units = [(self._admit_prefix_hit, req, [req]) for req in hits]
-                units += [(self._admit_group, g, g) for g in self._admit_groups(direct)]
-                for gpos, (admit_fn, arg, members) in enumerate(units):
+                units = [("hit", self._admit_prefix_hit, req, [req]) for req in hits]
+                units += [
+                    ("group", self._admit_group, g, g) for g in self._admit_groups(direct)
+                ]
+                for gpos, (kind, admit_fn, arg, members) in enumerate(units):
                     try:
-                        admit_fn(arg)
+                        with phase(
+                            "vlm.admit", kind=kind, rows=len(members),
+                            rids=",".join(str(r.rid) for r in members),
+                        ):
+                            admit_fn(arg)
                     except Exception as e:  # noqa: BLE001 - fail ONE unit
                         for req in members:
                             _fail(req, e)
@@ -636,7 +653,7 @@ class ContinuousScheduler:
                             # sweeps only _pending + _slots and this batch
                             # is already off _pending, so fail its
                             # unprocessed tail here first.
-                            for _, _, later in units[gpos + 1 :]:
+                            for *_, later in units[gpos + 1 :]:
                                 for req in later:
                                     _fail(req, e)
                             raise RuntimeError(
@@ -644,7 +661,8 @@ class ContinuousScheduler:
                             ) from e
                 self._advance_prefill_lane()
                 if self.migrator is not None:
-                    self._migrate_sweep()
+                    with phase("vlm.migrate"):
+                        self._migrate_sweep()
                 if self._slots:
                     self._run_block()
         except BaseException as e:  # noqa: BLE001 - never strand callers
@@ -659,6 +677,55 @@ class ContinuousScheduler:
                 _fail(req, RuntimeError(f"continuous scheduler died: {e!r}"))
             for job in jobs:
                 self._drop_job_hold(job)
+
+    def _gate(self, admit: list[_Request]) -> list[_Request]:
+        """The head of a turn: sweep cancelled arrivals, gate the rest on
+        pages in arrival order, send what does not fit back to the queue
+        head. Returns the requests to place this turn."""
+        live = []
+        for req in admit:
+            if req.cancelled:
+                # Stream consumer disconnected while queued: retire
+                # without wasting a prefill dispatch on a dead row.
+                # A parked spill record's tokens are what the row
+                # produced — deliver them, and free the lease.
+                rec = self._drop_spill(req)
+                _retire(req, list(rec.tokens) if rec else [], eos=False)
+            else:
+                live.append(req)
+        # Page gating: take requests in arrival order while the
+        # free list covers their prompts; the rest go back to the
+        # queue head and wait for retires to free pages. A
+        # finished chunk-lane job waiting on pages gets its need
+        # RESERVED out of the budget first — without that, a
+        # sustained stream of short arrivals re-grants every
+        # freed page each turn and starves the long prompt
+        # forever.
+        placeable, deferred = [], []
+        budget = self.kv.pages_free - self._lane_reserved_pages()
+        for req in live:
+            if req.spill is not None:
+                # A parked victim resumes into exactly its exported
+                # grant; growth past it is _ensure_growth's job.
+                need = req.spill.n_pages
+            else:
+                n = int(np.asarray(req.length)[0])
+                # A cached prefix needs no fresh grant — coverage
+                # is re-checked at admission (eviction between the
+                # peek and the attach degrades to a requeue).
+                covered = len(self._prefix_lookup(req, n))
+                need = self.kv.pages_for(n + 1) - covered
+            if need > budget and self.prefix is not None and not deferred:
+                # Cached history yields to live admissions before
+                # any request waits on retires.
+                budget += self.prefix.reclaim(need - budget)
+            if deferred or need > budget:
+                deferred.append(req)
+            else:
+                budget -= need
+                placeable.append(req)
+        self._requeue_front(deferred)
+        return placeable
 
     def _pool_invalid(self) -> bool:
         """True when the page pool's buffers were deleted by a donation
@@ -785,7 +852,7 @@ class ContinuousScheduler:
             slot_state.pending_tok = int(np.asarray(tok0)[0])
         with self._cond:
             self._slots[slot] = slot_state
-        self.admitted += 1
+        self._count_admitted(req)
         if self.prefix is not None:
             if shared:
                 self.prefix_hits += 1
@@ -964,13 +1031,14 @@ class ContinuousScheduler:
                 # one tiny compiled slice; counts are bounded by the
                 # prompt buckets over the chunk size.
                 c = min(self.prefill_chunk, int(req.embeds.shape[1]) - off)
-                chunk = req.embeds[:, off : off + c]
-                positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
-                valid = jnp.asarray([min(job.length, off + c)], jnp.int32)
-                job.last_logits, job.caches = self.gen._prefill_chunk(
-                    self.params, job.caches, chunk, positions,
-                    jnp.asarray(off, jnp.int32), valid,
-                )
+                with phase("vlm.prefill_chunk", rid=req.rid, offset=off, tokens=c):
+                    chunk = req.embeds[:, off : off + c]
+                    positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
+                    valid = jnp.asarray([min(job.length, off + c)], jnp.int32)
+                    job.last_logits, job.caches = self.gen._prefill_chunk(
+                        self.params, job.caches, chunk, positions,
+                        jnp.asarray(off, jnp.int32), valid,
+                    )
                 job.last_off = off
                 job.offset = off + c
                 self.chunks_run += 1
@@ -991,30 +1059,38 @@ class ContinuousScheduler:
                         return
                 else:
                     return
-            sub = jax.random.fold_in(req.rng, 0)
-            tok0, seen = self.gen._chunk_finish(
-                job.last_logits, jnp.asarray([job.length - 1 - job.last_off], jnp.int32),
-                req.prompt_ids, req.length, sub,
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_p], jnp.float32),
-                jnp.asarray([req.do_sample]),
-                jnp.asarray([req.repetition_penalty], jnp.float32),
-            )
-            self._prefill_jobs.popleft()
-            try:
-                self._install_row(
-                    req, job.caches, tok0, seen, req.length,
-                    shared_pages=job.shared,
-                )
-            except Exception as e:  # noqa: BLE001
-                _fail(req, e)
-                if self._pool_invalid():
-                    raise RuntimeError(
-                        "slot pool invalidated by failed admission"
-                    ) from e
-            finally:
-                self._drop_job_hold(job)
+            with phase("vlm.lane_finish", rid=req.rid):
+                self._finish_lane_job(job)
             return
+
+    def _finish_lane_job(self, job: _PrefillJob) -> None:
+        """Sample the head job's first token and install its row."""
+        req = job.request
+        sub = jax.random.fold_in(req.rng, 0)
+        tok0, seen = self.gen._chunk_finish(
+            job.last_logits, jnp.asarray([job.length - 1 - job.last_off], jnp.int32),
+            req.prompt_ids, req.length, sub,
+            jnp.asarray([req.temperature], jnp.float32),
+            jnp.asarray([req.top_p], jnp.float32),
+            jnp.asarray([req.do_sample]),
+            jnp.asarray([req.repetition_penalty], jnp.float32),
+        )
+        self._prefill_jobs.popleft()
+        try:
+            self._install_row(
+                req, job.caches, tok0, seen, req.length,
+                shared_pages=job.shared,
+            )
+            self.lane_ms_sum += (time.perf_counter() - req.t_taken) * 1e3
+            self.lane_jobs += 1
+        except Exception as e:  # noqa: BLE001
+            _fail(req, e)
+            if self._pool_invalid():
+                raise RuntimeError(
+                    "slot pool invalidated by failed admission"
+                ) from e
+        finally:
+            self._drop_job_hold(job)
 
     # -- decode blocks ------------------------------------------------------
 
@@ -1058,6 +1134,7 @@ class ContinuousScheduler:
             slot = self._slots.pop(idx)
         self.kv.release(idx)
         self.preemptions += 1
+        slot.request.t_queued = time.perf_counter()  # back to the queue: a new wait
         metrics.count("vlm_paged_preemptions")
         now = time.monotonic()
         if now - self._preempt_log_t >= 1.0:
@@ -1325,7 +1402,7 @@ class ContinuousScheduler:
             slot_state.pending_tok = rec.cur_tok
         with self._cond:
             self._slots[slot] = slot_state
-        self.admitted += 1
+        self._count_admitted(req)
         self.spill_resumes += 1
         metrics.count("vlm_spill_resumes")
         self._drop_spill(req)
@@ -1426,6 +1503,7 @@ class ContinuousScheduler:
             self._fail_preempted(req, None)
             return
         req.spill = rec
+        req.t_queued = time.perf_counter()  # back to the queue: a new wait
         self._spill_ledger[id(req)] = rec
         self._spill_bytes_live += rec.nbytes
         self._requeue_front([req])
@@ -1458,6 +1536,7 @@ class ContinuousScheduler:
                 raise RuntimeError("continuous scheduler is closed")
             self._spill_ledger[id(req)] = rec
             self._spill_bytes_live += rec.nbytes
+            self._stamp_submit(req)
             self._pending.append(req)
             self._cond.notify()
 
@@ -1574,6 +1653,81 @@ class ContinuousScheduler:
             )
 
     def _run_block(self) -> None:
+        step = self.blocks_run + 1  # the number this block's phases and spans carry
+        with phase("vlm.block.prepare", step=step):
+            if self._retire_cancelled():
+                return
+            width, drafts, bucket = self._plan_block()
+        active = len(self._slots)
+        t0 = time.perf_counter()
+        tm0 = time.monotonic()
+        with phase("vlm.block.dispatch", step=step, rows=active, bucket=bucket):
+            if width:
+                q = np.zeros((self.n_slots, width), np.int32)
+                ql = np.ones((self.n_slots,), np.int32)
+                for i, d in drafts.items():
+                    q[i, 1 : 1 + len(d)] = d
+                    ql[i] = 1 + len(d)
+                self.pool, self._rng, toks = self.gen._verify(
+                    self.params, self.pool,
+                    jnp.asarray(self.kv.block_tables[:, :bucket]),
+                    self._rng, jnp.asarray(q), jnp.asarray(ql), width=width,
+                )
+                self.spec_turns += 1
+            else:
+                ql = None
+                self.pool, self._rng, toks = self.gen._step_block(
+                    self.params, self.pool,
+                    jnp.asarray(self.kv.block_tables[:, :bucket]),
+                    self._rng, block=self.block,
+                )
+        self.blocks_run += 1
+        self._occ_rows += active
+        self._occ_blocks += 1
+        # One fused device->host transfer for everything the bookkeeping
+        # below needs (four separate np.asarray calls = four round trips
+        # on the per-block hot path). cur_tok rides along ONLY when
+        # speculation is configured — the unconfigured transfer is
+        # byte-identical to the non-speculative build.
+        with phase("vlm.block.fetch", step=step):
+            if self.spec_k > 0:
+                toks_np, n_gen, done, eos, cur_tok = jax.device_get(
+                    (
+                        toks, self.pool["n_gen"], self.pool["done"],
+                        self.pool["eos"], self.pool["cur_tok"],
+                    )
+                )
+            else:
+                cur_tok = None
+                toks_np, n_gen, done, eos = jax.device_get(
+                    (toks, self.pool["n_gen"], self.pool["done"], self.pool["eos"])
+                )
+        t1 = time.perf_counter()
+        # Decode pace for the PreemptionShed drain hint (first block seeds
+        # the EWMA; compile-heavy first blocks wash out within a few).
+        dt = t1 - t0
+        if self._step_floor_s > 0.0:
+            # Pace BEFORE tokens stream out so first-token latency pays
+            # the floor too — a paced block models a slower chip, not a
+            # faster chip with delayed bookkeeping.
+            lag = self.block * self._step_floor_s - dt
+            if lag > 0.0:
+                time.sleep(lag)
+                dt = time.perf_counter() - t0
+        self._block_s_ewma = (
+            dt if self._block_s_ewma == 0.0 else 0.8 * self._block_s_ewma + 0.2 * dt
+        )
+        # Duty credit covers the paced window too: a step floor models a
+        # slower chip, and the duty meter should describe that chip.
+        telemetry.busy(f"device:{self.name}", tm0, time.monotonic())
+        with phase("vlm.block.emit", step=step, rows=active):
+            self._emit_block(
+                step, active, t0, t1, width, ql, toks_np, n_gen, done, eos, cur_tok
+            )
+
+    def _retire_cancelled(self) -> bool:
+        """Retire the rows whose stream consumer went away; True when no
+        live row is left to step."""
         cancelled = [
             i for i, slot in self._slots.items() if slot.request.cancelled
         ]
@@ -1585,8 +1739,11 @@ class ContinuousScheduler:
                     slot = self._slots.pop(i)
                 self.kv.release(i)
                 _retire(slot.request, slot.tokens, eos=False)
-            if not self._slots:
-                return
+        return not self._slots
+
+    def _plan_block(self) -> tuple[int, dict[int, list[int]], int]:
+        """Drafts, page growth and the block-table bucket of the next
+        block: ``(verify width or 0, drafts by slot, table bucket)``."""
         # A verify turn runs only when some row drafted AND every live
         # row's window fits its table capacity — the verify program's
         # position clamp must never engage on a live row (it would
@@ -1612,9 +1769,6 @@ class ContinuousScheduler:
             drafts = {i: d for i, d in drafts.items() if i in self._slots}
             if not drafts:
                 width = 0
-        active = len(self._slots)
-        t0 = time.perf_counter()
-        tm0 = time.monotonic()
         # Ragged page bucketing: ship only a power-of-2 prefix of the
         # block tables covering the longest live row. The CPU reference
         # gathers every table entry it is given, so a pool of short
@@ -1631,66 +1785,15 @@ class ContinuousScheduler:
         bucket = 1
         while bucket < maxp_live:
             bucket *= 2
-        bucket = min(bucket, self.kv.max_pages)
-        if width:
-            q = np.zeros((self.n_slots, width), np.int32)
-            ql = np.ones((self.n_slots,), np.int32)
-            for i, d in drafts.items():
-                q[i, 1 : 1 + len(d)] = d
-                ql[i] = 1 + len(d)
-            self.pool, self._rng, toks = self.gen._verify(
-                self.params, self.pool,
-                jnp.asarray(self.kv.block_tables[:, :bucket]),
-                self._rng, jnp.asarray(q), jnp.asarray(ql), width=width,
-            )
-            self.spec_turns += 1
-        else:
-            ql = None
-            self.pool, self._rng, toks = self.gen._step_block(
-                self.params, self.pool,
-                jnp.asarray(self.kv.block_tables[:, :bucket]),
-                self._rng, block=self.block,
-            )
-        self.blocks_run += 1
-        self._occ_rows += active
-        self._occ_blocks += 1
-        # One fused device->host transfer for everything the bookkeeping
-        # below needs (four separate np.asarray calls = four round trips
-        # on the per-block hot path). cur_tok rides along ONLY when
-        # speculation is configured — the unconfigured transfer is
-        # byte-identical to the non-speculative build.
-        if self.spec_k > 0:
-            toks_np, n_gen, done, eos, cur_tok = jax.device_get(
-                (
-                    toks, self.pool["n_gen"], self.pool["done"],
-                    self.pool["eos"], self.pool["cur_tok"],
-                )
-            )
-        else:
-            cur_tok = None
-            toks_np, n_gen, done, eos = jax.device_get(
-                (toks, self.pool["n_gen"], self.pool["done"], self.pool["eos"])
-            )
-        t1 = time.perf_counter()
-        # Decode pace for the PreemptionShed drain hint (first block seeds
-        # the EWMA; compile-heavy first blocks wash out within a few).
-        dt = t1 - t0
-        if self._step_floor_s > 0.0:
-            # Pace BEFORE tokens stream out so first-token latency pays
-            # the floor too — a paced block models a slower chip, not a
-            # faster chip with delayed bookkeeping.
-            lag = self.block * self._step_floor_s - dt
-            if lag > 0.0:
-                time.sleep(lag)
-                dt = time.perf_counter() - t0
-        self._block_s_ewma = (
-            dt if self._block_s_ewma == 0.0 else 0.8 * self._block_s_ewma + 0.2 * dt
-        )
-        # Duty credit covers the paced window too: a step floor models a
-        # slower chip, and the duty meter should describe that chip.
-        telemetry.busy(f"device:{self.name}", tm0, time.monotonic())
+        return width, drafts, min(bucket, self.kv.max_pages)
+
+    def _emit_block(
+        self, step, active, t0, t1, width, ql, toks_np, n_gen, done, eos, cur_tok
+    ) -> None:
+        """A block's bookkeeping: spans, acceptance tallies, tokens out to
+        the streams, finished rows retired."""
         span_meta = {
-            "step": self.blocks_run,
+            "step": step,
             "rows": active,
             "fill_pct": round(100.0 * active / self.n_slots, 1),
             "block": self.block,
@@ -1699,7 +1802,7 @@ class ContinuousScheduler:
             slot = self._slots[idx]
             req = slot.request
             if req.trace is not None:
-                req.trace.add_span("batch.device", t0, t1, dict(span_meta))
+                req.trace.add_span("batch.device", t0, t1, dict(span_meta, rid=req.rid))
             new = int(n_gen[idx]) - len(slot.tokens)
             if width and int(ql[idx]) > 1:
                 # First emission of a verify turn is the pending token
@@ -1724,6 +1827,10 @@ class ContinuousScheduler:
                     # moving the watermark backward would re-emit every
                     # token from here to the crash point as duplicates.
                     req.delivered = max(req.delivered, len(slot.tokens))
+                if not req.t_first_token:
+                    req.t_first_token = time.perf_counter()
+                    self.first_token_ms_sum += (req.t_first_token - req.t_submit) * 1e3
+                    self.first_token_count += 1
             if done[idx]:
                 with self._cond:
                     del self._slots[idx]

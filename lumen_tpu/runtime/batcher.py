@@ -95,7 +95,7 @@ from ..utils.metrics import metrics
 from . import telemetry
 from .qos import WFQAdmissionQueue, wfq_enabled
 from .quarantine import QuarantineRegistry, get_quarantine
-from .trace import current_trace
+from .trace import current_trace, phase
 
 logger = logging.getLogger(__name__)
 
@@ -149,10 +149,15 @@ def mesh_sharded(fn, mesh):
 
     sharding = data_sharding(mesh)
 
-    def wrapped(tree, n):
-        tree = jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding), tree)
-        return fn(tree, n)
+    def put(tree):
+        return jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding), tree)
 
+    def wrapped(tree, n):
+        return fn(put(tree), n)
+
+    # The two halves, for a caller that marks them apart (the batcher's
+    # ``batch.put`` / ``batch.dispatch`` phases).
+    wrapped.put, wrapped.dispatch = put, fn
     return wrapped
 
 
@@ -499,7 +504,7 @@ class _Inflight:
     the collector's reusable arenas were used) so the fetch path can
     detect — and copy out of — a result that aliases them."""
 
-    __slots__ = ("futures", "result", "n", "size", "entries", "arena", "t_dispatch")
+    __slots__ = ("futures", "result", "n", "size", "entries", "arena", "t_dispatch", "seq")
 
     def __init__(
         self,
@@ -510,6 +515,7 @@ class _Inflight:
         entries: list[tuple] | None = None,
         arena: list | None = None,
         t_dispatch: float = 0.0,
+        seq: int = 0,
     ):
         self.futures = futures
         self.result = result  # un-fetched device result tree
@@ -517,6 +523,7 @@ class _Inflight:
         self.size = size
         self.entries = entries or []
         self.arena = arena
+        self.seq = seq  # the batch's sequence number (phases and span metas)
         # Dispatch instant (monotonic): the fetch worker credits the
         # dispatch->settle envelope to the ``device:{name}`` duty meter —
         # the same envelope the ``batch.device`` trace span covers.
@@ -672,7 +679,20 @@ class MicroBatcher:
             "poisoned": 0,
             "quarantine_rejected": 0,
             "watchdog": 0,
+            # Cumulative waits (a window's mean is a ratio of deltas):
+            # submit -> picked into a dispatched batch, summed over the
+            # items picked; dispatch -> settle (the ``batch.device``
+            # envelope; envelopes of pipelined batches overlap), summed
+            # over the batches settled.
+            "collect_wait_ms_sum": 0.0,
+            "collect_items": 0,
+            "device_ms_sum": 0.0,
+            "device_batches": 0,
         }
+        # Batches dispatched so far. The number rides the feeder threads'
+        # phases and the requests' ``batch.collect`` / ``batch.device`` span
+        # metas, so the two join on (batcher, seq).
+        self._batch_seq = 0
 
     # -- lifecycle --------------------------------------------------------
 
@@ -846,6 +866,7 @@ class MicroBatcher:
         if self.adaptive:
             self._window.observe()
         fut: Future = Future()
+        fut._lumen_t_submit = time.perf_counter()  # collect wait starts here
         # Request tracing: the collect span begins HERE (caller thread,
         # where the contextvar is visible) and ends when the collector
         # picks the batch for dispatch — queue wait + collect window, one
@@ -956,9 +977,28 @@ class MicroBatcher:
 
     def _run(self) -> None:
         while not self._closed.is_set() and self._wedged is None:
-            first = self._queue.get()
+            # This thread alone dispatches, so the batch being collected
+            # is the next number.
+            seq = self._batch_seq + 1
+            with phase("batch.wait_items", batcher=self.name, seq=seq):
+                first = self._queue.get()
             if first is None:
                 break
+            batch = self._collect(first, seq)
+            self._dispatch(batch)
+        # Drain anything left after close.
+        while True:
+            try:
+                entry = self._queue.get_nowait()
+            except queue.Empty:
+                break
+            if entry is not None:
+                _settle(entry[1], exception=RuntimeError(f"{self.name} closed"))
+
+    def _collect(self, first: tuple, seq: int) -> list[tuple]:
+        """Hold the collect window open from ``first``'s pickup and return
+        the batch (the ``batch.window`` phase)."""
+        with phase("batch.window", batcher=self.name, seq=seq):
             batch = [first]
             # Window from the FIRST item's pickup. Fixed mode keeps the
             # historical ``max_latency_ms`` wait; adaptive mode asks the
@@ -994,15 +1034,7 @@ class MicroBatcher:
                         t_first + self.window_cap_s,
                         time.monotonic() + self._window.window_s(len(batch)),
                     )
-            self._dispatch(batch)
-        # Drain anything left after close.
-        while True:
-            try:
-                entry = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            if entry is not None:
-                _settle(entry[1], exception=RuntimeError(f"{self.name} closed"))
+            return batch
 
     def _dispatch(self, batch: list[tuple[Any, Future, float | None, str | None]]) -> None:
         # Reserve an in-flight slot FIRST: this wait is where the collector
@@ -1066,6 +1098,12 @@ class MicroBatcher:
         n = len(items)
         size = bucket_for(n, self.buckets)
         self._occupancy.record(n, size)
+        seq = self._batch_seq = self._batch_seq + 1
+        picked = time.perf_counter()
+        self.stats["collect_wait_ms_sum"] += 1e3 * sum(
+            picked - f._lumen_t_submit for f in futures
+        )
+        self.stats["collect_items"] += n
         # Trace hand-off at the thread hop: collect ends on THIS (collector)
         # thread; the device span opens here and is closed by whatever
         # settles the future (fetch worker on the happy path — see
@@ -1074,15 +1112,16 @@ class MicroBatcher:
         for _, fut, _ in live:
             h = getattr(fut, "_lumen_collect", None)
             if h is not None:
-                h.end()
-                attrs = {"batcher": self.name, "n": n, "size": size}
+                h.end(seq=seq)
+                attrs = {"batcher": self.name, "seq": seq, "n": n, "size": size}
                 if self.replica is not None:
                     attrs["replica"] = self.replica
                 fut._lumen_device = fut._lumen_trace.begin("batch.device", attrs)
         arena = None
         t_dispatch = time.monotonic()
         try:
-            stacked, arena = self._stack(items, size)
+            with phase("batch.stack", batcher=self.name, seq=seq, n=n, size=size):
+                stacked, arena = self._stack(items, size)
             if telemetry.enabled():
                 # Host->device payload for this batch (the staged numpy
                 # tree the backend will transfer). Per-batch, not
@@ -1090,7 +1129,7 @@ class MicroBatcher:
                 telemetry.count(
                     f"transfer_h2d:{self.name}", _tree_nbytes(stacked)
                 )
-            result = self._execute(live, n, size, stacked=stacked)
+            result = self._execute(live, n, size, stacked=stacked, seq=seq)
         except Exception as e:  # noqa: BLE001 - contain, or fan out to callers
             self._contain_failure(live, e)
             return
@@ -1101,7 +1140,7 @@ class MicroBatcher:
                 self._inflight.append(
                     _Inflight(
                         futures, result, n, size, entries=live, arena=arena,
-                        t_dispatch=t_dispatch,
+                        t_dispatch=t_dispatch, seq=seq,
                     )
                 )
                 self._inflight_cv.notify_all()
@@ -1114,6 +1153,7 @@ class MicroBatcher:
         n: int,
         size: int,
         stacked: Any | None = None,
+        seq: int = 0,
     ):
         """Fault checks + stack + dispatch for one (sub-)batch, watched by
         the watchdog. Shared by the normal dispatch path (which pre-stacks
@@ -1135,9 +1175,18 @@ class MicroBatcher:
                     faults.check("batch_poison", f"{self.name}:{fingerprint}")
             if faults.fires("batch_hang", self.name):
                 self._hang()
-            if stacked is None:
-                stacked = stack_and_pad([e[0] for e in entries], size)
-            return self.fn(stacked, n)  # async dispatch; fetch worker settles
+            args = {"batcher": self.name, "seq": seq, "n": n, "size": size}
+            if stacked is None:  # a bisection probe (seq 0) re-stacks its half
+                with phase("batch.stack", **args):
+                    stacked = stack_and_pad([e[0] for e in entries], size)
+            fn = self.fn
+            put = getattr(fn, "put", None)
+            if put is not None:  # mesh_sharded: the placement, marked apart
+                with phase("batch.put", **args):
+                    stacked = put(stacked)
+                fn = fn.dispatch
+            with phase("batch.dispatch", **args):
+                return fn(stacked, n)  # async dispatch; fetch worker settles
 
     #: bound on distinct (bucket, leaf-signature) arena keys; past it new
     #: shapes fall back to allocating stacks (a shape-churning caller must
@@ -1482,8 +1531,9 @@ class MicroBatcher:
                 # completes, so the in-flight bound counts batches whose
                 # device work (or transfer) is genuinely outstanding.
                 entry = self._inflight[0]
+            args = {"batcher": self.name, "seq": entry.seq, "n": entry.n, "size": entry.size}
             try:
-                with self._watched(entry.futures):
+                with self._watched(entry.futures), phase("batch.fetch", **args):
                     rows = _unstack_guarded(entry.result, entry.n, entry.arena)
             except Exception as e:  # noqa: BLE001 - contain, or fan out to THIS batch only
                 # A device error often surfaces at the FETCH, not the
@@ -1502,13 +1552,16 @@ class MicroBatcher:
                 self.stats["items"] += entry.n
                 self.stats["padded"] += entry.size - entry.n
                 self._drain.record(entry.n)
+                now = time.monotonic()
+                if entry.t_dispatch:
+                    self.stats["device_ms_sum"] += (now - entry.t_dispatch) * 1e3
+                    self.stats["device_batches"] += 1
                 if telemetry.enabled():
                     # Capacity telemetry, all per-batch: the device duty
                     # envelope (dispatch->settle, union-merged so the
                     # pipelined overlap isn't double-counted), windowed
                     # batch fill vs padding, the bucket the batch
                     # compiled into, and the device->host result bytes.
-                    now = time.monotonic()
                     if entry.t_dispatch:
                         telemetry.busy(
                             f"device:{self.name}", entry.t_dispatch, now
@@ -1525,8 +1578,9 @@ class MicroBatcher:
                             f"transfer_d2h:{self.name}",
                             _tree_nbytes(rows[0]) * entry.n,
                         )
-                for f, row in zip(entry.futures, rows):
-                    _settle(f, result=row)
+                with phase("batch.settle", **args):
+                    for f, row in zip(entry.futures, rows):
+                        _settle(f, result=row)
             with self._inflight_cv:
                 # Identity-guarded: _fire_watchdog may have cleared the
                 # deque while this entry was being unstacked (it was only

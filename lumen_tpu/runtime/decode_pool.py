@@ -33,8 +33,9 @@ work routes through: the four model managers' decode calls and the
   rebuilt on the next submission.
 
 Queue-wait telemetry is exported as metrics gauges (``decode_pool``
-provider: ``queue_depth``, ``wait_ms_p50``, arena accounting, spill and
-crash counters), so an operator can see when the decode lane — not the
+provider: ``queue_depth``, the rolling ``wait_ms_p50`` beside the
+cumulative ``wait_ms_sum``/``wait_count``/``run_ms_sum``, arena accounting,
+spill and crash counters), so an operator can see when the decode lane — not the
 device — binds, and whether zero-copy transport is actually engaged.
 
 Deliberately jax-free: the pool is pure host plumbing and must stay
@@ -136,6 +137,10 @@ class DecodePool:
         self._pending = 0  # submitted, not yet started (queue depth)
         self._tasks = 0
         self._wait_ms: deque[float] = deque(maxlen=512)
+        # Cumulative twins of the rolling sample (a window's mean wait is
+        # a ratio of deltas) and the workers' summed run time.
+        self._wait_ms_sum = 0.0
+        self._run_ms_sum = 0.0
         # Process lane (built lazily on first spec decode: spawning
         # workers costs ~0.5s each and a thread-mode-only deployment must
         # never pay it). The arena is parent-owned; workers only attach.
@@ -186,10 +191,7 @@ class DecodePool:
     ) -> Any:
         self._local.in_pool = True
         wait_ms = (time.perf_counter() - t_submit) * 1e3
-        with self._lock:
-            self._pending -= 1
-            self._tasks += 1
-            self._wait_ms.append(wait_ms)
+        self._account_wait(wait_ms)
         # Trace hand-off at the thread hop: the queue span (begun on the
         # submitting thread) ends here on the pool worker, and the run
         # span covers the decode itself.
@@ -213,22 +215,38 @@ class DecodePool:
             try:
                 return fn(*args, **kwargs)
             finally:
-                telemetry.busy(self._duty_name, t_run, time.monotonic())
+                self._account_run(t_run, time.monotonic())
         rspan = qspan.trace.begin("decode", {"pool": self.name})
         try:
             result = fn(*args, **kwargs)
         except BaseException as e:
             rspan.end(error=type(e).__name__)
-            telemetry.busy(self._duty_name, t_run, time.monotonic())
+            self._account_run(t_run, time.monotonic())
             raise
         rspan.end()
-        telemetry.busy(self._duty_name, t_run, time.monotonic())
+        self._account_run(t_run, time.monotonic())
         if box is not None:
             # Completion instant for the caller's ``decode.wake`` span —
             # written before _task returns, so run() can never read a
             # half-stamped box.
             box["settled"] = time.perf_counter()
         return result
+
+    def _account_wait(self, wait_ms: float) -> None:
+        """One task left the queue after ``wait_ms`` (both lanes)."""
+        with self._lock:
+            self._pending -= 1
+            self._tasks += 1
+            self._wait_ms.append(wait_ms)
+            self._wait_ms_sum += wait_ms
+
+    def _account_run(self, t0_m: float, t1_m: float) -> None:
+        """One task ran from ``t0_m`` to ``t1_m`` (monotonic; a worker
+        process's own stamps in process mode): duty-meter credit and the
+        cumulative run time."""
+        telemetry.busy(self._duty_name, t0_m, t1_m)
+        with self._lock:
+            self._run_ms_sum += (t1_m - t0_m) * 1e3
 
     def submit(self, fn: Callable, *args, **kwargs) -> Future:
         # The ambient deadline is a contextvar of the CALLING thread;
@@ -467,11 +485,9 @@ class DecodePool:
         """Queue-depth/wait bookkeeping for one settled process task —
         wait is measured submit -> worker pickup, directly comparable
         across processes (CLOCK_MONOTONIC is machine-wide on Linux)."""
-        wait_ms = 0.0 if t_pickup is None else max(0.0, (t_pickup - t_submit) * 1e3)
-        with self._lock:
-            self._pending -= 1
-            self._tasks += 1
-            self._wait_ms.append(wait_ms)
+        self._account_wait(
+            0.0 if t_pickup is None else max(0.0, (t_pickup - t_submit) * 1e3)
+        )
 
     def _proc_telemetry(self, tr, t_submit, t0_pc, t1_pc, t0_m, t1_m) -> None:
         """Duty-meter credit + trace spans for a process-lane decode,
@@ -479,7 +495,7 @@ class DecodePool:
         ``decode`` / ``decode.wake`` report identically to thread mode
         (the PR 6 cross-thread contract, extended across the process
         hop)."""
-        telemetry.busy(self._duty_name, t0_m, t1_m)
+        self._account_run(t0_m, t1_m)
         if tr is None:
             return
         meta = {"pool": self.name, "proc": "1"}
@@ -537,6 +553,7 @@ class DecodePool:
         with self._lock:
             pending, tasks = self._pending, self._tasks
             spills, crashes = self._spills, self._crashes
+            wait_sum, run_sum = self._wait_ms_sum, self._run_ms_sum
         # Numeric-only: the metrics registry drops non-numeric gauge
         # values at snapshot (Prometheus exposition contract), so the
         # mode flag is an int and the arena block is flattened with an
@@ -547,6 +564,9 @@ class DecodePool:
             "queue_depth": pending,
             "tasks": tasks,
             "wait_ms_p50": round(self.wait_ms_p50(), 3),
+            "wait_ms_sum": round(wait_sum, 3),
+            "wait_count": tasks,  # every task that left the queue has a wait
+            "run_ms_sum": round(run_sum, 3),
             "process_mode": int(self.process_mode),
             "procs": self.procs,
         }

@@ -23,6 +23,7 @@ from ..utils.trace import (  # noqa: F401 - re-exported runtime surface
     enabled,
     finish_request,
     get_recorder,
+    phase,
     reset_recorder,
     span,
 )
@@ -33,6 +34,7 @@ __all__ = [
     "enabled",
     "finish_request",
     "get_recorder",
+    "phase",
     "reset_recorder",
     "span",
 ]

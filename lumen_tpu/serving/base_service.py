@@ -102,6 +102,67 @@ def _get_bulk_pool() -> ThreadPoolExecutor:
     return _bulk_pool
 
 
+class _BulkLane:
+    """Wait and run sums of the bulk executor, process-wide like the pool:
+    how long a bulk item waited between the instant its assembly completed
+    and the instant a ``bulk-infer`` worker picked it up (the stream's
+    backpressure window and the executor's queue, one number), and how
+    long the worker then held it. Exported as the ``bulk-lane`` gauge
+    provider; a window's mean wait is the delta of ``queue_ms_sum`` over
+    the delta of ``items``."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._items = 0
+        self._queued = 0
+        self._queue_ms_sum = 0.0
+        self._run_ms_sum = 0.0
+        metrics.register_gauges("bulk-lane", self.gauges)
+
+    def queued(self, n: int = 1) -> None:
+        """An item entered the wait (``n=-1``: it left without running —
+        its stream was abandoned or cancelled)."""
+        with self._lock:
+            self._queued += n
+
+    def started(self, t_ready: float) -> float:
+        """A worker picked the item up: book its wait since ``t_ready``
+        and return the pickup instant."""
+        now = time.perf_counter()
+        with self._lock:
+            self._queued -= 1
+            self._items += 1
+            self._queue_ms_sum += (now - t_ready) * 1e3
+        return now
+
+    def finished(self, t_picked: float) -> None:
+        run_ms = (time.perf_counter() - t_picked) * 1e3
+        with self._lock:
+            self._run_ms_sum += run_ms
+
+    def gauges(self) -> dict:
+        with self._lock:
+            return {
+                "workers": bulk_workers(),
+                "queued": self._queued,
+                "items": self._items,
+                "queue_ms_sum": round(self._queue_ms_sum, 3),
+                "run_ms_sum": round(self._run_ms_sum, 3),
+            }
+
+
+_bulk_lane: _BulkLane | None = None
+
+
+def _get_bulk_lane() -> _BulkLane:
+    global _bulk_lane
+    if _bulk_lane is None:
+        with _bulk_pool_lock:
+            if _bulk_lane is None:
+                _bulk_lane = _BulkLane()
+    return _bulk_lane
+
+
 #: LUMEN_RPC_TRIM (default on): request-path micro-trims — response-proto
 #: reuse on the real-gRPC direct lane (the server serializes each yielded
 #: message before pulling the next, so one scratch proto per thread
@@ -209,6 +270,12 @@ class _Assembly:
     #: first-chunk arrival instant — the request trace back-dates to here
     #: so the ``rpc.recv`` span covers chunked-payload reassembly.
     t0: float = field(default_factory=time.perf_counter)
+    #: instant the last chunk arrived (``rpc.recv`` ends here; on a bulk
+    #: stream the executor's wait is counted from here). 0.0 = incomplete.
+    t_ready: float = 0.0
+    #: instant a bulk worker picked the item up (``bulk.queue`` ends here);
+    #: 0.0 on the direct lane, which dispatches on the receiving thread.
+    t_picked: float = 0.0
 
     def add(self, req: pb.InferRequest) -> None:
         if not self.task:
@@ -219,6 +286,8 @@ class _Assembly:
         self.chunks[req.seq] = req.payload
         if req.total:
             self.total = req.total
+        if self.complete:
+            self.t_ready = time.perf_counter()
 
     @property
     def complete(self) -> bool:
@@ -350,6 +419,7 @@ class BaseService(InferenceServicer):
         # every buffered response list since the stream began.
         pending: set = set()
         pool = _get_bulk_pool()
+        lane = _get_bulk_lane()
         # Request-path trim: the stream's gRPC request metadata (where the
         # tenant id lives) is identical for every item — resolve it ONCE
         # instead of scanning the metadata tuple per item (BENCH_r05
@@ -364,20 +434,31 @@ class BaseService(InferenceServicer):
         window = threading.Semaphore(bulk_workers() * 4)
 
         def run_one(cid: str, asm: _Assembly):
-            if stop.is_set():
-                return None
-            return list(self._dispatch(cid, asm, context, tenant=stream_tenant))
+            asm.t_picked = lane.started(asm.t_ready)
+            try:
+                if stop.is_set():
+                    return None
+                return list(self._dispatch(cid, asm, context, tenant=stream_tenant))
+            finally:
+                lane.finished(asm.t_picked)
+
+        def settled(cid: str, asm: _Assembly, fut) -> None:
+            if not asm.t_picked:  # cancelled while queued: run_one never ran
+                lane.queued(-1)
+            out.put((cid, fut))
 
         def submit(cid: str, asm: _Assembly) -> bool:
+            lane.queued()
             while not window.acquire(timeout=0.1):
                 if stop.is_set():
+                    lane.queued(-1)
                     return False  # abandoned stream: stop buffering
             with lock:
                 state["submitted"] += 1
             fut = pool.submit(run_one, cid, asm)
             with lock:
                 pending.add(fut)
-            fut.add_done_callback(lambda f, c=cid: out.put((c, f)))
+            fut.add_done_callback(lambda f, c=cid, a=asm: settled(c, a, f))
             return True
 
         submit(first_cid, first_asm)
@@ -516,9 +597,11 @@ class BaseService(InferenceServicer):
         tracing off (``LUMEN_TRACE_SAMPLE=0``, the default) the cost is
         one cached env check; with it on, the request gets a contextvar-
         propagated :class:`~lumen_tpu.utils.trace.Trace` back-dated to
-        the first chunk's arrival (the ``rpc.recv`` span), every error
-        response marks the trace errored (tail sampling always retains
-        those), and the finished trace lands in the process recorder."""
+        the first chunk's arrival (the ``rpc.recv`` span, which ends when
+        the assembly completed; on a bulk stream ``bulk.queue`` follows it
+        up to the worker's pickup), every error response marks the trace
+        errored (tail sampling always retains those), and the finished
+        trace lands in the process recorder."""
         tr = None
         if request_trace.enabled():
             tr = request_trace.begin_request(
@@ -527,7 +610,10 @@ class BaseService(InferenceServicer):
         if tr is None:
             yield from self._dispatch_inner(cid, asm, context, tenant, reuse)
             return
-        tr.add_span("rpc.recv", asm.t0, time.perf_counter())
+        t_ready = asm.t_ready or time.perf_counter()
+        tr.add_span("rpc.recv", asm.t0, t_ready)
+        if asm.t_picked:
+            tr.add_span("bulk.queue", t_ready, asm.t_picked)
         token = request_trace.activate(tr)
         try:
             for resp in self._dispatch_inner(cid, asm, context, tenant, reuse):

@@ -25,8 +25,13 @@ layer that makes that legible:
   sidecar) and as Chrome trace-event JSON (``GET /traces/perfetto``,
   loadable in Perfetto/chrome://tracing next to a ``jax.profiler`` dump);
 - each span also feeds a ``stage:{task}/{span}`` latency histogram in
-  the process metrics registry, so ``bench.py --phase attribution`` can
-  print a per-stage time-budget table without parsing traces.
+  the process metrics registry, so per-stage p50/p99 is on
+  ``/metrics.json`` without parsing traces;
+- the threads that FEED the device (the VLM scheduler loop, the
+  batcher's collector and fetch workers) carry no request, so they mark
+  their turn with :func:`phase` instead: a ``jax.profiler``
+  ``TraceAnnotation`` that lands in the profiler's own trace, on the
+  device's clock, and records only while a profiler session is live.
 
 **Overhead contract**: with ``LUMEN_TRACE_SAMPLE=0`` (the default) the
 per-request cost is one cached env check plus contextvar reads that
@@ -49,10 +54,11 @@ import contextvars
 import heapq
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterator
 
 from .env import env_int
@@ -240,19 +246,21 @@ class Trace:
             # must not show up as unattributed time in the stage budget.
             t_end = max((s[2] for s in spans), default=time.perf_counter())
             t_end = max(t_end, self.t0)
+        # Export order is by start; ``parent`` indexes into that order.
+        spans.sort(key=lambda s: s[1])
         out_spans = []
-        for name, s0, s1, bt, et, meta in spans:
+        for i, (name, s0, s1, bt, et, meta) in enumerate(spans):
             span: dict[str, Any] = {
                 "name": name,
                 "start_ms": round((s0 - self.t0) * 1e3, 3),
                 "dur_ms": round((s1 - s0) * 1e3, 3),
                 "begin_thread": bt,
                 "end_thread": et,
+                "parent": _parent_index(spans, i),
             }
             if meta:
                 span["meta"] = meta
             out_spans.append(span)
-        out_spans.sort(key=lambda s: s["start_ms"])
         rec = {
             "trace_id": self.trace_id,
             "task": self.task,
@@ -263,6 +271,61 @@ class Trace:
         if self.error:
             rec["error"] = self.error
         return rec
+
+
+def _parent_index(spans: list[tuple], i: int) -> int | None:
+    """Index of the span that caused ``spans[i]``: the innermost span of
+    the same trace that began on the same thread and was still open when
+    this one began (None at top level). Worked out at export from the
+    recorded bounds, so the hot path keeps no per-thread stack. A span
+    that shares its start with another is the child of the longer one."""
+    _, s0, s1, thread, _, _ = spans[i]
+    best = None
+    for j, (_, p0, p1, p_thread, _, _) in enumerate(spans):
+        if j == i or p_thread != thread or not p0 <= s0 < p1:
+            continue
+        if p0 == s0 and (p1, j) <= (s1, i):
+            continue
+        if best is None or (p0, -p1) > (spans[best][1], -spans[best][2]):
+            best = j
+    return best
+
+
+# -- feeder-thread phases (the profiler's own clock) --------------------------
+
+
+#: what :func:`phase` hands out in a process that never loaded JAX
+_NO_PHASE = nullcontext()
+_annotation: Any = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def phase(name: str, **args: Any):
+    """Context manager marking one phase of a feeder thread's turn
+    (``vlm.block.dispatch``, ``batch.stack``, ...) as a
+    ``jax.profiler.TraceAnnotation`` named ``lumen:<name>`` (the prefix
+    tells the program's spans from XLA's own host events), ``args`` as
+    its stats. It lands in the profiler's ``.xplane.pb`` beside the
+    device's operations, on one clock, so an idle gap of the device can be
+    named by what the program's own thread was doing in it.
+
+    There is no switch: an annotation records only while a profiler
+    session is live (``POST /profiler/start`` on the sidecar, or a
+    harness's ``jax.profiler.start_trace``) and is a flag check otherwise.
+    JAX is never imported from here — a process that has not loaded it
+    (the example client, a load generator) gets a no-op.
+
+    Phases are leaves and siblings on their thread: a reducer gives each
+    idle gap to the host event that covers most of it, so a phase that
+    enclosed another would take its gaps. What describes the whole turn
+    (the block's number, the batch's sequence) goes into ``args``."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return _NO_PHASE
+        ann = _annotation = profiler.TraceAnnotation
+    return ann("lumen:" + name, **args)
 
 
 # -- contextvar propagation --------------------------------------------------

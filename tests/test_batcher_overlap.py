@@ -76,3 +76,33 @@ def test_inflight_one_serializes_dispatch():
         f"inflight=1 pipelined anyway: wall {wall:.3f}s vs synchronous "
         f"{synchronous:.3f}s"
     )
+
+
+def test_cumulative_collect_wait_and_device_envelope():
+    """The always-on sums behind ``batcher:{name}``: every item picked into
+    a batch books its wait since submit, every settled batch its
+    dispatch->settle envelope; they only grow, and they hold what the fake
+    device charges."""
+    b = MicroBatcher(
+        sleepy_device_fn, max_batch=2, max_latency_ms=5, inflight=2,
+        name="overlap-sums",
+    ).start()
+    seen = []
+    try:
+        for burst in (4, 2):
+            futs = [b.submit(np.array([float(i)])) for i in range(burst)]
+            for f in futs:
+                f.result(timeout=30)
+            time.sleep(0.05)  # the fetch worker books the batch after it settles its futures
+            seen.append(dict(b.stats))
+    finally:
+        b.close()
+    a, c = seen
+    assert (a["collect_items"], c["collect_items"]) == (4, 6) == (a["items"], c["items"])
+    assert a["device_batches"] == a["batches"] and c["device_batches"] == c["batches"]
+    for key in ("collect_wait_ms_sum", "device_ms_sum"):
+        assert 0 < a[key] < c[key]
+    # an envelope holds one dispatch and one fetch of the fake device
+    assert a["device_ms_sum"] >= a["device_batches"] * (DISPATCH_S + FETCH_S) * 1e3 * 0.9
+    # the second batch's items waited at least the first batch's dispatch
+    assert a["collect_wait_ms_sum"] >= 2 * DISPATCH_S * 1e3 * 0.9

@@ -161,6 +161,32 @@ class TestBulkStream:
         assert max(svc.batch_sizes) >= 2  # real coalescing happened
         assert len(svc.batch_sizes) <= 12
 
+    def test_bulk_lane_counts_every_item_and_its_wait(self, bulk_hub):
+        """``bulk-lane`` (process-wide): one item for every item sent, the
+        sums only grow, nothing left queued once the stream has drained; a
+        stream without the bulk meta does not pass through it."""
+        from lumen_tpu.client import infer_bulk
+        from lumen_tpu.utils.metrics import metrics
+
+        stub, _svc = bulk_hub
+        list(infer_bulk(stub, "bulk_embed", [b"lane-warm"]))  # the provider exists from the first item on
+        seen = [metrics.snapshot()["gauges"]["bulk-lane"]]
+        for burst in (12, 5):
+            payloads = [f"lane-{burst}-{i}".encode() for i in range(burst)]
+            assert len(dict(infer_bulk(stub, "bulk_embed", payloads))) == burst
+            seen.append(metrics.snapshot()["gauges"]["bulk-lane"])
+        a, b, c = seen
+        assert (b["items"] - a["items"], c["items"] - b["items"]) == (12, 5)
+        for key in ("queue_ms_sum", "run_ms_sum"):
+            assert a[key] <= b[key] <= c[key]
+        assert c["run_ms_sum"] > a["run_ms_sum"] and c["queue_ms_sum"] > a["queue_ms_sum"]
+        assert c["queued"] == 0 and c["workers"] >= 8
+        list(stub.Infer(iter([pb.InferRequest(
+            correlation_id="u9", task="bulk_embed", payload=b"lane-unary",
+            payload_mime="application/octet-stream",
+        )])))
+        assert metrics.snapshot()["gauges"]["bulk-lane"]["items"] == c["items"]
+
     def test_mixed_unary_stream_unaffected(self, bulk_hub):
         """A stream WITHOUT the bulk meta keeps the sequential unary path."""
         stub, _svc = bulk_hub
@@ -230,3 +256,7 @@ class TestBulkCancellation:
         # item's response goes nowhere (the client is gone).
         assert responses == []
         pool.shutdown(wait=False)
+        # The cancelled remainder left the lane's queue without running.
+        from lumen_tpu.utils.metrics import metrics
+
+        assert metrics.snapshot()["gauges"]["bulk-lane"]["queued"] == 0

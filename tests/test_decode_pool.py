@@ -119,6 +119,25 @@ class TestTelemetry:
             pool.close()
         assert "t-gauge" not in metrics.snapshot().get("gauges", {})
 
+    def test_cumulative_wait_and_run_sums(self):
+        """A window's mean wait is a ratio of deltas: the sums only grow,
+        every task that left the queue has one wait, and two tasks behind
+        one worker waited at least as long as the first ran."""
+        pool = DecodePool(workers=1, name="t-sums")
+        try:
+            seen = []
+            for burst in (3, 2):
+                pool.map(lambda x: time.sleep(0.01) or x, range(burst))
+                seen.append(pool.gauges())
+            a, b = seen
+            assert a["wait_count"] == a["tasks"] == 3 and b["wait_count"] == b["tasks"] == 5
+            for key in ("wait_ms_sum", "run_ms_sum"):
+                assert 0 < a[key] < b[key]
+            assert a["run_ms_sum"] >= 3 * 10 * 0.9
+            assert a["wait_ms_sum"] >= (10 + 20) * 0.9  # the 2nd waited one run, the 3rd two
+        finally:
+            pool.close()
+
     def test_shared_pool_is_singleton(self):
         shutdown_decode_pool()
         try:

@@ -417,6 +417,18 @@ class TestProcessDecode:
             other.release()
             monkeypatch.setattr(dp_mod, "_shared", None)
 
+    def test_process_lane_feeds_the_cumulative_sums(self, proc_pool):
+        """Both paths that feed the rolling ``wait_ms_p50`` feed the sums:
+        a process-lane decode books one wait and the worker's own run."""
+        before = proc_pool.gauges()
+        out = proc_pool.map_decode("decode", [_jpeg(i) for i in range(3)])
+        for r in out:
+            r.release()
+        after = proc_pool.gauges()
+        assert after["wait_count"] == after["tasks"] == before["tasks"] + 3
+        assert after["wait_ms_sum"] >= before["wait_ms_sum"]
+        assert after["run_ms_sum"] > before["run_ms_sum"]
+
     def test_gauges_report_mode_and_arena(self, proc_pool):
         """Gauge values are numeric-only — the metrics registry drops
         strings/dicts at snapshot, and the arena invariant must survive

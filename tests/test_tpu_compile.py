@@ -101,3 +101,23 @@ def test_kernel_compiles_for_v5e(v5e, name):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["paged_decode", "paged_varq_w5"])
+def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
+    """The benchmark's ``paged_attn_*`` metrics find the kernel on the
+    device's ``XLA Ops`` line by ``^paged_attention`` on the instruction's
+    name (number and HLO text stripped). A rename would null them without
+    failing anything, so pin it here: the decode step's paged kernels
+    compile to a ``tpu_custom_call`` instruction of that name."""
+    import re
+
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = [
+        re.sub(r"\.\d+$", "", line.split(" = ", 1)[0].strip().lstrip("%"))
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line and " = " in line
+    ]
+    assert kernels and all(re.search("^paged_attention", k) for k in kernels), kernels

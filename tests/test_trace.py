@@ -465,3 +465,160 @@ class TestLogCorrelation:
         fmt = _ColorFormatter("%(name)s%(trace_tag)s: %(message)s")
         rec = logging.LogRecord("x", logging.INFO, "p", 1, "m", (), None)
         assert fmt.format(rec) == "x: m"
+
+
+class TestParentAndSelfTime:
+    def test_parent_is_the_innermost_open_span_on_the_beginning_thread(self):
+        tr = Trace("nested")
+        with tr.span("device.dispatch"):
+            with tr.span("stage.clip"):
+                time.sleep(0.001)
+            with tr.span("stage.face"):
+                time.sleep(0.001)
+        with tr.span("fetch"):
+            pass
+        rec = tr.to_record()
+        by_name = {s["name"]: s for s in rec["spans"]}
+        names = [s["name"] for s in rec["spans"]]
+        assert by_name["device.dispatch"]["parent"] is None
+        assert by_name["fetch"]["parent"] is None
+        for child in ("stage.clip", "stage.face"):
+            assert names[by_name[child]["parent"]] == "device.dispatch"
+        # self time = the span less what its children cover
+        kids = sum(s["dur_ms"] for s in rec["spans"] if s["parent"] == names.index("device.dispatch"))
+        assert 0 <= by_name["device.dispatch"]["dur_ms"] - kids < by_name["device.dispatch"]["dur_ms"]
+
+    def test_a_span_begun_on_another_thread_is_top_level_there(self):
+        tr = Trace("hop")
+        outer = tr.begin("batch.collect")  # begins here, ends on the "collector"
+        box = {}
+
+        def collector():
+            outer.end()
+            box["h"] = tr.begin("batch.device")
+            time.sleep(0.001)
+            box["h"].end()
+
+        t = threading.Thread(target=collector, name="collector")
+        t.start()
+        t.join(5)
+        assert not t.is_alive()
+        t0 = time.perf_counter()
+        tr.add_span("decode.queue", t0, t0 + 0.001)
+        tr.add_span("decode", t0 + 0.001, t0 + 0.002)  # starts where its sibling ends
+        rec = tr.to_record()
+        assert [s["parent"] for s in rec["spans"]] == [None] * 4
+        assert {s["name"]: s["begin_thread"] for s in rec["spans"]}["batch.device"] == "collector"
+
+
+class TestPhase:
+    def test_phase_never_imports_jax(self):
+        """``utils/trace.py`` is imported by the client and by load
+        generators that must stay off JAX: ``phase`` is a no-op there."""
+        import subprocess
+        import sys
+
+        code = (
+            "import sys\n"
+            "from lumen_tpu.utils import trace\n"
+            "with trace.phase('vlm.block.dispatch', step=1, rows=4) as p:\n"
+            "    assert p is None\n"
+            "assert not [m for m in sys.modules if m == 'jax' or m.startswith('jax.')]\n"
+        )
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=60)
+        assert out.returncode == 0, out.stderr[-2000:]
+
+    def test_phase_without_a_session_under_2us(self):
+        """No profiler session is live: a phase is an annotation's flag
+        check. Same method as the disabled-path guard above."""
+        import jax  # noqa: F401 - the loaded-JAX path is the one that costs
+
+        with utrace.phase("warm"):
+            pass
+        n = 20000
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with utrace.phase("batch.stack"):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+        assert best < 2e-6, f"phase cost {best * 1e6:.2f}µs with no session"
+
+
+class TestBatcherSequenceNumbers:
+    def test_collect_and_device_spans_carry_the_batch_number(self, traced_env):
+        from lumen_tpu.runtime.batcher import MicroBatcher
+
+        b = MicroBatcher(lambda tree, n: tree, max_batch=4, max_latency_ms=1, name="trace-seq").start()
+        try:
+            for want in (1, 2):
+                tr = utrace.begin_request("batched_task")
+                token = utrace.activate(tr)
+                try:
+                    b([1.0])
+                finally:
+                    utrace.deactivate(token)
+                utrace.finish_request(tr)
+                spans = {s["name"]: s for s in traced_env.traces()[-1]["spans"]}
+                assert spans["batch.collect"]["meta"]["seq"] == want
+                assert spans["batch.device"]["meta"]["seq"] == want
+                assert spans["batch.device"]["meta"]["batcher"] == "trace-seq"
+        finally:
+            b.close()
+
+
+class TestBulkQueueSpan:
+    def test_recv_ends_at_assembly_and_bulk_queue_follows(self, traced_env, monkeypatch):
+        """On a bulk stream the wait for a ``bulk-infer`` worker is its own
+        span: with one worker and a 30-ms handler, the third item's
+        ``bulk.queue`` holds the two handlers before it and its
+        ``rpc.recv`` holds none of them."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from lumen_tpu.serving import BaseService, TaskDefinition, TaskRegistry, base_service
+        from lumen_tpu.serving.proto import ml_service_pb2 as pb
+
+        pool = ThreadPoolExecutor(1, thread_name_prefix="bulk-one")
+        monkeypatch.setattr(base_service, "_bulk_pool", pool)
+
+        class Slow(BaseService):
+            def __init__(self):
+                registry = TaskRegistry("slow")
+                registry.register(TaskDefinition(name="slow_echo", handler=self._echo))
+                super().__init__(registry)
+
+            def capability(self):
+                return self.registry.build_capability(model_ids=["slow"], runtime="jax-cpu")
+
+            def _echo(self, payload, mime, meta):
+                time.sleep(0.03)
+                return payload, "application/octet-stream", {}
+
+        reqs = [pb.InferRequest(correlation_id=str(i), task="slow_echo", payload=b"p",
+                                meta={"bulk": "1"}) for i in range(3)]
+        try:
+            resps = list(Slow().Infer(iter(reqs), None))
+        finally:
+            pool.shutdown(wait=True)
+        assert len(resps) == 3 and not any(r.HasField("error") for r in resps)
+        recs = sorted(traced_env.traces(), key=lambda r: r["start_unix_ms"])
+        assert len(recs) == 3
+        for rec in recs:
+            spans = {s["name"]: s for s in rec["spans"]}
+            recv, queued = spans["rpc.recv"], spans["bulk.queue"]
+            assert recv["start_ms"] == 0 and recv["dur_ms"] < 20
+            assert queued["start_ms"] == pytest.approx(recv["dur_ms"], abs=0.01)
+            assert queued["parent"] is None
+        assert {s["name"]: s for s in recs[-1]["spans"]}["bulk.queue"]["dur_ms"] >= 50
+
+    def test_a_direct_request_has_no_bulk_queue_span(self, traced_env):
+        from lumen_tpu.serving.proto import ml_service_pb2 as pb
+        from tests.test_serving_grpc import EchoService
+
+        req = pb.InferRequest(correlation_id="d1", task="techo_echo", payload=b"hi",
+                              payload_mime="text/plain")
+        (resp,) = EchoService("techo").Infer(iter([req]), None)
+        assert not resp.HasField("error")
+        names = [s["name"] for s in traced_env.traces()[-1]["spans"]]
+        assert "rpc.recv" in names and "bulk.queue" not in names

@@ -1140,3 +1140,152 @@ class TestPrefixReuseAndSpec:
             assert not tiny._spill_ledger
         finally:
             mgr.close()
+
+
+class TestWaitCounters:
+    """The cumulative sums behind ``admit_wait``/``prefill_lane``/``server
+    ttft``: written by the loop thread, read as ratios of deltas."""
+
+    def _gauges(self, sched):
+        # The provider itself: the registry's slot for this name is
+        # last-writer-wins, and other tests build engines of the same name.
+        return sched._gauge_fn()
+
+    def test_every_admission_books_one_wait_and_one_first_token(self, cont_mgr):
+        sched = cont_mgr._continuous
+        before = self._gauges(sched)
+        prompts = ["alpha", "beta gamma", "delta", "epsilon zeta eta", "theta", "iota"]
+        threads = [
+            threading.Thread(
+                target=cont_mgr.generate,
+                args=([ChatMessage(role="user", content=p)],),
+                kwargs={"max_new_tokens": 6},
+            )
+            for p in prompts
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+        after = self._gauges(sched)
+        # drained: every request taken out of the queue was installed once
+        assert after["pending_count"] == after["admitted"]
+        assert after["pending_count"] - before["pending_count"] == len(prompts)
+        assert after["first_token_count"] - before["first_token_count"] == len(prompts)
+        assert after["rows_stepped"] - before["rows_stepped"] >= after["blocks_run"] - before["blocks_run"] > 0
+        for key in ("pending_ms_sum", "first_token_ms_sum", "lane_ms_sum"):
+            assert after[key] >= before[key]
+        # six requests on four slots: somebody waited for a slot, and a
+        # first token comes no sooner than the admission it follows
+        waited = after["pending_ms_sum"] - before["pending_ms_sum"]
+        first = after["first_token_ms_sum"] - before["first_token_ms_sum"]
+        assert 0 < waited < first
+        assert after["lane_jobs"] == before["lane_jobs"]  # short prompts never enter the lane
+
+    def test_lane_jobs_book_lane_time(self, model_dir, monkeypatch):
+        monkeypatch.setenv("LUMEN_VLM_PREFILL_CHUNK", "32")
+        mgr = VLMManager(
+            model_dir, dtype="float32", max_seq=256, max_new_cap=16,
+            prefill_buckets=(64,), scheduler="continuous", gen_slots=2, gen_block=4,
+        )
+        mgr.initialize()
+        try:
+            msgs = [ChatMessage(role="user", content="word " * 40)]  # the 64 bucket: two chunks
+            for _ in range(2):
+                mgr.generate(msgs, max_new_tokens=4)
+            g = self._gauges(mgr._continuous)
+            assert g["lane_jobs"] == 2 == g["pending_count"] == g["admitted"] == g["first_token_count"]
+            assert g["lane_ms_sum"] > 0
+            # submit -> lane entry -> first token sampled -> first token out
+            assert g["pending_ms_sum"] + g["lane_ms_sum"] <= g["first_token_ms_sum"]
+        finally:
+            mgr.close()
+
+    def test_the_step_programs_name_is_the_one_the_benchmark_reads(self, cont_mgr):
+        """``vlm_step_hbm_pct`` finds the decode program's runs on the
+        device's ``XLA Modules`` line by ``step_block``: pin the name XLA
+        gives the jitted step."""
+        import re
+
+        sched = cont_mgr._continuous
+        lowered = sched.gen._step_block.lower(
+            sched.params, sched.pool, sched.kv.block_tables[:, :1], sched._rng, block=sched.block
+        )
+        module = re.search(r"module @(\S+)", lowered.as_text()).group(1)
+        assert module == "jit__step_block_impl" and re.search("step_block", module)
+
+
+@pytest.fixture(scope="module")
+def profiled(cont_mgr, tmp_path_factory):
+    """ONE profiler session over a few generations and a few micro-batches:
+    ``lumen:`` events by (plane, line), and how far ``blocks_run`` rose."""
+    import glob
+    import os
+
+    import jax
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from lumen_tpu.runtime.batcher import MicroBatcher
+
+    sched = cont_mgr._continuous
+    cont_mgr.generate([ChatMessage(role="user", content="warm")], max_new_tokens=4)
+    batcher = MicroBatcher(lambda tree, n: tree, max_batch=4, max_latency_ms=2, name="prof-b").start()
+    batcher(np.zeros(2, np.float32))
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    blocks0 = sched.blocks_run
+    jax.profiler.start_trace(trace_dir)
+    try:
+        for prompt in ("profile me", "the quick brown fox"):
+            cont_mgr.generate([ChatMessage(role="user", content=prompt)], max_new_tokens=8)
+        for i in range(3):
+            batcher(np.full(2, i, np.float32))
+        time.sleep(0.05)  # the fetch worker leaves batch.settle after the caller wakes
+    finally:
+        jax.profiler.stop_trace()
+        batcher.close()
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            events = [(int(e.start_ns), int(e.start_ns + e.duration_ns), e.name, dict(e.stats))
+                      for e in line.events if e.name.startswith("lumen:")]
+            if events:
+                lines[(plane.name, i)] = sorted(events)
+    return {"lines": lines, "blocks": sched.blocks_run - blocks0}
+
+
+class TestFeederThreadPhases:
+    def _named(self, profiled, name):
+        return [e for events in profiled["lines"].values() for e in events if e[2] == name]
+
+    def test_one_dispatch_phase_for_every_block(self, profiled):
+        assert profiled["blocks"] > 0
+        for name in ("dispatch", "fetch", "emit", "prepare"):
+            assert len(self._named(profiled, f"lumen:vlm.block.{name}")) == profiled["blocks"], name
+        steps = [e[3]["step"] for e in self._named(profiled, "lumen:vlm.block.dispatch")]
+        assert steps == list(range(steps[0], steps[0] + len(steps)))  # the numbers batch.device spans carry
+        assert all(e[3]["rows"] >= 1 for e in self._named(profiled, "lumen:vlm.block.dispatch"))
+
+    def test_admissions_name_their_requests(self, profiled):
+        admits = self._named(profiled, "lumen:vlm.admit")
+        assert len(admits) == 2 and all(e[3]["rows"] == 1 and e[3]["kind"] == "group" for e in admits)
+        assert len({e[3]["rids"] for e in admits}) == 2
+
+    def test_batcher_phases_share_the_batch_number(self, profiled):
+        by_seq = {}
+        for name in ("window", "stack", "dispatch", "fetch", "settle"):
+            for e in self._named(profiled, f"lumen:batch.{name}"):
+                assert e[3]["batcher"] == "prof-b"
+                by_seq.setdefault(e[3]["seq"], set()).add(name)
+        whole = [seq for seq, names in by_seq.items() if len(names) == 5]
+        assert len(whole) == 3, by_seq  # the three batches wholly inside the session
+        assert not self._named(profiled, "lumen:batch.put")  # no mesh placement on this batcher
+
+    def test_no_phase_encloses_another_on_one_thread(self, profiled):
+        for key, events in profiled["lines"].items():
+            for a, b in zip(events, events[1:]):
+                assert b[0] >= a[1], (key, a, b)
+        threads = {key for key, events in profiled["lines"].items()}
+        assert len(threads) >= 3  # the scheduler, the collector, the fetch worker
