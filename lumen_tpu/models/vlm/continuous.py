@@ -18,8 +18,10 @@ bucket. This engine is the paged rebuild:
   runs the same code path end to end;
 - a burst of same-shaped arrivals still prefills as ONE batched forward
   (``ADMIT_BUCKETS``), and long prompts go through a CHUNKED PREFILL LANE
-  — one prompt chunk per scheduler turn — so a 1k-token prompt never
-  stalls in-flight decode steps;
+  — every job in the lane runs one of its chunks per scheduler turn,
+  oldest first, and installs in the turn its last chunk runs — so a
+  1k-token prompt never stalls in-flight decode steps and a burst of
+  long prompts does not queue behind its own head;
 - rows retire on EOS / per-request cap without stopping the others; if
   the pool runs dry mid-decode the newest row is PREEMPTED: its live KV
   pages are exported to a host SPILL TIER (one fused ``jax.device_get``
@@ -157,9 +159,11 @@ class _Slot:
     pending_tok: "int | None" = None
 
 
-@dataclass
+@dataclass(eq=False)
 class _PrefillJob:
-    """One long prompt moving through the chunked prefill lane."""
+    """One long prompt moving through the chunked prefill lane. Jobs are
+    compared by identity: any of them may leave the lane, not the head
+    alone, and a field-wise ``==`` would compare device arrays."""
 
     request: _Request
     caches: object = None  # contiguous [1, kvh, Lb, dh] scratch per layer
@@ -261,8 +265,9 @@ class ContinuousScheduler:
 
             self.pool = replicate(self.pool, mesh)
         # Prompts longer than this (padded length) prefill through the
-        # chunk lane, one chunk per scheduler turn; the chunk is rounded
-        # to a page multiple so scratch caches scatter cleanly into pages.
+        # chunk lane, one chunk per job per scheduler turn; the chunk is
+        # rounded to a page multiple so scratch caches scatter cleanly
+        # into pages.
         chunk = prefill_chunk or env_int(
             "LUMEN_VLM_PREFILL_CHUNK", 256, minimum=32, maximum=4096
         )
@@ -297,6 +302,7 @@ class ContinuousScheduler:
         self.admitted = 0
         self.preemptions = 0
         self.chunks_run = 0
+        self.lane_turns = 0  # turns in which the lane dispatched a chunk
         # -- KV spill tier: preemption victims park their pages on the
         # host instead of re-prefilling. Bounded two ways: total payload
         # bytes (also the shm arena's budget, so the lease path and the
@@ -400,6 +406,7 @@ class ContinuousScheduler:
                 "first_token_count": s.first_token_count,
                 "preempted": s.preemptions,
                 "prefill_chunks_run": s.chunks_run,
+                "lane_turns": s.lane_turns,
                 "prefill_lane_depth": len(s._prefill_jobs),
                 "slots_total": s.n_slots,
                 "slots_live": len(s._slots),
@@ -967,14 +974,14 @@ class ContinuousScheduler:
     # -- chunked prefill lane ----------------------------------------------
 
     def _lane_reserved_pages(self) -> int:
-        """Pages spoken for by the head chunk-lane job once its chunks
-        have all run (it admits the moment the free list covers them)."""
-        if not self._prefill_jobs:
-            return 0
-        job = self._prefill_jobs[0]
-        if job.offset < job.length or job.request.cancelled:
-            return 0
-        return self.kv.pages_for(job.length + 1) - len(job.shared)
+        """Pages spoken for by the chunk-lane jobs whose chunks have all
+        run: each installs, oldest first, the moment the free list covers
+        it, so every one of them still in the lane is waiting on pages."""
+        return sum(
+            self.kv.pages_for(job.length + 1) - len(job.shared)
+            for job in self._prefill_jobs
+            if job.offset >= job.length and not job.request.cancelled
+        )
 
     def _start_chunk_job(self, req: _Request) -> _PrefillJob:
         n = int(np.asarray(req.length)[0])
@@ -1013,58 +1020,69 @@ class ContinuousScheduler:
             job.shared = []
 
     def _advance_prefill_lane(self) -> None:
-        """Run ONE chunk of the head-of-lane prefill job (decode blocks
-        interleave between chunks), admitting the job when its last live
-        chunk has run and pages are free."""
-        while self._prefill_jobs:
-            job = self._prefill_jobs[0]
-            req = job.request
-            if req.cancelled:
-                self._prefill_jobs.popleft()
+        """One lane turn: EVERY job runs one chunk, oldest first (decode
+        blocks interleave between a job's chunks), then the jobs whose
+        last live chunk has run install in arrival order — in this same
+        turn when pages are free."""
+        ran = 0
+        for job in list(self._prefill_jobs):
+            if job.request.cancelled:
+                self._prefill_jobs.remove(job)
                 self._drop_job_hold(job)
-                _retire(req, [], eos=False)
-                continue
+                _retire(job.request, [], eos=False)
+            elif job.offset < job.length:
+                self._run_lane_chunk(job)
+                ran += 1
+        if ran:
+            self.lane_turns += 1
+        for job in list(self._prefill_jobs):
             if job.offset < job.length:
-                off = job.offset
-                # Tail chunks shrink to the padded span — off and the
-                # chunk size are host ints, so each (span, off) pair is
-                # one tiny compiled slice; counts are bounded by the
-                # prompt buckets over the chunk size.
-                c = min(self.prefill_chunk, int(req.embeds.shape[1]) - off)
-                with phase("vlm.prefill_chunk", rid=req.rid, offset=off, tokens=c):
-                    chunk = req.embeds[:, off : off + c]
-                    positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
-                    valid = jnp.asarray([min(job.length, off + c)], jnp.int32)
-                    job.last_logits, job.caches = self.gen._prefill_chunk(
-                        self.params, job.caches, chunk, positions,
-                        jnp.asarray(off, jnp.int32), valid,
-                    )
-                job.last_off = off
-                job.offset = off + c
-                self.chunks_run += 1
-                return  # one chunk per turn: decode gets the next slice
-            # All live chunks ran: admit when pages allow, else wait.
-            # Shared prefix pages are already granted-by-reference, so
-            # only the fresh suffix competes for the free list; cached
-            # history yields (reclaim) before the job stalls.
-            if not self.kv.can_admit(job.length, shared_pages=len(job.shared)):
-                if self.prefix is not None:
-                    short = (
-                        self.kv.pages_for(job.length + 1)
-                        - len(job.shared) - self.kv.pages_free
-                    )
-                    if short <= 0 or not self.prefix.reclaim(short):
-                        return
-                    if not self.kv.can_admit(job.length, shared_pages=len(job.shared)):
-                        return
-                else:
-                    return
-            with phase("vlm.lane_finish", rid=req.rid):
+                continue  # chunks left: holds no pages, blocks nobody
+            if not self._lane_pages_ready(job):
+                # Short of pages: wait for retires, and hold back the
+                # younger jobs too — _lane_reserved_pages keeps the gate
+                # from granting what they wait for to new arrivals.
+                return
+            with phase("vlm.lane_finish", rid=job.request.rid):
                 self._finish_lane_job(job)
-            return
+
+    def _run_lane_chunk(self, job: _PrefillJob) -> None:
+        """Dispatch the job's next prompt chunk into its scratch cache."""
+        req = job.request
+        off = job.offset
+        # Tail chunks shrink to the padded span — off and the chunk size
+        # are host ints, so each (span, off) pair is one tiny compiled
+        # slice; counts are bounded by the prompt buckets over the chunk
+        # size.
+        c = min(self.prefill_chunk, int(req.embeds.shape[1]) - off)
+        with phase("vlm.prefill_chunk", rid=req.rid, offset=off, tokens=c):
+            chunk = req.embeds[:, off : off + c]
+            positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
+            valid = jnp.asarray([min(job.length, off + c)], jnp.int32)
+            job.last_logits, job.caches = self.gen._prefill_chunk(
+                self.params, job.caches, chunk, positions,
+                jnp.asarray(off, jnp.int32), valid,
+            )
+        job.last_off = off
+        job.offset = off + c
+        self.chunks_run += 1
+
+    def _lane_pages_ready(self, job: _PrefillJob) -> bool:
+        """Whether the free list covers a finished job's row. Shared
+        prefix pages are already granted-by-reference, so only the fresh
+        suffix competes for the free list; cached history yields
+        (reclaim) before the job stalls."""
+        if self.kv.can_admit(job.length, shared_pages=len(job.shared)):
+            return True
+        if self.prefix is None:
+            return False
+        short = self.kv.pages_for(job.length + 1) - len(job.shared) - self.kv.pages_free
+        if short <= 0 or not self.prefix.reclaim(short):
+            return False
+        return self.kv.can_admit(job.length, shared_pages=len(job.shared))
 
     def _finish_lane_job(self, job: _PrefillJob) -> None:
-        """Sample the head job's first token and install its row."""
+        """Sample a finished job's first token and install its row."""
         req = job.request
         sub = jax.random.fold_in(req.rng, 0)
         tok0, seen = self.gen._chunk_finish(
@@ -1075,7 +1093,7 @@ class ContinuousScheduler:
             jnp.asarray([req.do_sample]),
             jnp.asarray([req.repetition_penalty], jnp.float32),
         )
-        self._prefill_jobs.popleft()
+        self._prefill_jobs.remove(job)
         try:
             self._install_row(
                 req, job.caches, tok0, seen, req.length,

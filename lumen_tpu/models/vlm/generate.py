@@ -484,11 +484,12 @@ class Generator:
     #
     # A long prompt prefilled in one shot would hold the scheduler loop
     # (and every in-flight decode row) hostage for the whole forward; the
-    # chunk programs let the engine interleave one prompt chunk per decode
-    # block instead. Chunks write into a CONTIGUOUS per-request scratch
-    # cache (offset semantics identical to one-shot prefill — causal
-    # attention over earlier chunks already in the scratch), and the
-    # finished scratch admits into pages exactly like a one-shot prefill.
+    # chunk programs let the engine run one chunk of each waiting prompt
+    # between decode blocks instead. Chunks write into a CONTIGUOUS
+    # per-request scratch cache (offset semantics identical to one-shot
+    # prefill — causal attention over earlier chunks already in the
+    # scratch), and the finished scratch admits into pages exactly like
+    # a one-shot prefill.
 
     def new_prefill_cache(self, kv_len: int):
         """Contiguous batch-1 scratch cache for one chunked prefill."""

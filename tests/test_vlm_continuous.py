@@ -431,6 +431,111 @@ class TestPagedPoolBehavior:
             mgr.close()
 
 
+def _lane_prompt(k: int, words: int = 39) -> str:
+    """A distinct in-vocabulary prompt (the test tokenizer is word-level):
+    ``words`` + 6 scaffolding tokens, so 39 words is a 45-token prompt in
+    the 64 bucket (three 16-token pages, two 32-token chunks) and 100
+    words one in the 128 bucket (four chunks)."""
+    return " ".join(f"w{16 + (37 * k + 5 * j) % 200}" for j in range(words))
+
+
+def _make_lane_mgr(model_dir, chunk: "int | None" = 32, **kw):
+    """A continuous engine whose 64 and 128 buckets take the chunk lane
+    (``chunk`` 32) or, with ``chunk`` None, the one-shot prefill."""
+    cfg = dict(
+        dtype="float32", max_seq=256, max_new_cap=32,
+        prefill_buckets=(16, 64, 128), scheduler="continuous",
+        gen_slots=4, gen_block=4,
+    )
+    cfg.update(kw)
+    mp = pytest.MonkeyPatch()
+    if chunk is not None:
+        mp.setenv("LUMEN_VLM_PREFILL_CHUNK", str(chunk))  # read once, at construction
+    try:
+        mgr = VLMManager(model_dir, **cfg)
+        mgr.initialize()
+    finally:
+        mp.undo()
+    return mgr
+
+
+def _enqueue(mgr, prompts, max_new, prefix=False):
+    """Build every request up front and queue them under the scheduler's
+    lock with ONE notify, so the whole burst is there when the loop wakes
+    (submitting from threads would race its turns)."""
+    sched = mgr._continuous
+    reqs = []
+    for p, n_new in zip(prompts, max_new):
+        e, pos, ln, ids, n = mgr._prepare_inputs([ChatMessage(role="user", content=p)], None, True)
+        content = mgr._prefix_content(ids, n, None) if prefix else None
+        reqs.append(mgr._make_gen_request(e, pos, ln, ids, n_new, 0.0, 1.0, False, 1.0, prefix_content=content))
+    with sched._cond:
+        for req in reqs:
+            sched._stamp_submit(req)
+            sched._pending.append(req)
+        sched._cond.notify()
+    return reqs
+
+
+def _tokens(req, timeout=120):
+    tokens, n_gen, _eos = req.future.result(timeout=timeout)
+    return [int(t) for t in tokens[:n_gen]]
+
+
+def _wait_drained(sched, timeout=20):
+    deadline = time.time() + timeout
+    while (sched._slots or sched._prefill_jobs or sched._pending) and time.time() < deadline:
+        time.sleep(0.01)
+    assert not (sched._slots or sched._prefill_jobs or sched._pending)
+
+
+@pytest.fixture(scope="module")
+def oneshot_mgr(model_dir):
+    """Same buckets, default chunk (256): every prompt prefills one-shot."""
+    mgr = _make_lane_mgr(model_dir, chunk=None)
+    assert mgr._continuous.prefill_chunk == 256
+    yield mgr
+    mgr.close()
+
+
+@pytest.fixture(scope="module")
+def lane_mgr(model_dir):
+    mgr = _make_lane_mgr(model_dir)
+    assert mgr._continuous.prefill_chunk == 32
+    yield mgr
+    mgr.close()
+
+
+@pytest.fixture(scope="module")
+def lane_burst(lane_mgr, oneshot_mgr):
+    """Four two-chunk prompts queued at once on four free slots: what the
+    lane did with them, and what the one-shot prefill makes of each."""
+    prompts = [_lane_prompt(k) for k in range(4)]
+    want = [
+        oneshot_mgr.generate([ChatMessage(role="user", content=p)], max_new_tokens=8).tokens
+        for p in prompts
+    ]
+    sched = lane_mgr._continuous
+    before = sched._gauge_fn()
+    installed_at = []  # blocks run when each row was installed
+    real_install = sched._install_row
+
+    def spying_install(*a, **kw):
+        installed_at.append(sched.blocks_run)
+        return real_install(*a, **kw)
+
+    sched._install_row = spying_install
+    try:
+        got = [_tokens(r) for r in _enqueue(lane_mgr, prompts, [8] * 4)]
+    finally:
+        sched._install_row = real_install
+    _wait_drained(sched)
+    return dict(
+        want=want, got=got, before=before, after=sched._gauge_fn(),
+        installed_at=installed_at, pages_live=sched.kv.stats().pages_live,
+    )
+
+
 class TestChunkedPrefillLane:
     def test_long_prompt_chunks_and_matches_oneshot(self, model_dir, monkeypatch):
         """A prompt bucket above LUMEN_VLM_PREFILL_CHUNK runs the chunk
@@ -466,6 +571,187 @@ class TestChunkedPrefillLane:
             # Decode keeps running between chunks: a short request behind
             # a chunked long one is not stalled by the whole prefill.
             assert sched.kv.stats().pages_live == 0
+        finally:
+            mgr.close()
+
+
+    def test_burst_advances_every_job_each_turn(self, lane_burst):
+        """Every lane job runs a chunk a turn and installs in the turn of
+        its last chunk: four two-chunk prompts are all in within two lane
+        turns, not four times (chunk, chunk, finish)."""
+        b, before, after = lane_burst, lane_burst["before"], lane_burst["after"]
+        chunks_per_job = 2
+        assert after["lane_jobs"] - before["lane_jobs"] == 4
+        chunks = after["prefill_chunks_run"] - before["prefill_chunks_run"]
+        turns = after["lane_turns"] - before["lane_turns"]
+        assert chunks == 4 * chunks_per_job
+        assert turns == chunks_per_job
+        assert chunks / turns > 1
+        assert len(b["installed_at"]) == 4
+        assert max(b["installed_at"]) - before["blocks_run"] <= chunks_per_job + 1
+        assert b["pages_live"] == 0
+
+    @pytest.mark.parametrize("k", range(4))
+    def test_burst_rows_match_oneshot(self, lane_burst, k):
+        """Chunks of several jobs interleaved in one turn: each row is
+        still token for token what its one-shot prefill decodes (greedy)."""
+        assert lane_burst["got"][k] == lane_burst["want"][k]
+        assert len(lane_burst["got"][k]) > 1
+
+    def test_long_prompt_still_one_chunk_a_turn_beside_decode(self, lane_mgr, oneshot_mgr):
+        """The contract the lane exists for: a four-chunk prompt is spread
+        over four turns, and the rows already decoding get a block in
+        every one of them."""
+        prompts = ["describe the cat", "describe a dog", _lane_prompt(9, words=100)]
+        budgets = [24, 24, 8]
+        want = [
+            oneshot_mgr.generate([ChatMessage(role="user", content=p)], max_new_tokens=n).tokens
+            for p, n in zip(prompts, budgets)
+        ]
+        assert len(want[0]) > 17 and len(want[1]) > 17  # alive through four blocks
+        sched = lane_mgr._continuous
+        before = sched._gauge_fn()
+        chunk_at = []  # blocks run when each chunk was dispatched
+        real_chunk = sched.gen._prefill_chunk
+
+        def spying_chunk(*a, **kw):
+            chunk_at.append(sched.blocks_run)
+            return real_chunk(*a, **kw)
+
+        sched.gen._prefill_chunk = spying_chunk
+        try:
+            got = [_tokens(r) for r in _enqueue(lane_mgr, prompts, budgets)]
+        finally:
+            sched.gen._prefill_chunk = real_chunk
+        _wait_drained(sched)
+        after = sched._gauge_fn()
+        base = before["blocks_run"]
+        assert chunk_at == [base, base + 1, base + 2, base + 3]
+        assert after["lane_turns"] - before["lane_turns"] == 4
+        assert after["prefill_chunks_run"] - before["prefill_chunks_run"] == 4
+        assert got == want
+
+    def test_short_pool_installs_in_arrival_order(self, model_dir, oneshot_mgr):
+        """Two finished jobs wait on pages: both are reserved against new
+        arrivals, the older installs first, and a later short prompt that
+        would fit does not get in before the younger. Pages balance."""
+        from lumen_tpu.models.vlm.continuous import ContinuousScheduler
+
+        prompts = [_lane_prompt(20), _lane_prompt(21), "describe the image"]
+        budgets = [2, 2, 2]  # 45 + 2 + 1 tokens: a long row never outgrows its 3 pages
+        want = [
+            oneshot_mgr.generate([ChatMessage(role="user", content=p)], max_new_tokens=n).tokens
+            for p, n in zip(prompts, budgets)
+        ]
+        mgr = _make_lane_mgr(model_dir)
+        try:
+            mgr._continuous.close()
+            sched = ContinuousScheduler(
+                mgr.generator, mgr.params, slots=4, block=4, name=mgr.info.name,
+                page_size=16, pages=9, prefill_chunk=32,
+            )
+            mgr._continuous = sched
+            mgr._engines = [sched]
+            held, arrival = [], [False]
+            turn = [0]
+            seen_at, installed_at = {}, {}
+            real_gate, real_install = sched._gate, sched._install_row
+
+            def spying_gate(admit):
+                # Pages leave and come back on the loop's own thread, at
+                # the head of a turn, so every turn sees one state.
+                turn[0] += 1
+                for req in admit:
+                    seen_at.setdefault(id(req), turn[0])
+                if admit and arrival[0]:
+                    # Pages for ONE long row come free as the short prompt
+                    # arrives: 4 free covers the older job and the short
+                    # one, which a head-only reservation would let through.
+                    arrival[0] = False
+                    sched.kv.decref(held[:2])
+                placeable = real_gate(admit)
+                if len(placeable) == 2 and not held:
+                    held.extend(sched.kv._pop_fresh(6))  # both in the lane: 2 of 8 pages stay free
+                return placeable
+
+            def spying_install(req, *a, **kw):
+                installed_at[id(req)] = turn[0]
+                return real_install(req, *a, **kw)
+
+            sched._gate, sched._install_row = spying_gate, spying_install
+            a, b = _enqueue(mgr, prompts[:2], budgets[:2])
+            deadline = time.time() + 60
+            while sched.lane_turns < 2 and time.time() < deadline:
+                time.sleep(0.005)
+            time.sleep(0.1)  # a few more turns: nothing may install on 2 free pages
+            with sched._cond:
+                assert sched.chunks_run == 4 and sched.admitted == 0
+                assert len(sched._prefill_jobs) == 2 and sched.kv.pages_free == 2
+                assert sched._lane_reserved_pages() == 6  # both, not the head alone
+            arrival[0] = True
+            (c,) = _enqueue(mgr, prompts[2:], budgets[2:])
+            got = [_tokens(r) for r in (a, b, c)]
+            _wait_drained(sched)
+            assert got == want
+            assert installed_at[id(a)] < installed_at[id(b)] <= installed_at[id(c)]
+            assert installed_at[id(c)] > seen_at[id(c)]  # held back at its first gate
+            assert sched.preemptions == 0
+            sched.kv.decref(held[2:])
+            stats = sched.kv.stats()
+            assert stats.pages_live == 0
+            assert stats.allocated_total == stats.freed_total > 0
+            assert not sched._spill_ledger and sched._spill_arena is None
+        finally:
+            mgr.close()
+
+    def test_cancelled_job_behind_the_head_is_retired(self, model_dir, monkeypatch):
+        """A lane job that is not the head and whose consumer went away
+        is retired in the next lane turn, and the reference it held on
+        its cached prefix pages is dropped."""
+        monkeypatch.setenv("LUMEN_VLM_PREFIX_BYTES", str(8 << 20))
+        mgr = _make_lane_mgr(model_dir)
+        try:
+            sched = mgr._continuous
+            assert sched.prefix is not None
+            head, repeat = _lane_prompt(30), _lane_prompt(31)
+            want = mgr.generate([ChatMessage(role="user", content=head)], max_new_tokens=6).tokens
+            mgr.generate([ChatMessage(role="user", content=repeat)], max_new_tokens=6)
+            _wait_drained(sched)
+            sched.prefix.clear()
+            assert sched.kv.stats().pages_live == 0
+            mgr.generate([ChatMessage(role="user", content=repeat)], max_new_tokens=6)  # cached again
+            _wait_drained(sched)
+            cached = sched.kv.stats().pages_live
+            assert cached == 2  # 45 tokens: two full pages in the cache
+
+            jobs = []
+            real_start = sched._start_chunk_job
+
+            def cancelling_start(req):
+                job = real_start(req)
+                jobs.append((job, list(job.shared)))
+                if len(jobs) == 2:
+                    req.cancelled = True  # in the lane, behind the head
+                return job
+
+            sched._start_chunk_job = cancelling_start
+            chunks0 = sched.chunks_run
+            try:
+                a, b = _enqueue(mgr, [head, repeat], [6, 6], prefix=True)
+                assert _tokens(b) == []
+                assert _tokens(a) == want
+            finally:
+                sched._start_chunk_job = real_start
+            _wait_drained(sched)
+            (_, head_shared), (job, shared) = jobs
+            assert head_shared == [] and len(shared) == 2
+            assert job.shared == []  # hold dropped
+            assert all(sched.kv.refcount(p) == 1 for p in shared)  # the cache's own
+            assert sched.chunks_run - chunks0 == 2  # the head's; the retired job ran none
+            sched.prefix.clear()
+            stats = sched.kv.stats()
+            assert stats.pages_live == 0
+            assert stats.allocated_total == stats.freed_total
         finally:
             mgr.close()
 
@@ -857,20 +1143,9 @@ class TestBatchedAdmission:
 
             sched.gen._prefill = counting_prefill
             try:
-                # Build all 8 requests up front and enqueue them under the
-                # scheduler lock with ONE notify: the backlog is fully
-                # formed before the scheduler thread wakes, so grouping is
-                # deterministic (submitting from threads would race the
-                # admit loop and flake on slow machines).
-                reqs = []
-                for p in prompts:
-                    e, pos, ln, ids, _n = mgr._prepare_inputs(
-                        [ChatMessage(role="user", content=p)], None, True
-                    )
-                    reqs.append(mgr._make_gen_request(e, pos, ln, ids, 6, 0.0, 1.0, False, 1.0))
-                with sched._cond:
-                    sched._pending.extend(reqs)
-                    sched._cond.notify()
+                # The backlog is fully formed before the scheduler thread
+                # wakes (see _enqueue), so grouping is deterministic.
+                reqs = _enqueue(mgr, prompts, [6] * len(prompts))
                 results = [r.future.result(timeout=120) for r in reqs]
             finally:
                 sched.gen._prefill = real_prefill
@@ -1214,6 +1489,34 @@ class TestWaitCounters:
         )
         module = re.search(r"module @(\S+)", lowered.as_text()).group(1)
         assert module == "jit__step_block_impl" and re.search("step_block", module)
+
+    def test_the_lanes_counters_are_the_ones_the_benchmark_reads(self, lane_mgr, lane_burst):
+        """``lane_chunks_per_turn`` is a data file: pin the gauge fields it
+        names, and what their ratio says (1.0 for a lone lane job, more
+        when a turn advances several)."""
+        import json
+        import os
+
+        from benchmark.cells import load_module
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "layer_metrics", "lane_chunks_per_turn.json")) as f:
+            spec = json.load(f)
+        reader = load_module("readers", spec["reader"])
+        sched = lane_mgr._continuous
+        name = f"vlm-continuous:{sched.name}"
+        assert name.startswith(spec["gauge"])
+        assert {spec["numerator"], spec["denominator"]} <= set(self._gauges(sched))
+
+        def ratio(before, after):
+            ctx = {"result": {"before": {"gauges": {name: before}}, "after": {"gauges": {name: after}}}}
+            return reader.read(ctx, spec)
+
+        before = self._gauges(sched)
+        assert ratio(before, before) is None  # nothing went through the lane
+        lane_mgr.generate([ChatMessage(role="user", content=_lane_prompt(40))], max_new_tokens=4)
+        assert ratio(before, self._gauges(sched)) == 1.0
+        assert ratio(lane_burst["before"], lane_burst["after"]) == 4.0
 
 
 @pytest.fixture(scope="module")
