@@ -6,6 +6,14 @@ edits nothing that is there:
 
 - a cell ``<name>``         -> its ``workloads`` entry: ``config`` + ``traffic``
 - a configuration           -> the ``file`` of its ``configs`` entry
+- a model entry of it       -> ``models.<family>`` of that file; its optional
+  (``clip``, ``vlm``)          ``tensors`` key -> ``benchmark/tensors/<t>.py``,
+                               which lists the checkpoint (``weights.listing``);
+                               its optional ``counts`` key ->
+                               ``benchmark/counts/<c>.py``, the work behind the
+                               whole-step shares (``readers/common.counts``).
+                               An entry without a key means the family's own
+                               name: ``tensors/vlm.py``, ``counts/clip.py``
 - a traffic mix ``<t>``     -> ``benchmark/traffic/<t>.json``; its ``generator``
                                key -> ``benchmark/generators/<g>.py``; its
                                ``reference`` key -> ``benchmark/references/<r>.py``
@@ -13,6 +21,25 @@ edits nothing that is there:
                                ``reader`` key -> ``benchmark/readers/<r>.py``;
                                an optional ``roofline`` key ->
                                ``benchmark/rooflines/<k>.py``
+
+What a PR that adds a configuration of a new decoder architecture touches,
+and all it touches (``tests/test_cells.py::add_decoder`` does exactly this):
+
+- new files: ``tensors/<t>.py`` (``tensors(cfg)``, optionally
+  ``special_words(cfg)``), ``counts/<c>.py`` (``image_flops``,
+  ``prefill_flops``, ``decode_token_flops``, ``decode_step_bytes``),
+  ``references/<r>.py`` (``compare``, ``fault``, ``prompt_ids``),
+  ``configs/<name>.json`` whose ``models.vlm`` names the first two, and
+  ``traffic/<mix>.json`` that names the reference (a mix belongs to one
+  reference, so a new decoder brings a mix of its own, data only);
+- ``BENCHMARK.json``: one ``configs`` entry, one ``workloads`` entry for each
+  cell, and the cell's name appended to the ``workloads`` list of every
+  metric it reports: ``caption_tokens_per_s``, ``ttft_p50_ms``,
+  ``vlm_step_mfu``, ``vlm_step_hbm_pct`` and the captioning cell's other
+  per-layer metrics;
+- a kernel of its own brings ``layer_metrics/<m>.json``, ``rooflines/<k>.py``
+  and, where ``kernel_roofline``'s grouped-query call does not fit it, a
+  reader.
 """
 
 from __future__ import annotations

@@ -1,29 +1,10 @@
-"""Operations and bytes the algorithm needs, counted from the
-configuration's shapes alone, whatever implements it. A multiply-add is two
-operations. Used for the whole-step shares of the chip's peak
-(``*_step_mfu``, ``vlm_step_hbm_pct``); a kernel's own count lives in
-``benchmark/rooflines/<kernel>.py``."""
+"""A Qwen2 decoder behind the repo's ViT tower: the counts of every
+``models.vlm`` entry that names no other. Another decoder's counts take
+``image_flops`` from here where they share the tower."""
 
 from __future__ import annotations
 
-
-def _block_flops(tokens: int, width: int, inter: int) -> int:
-    """One pre-LN transformer block over ``tokens`` tokens attending to each
-    other: q/k/v/out projections, scores and weighted values, two-matrix MLP."""
-    proj = 4 * 2 * tokens * width * width
-    attn = 2 * 2 * tokens * tokens * width
-    mlp = 2 * 2 * tokens * width * inter
-    return proj + attn + mlp
-
-
-def clip_image_flops(cfg: dict) -> int:
-    """One image through the vision tower and the projection."""
-    v = cfg["vision_config"]
-    w, p = v["hidden_size"], v["patch_size"]
-    n = (v["image_size"] // p) ** 2
-    patch = 2 * n * (3 * p * p) * w
-    blocks = v["num_hidden_layers"] * _block_flops(n + 1, w, v["intermediate_size"])
-    return patch + blocks + 2 * w * cfg["projection_dim"]
+from benchmark.counts.common import block_flops
 
 
 def _decoder_dims(cfg: dict) -> dict:
@@ -49,12 +30,12 @@ def decoder_token_flops(cfg: dict, context: float, with_head: bool) -> float:
     return 2 * decoder_matmul_params(cfg) + attn + head
 
 
-def vlm_tower_flops(cfg: dict) -> int:
+def image_flops(cfg: dict) -> int:
     """One image through the captioner's tower and projector."""
     v, h = cfg["vision_config"], cfg["text_config"]["hidden_size"]
     w, p = v["hidden_size"], v["patch_size"]
     n = (v["image_size"] // p) ** 2
-    return (2 * n * 3 * p * p * w + v["num_hidden_layers"] * _block_flops(n, w, 4 * w)
+    return (2 * n * 3 * p * p * w + v["num_hidden_layers"] * block_flops(n, w, 4 * w)
             + 2 * n * w * h + 2 * n * h * h)
 
 
@@ -63,6 +44,11 @@ def prefill_flops(cfg: dict, prompt_tokens: int) -> float:
     those before it (half the prompt on average), the head at the last one."""
     head = 2 * cfg["text_config"]["hidden_size"] * cfg["text_config"]["vocab_size"]
     return prompt_tokens * decoder_token_flops(cfg, prompt_tokens / 2, False) + head
+
+
+def decode_token_flops(cfg: dict, context: float) -> float:
+    """One decoded token, the head included."""
+    return decoder_token_flops(cfg, context, True)
 
 
 def decode_step_bytes(cfg: dict, rows: float, context: float, weight_bytes: float, kv_bytes: int = 2) -> float:

@@ -6,6 +6,8 @@ file. A reader that finds nothing to read returns None."""
 
 from __future__ import annotations
 
+from benchmark import cells
+
 
 def gauge(snapshot: dict, prefix: str) -> dict:
     """The first gauge whose name is ``prefix`` or starts with it."""
@@ -27,6 +29,14 @@ def delta(ctx: dict, prefix: str, field: str):
 
 def model_config(ctx: dict, family: str) -> dict:
     return ctx["cell"].config["models"][family]["config"]
+
+
+def counts(ctx: dict, family: str):
+    """The module that counts the work of the cell's ``family`` model:
+    ``benchmark/counts/<name>.py``, where ``<name>`` is the model entry's
+    ``counts`` key or, for an entry without one, the family's own name."""
+    model = ctx["cell"].config["models"][family]
+    return cells.load_module("counts", model.get("counts", family), ctx["cell"].here)
 
 
 def vlm_prompt_tokens(ctx: dict) -> int:

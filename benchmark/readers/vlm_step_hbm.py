@@ -5,8 +5,7 @@ time of the decode program's runs in the trace over the steps they ran."""
 
 import re
 
-from benchmark import work
-from benchmark.readers.common import decode_rows, mean_context, model_config
+from benchmark.readers.common import counts, decode_rows, mean_context, model_config
 
 
 def read(ctx, spec):
@@ -21,5 +20,6 @@ def read(ctx, spec):
     block = int(ctx["result"]["settings"]["vlm"]["decode_block"])
     step_s = sum(ev[2] for ev in runs) / 1e9 / (len(runs) * block)
     weight_bytes = 1 if ctx["cell"].config["precision"]["vlm"] == "int8" else 2
-    need = work.decode_step_bytes(model_config(ctx, "vlm"), decode_rows(ctx) or 1.0, mean_context(ctx), weight_bytes)
+    need = counts(ctx, "vlm").decode_step_bytes(
+        model_config(ctx, "vlm"), decode_rows(ctx) or 1.0, mean_context(ctx), weight_bytes)
     return 100.0 * need / (step_s * ctx["peaks"]["hbm_bytes_per_s"])

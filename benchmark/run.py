@@ -114,10 +114,10 @@ def memory_peak() -> int:
 def generator_context(cell: cells.Cell) -> dict:
     if cell.family != "vlm":
         return {}
-    cfg = cell.config["models"]["vlm"]["config"]
-    vocab = weights.vlm_vocab(cfg)
+    model = cell.config["models"]["vlm"]
+    vocab = weights.vlm_vocab(model)
     special = {w: i for w, i in vocab.items() if not (w[:1] == "w" and w[1:].isdigit())}
-    return {"vocab_size": cfg["text_config"]["vocab_size"], "special": special}
+    return {"vocab_size": model["config"]["text_config"]["vocab_size"], "special": special}
 
 
 class Bench:
@@ -297,12 +297,13 @@ def judge(numbers: dict, limit: dict, client: dict) -> tuple[bool, dict]:
 
 def end_to_end(cell: cells.Cell, result: dict, setup_s: float) -> dict:
     client, names = result["client"], cell.traffic["end_to_end"]
+    q = float(names.get("tail_q", 95))  # which percentile of all requests the mix's latency metric is
     if cell.family == "clip":
         rate = client["completed_in_window"] / client["window_s"]
-        tail = percentile(client["latency_ms"], 95)
+        tail = percentile(client["latency_ms"], q)
     else:
         rate = client["tokens_in_window"] / client["window_s"]
-        tail = percentile(client["ttft_ms"], 95)
+        tail = percentile(client["ttft_ms"], q)
     values = {names["rate"]: rate, names["tail"]: tail, "setup_s": setup_s}
     units = {m["name"]: m["unit"] for m in cell.bench["end_to_end"]}
     wanted = [m["name"] for m in cell.end_to_end() if m["name"] in values]

@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from benchmark import cells, weights
 from benchmark.photos import photo_jpeg, pool_sizes, seeded_order, tagged
 
@@ -50,10 +52,17 @@ def test_instruction_and_word_ids_round_trip():
 
 
 def test_vocabulary_covers_every_id_once():
-    cfg = cells._read_json(os.path.join(cells.HERE, "configs", "rehearsal-tiny.json"))["models"]["vlm"]["config"]
-    vocab = weights.vlm_vocab(cfg)
+    model = cells._read_json(os.path.join(cells.HERE, "configs", "rehearsal-tiny.json"))["models"]["vlm"]
+    cfg, vocab = model["config"], weights.vlm_vocab(model)
     assert sorted(vocab.values()) == list(range(cfg["text_config"]["vocab_size"]))
     assert vocab["<image>"] == cfg["image_token_index"]
+
+
+def test_a_special_word_outside_the_vocabulary_is_an_error():
+    model = cells._read_json(os.path.join(cells.HERE, "configs", "rehearsal-tiny.json"))["models"]["vlm"]
+    model["config"]["text_config"]["vocab_size"] = 4002  # a sliced vocabulary: <image> (4002) falls outside
+    with pytest.raises(cells.CellError, match=r"'<image>' has id 4002 \(image_token_index\)"):
+        weights.vlm_vocab(model)
 
 
 def test_the_load_generator_never_imports_jax():

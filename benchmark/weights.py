@@ -1,15 +1,17 @@
 """Seeded model directories, written from a configuration file alone.
 
 A configuration's ``models`` section holds the published HF-style
-``config.json`` of each family. From those shapes this module lists every
-tensor under its HF checkpoint name, draws it from ``(weights_seed, name)``
-and writes one ``model.safetensors`` beside the small files the program's
-normal load path asks for (``config.json``, ``model_info.json``, a
-word-level ``tokenizer.json``). Every value is rounded to bf16, the served
-type, and stored as float16: a two-byte type numpy knows, which the load
-path transposes and casts three times faster than bf16 itself (my sandbox
-timing, PR 26). Values under float16's normal range (|w| < 6e-5, 0.2% of a
-N(0, 0.02) draw) keep float16's absolute spacing of 6e-8.
+``config.json`` of each family. The entry's tensor listing (found by name
+in ``benchmark/tensors/``, see :func:`listing`) gives every tensor under its
+HF checkpoint name from those shapes; this module draws each from
+``(weights_seed, name)`` and writes one ``model.safetensors`` beside the
+small files the program's normal load path asks for (``config.json``,
+``model_info.json``, a word-level ``tokenizer.json``). Every value is
+rounded to bf16, the served type, and stored as float16: a two-byte type
+numpy knows, which the load path transposes and casts three times faster
+than bf16 itself (my sandbox timing, PR 26). Values under float16's normal
+range (|w| < 6e-5, 0.2% of a N(0, 0.02) draw) keep float16's absolute
+spacing of 6e-8.
 
 Nothing here imports the program or JAX: the plain references read the
 same files back by the same names.
@@ -26,6 +28,8 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from benchmark import cells
+
 #: elements drawn by one generator; larger tensors are drawn in row blocks
 _BLOCK_ELEMS = 1 << 24
 
@@ -35,102 +39,12 @@ CHAT_TEMPLATE = (
 )
 
 
-def _tower_tensors(prefix: str, width: int, inter: int, layers: int, names: dict) -> list:
-    """One pre-LN transformer tower under ``names`` (HF CLIP or the VLM tower)."""
-    out = []
-    for i in range(layers):
-        p = f"{prefix}.{i}."
-        for proj in ("q_proj", "k_proj", "v_proj", "out_proj"):
-            out.append((f"{p}{names['attn']}.{proj}.weight", (width, width)))
-            out.append((f"{p}{names['attn']}.{proj}.bias", (width,)))
-        for ln in (names["ln1"], names["ln2"]):
-            out.append((f"{p}{ln}.weight", (width,)))
-            out.append((f"{p}{ln}.bias", (width,)))
-        out.append((f"{p}mlp.fc1.weight", (inter, width)))
-        out.append((f"{p}mlp.fc1.bias", (inter,)))
-        out.append((f"{p}mlp.fc2.weight", (width, inter)))
-        out.append((f"{p}mlp.fc2.bias", (width,)))
-    return out
+def listing(family: str, model: dict):
+    """The module that lists a model entry's tensors:
+    ``benchmark/tensors/<name>.py``, where ``<name>`` is the entry's
+    ``tensors`` key or, for an entry without one, the family's own name."""
+    return cells.load_module("tensors", model.get("tensors", family))
 
-
-_HF_CLIP = {"attn": "self_attn", "ln1": "layer_norm1", "ln2": "layer_norm2"}
-_VLM_TOWER = {"attn": "attn", "ln1": "norm1", "ln2": "norm2"}
-
-
-def clip_tensors(cfg: dict) -> list[tuple[str, tuple]]:
-    """HF ``CLIPModel`` state-dict names and shapes for ``cfg`` (config.json)."""
-    v, t, proj = cfg["vision_config"], cfg["text_config"], cfg["projection_dim"]
-    vw, tw = v["hidden_size"], t["hidden_size"]
-    n_pos = (v["image_size"] // v["patch_size"]) ** 2 + 1
-    out = [
-        ("logit_scale", ()),
-        ("text_model.embeddings.token_embedding.weight", (t["vocab_size"], tw)),
-        ("text_model.embeddings.position_embedding.weight", (t["max_position_embeddings"], tw)),
-    ]
-    out += _tower_tensors("text_model.encoder.layers", tw, t["intermediate_size"],
-                          t["num_hidden_layers"], _HF_CLIP)
-    out += [
-        ("text_model.final_layer_norm.weight", (tw,)),
-        ("text_model.final_layer_norm.bias", (tw,)),
-        ("text_projection.weight", (proj, tw)),
-        ("vision_model.embeddings.class_embedding", (vw,)),
-        ("vision_model.embeddings.patch_embedding.weight", (vw, 3, v["patch_size"], v["patch_size"])),
-        ("vision_model.embeddings.position_embedding.weight", (n_pos, vw)),
-        ("vision_model.pre_layrnorm.weight", (vw,)),
-        ("vision_model.pre_layrnorm.bias", (vw,)),
-    ]
-    out += _tower_tensors("vision_model.encoder.layers", vw, v["intermediate_size"],
-                          v["num_hidden_layers"], _HF_CLIP)
-    out += [
-        ("vision_model.post_layernorm.weight", (vw,)),
-        ("vision_model.post_layernorm.bias", (vw,)),
-        ("visual_projection.weight", (proj, vw)),
-    ]
-    return out
-
-
-def vlm_tensors(cfg: dict) -> list[tuple[str, tuple]]:
-    """Qwen2 decoder (HF names) + the repo's ViT tower and 2-layer projector."""
-    t, v = cfg["text_config"], cfg["vision_config"]
-    h, inter = t["hidden_size"], t["intermediate_size"]
-    dh = t.get("head_dim") or h // t["num_attention_heads"]
-    q, kv = t["num_attention_heads"] * dh, t["num_key_value_heads"] * dh
-    out = [("model.embed_tokens.weight", (t["vocab_size"], h))]
-    for i in range(t["num_hidden_layers"]):
-        p = f"model.layers.{i}."
-        out += [
-            (p + "self_attn.q_proj.weight", (q, h)), (p + "self_attn.q_proj.bias", (q,)),
-            (p + "self_attn.k_proj.weight", (kv, h)), (p + "self_attn.k_proj.bias", (kv,)),
-            (p + "self_attn.v_proj.weight", (kv, h)), (p + "self_attn.v_proj.bias", (kv,)),
-            (p + "self_attn.o_proj.weight", (h, q)),
-            (p + "mlp.gate_proj.weight", (inter, h)),
-            (p + "mlp.up_proj.weight", (inter, h)),
-            (p + "mlp.down_proj.weight", (h, inter)),
-            (p + "input_layernorm.weight", (h,)),
-            (p + "post_attention_layernorm.weight", (h,)),
-        ]
-    out.append(("model.norm.weight", (h,)))
-    if not t.get("tie_word_embeddings", True):
-        out.append(("lm_head.weight", (t["vocab_size"], h)))
-    vw, patch = v["hidden_size"], v["patch_size"]
-    out += [
-        ("vision_tower.patch_embed.weight", (vw, 3, patch, patch)),
-        ("vision_tower.patch_embed.bias", (vw,)),
-        ("vision_tower.position_embedding", ((v["image_size"] // patch) ** 2, vw)),
-    ]
-    out += _tower_tensors("vision_tower.blocks", vw, 4 * vw, v["num_hidden_layers"], _VLM_TOWER)
-    out += [
-        ("vision_tower.post_norm.weight", (vw,)),
-        ("vision_tower.post_norm.bias", (vw,)),
-        ("multi_modal_projector.linear_1.weight", (h, vw)),
-        ("multi_modal_projector.linear_1.bias", (h,)),
-        ("multi_modal_projector.linear_2.weight", (h, h)),
-        ("multi_modal_projector.linear_2.bias", (h,)),
-    ]
-    return out
-
-
-TENSORS = {"clip": clip_tensors, "vlm": vlm_tensors}
 
 _NORM_WEIGHT = re.compile(r"(layer_?norm\d?|layrnorm|norm\d?)\.weight$")
 _NORM_BIAS = re.compile(r"(layer_?norm\d?|layrnorm|norm\d?)\.bias$")
@@ -183,18 +97,37 @@ def draw_tensor(seed: int, name: str, shape: tuple, rule, pool=None) -> np.ndarr
     return np.concatenate(parts, axis=0)
 
 
-def vlm_vocab(cfg: dict) -> dict[str, int]:
-    """Word-level vocabulary covering every id, so that any generated id
-    decodes to one word and the text maps back to ids: ``w<id>``, but for
-    the ids the chat template and the configuration name."""
+def _special_words(cfg: dict) -> dict[int, str]:
+    """The ids the chat template and a LLaVA-style configuration name."""
     t = cfg["text_config"]
-    special = {
+    return {
         cfg["image_token_index"]: "<image>",
         t["eos_token_id"]: "<eos>",
         t["bos_token_id"]: "<bos>",
         0: "<unk>", 1: "role_user", 2: "role_assistant", 3: "role_system",
     }
-    return {special.get(i, f"w{i}"): i for i in range(t["vocab_size"])}
+
+
+#: where the configuration states the id of a special word, for the error below
+_SPECIAL_KEYS = {"<image>": "image_token_index", "<eos>": "text_config.eos_token_id",
+                 "<bos>": "text_config.bos_token_id"}
+
+
+def vlm_vocab(model: dict) -> dict[str, int]:
+    """Word-level vocabulary of a ``models.vlm`` entry covering every id, so
+    that any generated id decodes to one word and the text maps back to ids:
+    ``w<id>``, but for the special words: ``{id: word}`` from the entry's
+    listing (``special_words(cfg)``) or, where it gives none, the ids the
+    chat template and the configuration name."""
+    cfg = model["config"]
+    size = cfg["text_config"]["vocab_size"]
+    special = getattr(listing("vlm", model), "special_words", _special_words)(cfg)
+    for i, word in special.items():
+        if not 0 <= i < size:
+            key = _SPECIAL_KEYS.get(word, "the listing's special_words")
+            raise cells.CellError(f"special word {word!r} has id {i} ({key}), outside text_config.vocab_size "
+                                  f"{size}: the tokenizer would lose it")
+    return {special.get(i, f"w{i}"): i for i in range(size)}
 
 
 def _write_tokenizer(model_dir: str, vocab: dict[str, int], unk: str, template: str | None) -> None:
@@ -225,7 +158,7 @@ def ensure_model_dir(root: str, config_name: str, family: str, model: dict) -> s
 
     os.makedirs(model_dir, exist_ok=True)
     cfg, seed, init = model["config"], int(model["weights_seed"]), model["init"]
-    specs = TENSORS[family](cfg)
+    specs = listing(family, model).tensors(cfg)
     with ThreadPoolExecutor(min(12, os.cpu_count() or 4)) as blocks, ThreadPoolExecutor(4) as outer:
         tensors = dict(zip(
             (n for n, _ in specs),
@@ -237,7 +170,7 @@ def ensure_model_dir(root: str, config_name: str, family: str, model: dict) -> s
     with open(os.path.join(model_dir, "config.json"), "w") as f:
         json.dump(cfg, f)
     if family == "vlm":
-        _write_tokenizer(model_dir, vlm_vocab(cfg), "<unk>", CHAT_TEMPLATE)
+        _write_tokenizer(model_dir, vlm_vocab(model), "<unk>", CHAT_TEMPLATE)
         extra = {}
     else:
         t = cfg["text_config"]
