@@ -15,6 +15,7 @@
  * @property {"coalesce"|"continuous"=} scheduler
  * @property {number=} decode_block
  * @property {(string|null)=} quantize
+ * @property {(number|null)=} max_seq
  */
 
 /** @typedef {Object} Deployment

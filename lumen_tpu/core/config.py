@@ -182,6 +182,11 @@ class BackendSettings(BaseModel):
     # bandwidth-bound decode; embeddings/norms/MoE banks stay full
     # precision. Other services ignore this.
     quantize: Literal["int8"] | None = None
+    # VLM only: the longest row (prompt + image tokens + new tokens) a
+    # request may reach. None = the manager's default (2048). The prompt
+    # buckets, the prefill lane's chunk count, the scratch caches and the
+    # page pool's block tables follow it. Other services ignore this.
+    max_seq: int | None = Field(None, ge=256)
 
 
 class ServiceConfig(BaseModel):

@@ -71,7 +71,16 @@ from ...utils.telemetry import record_event
 from ...utils.trace import current_trace, phase
 from . import migration
 from .manager import _PendingGen
-from .paged_kv import DEFAULT_PAGE_SIZE, PagedKVPool, PoolExhausted, page_bytes
+from .modeling import prefix_ladder
+from .paged_kv import (
+    DEFAULT_PAGE_SIZE,
+    LATENT_PAGE_SIZE,
+    PagedKVPool,
+    PoolExhausted,
+    WindowPages,
+    page_bytes,
+    window_pool_pages,
+)
 from .prefix_cache import PrefixCache, chunk_keys, prefix_cache_enabled
 
 logger = logging.getLogger(__name__)
@@ -172,6 +181,7 @@ class _PrefillJob:
     length: int = 0  # live prompt tokens (host int)
     last_logits: object = None  # logits of the most recent chunk
     last_off: int = 0  # offset of that chunk
+    chunk: int = 0  # tokens a chunk of this job (``prefill_chunk``, or its even share)
     #: shared prefix pages seeded into the scratch; the JOB holds one
     #: reference on each until admission or cancellation.
     shared: list = field(default_factory=list)
@@ -252,14 +262,33 @@ class ContinuousScheduler:
         telemetry.set_capacity(f"device:{self.name}", 1.0, union=True)
         self.n_slots = slots
         self.block = block
+        dec = generator.cfg.decoder
         self.page_size = page_size or env_int(
-            "LUMEN_VLM_PAGE_SIZE", DEFAULT_PAGE_SIZE, minimum=8, maximum=256
+            "LUMEN_VLM_PAGE_SIZE", LATENT_PAGE_SIZE if dec.latent else DEFAULT_PAGE_SIZE,
+            minimum=8, maximum=256,
         )
         max_pages = -(-generator.max_seq // self.page_size)
         if pages is None:
             pages = slots * max_pages + 1  # slot-era footprint fallback
-        self.kv = PagedKVPool(pages, self.page_size, slots, max_pages)
-        self.pool = generator.init_pool(slots, pages=pages, page_size=self.page_size)
+        window = window_pages = None
+        if dec.latent:
+            # Window layers keep pages of their own and free those behind
+            # the window; what shares or exports whole rows of pages (prefix
+            # cache, spill tier, speculative verify, migration) has no
+            # latent form yet and is refused or off, never silently wrong.
+            if prefix_cache_enabled() or env_int("LUMEN_VLM_SPEC_K", 0, minimum=0, maximum=15):
+                raise NotImplementedError(
+                    "LUMEN_VLM_PREFIX_BYTES and LUMEN_VLM_SPEC_K are not implemented for a "
+                    "latent decoder (window layers free the pages a shared prefix would need)"
+                )
+            window_pages = window_pool_pages(generator.cfg, self.page_size, slots, block)
+            window = WindowPages(
+                window_pages, self.page_size, slots, max_pages, dec.sliding_window
+            )
+        self.kv = PagedKVPool(pages, self.page_size, slots, max_pages, window=window)
+        self.pool = generator.init_pool(
+            slots, pages=pages, page_size=self.page_size, window_pages=window_pages
+        )
         if mesh is not None:
             from ...parallel.sharding import replicate
 
@@ -268,10 +297,25 @@ class ContinuousScheduler:
         # chunk lane, one chunk per job per scheduler turn; the chunk is
         # rounded to a page multiple so scratch caches scatter cleanly
         # into pages.
+        # 256 tokens at the default max_seq of 2048, an eighth of a longer
+        # one: a prompt is at most eight chunks (eight lane turns to its
+        # first token) however long rows may get, and every chunk streams
+        # the decoder's weights once, so longer rows get longer chunks.
         chunk = prefill_chunk or env_int(
-            "LUMEN_VLM_PREFILL_CHUNK", 256, minimum=32, maximum=4096
+            "LUMEN_VLM_PREFILL_CHUNK", max(256, -(-generator.max_seq // 8)),
+            minimum=32, maximum=4096,
         )
         self.prefill_chunk = -(-chunk // self.page_size) * self.page_size
+        # With a longer max_seq a prompt's chunks are its even shares (whole
+        # pages) and the tail is padded up to one: every chunk of a prompt
+        # bucket then runs ONE program, where a short tail would compile a
+        # second as large. At the default max_seq the lane is as it was
+        # (full chunks, then the tail as a program of its own) only so that
+        # the benchmark's accepted cells keep the programs they were measured
+        # with: even shares would serve them too (a 320-token span as two
+        # chunks of 160), and ROADMAP.md S-queue holds the change that makes
+        # them the one path and deletes this flag, measured on those cells.
+        self._even_chunks = generator.max_seq > 2048
         from ...utils.env import env_float
 
         # Decode pacing floor: minimum wall time per decode STEP (a block
@@ -311,6 +355,9 @@ class ContinuousScheduler:
         # degrades exactly as the pre-spill engine did, minus the bare
         # RuntimeError (sampled victims get the typed retryable shed).
         self._spill_budget = env_int("LUMEN_VLM_SPILL_BYTES", 256 << 20, minimum=0)
+        if dec.latent and self._spill_budget:
+            logger.info("VLM spill tier off: a latent decoder's preempted rows restart from the prompt")
+            self._spill_budget = 0
         self._spill_max = env_int("LUMEN_VLM_SPILL_MAX", 32, minimum=0)
         self._spill_arena: ShmArena | None = None  # created on first spill
         self._spill_ledger: dict[int, _SpillRecord] = {}  # id(req) -> record
@@ -385,6 +432,15 @@ class ContinuousScheduler:
         self.lane_jobs = 0
         self.first_token_ms_sum = 0.0
         self.first_token_count = 0
+        # A latent decoder's own counters. The indexer's are host arithmetic
+        # over the lengths the loop dispatches (a query whose context is
+        # over ``index_topk`` has every causal key scored, in each full
+        # layer); the expert layers' come back from the device with each
+        # block's tokens (``Generator._apply``).
+        self._indexer_layers = dec.layers_of("full_attention") if dec.latent else 0
+        self.indexer_rows = 0
+        self.indexer_keys_scored = 0
+        self.moe_stats = np.zeros((4,), np.int64)  # routed, held, experts touched, calls
         self._thread = threading.Thread(target=self._loop, name="vlm-continuous", daemon=True)
         self._thread.start()
         ref = weakref.ref(self)  # registry must not pin the pool/params
@@ -439,6 +495,18 @@ class ContinuousScheduler:
                 "migrated_in": s.migrated_in,
                 "migrate_in_rejected": s.migrate_in_rejected,
             }
+            if s.kv.window is not None:
+                out["window_pages_freed"] = s.kv.window.freed_behind
+                out["window_pages_live"] = s.kv.window.pages_live
+                out["window_pages_total"] = s.kv.window.pages_total
+                out["indexer_rows"] = s.indexer_rows
+                out["indexer_keys_scored"] = s.indexer_keys_scored
+            if s.gen._counts_experts:
+                routed, held, touched, calls = (int(v) for v in s.moe_stats)
+                out["moe_tokens_routed"] = routed
+                out["moe_tokens_held"] = held
+                out["moe_experts_touched"] = touched
+                out["moe_layer_calls"] = calls
             if s._spill_arena is not None:
                 arena = s._spill_arena.stats()
                 out["spill_arena_segments"] = arena["segments"]
@@ -843,6 +911,8 @@ class ContinuousScheduler:
         else:
             bt_row = self.kv.admit(slot, n)
             bt_dev = bt_row
+            if self.kv.window is not None:
+                bt_dev = np.stack([bt_row, self.kv.window.tables[slot]])
         try:
             self.pool = self.gen._admit(
                 self.pool, slot, caches1, tok0, seen1, length,
@@ -989,11 +1059,16 @@ class ContinuousScheduler:
         # Sized to the padded span only (tail chunks shrink to fit): the
         # scratch must stay within what a block-table row can address.
         scratch_len = self._admit_kv_len(span)
+        chunk = self.prefill_chunk
+        if self._even_chunks:
+            share = -(-span // -(-span // chunk))  # span over its chunk count
+            chunk = min(chunk, -(-share // self.page_size) * self.page_size)
         job = _PrefillJob(
             request=req,
             caches=self.gen.new_prefill_cache(scratch_len),
             scratch_len=scratch_len,
             length=n,
+            chunk=chunk,
         )
         # Lane jobs reuse cached prefixes too: seed the scratch from the
         # shared pages and start chunking AFTER the covered span. The job
@@ -1054,11 +1129,16 @@ class ContinuousScheduler:
         # are host ints, so each (span, off) pair is one tiny compiled
         # slice; counts are bounded by the prompt buckets over the chunk
         # size.
-        c = min(self.prefill_chunk, int(req.embeds.shape[1]) - off)
+        c = min(job.chunk, int(req.embeds.shape[1]) - off)
         with phase("vlm.prefill_chunk", rid=req.rid, offset=off, tokens=c):
             chunk = req.embeds[:, off : off + c]
-            positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
             valid = jnp.asarray([min(job.length, off + c)], jnp.int32)
+            if self._even_chunks and c < job.chunk and off + job.chunk <= job.scratch_len:
+                # the tail, padded to the job's chunk: the padding's rows land
+                # past the prompt in the scratch, where ``valid`` hides them
+                chunk = jnp.pad(chunk, ((0, 0), (0, job.chunk - c), (0, 0)))
+                c = job.chunk
+            positions = jnp.broadcast_to(jnp.arange(off, off + c)[None, :], (1, c))
             job.last_logits, job.caches = self.gen._prefill_chunk(
                 self.params, job.caches, chunk, positions,
                 jnp.asarray(off, jnp.int32), valid,
@@ -1066,6 +1146,19 @@ class ContinuousScheduler:
         job.last_off = off
         job.offset = off + c
         self.chunks_run += 1
+        if self._indexer_layers:
+            # the chunk ran against the smallest rung of the scratch's key ladder over its end
+            rung = next(n for n in prefix_ladder(job.scratch_len) if n >= off + c)
+            self._count_indexer(c, rung)
+
+    def _count_indexer(self, rows: int, keys: int) -> None:
+        """``rows`` queries were dispatched through each full layer against
+        ``keys`` key slots: where that is over ``index_topk`` the indexer
+        scored every slot for every row (masked ones and idle rows too: this
+        counts the work dispatched, not the work a tighter dispatch needs)."""
+        if keys > self.gen.cfg.decoder.index_topk:
+            self.indexer_rows += self._indexer_layers * rows
+            self.indexer_keys_scored += self._indexer_layers * rows * keys
 
     def _lane_pages_ready(self, job: _PrefillJob) -> bool:
         """Whether the free list covers a finished job's row. Shared
@@ -1696,9 +1789,11 @@ class ContinuousScheduler:
                 ql = None
                 self.pool, self._rng, toks = self.gen._step_block(
                     self.params, self.pool,
-                    jnp.asarray(self.kv.block_tables[:, :bucket]),
+                    jnp.asarray(self.kv.device_tables(bucket)),
                     self._rng, block=self.block,
                 )
+                if self._indexer_layers:
+                    self._count_indexer(self.n_slots * self.block, bucket * self.page_size)
         self.blocks_run += 1
         self._occ_rows += active
         self._occ_blocks += 1
@@ -1715,6 +1810,13 @@ class ContinuousScheduler:
                         self.pool["eos"], self.pool["cur_tok"],
                     )
                 )
+            elif self.gen._counts_experts:
+                cur_tok = None
+                toks_np, n_gen, done, eos, moe_block = jax.device_get(
+                    (toks, self.pool["n_gen"], self.pool["done"], self.pool["eos"],
+                     self.pool["moe_block"])
+                )
+                self.moe_stats += moe_block  # int64 here; the device's int32 is one block's
             else:
                 cur_tok = None
                 toks_np, n_gen, done, eos = jax.device_get(
@@ -1780,6 +1882,9 @@ class ContinuousScheduler:
                 }
                 if drafts:
                     width = self.spec_k + 1
+        if self.kv.window is not None:
+            for idx, slot in self._slots.items():
+                self.kv.window.trim(idx, slot.prompt_len + len(slot.tokens))
         self._ensure_growth(horizon=width or None)
         # Growth may have preempted a drafted row; verify only helps if a
         # surviving row still carries a draft.
@@ -1803,7 +1908,12 @@ class ContinuousScheduler:
         bucket = 1
         while bucket < maxp_live:
             bucket *= 2
-        return width, drafts, min(bucket, self.kv.max_pages)
+        if bucket * 2 > self.kv.max_pages:
+            # the ladder's last rung is the whole table: where max_pages is
+            # no power of two (4,608 tokens of 64: 72) the rung under it
+            # would be a second program for the same rows
+            bucket = self.kv.max_pages
+        return width, drafts, bucket
 
     def _emit_block(
         self, step, active, t0, t1, width, ql, toks_np, n_gen, done, eos, cur_tok

@@ -46,6 +46,22 @@ DECODER_RULES = [
     (r"model\.layers\.(\d+)\.mlp\.shared_expert\.up_proj\.weight", r"decoder/layers_\1/mlp/shared/up_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.mlp\.shared_expert\.down_proj\.weight", r"decoder/layers_\1/mlp/shared/down_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.mlp\.shared_expert_gate\.weight", r"decoder/layers_\1/mlp/shared_gate/kernel", linear_kernel),
+    # Latent attention (``dots3_note``): low-rank q and kv projections, one
+    # up-projection folded into the query at run time (a bare kernel, not a
+    # Dense), a gate a head, the indexer, and the router's selection bias.
+    (r"model\.layers\.(\d+)\.self_attn\.(q_a_proj|q_b_proj)\.weight", r"decoder/layers_\1/attn/\2/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.kv_a_proj_with_mqa\.weight", r"decoder/layers_\1/attn/kv_a_proj/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.kv_b_proj\.weight", r"decoder/layers_\1/attn/kv_b_proj", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.q_a_layernorm\.weight", r"decoder/layers_\1/attn/q_a_norm/scale", None),
+    (r"model\.layers\.(\d+)\.self_attn\.kv_a_layernorm\.weight", r"decoder/layers_\1/attn/kv_a_norm/scale", None),
+    (r"model\.layers\.(\d+)\.self_attn\.attn_gate\.weight", r"decoder/layers_\1/attn/attn_gate/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.indexer\.wq_b\.weight", r"decoder/layers_\1/attn/index_q/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.indexer\.wk\.weight", r"decoder/layers_\1/attn/index_k/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.indexer\.weights_proj\.weight", r"decoder/layers_\1/attn/index_w/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.self_attn\.indexer\.k_norm\.weight", r"decoder/layers_\1/attn/index_k_norm/scale", None),
+    (r"model\.layers\.(\d+)\.self_attn\.indexer\.k_norm\.bias", r"decoder/layers_\1/attn/index_k_norm/bias", None),
+    (r"model\.layers\.(\d+)\.mlp\.gate\.e_score_correction_bias", r"decoder/layers_\1/mlp/select_bias", None),
+    (r"model\.layers\.(\d+)\.mlp\.shared_experts\.(gate_proj|up_proj|down_proj)\.weight", r"decoder/layers_\1/mlp/shared/\2/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.input_layernorm\.weight", r"decoder/layers_\1/input_norm/scale", None),
     (r"model\.layers\.(\d+)\.post_attention_layernorm\.weight", r"decoder/layers_\1/post_attn_norm/scale", None),
     (r"model\.norm\.weight", r"decoder/final_norm/scale", None),

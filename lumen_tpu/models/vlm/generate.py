@@ -102,16 +102,47 @@ class Generator:
 
     # -- shared pieces ------------------------------------------------------
 
-    def _decode(self, params, embeds, positions, caches, offset, kv_valid_len):
-        return self.model.apply(
-            {"params": params},
-            embeds,
-            positions,
-            caches,
-            offset,
-            kv_valid_len,
-            method=VLMModel.decode,
+    @property
+    def _counts_experts(self) -> bool:
+        """Whether the decoder's expert layers count what they route
+        (``moe_stats``: a held share of the bank): the programs below then
+        carry the sums to the pool, where the scheduler reads them."""
+        return self.cfg.decoder.moe_held is not None
+
+    def _apply(self, params, method, *args):
+        """``(logits, caches, moe stats [4] int32 or None)`` of one decoder
+        pass."""
+        if not self._counts_experts:
+            return (*self.model.apply({"params": params}, *args, method=method), None)
+        (logits, caches), state = self.model.apply(
+            {"params": params}, *args, method=method, mutable=["moe_stats"]
         )
+        stats = sum(jax.tree.leaves(state), jnp.zeros((4,), jnp.int32))
+        return logits, caches, stats
+
+    def _decode(self, params, embeds, positions, caches, offset, kv_valid_len):
+        """A prefill segment into contiguous ``caches`` (a latent decoder's
+        carry one more entry, the expert layers' counts so far)."""
+        if not self._counts_experts:
+            return self._apply(
+                params, VLMModel.decode, embeds, positions, caches, offset, kv_valid_len
+            )[:2]
+        valid = positions < kv_valid_len[:, None]
+        logits, new, stats = self._apply(
+            params, VLMModel.decode, embeds, positions, caches[:-1], offset, kv_valid_len, valid
+        )
+        counts = caches[-1]["moe_stats"]
+        return logits, [*new, {"moe_stats": counts.at[0].add(stats)}]
+
+    def _scratch(self, batch: int, kv_len: int) -> list[dict]:
+        caches = init_kv_cache(self.cfg, batch, kv_len, self.cache_dtype)
+        if self._counts_experts:
+            caches.append({"moe_stats": jnp.zeros((batch, 4), jnp.int32)})
+        return caches
+
+    def _no_latent(self, what: str) -> None:
+        if self.cfg.decoder.latent:
+            raise NotImplementedError(f"{what} is not implemented for a latent decoder")
 
     def _embed(self, params, ids):
         return self.model.apply({"params": params}, ids, method=VLMModel.embed_tokens)
@@ -132,7 +163,7 @@ class Generator:
 
     def _prefill_core(self, params, embeds, positions, lengths, kv_len: int | None = None):
         b = embeds.shape[0]
-        caches = init_kv_cache(self.cfg, b, kv_len or self.max_seq, self.cache_dtype)
+        caches = self._scratch(b, kv_len or self.max_seq)
         logits, caches = self._decode(
             params, embeds, positions, caches, jnp.zeros((), jnp.int32), lengths
         )
@@ -157,6 +188,7 @@ class Generator:
         kv_len: int | None = None,  # static: KV bucket (defaults to max_seq)
     ):
         cfg = self.cfg
+        self._no_latent("the fused generate program")
         b = embeds.shape[0]
         caches, last_logits = self._prefill_core(params, embeds, positions, lengths, kv_len)
         seen = self._seen_from_prompt(prompt_ids, lengths)
@@ -289,6 +321,7 @@ class Generator:
         self, params, caches, cur_tok, cur_len, seen, rng,
         temperature, top_p, do_sample, repetition_penalty,
     ):
+        self._no_latent("contiguous-cache decode")
         b = cur_tok.shape[0]
         seen = seen.at[jnp.arange(b), cur_tok].max(True)
         tok_embed = self._embed(params, cur_tok[:, None]).astype(self.cache_dtype)
@@ -312,28 +345,43 @@ class Generator:
     # retire returns the pages — long and short generations share the pool
     # instead of every slot paying a contiguous max_seq region.
 
-    def _decode_paged(self, params, embeds, positions, caches, block_tables, offset, kv_len):
-        return self.model.apply(
-            {"params": params},
-            embeds,
-            positions,
-            caches,
-            block_tables,
-            offset,
-            kv_len,
-            method=VLMModel.decode_paged,
+    def _decode_paged(
+        self, params, embeds, positions, caches, block_tables, offset, kv_len, active=None
+    ):
+        """``(logits, caches, moe stats or None)``; ``active`` [B] marks the
+        rows whose tokens the expert layers count."""
+        valid = None if active is None else active[:, None]
+        return self._apply(
+            params, VLMModel.decode_paged, embeds, positions, caches, block_tables,
+            offset, kv_len, valid,
         )
 
-    def init_pool(self, slots: int, pages: int | None = None, page_size: int = 16) -> dict:
+    @staticmethod
+    def _page_size_of(pool: dict) -> int:
+        first = pool["caches"][0]
+        return first["c"].shape[1] if "c" in first else first["k"].shape[2]
+
+    def init_pool(
+        self, slots: int, pages: int | None = None, page_size: int = 16,
+        window_pages: int | None = None,
+    ) -> dict:
         """Fresh all-slots-free paged pool state (host-callable, device
         arrays). ``pages`` defaults to the slot-era footprint (every slot
         could hold max_seq) — serving sizes it from HBM headroom instead
-        (``paged_kv.resolve_pool_pages``)."""
+        (``paged_kv.resolve_pool_pages``). ``window_pages`` sizes a latent
+        decoder's window layers (``paged_kv.window_pool_pages``)."""
         cfg = self.cfg
         if pages is None:
             pages = slots * (-(-self.max_seq // page_size)) + 1
+        # ``moe_stats``: the expert layers' counts since the last decode block
+        # (admissions add theirs); a block hands the sum over as ``moe_block``
+        # and starts the next from zero, so the int32 sums never grow past one
+        # block's work and the scheduler keeps the running totals in int64.
+        names = ("moe_stats", "moe_block") if self._counts_experts else ()
+        extra = {name: jnp.zeros((4,), jnp.int32) for name in names}
         return dict(
-            caches=init_paged_kv_cache(cfg, pages, page_size, self.cache_dtype),
+            **extra,
+            caches=init_paged_kv_cache(cfg, pages, page_size, self.cache_dtype, window_pages),
             cur_tok=jnp.zeros((slots,), jnp.int32),
             cur_len=jnp.zeros((slots,), jnp.int32),
             seen=jnp.zeros((slots, cfg.decoder.vocab_size), bool),
@@ -358,19 +406,42 @@ class Generator:
         scatter needs no masking."""
         z = jnp.zeros((), jnp.int32)
         s = jnp.asarray(slot, jnp.int32)
-        page = pool["caches"][0]["k"].shape[2]
-        lb = caches1[0]["k"].shape[2]
-        nseg = lb // page
-        kvh = pool["caches"][0]["k"].shape[1]
-        dh = pool["caches"][0]["k"].shape[3]
-        dst = bt_row[:nseg]
+        extra = {}
+        if self.cfg.decoder.latent:
+            # Latent rows: [1, Lb, width] scratch -> [nseg, page, width]
+            # pages; ``bt_row`` [2, MAXP] is the full layers' table and the
+            # window layers', whose entries behind the window are the dump
+            # page (``paged_kv.WindowPages.install``).
+            from .modeling import FULL_ATTENTION
 
-        def scatter(pages_arr, pre):
-            seg = pre[0].reshape(kvh, nseg, page, dh).transpose(1, 0, 2, 3)
-            return pages_arr.at[dst].set(seg.astype(pages_arr.dtype))
+            page = self._page_size_of(pool)
+            nseg = caches1[0]["c"].shape[1] // page
+            caches = []
+            for i, (dst_layer, pre) in enumerate(zip(pool["caches"], caches1)):
+                dst = bt_row[0 if self.cfg.decoder.layer_kind(i) == FULL_ATTENTION else 1, :nseg]
+                caches.append({
+                    name: arr.at[dst].set(
+                        pre[name][0].reshape(nseg, page, -1).astype(arr.dtype)
+                    )
+                    for name, arr in dst_layer.items()
+                })
+            if self._counts_experts:
+                extra["moe_stats"] = pool["moe_stats"] + caches1[-1]["moe_stats"].sum(0)
+        else:
+            page = pool["caches"][0]["k"].shape[2]
+            lb = caches1[0]["k"].shape[2]
+            nseg = lb // page
+            kvh = pool["caches"][0]["k"].shape[1]
+            dh = pool["caches"][0]["k"].shape[3]
+            dst = bt_row[:nseg]
 
-        caches = jax.tree.map(scatter, pool["caches"], caches1)
+            def scatter(pages_arr, pre):
+                seg = pre[0].reshape(kvh, nseg, page, dh).transpose(1, 0, 2, 3)
+                return pages_arr.at[dst].set(seg.astype(pages_arr.dtype))
+
+            caches = jax.tree.map(scatter, pool["caches"], caches1)
         return dict(
+            **extra,
             caches=caches,
             cur_tok=pool["cur_tok"].at[s].set(tok0[0]),
             cur_len=pool["cur_len"].at[s].set(length[0].astype(jnp.int32)),
@@ -393,6 +464,7 @@ class Generator:
         that the resume scatter writes straight back to the dump page.
         The caller ships the result host-side with ONE ``jax.device_get``
         (the spill tier's per-victim transfer budget)."""
+        self._no_latent("the spill tier's export")
         s = jnp.asarray(slot, jnp.int32)
         return dict(
             pages=jax.tree.map(lambda c: c[page_ids], pool["caches"]),
@@ -413,6 +485,7 @@ class Generator:
         but not-yet-emitted next token, so a resumed greedy row continues
         token-identically and a resumed sampled row continues its own
         draw without splicing."""
+        self._no_latent("the spill tier's resume")
         s = jnp.asarray(slot, jnp.int32)
         z = jnp.zeros((), jnp.int32)
         caches = jax.tree.map(
@@ -443,7 +516,7 @@ class Generator:
         ``cur_len + block`` before dispatching."""
         cfg = self.cfg
         b = pool["cur_tok"].shape[0]
-        capacity = block_tables.shape[1] * pool["caches"][0]["k"].shape[2]
+        capacity = block_tables.shape[-1] * self._page_size_of(pool)
 
         def body(carry, _):
             pool, rng = carry
@@ -457,16 +530,19 @@ class Generator:
             # Free slots hold cur_len=0 and done rows stop advancing, so the
             # clamp only guards a full slot writing past its block table.
             pos = jnp.minimum(pool["cur_len"], capacity - 1)
-            logits, caches = self._decode_paged(
-                params, tok_embed, pos[:, None], pool["caches"], block_tables, pos, pos + 1
+            logits, caches, stats = self._decode_paged(
+                params, tok_embed, pos[:, None], pool["caches"], block_tables, pos, pos + 1,
+                active,
             )
             rng, sub = jax.random.split(rng)
             nxt = self._sample_next(
                 sub, logits[:, 0], seen,
                 pool["temperature"], pool["top_p"], pool["do_sample"], pool["rep"],
             ).astype(jnp.int32)
+            counted = {} if stats is None else {"moe_stats": pool["moe_stats"] + stats}
             new_pool = dict(
                 pool,
+                **counted,
                 caches=caches,
                 cur_tok=nxt,
                 cur_len=pool["cur_len"] + active.astype(jnp.int32),
@@ -478,6 +554,9 @@ class Generator:
             return (new_pool, rng), tok
 
         (pool, rng), toks = jax.lax.scan(body, (pool, rng), None, length=block)
+        if self._counts_experts:
+            counts = pool["moe_stats"]
+            pool = dict(pool, moe_block=counts, moe_stats=jnp.zeros_like(counts))
         return pool, rng, toks.T  # [B, block]
 
     # -- chunked prefill lane ------------------------------------------------
@@ -493,7 +572,7 @@ class Generator:
 
     def new_prefill_cache(self, kv_len: int):
         """Contiguous batch-1 scratch cache for one chunked prefill."""
-        return init_kv_cache(self.cfg, 1, kv_len, self.cache_dtype)
+        return self._scratch(1, kv_len)
 
     def _prefill_chunk_impl(self, params, caches, embeds, positions, offset, valid_len):
         """One prompt chunk through the decoder: writes K/V at ``offset``
@@ -527,6 +606,7 @@ class Generator:
         with the dump page 0; pad segments land on slots the suffix chunks
         overwrite (decode writes K/V before attending) or the valid-length
         mask hides."""
+        self._no_latent("seeding a scratch from a cached prefix")
         nseg = page_ids.shape[0]
 
         def seed(dst, src):
@@ -553,6 +633,7 @@ class Generator:
         slots' KV writes land above the row's final ``cur_len`` where the
         valid-length mask hides them until real tokens overwrite them."""
         cfg = self.cfg
+        self._no_latent("speculative verify")
         b = pool["cur_tok"].shape[0]
         capacity = block_tables.shape[1] * pool["caches"][0]["k"].shape[2]
         toks_in = jnp.asarray(draft, jnp.int32).at[:, 0].set(pool["cur_tok"])
@@ -561,7 +642,7 @@ class Generator:
         pos0 = jnp.minimum(pool["cur_len"], capacity - width)
         positions = pos0[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
         embeds = self._embed(params, toks_in).astype(self.cache_dtype)
-        logits, caches = self._decode_paged(
+        logits, caches, _ = self._decode_paged(
             params, embeds, positions, pool["caches"], block_tables, pos0, pos0 + 1
         )
 

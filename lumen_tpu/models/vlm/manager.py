@@ -676,6 +676,16 @@ class VLMManager:
         # A prompt bucket is usable only if prompt + vision tokens + the
         # decode budget fit in the KV buffer.
         v = self.vision_tokens
+        if self.max_seq > 2048:
+            # Ladders are written for the default max_seq of 2048 (the
+            # presets' stop at 512, the default at 1024); a configured
+            # longer max_seq continues them by doubling, up to the longest
+            # prompt that still leaves room for the image and the decode cap.
+            top = (self.max_seq - v - self.max_new_cap) // 128 * 128
+            while self.prefill_buckets[-1] * 2 <= top:
+                self.prefill_buckets.append(self.prefill_buckets[-1] * 2)
+            if top > self.prefill_buckets[-1]:
+                self.prefill_buckets.append(top)
         self.prefill_buckets = [
             b for b in self.prefill_buckets if b - 1 + v + self.max_new_cap + 1 <= self.max_seq
         ]
@@ -747,14 +757,16 @@ class VLMManager:
             from ...runtime.fleet import batcher_name
             from ...utils.env import env_int
             from .continuous import ContinuousScheduler
-            from .paged_kv import DEFAULT_PAGE_SIZE, resolve_pool_pages
+            from .paged_kv import DEFAULT_PAGE_SIZE, LATENT_PAGE_SIZE, resolve_pool_pages
 
             self._page_size = env_int(
-                "LUMEN_VLM_PAGE_SIZE", DEFAULT_PAGE_SIZE, minimum=8, maximum=256
+                "LUMEN_VLM_PAGE_SIZE",
+                LATENT_PAGE_SIZE if self.cfg.decoder.latent else DEFAULT_PAGE_SIZE,
+                minimum=8, maximum=256,
             )
             self._pool_pages, self.pool_source = resolve_pool_pages(
                 self.cfg, self._page_size, self.gen_slots, self.max_seq,
-                dtype_bytes=jnp.dtype(compute).itemsize,
+                dtype_bytes=jnp.dtype(compute).itemsize, block=self.gen_block,
             )
             plan = self.fleet_plan
 

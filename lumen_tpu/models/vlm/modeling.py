@@ -33,6 +33,28 @@ import jax.numpy as jnp
 from ...ops.attention import attention, attention_cached, paged_attention, repeat_kv
 from ...ops.quant import QDense
 
+FULL_ATTENTION = "full_attention"
+WINDOW_ATTENTION = "sliding_attention"
+
+
+@dataclass(frozen=True)
+class LatentDims:
+    """Sizes of one kind of latent attention layer: low-rank query and
+    key/value projections, ``heads`` x (``nope`` no-position + ``rope``
+    RoPE) query/key values a head, ``v_dim`` value values a head."""
+
+    heads: int
+    q_lora: int
+    kv_lora: int
+    nope: int
+    rope: int
+    v_dim: int
+    rope_theta: float
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / float(self.nope + self.rope) ** 0.5
+
 
 @dataclass(frozen=True)
 class DecoderConfig:
@@ -77,10 +99,48 @@ class DecoderConfig:
     # (20 tok/s vs 3896 bf16 — the convert lowered to non-vectorized
     # code), so both formulations ship and the bench A/Bs them.
     weight_quant_kernel: str = "dequant"  # "dequant" | "dynamic"
+    # --- Latent attention decoder (``model_type`` ``dots3_note``). Empty
+    # ``layer_types`` = the Qwen2 layout above, untouched. Otherwise one
+    # kind a layer: "full_attention" layers use ``latent_full`` and a
+    # learned indexer that keeps the ``index_topk`` causal keys of largest
+    # index score; "sliding_attention" layers use ``latent_window`` and
+    # see the last ``sliding_window`` keys, the token itself included.
+    # Every head's output passes a sigmoid gate computed from the layer's
+    # normed input. ``latent_rescale`` multiplies the normed latents by
+    # sqrt(hidden / rank). The cache holds one latent row a token
+    # (``init_paged_kv_cache``), never K/V per head.
+    layer_types: tuple[str, ...] = ()
+    latent_full: LatentDims | None = None
+    latent_window: LatentDims | None = None
+    sliding_window: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    latent_rescale: bool = False
+    # --- Routing rule and the held share of the bank (``parallel.moe``):
+    # "sigmoid" scores with a learned selection bias, gates renormalised
+    # over the selected and scaled; an ungated shared expert. ``moe_held``
+    # = (lo, hi): this chip holds experts lo..hi-1 of ``moe_experts`` (the
+    # router's width) and computes their part of the layer alone.
+    moe_scoring: str = "softmax"
+    moe_select_bias: bool = False
+    moe_routed_scale: float = 1.0
+    moe_shared_gated: bool = True
+    moe_held: tuple[int, int] | None = None
 
     @property
     def dim_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.heads
+
+    @property
+    def latent(self) -> bool:
+        return bool(self.layer_types)
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_types[i]
+
+    def layers_of(self, kind: str) -> int:
+        return sum(1 for k in self.layer_types if k == kind)
 
     def is_moe_layer(self, i: int) -> bool:
         return (
@@ -143,7 +203,15 @@ class VLMConfig:
         ``vision_config``) or a flat Qwen2-style decoder config."""
         text = cfg.get("text_config", cfg)
         vis = cfg.get("vision_config", {})
-        decoder = DecoderConfig(
+        if text.get("model_type") == "dots3_note":
+            decoder = _dots3_decoder(text)
+        else:
+            decoder = cls._qwen2_decoder(cfg, text)
+        return cls._with_tower(cfg, text, vis, decoder)
+
+    @staticmethod
+    def _qwen2_decoder(cfg: dict[str, Any], text: dict[str, Any]) -> DecoderConfig:
+        return DecoderConfig(
             hidden_size=text.get("hidden_size", 896),
             layers=text.get("num_hidden_layers", 24),
             heads=text.get("num_attention_heads", 14),
@@ -166,6 +234,9 @@ class VLMConfig:
             moe_norm_topk=text.get("norm_topk_prob", not text.get("num_experts", 0)),
             moe_dense_layers=tuple(text.get("mlp_only_layers", ())),
         )
+
+    @classmethod
+    def _with_tower(cls, cfg, text, vis, decoder: DecoderConfig) -> "VLMConfig":
         vision = VisionTowerConfig(
             image_size=vis.get("image_size", 1024),
             patch_size=vis.get("patch_size", 64),
@@ -185,6 +256,62 @@ class VLMConfig:
         )
 
 
+def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
+    """``model_type`` ``dots3_note``: latent attention of two kinds by
+    ``layer_types``, leading dense layers, sigmoid-routed experts with one
+    ungated shared expert. ``n_routed_experts`` counts the experts HELD
+    here; ``ep_size`` chips share each layer (default 1: the whole bank),
+    so the router is ``n_routed_experts * ep_size`` wide and this chip,
+    ``ep_rank``, holds the range ``[rank * n, (rank + 1) * n)``."""
+    n = t["num_hidden_layers"]
+    kinds = tuple(t["layer_types"][:n])
+    if len(kinds) != n or set(kinds) - {FULL_ATTENTION, WINDOW_ATTENTION}:
+        raise ValueError(f"layer_types must name {n} full_attention/sliding_attention layers, got {kinds}")
+    held, ep, rank = t["n_routed_experts"], t.get("ep_size", 1), t.get("ep_rank", 0)
+    dense = t.get("first_k_dense_replace", 0)
+    return DecoderConfig(
+        hidden_size=t["hidden_size"],
+        layers=n,
+        heads=t["num_attention_heads"],
+        kv_heads=t.get("num_key_value_heads", t["num_attention_heads"]),
+        intermediate_size=t["intermediate_size"],
+        vocab_size=t["vocab_size"],
+        rope_theta=float(t["rope_theta"]),
+        rms_norm_eps=t.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=t.get("max_position_embeddings", 32768),
+        tie_word_embeddings=t.get("tie_word_embeddings", False),
+        moe_experts=held * ep,
+        moe_top_k=t["num_experts_per_tok"],
+        moe_intermediate_size=t["moe_intermediate_size"],
+        moe_shared_intermediate=t.get("n_shared_experts", 0) * t["moe_intermediate_size"],
+        moe_every=t.get("moe_layer_freq", 1),
+        moe_norm_topk=t.get("norm_topk_prob", True),
+        moe_dense_layers=tuple(range(dense)),
+        layer_types=kinds,
+        latent_full=LatentDims(
+            heads=t["num_attention_heads"], q_lora=t["q_lora_rank"], kv_lora=t["kv_lora_rank"],
+            nope=t["qk_nope_head_dim"], rope=t["qk_rope_head_dim"], v_dim=t["v_head_dim"],
+            rope_theta=float(t["rope_theta"]),
+        ),
+        latent_window=LatentDims(
+            heads=t["swa_num_attention_heads"], q_lora=t["swa_q_lora_rank"],
+            kv_lora=t["swa_kv_lora_rank"], nope=t["swa_qk_nope_head_dim"],
+            rope=t["swa_qk_rope_head_dim"], v_dim=t["swa_v_head_dim"],
+            rope_theta=float(t["swa_rope_theta"]),
+        ),
+        sliding_window=t["sliding_window_size"],
+        index_heads=t["index_n_heads"],
+        index_head_dim=t["index_head_dim"],
+        index_topk=t["index_topk"],
+        latent_rescale=bool(t.get("apply_mla_qkv_lora_rescale", False)),
+        moe_scoring=t.get("scoring_func", "softmax"),
+        moe_select_bias=t.get("topk_method") == "noaux_tc",
+        moe_routed_scale=float(t.get("routed_scaling_factor", 1.0)),
+        moe_shared_gated=False,
+        moe_held=(rank * held, (rank + 1) * held),
+    )
+
+
 # -- KV cache ---------------------------------------------------------------
 
 
@@ -192,6 +319,8 @@ def init_kv_cache(cfg: VLMConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) 
     """Preallocated per-layer cache: the reference's zero-length grow-by-
     concat cache (``onnxrt_backend.py:731-755``) becomes a fixed buffer."""
     d = cfg.decoder
+    if d.latent:
+        return [_latent_cache(d, i, (batch, max_seq), dtype) for i in range(d.layers)]
     shape = (batch, d.kv_heads, max_seq, d.dim_per_head)
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -199,14 +328,44 @@ def init_kv_cache(cfg: VLMConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) 
     ]
 
 
+def _latent_cache(d: DecoderConfig, i: int, lead: tuple[int, int], dtype) -> dict:
+    """One latent layer's cache over ``lead`` = (pages, page) or (batch,
+    max_seq): the normed latent row ``c`` and the rotated position key ``r``
+    a token; a full layer also keeps its indexer's key ``ik``."""
+    full = d.layer_kind(i) == FULL_ATTENTION
+    dims = d.latent_full if full else d.latent_window
+    cache = {
+        "c": jnp.zeros((*lead, dims.kv_lora), dtype),
+        "r": jnp.zeros((*lead, dims.rope), dtype),
+    }
+    if full:
+        cache["ik"] = jnp.zeros((*lead, d.index_head_dim), dtype)
+    return cache
+
+
 def init_paged_kv_cache(
-    cfg: VLMConfig, pages: int, page_size: int, dtype=jnp.bfloat16
+    cfg: VLMConfig, pages: int, page_size: int, dtype=jnp.bfloat16,
+    window_pages: int | None = None,
 ) -> list[dict]:
     """Per-layer PAGED cache: a pool of ``pages`` fixed-size pages shared
     by every decode row, addressed through per-row block tables
     (``models/vlm/paged_kv.py``) instead of one contiguous ``max_seq``
-    region per slot. Page 0 is the reserved dump page."""
+    region per slot. Page 0 is the reserved dump page.
+
+    A latent decoder's pages hold latent rows (``[pages, page, width]``), and
+    its window layers draw theirs from an id space of their own,
+    ``window_pages`` large (``paged_kv.WindowPages``): they free pages behind
+    the window while the full layers keep theirs."""
     d = cfg.decoder
+    if d.latent:
+        return [
+            _latent_cache(
+                d, i,
+                (pages if d.layer_kind(i) == FULL_ATTENTION else window_pages, page_size),
+                dtype,
+            )
+            for i in range(d.layers)
+        ]
     shape = (pages, d.kv_heads, page_size, d.dim_per_head)
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -371,6 +530,160 @@ class DecoderAttention(nn.Module):
         return _dense(c, c.hidden_size, "o_proj", False, x.dtype)(out), cache
 
 
+def prefix_ladder(length: int, step: int = 1024) -> list[int]:
+    """Static key-prefix lengths a prefill chunk may attend over: a chunk
+    ending at ``e`` uses the smallest one >= ``e``, so early chunks of a
+    long prompt do not pay for the whole scratch."""
+    ladder = list(range(step, length, step))
+    return ladder + [length]
+
+
+class LatentAttention(nn.Module):
+    """Latent attention in absorbed form (``ops.latent_attention``), of the
+    kind ``kind`` names: low-rank query (``q_a_proj`` -> norm -> ``q_b_proj``)
+    and key/value (``kv_a_proj`` -> norm; ``kv_b_proj`` folded into the query
+    and applied to the weighted latents), one RoPE key shared by all heads, a
+    sigmoid gate a head from the layer's normed input, and in a full layer
+    the indexer (``index_q`` from the query latent, ``index_k`` with a
+    LayerNorm, ``index_w``) that keeps the ``index_topk`` causal keys of
+    largest score. The cache is this layer's ``_latent_cache``."""
+
+    cfg: DecoderConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None):
+        from ...ops import latent_attention as la
+
+        c = self.cfg
+        windowed = self.kind == WINDOW_ATTENTION
+        d = c.latent_window if windowed else c.latent_full
+        b, s, _ = x.shape
+        h = d.heads
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, name=name, dtype=x.dtype)
+
+        cq = RMSNorm(c.rms_norm_eps, name="q_a_norm")(dense(d.q_lora, "q_a_proj")(x))
+        kv = dense(d.kv_lora + d.rope, "kv_a_proj")(x)
+        ckv = RMSNorm(c.rms_norm_eps, name="kv_a_norm")(kv[..., : d.kv_lora])
+        if c.latent_rescale:
+            cq = cq * (c.hidden_size / d.q_lora) ** 0.5
+            ckv = ckv * (c.hidden_size / d.kv_lora) ** 0.5
+        k_r = rope_rotate(kv[:, None, :, d.kv_lora :], positions, d.rope_theta)[:, 0]  # [B, S, R]
+        q = dense(h * (d.nope + d.rope), "q_b_proj")(cq)
+        q = q.reshape(b, s, h, d.nope + d.rope).transpose(0, 2, 1, 3)
+        q_r = rope_rotate(q[..., d.nope :], positions, d.rope_theta)  # [B, H, S, R]
+        w_kvb = self.param(
+            "kv_b_proj", nn.initializers.normal(0.02), (d.kv_lora, h * (d.nope + d.v_dim))
+        ).astype(x.dtype).reshape(d.kv_lora, h, d.nope + d.v_dim)
+        q_c = jnp.einsum("bhsn,chn->bhsc", q[..., : d.nope], w_kvb[..., : d.nope])  # absorbed
+        gate = jax.nn.sigmoid(dense(h, "attn_gate")(x).astype(jnp.float32))  # [B, S, H]
+        new = {"c": ckv, "r": k_r}
+        if not windowed:
+            j, di = c.index_heads, c.index_head_dim
+            q_i = dense(j * di, "index_q")(cq).reshape(b, s, j, di).transpose(0, 2, 1, 3)
+            q_i = jnp.concatenate(
+                [rope_rotate(q_i[..., : d.rope], positions, d.rope_theta), q_i[..., d.rope :]], -1
+            ).transpose(0, 2, 1, 3)  # [B, S, J, Di]
+            k_i = nn.LayerNorm(epsilon=c.rms_norm_eps, name="index_k_norm", dtype=x.dtype)(
+                dense(di, "index_k")(x)
+            )
+            k_i = jnp.concatenate(
+                [rope_rotate(k_i[:, None, :, : d.rope], positions, d.rope_theta)[:, 0], k_i[..., d.rope :]],
+                -1,
+            )  # [B, S, Di]
+            w_i = dense(j, "index_w")(x).astype(jnp.float32) * (j**-0.5 * di**-0.5)  # [B, S, J]
+            new["ik"] = k_i
+
+        if block_tables is not None:
+            if s != 1:
+                raise NotImplementedError("latent paged decode takes one token a row")
+            table = block_tables[:, 1 if windowed else 0]  # [B, MAXP] of this kind's id space
+            page = cache["c"].shape[1]
+            off = jnp.asarray(cache_offset, jnp.int32)
+            page_idx = table[jnp.arange(b), off // page]
+            slot = off % page
+            cache = {
+                name: cache[name].at[page_idx, slot].set(new[name][:, 0].astype(cache[name].dtype))
+                for name in cache
+            }
+            sel, span = None, None
+            if windowed:
+                kv_start = jnp.maximum(kv_valid_len - c.sliding_window, 0)
+                span = la.window_span_pages(c.sliding_window, page)
+            else:
+                kv_start = jnp.zeros_like(kv_valid_len)
+                slots = table.shape[1] * page
+                if slots > c.index_topk:
+                    scores = la.indexer_scores(q_i[:, 0], w_i[:, 0], cache["ik"], table)
+                    ok = jnp.arange(slots, dtype=jnp.int32)[None, :] < kv_valid_len[:, None]
+                    sel = la.topk_select(scores, ok, c.index_topk)
+            out = la.latent_paged_attention(
+                q_c[:, :, 0], q_r[:, :, 0], cache["c"], cache["r"], table,
+                kv_valid_len, kv_start, sel, scale=d.scale, span=span,
+            )[:, :, None]  # [B, H, 1, C]
+        else:
+            if cache is not None:
+                off = jnp.asarray(cache_offset, jnp.int32)
+                if off.ndim != 0:
+                    raise NotImplementedError(
+                        "a latent decoder decodes through the paged pool (continuous scheduler) only"
+                    )
+                zero = jnp.zeros((), jnp.int32)
+                cache = {
+                    name: jax.lax.dynamic_update_slice(
+                        cache[name], new[name].astype(cache[name].dtype), (zero, off, zero)
+                    )
+                    for name in cache
+                }
+                length = cache["c"].shape[1]
+            else:
+                off, length = jnp.zeros((), jnp.int32), s
+
+            def attend(keys: dict, k_pos):
+                """Queries of this segment against ``keys`` at ``k_pos`` [S']."""
+                see = (k_pos[None, None, :] <= positions[:, :, None]) & (
+                    k_pos[None, None, :] < kv_valid_len[:, None, None]
+                )
+                if windowed:
+                    see &= positions[:, :, None] - k_pos[None, None, :] < c.sliding_window
+                elif k_pos.shape[0] > c.index_topk:
+                    scores = la.indexer_scores_dense(q_i, w_i, keys["ik"].astype(x.dtype))
+                    see = la.topk_select(scores, see, c.index_topk)
+                return la.latent_prefill_attention(
+                    q_c, q_r, keys["c"].astype(x.dtype), keys["r"].astype(x.dtype), see,
+                    scale=d.scale,
+                )
+
+            keys = cache if cache is not None else new  # cacheless: the segment's own rows
+            if windowed:
+                # The chunk's windows lie inside one static-length slice.
+                pad = -(-(c.sliding_window - 1) // 128) * 128
+                klen = min(length, s + pad)
+                start = jnp.clip(off - pad, 0, length - klen)
+                sliced = {
+                    n: jax.lax.dynamic_slice_in_dim(keys[n], start, klen, axis=1) for n in keys
+                }
+                out = attend(sliced, start + jnp.arange(klen, dtype=jnp.int32))
+            else:
+                ladder = prefix_ladder(length)
+
+                def branch(n):
+                    return lambda ks: attend(
+                        {name: v[:, :n] for name, v in ks.items()}, jnp.arange(n, dtype=jnp.int32)
+                    )
+
+                if len(ladder) == 1:
+                    out = branch(length)(keys)
+                else:
+                    idx = jnp.searchsorted(jnp.asarray(ladder), off + s, side="left")
+                    out = jax.lax.switch(idx, [branch(n) for n in ladder], keys)
+        out = jnp.einsum("bhsc,chv->bshv", out, w_kvb[..., d.nope :])  # [B, S, H, V]
+        out = (out * gate[..., None].astype(out.dtype)).reshape(b, s, h * d.v_dim)
+        return dense(c.hidden_size, "o_proj")(out), cache
+
+
 class SwiGLU(nn.Module):
     cfg: DecoderConfig
     intermediate: int | None = None  # override cfg.intermediate_size
@@ -396,37 +709,59 @@ class MoEFFN(nn.Module):
     cfg: DecoderConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, token_valid: jax.Array | None = None) -> jax.Array:
         from ...parallel.moe import MoEParams, moe_ffn
 
         c = self.cfg
         e = c.moe_experts
         d = c.hidden_size
         f = c.moe_intermediate_size or c.intermediate_size
+        lo, hi = c.moe_held or (0, e)
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, e), jnp.float32)
-        w_gate = self.param("w_gate", init, (e, d, f), jnp.float32)
-        w_up = self.param("w_up", init, (e, d, f), jnp.float32)
-        w_down = self.param("w_down", init, (e, f, d), jnp.float32)
+        w_gate = self.param("w_gate", init, (hi - lo, d, f), jnp.float32)
+        w_up = self.param("w_up", init, (hi - lo, d, f), jnp.float32)
+        w_down = self.param("w_down", init, (hi - lo, f, d), jnp.float32)
         b, s, _ = x.shape
         tokens = x.reshape(b * s, d)
-        y = moe_ffn(
-            MoEParams(
-                router=router,
-                w_gate=w_gate.astype(x.dtype),
-                w_up=w_up.astype(x.dtype),
-                w_down=w_down.astype(x.dtype),
-            ),
-            tokens,
-            mesh=None,
-            k=c.moe_top_k,
-            capacity_factor=None,  # exact: no token drops at inference
-            norm_topk=c.moe_norm_topk,
-        ).reshape(b, s, d)
+        params = MoEParams(
+            router=router,
+            w_gate=w_gate.astype(x.dtype),
+            w_up=w_up.astype(x.dtype),
+            w_down=w_down.astype(x.dtype),
+        )
+        if c.moe_held is None and c.moe_scoring == "softmax":
+            y = moe_ffn(
+                params, tokens, mesh=None, k=c.moe_top_k,
+                capacity_factor=None,  # exact: no token drops at inference
+                norm_topk=c.moe_norm_topk,
+            )
+        else:
+            # One chip's share: route over all ``e``, compute the held
+            # experts' part, and count what the layer saw (``moe_stats``:
+            # assignments routed, of them held here, held experts that got
+            # a token, calls) for whoever applies with it mutable.
+            bias = (
+                self.param("select_bias", nn.initializers.zeros, (e,), jnp.float32)
+                if c.moe_select_bias else None
+            )
+            y, stats = moe_ffn(
+                params, tokens, mesh=None, k=c.moe_top_k, capacity_factor=None,
+                norm_topk=c.moe_norm_topk, scoring=c.moe_scoring, select_bias=bias,
+                routed_scale=c.moe_routed_scale, held=(lo, hi), n_experts=e,
+                token_valid=token_valid, with_stats=True,
+            )
+            self.sow(
+                "moe_stats", "counts", stats,
+                reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((4,), jnp.int32),
+            )
+        y = y.reshape(b, s, d)
         if c.moe_shared_intermediate:
             shared = SwiGLU(c, intermediate=c.moe_shared_intermediate, name="shared")(x)
-            gate = nn.Dense(1, use_bias=False, name="shared_gate", dtype=x.dtype)(x)
-            y = y + jax.nn.sigmoid(gate) * shared
+            if c.moe_shared_gated:
+                gate = nn.Dense(1, use_bias=False, name="shared_gate", dtype=x.dtype)(x)
+                shared = jax.nn.sigmoid(gate) * shared
+            y = y + shared
         return y
 
 
@@ -435,9 +770,16 @@ class DecoderLayer(nn.Module):
     layer_idx: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None):
-        h, cache = DecoderAttention(self.cfg, name="attn")(
-            RMSNorm(self.cfg.rms_norm_eps, name="input_norm")(x),
+    def __call__(
+        self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None, token_valid=None
+    ):
+        c = self.cfg
+        if c.latent:
+            attn = LatentAttention(c, c.layer_kind(self.layer_idx), name="attn")
+        else:
+            attn = DecoderAttention(c, name="attn")
+        h, cache = attn(
+            RMSNorm(c.rms_norm_eps, name="input_norm")(x),
             positions,
             cache,
             cache_offset,
@@ -445,10 +787,11 @@ class DecoderLayer(nn.Module):
             block_tables,
         )
         x = x + h
-        mlp_cls = MoEFFN if self.cfg.is_moe_layer(self.layer_idx) else SwiGLU
-        x = x + mlp_cls(self.cfg, name="mlp")(
-            RMSNorm(self.cfg.rms_norm_eps, name="post_attn_norm")(x)
-        )
+        y = RMSNorm(c.rms_norm_eps, name="post_attn_norm")(x)
+        if c.is_moe_layer(self.layer_idx):
+            x = x + MoEFFN(c, name="mlp")(y, token_valid)
+        else:
+            x = x + SwiGLU(c, name="mlp")(y)
         return x, cache
 
 
@@ -482,13 +825,14 @@ class Decoder(nn.Module):
         cache_offset: jax.Array | None,
         kv_valid_len: jax.Array,
         block_tables: jax.Array | None = None,
+        token_valid: jax.Array | None = None,
     ) -> tuple[jax.Array, list[dict] | None]:
         x = embeds
         new_caches: list[dict] = []
         for i, block in enumerate(self.blocks):
             layer_cache = caches[i] if caches is not None else None
             x, layer_cache = block(
-                x, positions, layer_cache, cache_offset, kv_valid_len, block_tables
+                x, positions, layer_cache, cache_offset, kv_valid_len, block_tables, token_valid
             )
             new_caches.append(layer_cache)
         x = self.final_norm(x)
@@ -539,15 +883,21 @@ class VLMModel(nn.Module):
     def embed_tokens(self, input_ids: jax.Array) -> jax.Array:
         return self.decoder.embed(input_ids)
 
-    def decode(self, embeds, positions, caches, cache_offset, kv_valid_len):
-        return self.decoder(embeds, positions, caches, cache_offset, kv_valid_len)
+    def decode(self, embeds, positions, caches, cache_offset, kv_valid_len, token_valid=None):
+        return self.decoder(
+            embeds, positions, caches, cache_offset, kv_valid_len, None, token_valid
+        )
 
-    def decode_paged(self, embeds, positions, caches, block_tables, cache_offset, kv_valid_len):
+    def decode_paged(
+        self, embeds, positions, caches, block_tables, cache_offset, kv_valid_len, token_valid=None
+    ):
         """Single-token decode against the paged KV pool (continuous
         engine): ``caches`` from :func:`init_paged_kv_cache`,
-        ``block_tables`` [B, max_pages] per-row page maps."""
+        ``block_tables`` [B, max_pages] per-row page maps ([B, 2, max_pages]
+        for a latent decoder: the full layers' table and the window
+        layers')."""
         return self.decoder(
-            embeds, positions, caches, cache_offset, kv_valid_len, block_tables
+            embeds, positions, caches, cache_offset, kv_valid_len, block_tables, token_valid
         )
 
     def __call__(self, input_ids: jax.Array, pixel_values: jax.Array | None = None):

@@ -53,6 +53,11 @@ logger = logging.getLogger(__name__)
 #: row) small against prompt lengths in the hundreds.
 DEFAULT_PAGE_SIZE = 16
 
+#: default tokens per page of a latent decoder's cache: a page is one
+#: [page, latent] tile shared by all heads, and the decode kernels visit a
+#: page a grid step, so 16 tokens would be an 18 KB step.
+LATENT_PAGE_SIZE = 64
+
 #: fraction of free HBM the pool may claim when sized from device stats.
 DEFAULT_HEADROOM_FRACTION = 0.6
 
@@ -74,6 +79,86 @@ class PageStats:
     pages_shared: int = 0  # physical pages with more than one reference
 
 
+class WindowPages:
+    """The window layers' pages of a latent decoder: an id space of their
+    own beside the pool's, under the same logical page numbers.
+
+    A window layer reads only the last ``window`` keys of a row, so a page
+    wholly behind the window is dead: :meth:`trim` returns it to this free
+    list (at install after prefill and as the row advances) and points its
+    table entry back at the dump page 0, while the full layers keep theirs.
+    A row therefore never holds more than :meth:`row_pages` of them, the
+    space is sized for every slot holding that many (:func:`window_pool_pages`)
+    and cannot run dry: admission and preemption go on gating on the full
+    layers' pages alone. Owned by :class:`PagedKVPool`, which keeps the two
+    id spaces in step.
+    """
+
+    def __init__(self, pages_total: int, page_size: int, slots: int, max_pages: int, window: int):
+        self.pages_total = pages_total
+        self.page_size = page_size
+        self.window = window
+        self._free = list(range(pages_total - 1, 0, -1))
+        self._first: dict[int, int] = {}  # slot -> first live logical page
+        self._count: dict[int, int] = {}  # slot -> logical pages granted so far
+        self.tables = np.zeros((slots, max_pages), np.int32)
+        #: pages let go behind a window: at install (never granted) and as
+        #: rows advance (returned by ``trim``); not those a retiring row returns
+        self.freed_behind = 0
+
+    @staticmethod
+    def row_pages(window: int, page_size: int, block: int) -> int:
+        """Most pages one row can hold: the window at its worst alignment,
+        grown by a decode block before the next trim."""
+        return (window + block + page_size - 2) // page_size + 1
+
+    @property
+    def pages_live(self) -> int:
+        return self.pages_total - 1 - len(self._free)
+
+    def first_live(self, length: int) -> int:
+        """First logical page the next query (position ``length``) can see."""
+        return max(0, (length - self.window + 1) // self.page_size)
+
+    def cover(self, slot: int, tokens: int) -> None:
+        """Grant pages so the row's table covers ``tokens`` positions."""
+        need = min(max(1, -(-int(tokens) // self.page_size)), self.tables.shape[1])
+        count = self._count.get(slot, 0)
+        self._first.setdefault(slot, 0)
+        while count < need:
+            if not self._free:
+                raise RuntimeError("window page space ran dry (it is sized so that it cannot)")
+            self.tables[slot, count] = self._free.pop()
+            count += 1
+        self._count[slot] = count
+
+    def install(self, slot: int, prompt_tokens: int) -> None:
+        """A prefilled row: pages for the window's tail of the prompt and
+        the first decode write; those behind the window are never granted."""
+        behind = self.first_live(prompt_tokens)
+        self._first[slot] = self._count[slot] = behind
+        self.freed_behind += behind  # the scratch held them; the pool never does
+        self.cover(slot, prompt_tokens + 1)
+
+    def trim(self, slot: int, length: int) -> int:
+        """Free the row's pages wholly behind the window of a query at
+        position ``length``; returns how many."""
+        first, live = self._first.get(slot, 0), self.first_live(length)
+        live = min(live, self._count.get(slot, 0))
+        for j in range(first, live):
+            self._free.append(int(self.tables[slot, j]))
+            self.tables[slot, j] = 0
+        if live > first:
+            self._first[slot] = live
+            self.freed_behind += live - first
+        return max(0, live - first)
+
+    def release(self, slot: int) -> None:
+        for j in range(self._first.pop(slot, 0), self._count.pop(slot, 0)):
+            self._free.append(int(self.tables[slot, j]))
+        self.tables[slot] = 0
+
+
 class PagedKVPool:
     """Free-list page allocator with per-slot block tables.
 
@@ -84,7 +169,12 @@ class PagedKVPool:
     step).
     """
 
-    def __init__(self, pages_total: int, page_size: int, slots: int, max_pages: int):
+    def __init__(
+        self, pages_total: int, page_size: int, slots: int, max_pages: int,
+        window: WindowPages | None = None,
+    ):
+        #: a latent decoder's window layers (None: every layer keeps every page)
+        self.window = window
         if pages_total < 2:
             raise ValueError(f"pages_total must be >= 2 (page 0 is the dump page), got {pages_total}")
         self.pages_total = pages_total
@@ -193,6 +283,14 @@ class PagedKVPool:
             self._shared[slot] = shared
         return self.block_tables[slot]
 
+    def device_tables(self, bucket: int) -> np.ndarray:
+        """What a decode program is given: the first ``bucket`` table
+        entries of every slot, ``[slots, bucket]``; with window layers the
+        full layers' table and theirs, ``[slots, 2, bucket]``."""
+        if self.window is None:
+            return self.block_tables[:, :bucket]
+        return np.stack([self.block_tables[:, :bucket], self.window.tables[:, :bucket]], axis=1)
+
     def admit(self, slot: int, prompt_tokens: int) -> np.ndarray:
         """Grant pages covering ``prompt_tokens`` + the first decode write
         and install the slot's block table row. Returns the row (view)."""
@@ -201,6 +299,8 @@ class PagedKVPool:
         need = self.pages_for(prompt_tokens + 1)
         if need > len(self._free):
             raise PoolExhausted(f"need {need} pages, {len(self._free)} free")
+        if self.window is not None:
+            self.window.install(slot, prompt_tokens)
         return self._install(slot, self._pop_fresh(need), 0)
 
     def admit_shared(
@@ -214,6 +314,8 @@ class PagedKVPool:
         path caps coverage at ``prompt_tokens - 1``), so the write
         frontier always lands in a private page and the row never
         mutates shared contents."""
+        if self.window is not None:
+            raise NotImplementedError("a shared prefix cannot be attached to window layers")
         if slot in self._owned:
             raise RuntimeError(f"slot {slot} already owns pages (allocator bug)")
         if len(shared_pages) * self.page_size > prompt_tokens:
@@ -245,6 +347,8 @@ class PagedKVPool:
         the fresh grant. Returns the row (view); same accounting as
         :meth:`admit`."""
         shared = list(shared_pages or ())
+        if self.window is not None:
+            raise NotImplementedError("the spill tier does not export window layers' pages")
         if slot in self._owned:
             raise RuntimeError(f"slot {slot} already owns pages (allocator bug)")
         if not 1 <= n_pages <= self.max_pages - len(shared):
@@ -276,6 +380,8 @@ class PagedKVPool:
         to report through is therefore an allocator-contract bug."""
         pages = self._owned[slot]
         need = min(self.pages_for(tokens), self.max_pages)
+        if self.window is not None:
+            self.window.cover(slot, tokens)
         if need > len(pages) and pages and self._ref.get(pages[-1], 0) > 1:
             if not self._free:
                 return False
@@ -307,6 +413,8 @@ class PagedKVPool:
         pages = self._owned.pop(slot, [])
         self._shared.pop(slot, None)
         self.block_tables[slot] = 0
+        if self.window is not None:
+            self.window.release(slot)
         # Reversed: the row's FIRST page ends on top of the LIFO free
         # list, preserving the pre-refcount reuse order exactly.
         self.decref(list(reversed(pages)))
@@ -326,10 +434,33 @@ class PagedKVPool:
 
 
 def page_bytes(cfg, page_size: int, dtype_bytes: int) -> int:
-    """HBM cost of ONE page id across every decoder layer (each page id
-    indexes a [page_size, head_dim] K and V tile in all layers)."""
+    """HBM cost of ONE page id across every decoder layer that keeps it
+    (each page id indexes a [page_size, head_dim] K and V tile in all
+    layers; in a latent decoder a [page_size, latent + rope (+ index key)]
+    tile in the FULL layers: the window layers' pages are
+    :func:`window_page_bytes` each, in their own id space)."""
     d = cfg.decoder
+    if d.latent:
+        from .modeling import FULL_ATTENTION
+
+        row = d.latent_full.kv_lora + d.latent_full.rope + d.index_head_dim
+        return d.layers_of(FULL_ATTENTION) * page_size * row * dtype_bytes
     return 2 * d.layers * d.kv_heads * page_size * d.dim_per_head * dtype_bytes
+
+
+def window_page_bytes(cfg, page_size: int, dtype_bytes: int) -> int:
+    """HBM cost of one window page id across the window layers."""
+    from .modeling import WINDOW_ATTENTION
+
+    d = cfg.decoder
+    row = d.latent_window.kv_lora + d.latent_window.rope
+    return d.layers_of(WINDOW_ATTENTION) * page_size * row * dtype_bytes
+
+
+def window_pool_pages(cfg, page_size: int, slots: int, block: int) -> int:
+    """Size of the window layers' id space: every slot at the most a row
+    can hold (:meth:`WindowPages.row_pages`), and the dump page."""
+    return slots * WindowPages.row_pages(cfg.decoder.sliding_window, page_size, block) + 1
 
 
 def resolve_pool_pages(
@@ -338,6 +469,7 @@ def resolve_pool_pages(
     slots: int,
     max_seq: int,
     dtype_bytes: int = 2,
+    block: int = 8,
 ) -> tuple[int, str]:
     """Pool size in pages, and where it came from (``"pinned"``,
     ``"device_memory"`` or ``"no_device_stats"``). ``LUMEN_VLM_KV_PAGES``
@@ -388,7 +520,13 @@ def resolve_pool_pages(
             cap, page_size, jax.default_backend(),
         )
         return cap, "no_device_stats"
-    pages = int(headroom * frac) // max(per_page, 1)
+    budget = int(headroom * frac)
+    if cfg.decoder.latent:
+        # the window layers' space is a fixed size: what is left buys pages
+        budget -= window_pool_pages(cfg, page_size, slots, block) * window_page_bytes(
+            cfg, page_size, dtype_bytes
+        )
+    pages = max(budget, 0) // max(per_page, 1)
     sized = max(floor, min(pages, cap))
     logger.info(
         "VLM paged-KV pool: %d pages x %d tokens (%.1f MB of %.1f MB headroom, cap %d)",

@@ -74,18 +74,42 @@ def moe_sharding(mesh: Mesh, axis_name: str = EXPERT_AXIS) -> MoEParams:
     )
 
 
-def _topk_gates(x: jnp.ndarray, router: jnp.ndarray, k: int, norm_topk: bool):
-    """Softmax-then-top-k routing: ``[T, k]`` gate values + expert ids."""
-    probs = jax.nn.softmax(x.astype(jnp.float32) @ router, axis=-1)  # [T, E]
-    gate_vals, gate_idx = lax.top_k(probs, k)  # [T, k]
+def _topk_gates(
+    x: jnp.ndarray, router: jnp.ndarray, k: int, norm_topk: bool,
+    scoring: str = "softmax", select_bias: jnp.ndarray | None = None,
+    routed_scale: float = 1.0,
+):
+    """Top-k routing: ``[T, k]`` gate values + expert ids, in float32.
+
+    ``scoring="softmax"``: softmax over the experts, then the k largest.
+    ``scoring="sigmoid"``: a sigmoid score an expert; the k largest of
+    ``score + select_bias`` are selected (the bias steers selection only)
+    and the gate is the selected expert's own score. ``norm_topk``
+    renormalises the gates over the selected; ``routed_scale`` multiplies
+    them."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)  # [T, E]
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        ranked = scores if select_bias is None else scores + select_bias.astype(jnp.float32)
+        _, gate_idx = lax.top_k(ranked, k)
+        gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    elif scoring == "softmax":
+        gate_vals, gate_idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
     if norm_topk:
         gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if routed_scale != 1.0:
+        gate_vals = gate_vals * routed_scale
     return gate_vals, gate_idx
 
 
 def _moe_exact_local(
-    params: MoEParams, x: jnp.ndarray, *, n_experts: int, k: int, norm_topk: bool
-) -> jnp.ndarray:
+    params: MoEParams, x: jnp.ndarray, *, n_experts: int, k: int, norm_topk: bool,
+    scoring: str = "softmax", select_bias: jnp.ndarray | None = None,
+    routed_scale: float = 1.0, held: tuple[int, int] | None = None,
+    token_valid: jnp.ndarray | None = None, with_stats: bool = False,
+):
     """Exact (zero-drop) single-device MoE via grouped GEMM.
 
     Sorts the ``T*k`` (token, choice) assignments by expert and runs the
@@ -94,19 +118,44 @@ def _moe_exact_local(
     needs an ``[E, T, D]`` buffer and O(T^2*E*D) one-hot einsums. This is
     the inference path that reproduces dense-gather references (HF MoE)
     token-for-token.
+
+    ``held=(lo, hi)``: the bank in ``params`` holds experts ``lo..hi-1`` of
+    ``n_experts`` (one chip's share of an expert-parallel deployment). The
+    router still scores all ``n_experts``; assignments to absent experts
+    sort behind the held groups, are left out of the grouped GEMMs and add
+    nothing — what the chips that hold them would add. ``with_stats`` also
+    returns int32 ``[routed, held, experts touched, 1]`` over the tokens
+    ``token_valid`` [T] allows (all, when None).
     """
     t, d = x.shape
-    gate_vals, gate_idx = _topk_gates(x, params.router, k, norm_topk)
+    gate_vals, gate_idx = _topk_gates(
+        x, params.router, k, norm_topk, scoring, select_bias, routed_scale
+    )
+    lo, hi = held if held is not None else (0, n_experts)
+    n_held = hi - lo
+    if params.w_gate.shape[0] != n_held:
+        raise ValueError(f"bank holds {params.w_gate.shape[0]} experts, held range {lo}:{hi} asks {n_held}")
     e_flat = gate_idx.reshape(-1)  # [N], N = T*k; index t*k+j = (token t, choice j)
-    order = jnp.argsort(e_flat, stable=True)
+    here = (e_flat >= lo) & (e_flat < hi)
+    local = jnp.where(here, e_flat - lo, n_held)  # absent experts: one group past the bank
+    order = jnp.argsort(local, stable=True)
     inv = jnp.argsort(order)
     xs = jnp.repeat(x, k, axis=0)[order].astype(params.w_gate.dtype)  # [N, D]
-    group_sizes = jnp.bincount(e_flat, length=n_experts).astype(jnp.int32)
+    group_sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
     hg = lax.ragged_dot(xs, params.w_gate, group_sizes)
     hu = lax.ragged_dot(xs, params.w_up, group_sizes)
     ys = lax.ragged_dot(jax.nn.silu(hg) * hu, params.w_down, group_sizes)  # [N, D]
-    ys = ys[inv].reshape(t, k, d).astype(jnp.float32)
-    return (ys * gate_vals[..., None]).sum(axis=1).astype(x.dtype)
+    ys = jnp.where(here[:, None], ys[inv], 0).reshape(t, k, d).astype(jnp.float32)
+    y = (ys * gate_vals[..., None]).sum(axis=1).astype(x.dtype)
+    if not with_stats:
+        return y
+    valid = jnp.ones((t,), bool) if token_valid is None else token_valid.reshape(t)
+    counted = here & jnp.repeat(valid, k)
+    touched = jnp.bincount(jnp.where(counted, local, n_held), length=n_held + 1)[:n_held] > 0
+    stats = jnp.stack([
+        valid.sum() * k, counted.sum(), touched.sum(), jnp.ones((), jnp.int32)
+    ]).astype(jnp.int32)
+    return y, stats
 
 
 def _route(
@@ -193,6 +242,13 @@ def moe_ffn(
     capacity_factor: float | None = 1.25,
     axis_name: str = EXPERT_AXIS,
     norm_topk: bool = True,
+    scoring: str = "softmax",
+    select_bias: jax.Array | None = None,
+    routed_scale: float = 1.0,
+    held: tuple[int, int] | None = None,
+    n_experts: int | None = None,
+    token_valid: jax.Array | None = None,
+    with_stats: bool = False,
 ) -> jax.Array:
     """Apply the routed expert FFN to ``x: [T, D]`` (flatten [B, S, D]
     upstream).
@@ -209,8 +265,31 @@ def moe_ffn(
     the local token count — the worst per-expert load, since a token's
     top-k choices are distinct experts — at an ``[E, T_local, D]`` buffer
     memory cost, so prefer a finite factor at scale.
+
+    ``scoring`` / ``select_bias`` / ``routed_scale``: the routing rule (see
+    :func:`_topk_gates`). ``held=(lo, hi)`` with ``n_experts`` (the router's
+    width): ``params`` is one chip's share of the bank, experts ``lo..hi-1``;
+    the layer routes over all ``n_experts`` and computes its own experts'
+    part of the result, exactly and without an exchange (single device,
+    ``capacity_factor=None`` only: it is what each chip of an expert-
+    parallel deployment computes before the combine). ``with_stats``
+    returns ``(y, [routed, held, experts touched, 1])``.
     """
-    n_experts = params.w_gate.shape[0]
+    n_experts = n_experts or params.w_gate.shape[0]
+    plain = scoring == "softmax" and select_bias is None and held is None and not with_stats
+    if not plain:
+        if capacity_factor is not None or not (
+            mesh is None or axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1
+        ):
+            raise NotImplementedError(
+                "sigmoid scoring, a selection bias, a held range and routing stats run the "
+                "exact single-device path only (capacity_factor=None, no expert mesh axis)"
+            )
+        return _moe_exact_local(
+            params, x, n_experts=n_experts, k=k, norm_topk=norm_topk, scoring=scoring,
+            select_bias=select_bias, routed_scale=routed_scale, held=held,
+            token_valid=token_valid, with_stats=with_stats,
+        )
     if mesh is None or axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1:
         if capacity_factor is None:
             return _moe_exact_local(

@@ -69,6 +69,8 @@ class VlmService(BaseService):
         kw = {}
         if bs.batch_buckets:
             kw["prefill_buckets"] = tuple(bs.batch_buckets)
+        if bs.max_seq:
+            kw["max_seq"] = bs.max_seq
         # batch_size here is the decode batch (requests coalesced per
         # program) and the stream-cache bound — NOT a CLIP-style image
         # batch. Configs written before per-family sizing may carry the

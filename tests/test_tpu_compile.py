@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 # ``lumen_tpu.ops`` re-exports the ``attention`` FUNCTION over the submodule.
 att = importlib.import_module("lumen_tpu.ops.attention")
+from lumen_tpu.ops import latent_attention as lat
 from lumen_tpu.ops import quant_matmul
 
 B, HEADS, KV_HEADS, HEAD_DIM, PAGE = 8, 14, 2, 64, 16
@@ -76,6 +77,25 @@ def _flash_cache(sq, sk):
     )
 
 
+def _latent(heads, c_dim, span, sel):
+    """The latent decode kernel at the published ``dots3_note`` widths: 16
+    rows, 64-token pages, 72 pages a row (max_seq 4,608)."""
+    rows, maxp, page, rope = 16, 72, 64, 64
+    shapes = [
+        ((rows, heads, c_dim), BF16), ((rows, heads, rope), BF16),
+        ((rows * maxp + 1, page, c_dim), BF16), ((rows * maxp + 1, page, rope), BF16),
+        ((rows, maxp), I32), ((rows,), I32), ((rows,), I32),
+    ]
+    if sel:
+        shapes.append(((rows, maxp * page), jnp.bool_))
+    return (
+        lambda *a: lat.latent_paged_attention_kernel(
+            *a, scale=0.07, span=span, interpret=False
+        ),
+        shapes,
+    )
+
+
 CASES = {
     # 128 pages a row is the serving default (max_seq 2048); 512 pages is
     # the 8,192-token row the old kernel capped at.
@@ -88,6 +108,13 @@ CASES = {
     ),
     "flash_cache_sq1": _flash_cache(1, 2048),
     "flash_cache_sq256": _flash_cache(256, 2048),
+    "latent_full": _latent(128, 512, None, sel=False),
+    "latent_full_selected": _latent(128, 512, None, sel=True),
+    "latent_window": _latent(64, 1024, 9, sel=False),
+    "indexer_scores": (
+        lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
+        [((16, 64, 128), BF16), ((16, 64), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],
+    ),
     "w8a16": (
         lambda x, q, s: quant_matmul._w8a16_2d(x, q, s, block_n=256, interpret=False),
         [((B, HIDDEN), BF16), ((HIDDEN, MLP), jnp.int8), ((MLP,), jnp.float32)],
@@ -103,6 +130,18 @@ def test_kernel_compiles_for_v5e(v5e, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_names(hlo: str) -> list[str]:
+    """The names of a compiled program's Pallas kernels, as the benchmark's
+    ``trace_reduce.stem`` reads them: number and HLO text stripped."""
+    import re
+
+    return [
+        re.sub(r"\.\d+$", "", line.split(" = ", 1)[0].strip().removeprefix("ROOT ").lstrip("%"))
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line and " = " in line
+    ]
+
+
 @pytest.mark.parametrize("name", ["paged_decode", "paged_varq_w5"])
 def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
     """The benchmark's ``paged_attn_*`` metrics find the kernel on the
@@ -115,9 +154,23 @@ def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    kernels = [
-        re.sub(r"\.\d+$", "", line.split(" = ", 1)[0].strip().lstrip("%"))
-        for line in text.splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line and " = " in line
-    ]
+    kernels = _kernel_names(text)
     assert kernels and all(re.search("^paged_attention", k) for k in kernels), kernels
+
+
+@pytest.mark.parametrize(
+    "name,pattern",
+    [("latent_full_selected", "^latent_paged_attention"), ("latent_window", "^latent_paged_attention"),
+     ("indexer_scores", "^indexer_scores")],
+)
+def test_latent_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
+    """``latent_attn_roofline`` / ``latent_attn_time_pct`` match
+    ``^latent_paged_attention`` and ``indexer_roofline`` matches
+    ``^indexer_scores`` on the device's ``XLA Ops`` line, as above."""
+    import re
+
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = _kernel_names(text)
+    assert kernels and all(re.search(pattern, k) for k in kernels), kernels
