@@ -7,7 +7,6 @@ from .attention import (
     flash_attention,
     flash_attention_cache,
     record_flash_ab,
-    flash_enabled,
     flash_for_seq,
     repeat_kv,
 )
@@ -34,7 +33,6 @@ __all__ = [
     "flash_attention",
     "flash_attention_cache",
     "record_flash_ab",
-    "flash_enabled",
     "flash_for_seq",
     "repeat_kv",
     "ctc_greedy_device",

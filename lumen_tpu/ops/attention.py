@@ -14,6 +14,7 @@ from __future__ import annotations
 import functools
 import math
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +23,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128  # VMEM lane width; scratch stats are padded to this
-
-from ..utils.env import env_int
 
 #: batch*heads and q-block axes carry no state between steps, so megacore
 #: chips (v4/v5p: two TensorCores per chip) may split them; the k axis is
@@ -40,18 +39,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def flash_enabled() -> bool:
-    """Would :func:`attention` route an unmasked long-sequence call through
-    the Pallas kernel right now? (Reported by ``bench.py`` so perf numbers
-    record which attention path produced them.)"""
-    return _flash_usable(0, None, _min_flash_seq())
-
-
 def flash_for_seq(sq: int) -> bool:
     """Would :func:`attention` use the Pallas kernel for THIS query length?
-    Workload-accurate variant of :func:`flash_enabled` — the CLIP towers
-    (seq 50/77) sit below the min-seq gate, so benchmarks must not stamp
-    their numbers with the long-sequence answer."""
+    (Reported by ``bench.py`` so perf numbers record which attention path
+    produced them: the CLIP towers, 77 text tokens and 50-257 image
+    tokens, sit below the crossover.)"""
     return _flash_usable(0, None, sq)
 
 
@@ -373,15 +365,27 @@ def flash_attention_cache(
     return out.reshape(b, h, sq_p, d)[:, :, :sq]
 
 
-#: Below this query length the whole problem fits one fused XLA attention
-#: and the kernel's grid degenerates (CLIP towers are seq 50/77: the grid
-#: would be (B*heads, 1, 1) sequential steps of sub-MXU-tile matmuls).
-#: Flash pays where online softmax saves HBM traffic — long sequences.
-_MIN_FLASH_SEQ_DEFAULT = 256
+#: Shortest sequence :func:`attention` hands to the tiled kernel: where the
+#: two programs cross on a TPU v5e (``PERF.md`` section 6, PR 32; bf16, 16
+#: heads of 64, time of the kernel over time of ``attention_reference``):
+#:
+#:   tokens x rows   257x8  512x8  1024x8  2048x2  2048x8  3072x1  4096x1  4096x2  8192x1
+#:   unmasked         3.57   2.79    2.84    2.66    2.94    2.77    1.11    2.75    0.08
+#:   causal           2.53   1.92    2.15    1.53    2.20    1.58    0.62    0.81    0.05
+#:
+#: Under it the float32 scores fit the device's memory and one batched XLA
+#: attention is the faster program at every length and row count measured:
+#: the kernel pays for each 128 x 128 grid step (nine a head at 257 tokens,
+#: 0.58 us each, float32 operands). From it up the scores of one row are
+#: 1 GiB, the kernel wins under a causal mask, and by 8,192 tokens XLA's
+#: program takes 449 ms against the kernel's 35.
+_FLASH_CROSSOVER_SEQ = 4096
 
-
-def _min_flash_seq() -> int:
-    return env_int("LUMEN_FLASH_MIN_SEQ", _MIN_FLASH_SEQ_DEFAULT)
+#: Key length from which :func:`attention_cached` hands a prefill-size
+#: query block to the cache kernel. Not the crossover above: that kernel
+#: reads its mask from two scalars a row where the XLA path builds a
+#: [B, 1, Sq, Sk] mask, and no A/B of it is on record (``ROADMAP.md`` D3).
+_FLASH_CACHE_MIN_KEYS = 256
 
 
 #: fallback reasons already logged this process (log ONCE per distinct
@@ -402,7 +406,7 @@ def _log_fallback_once(reason: str) -> None:
     )
 
 
-def _flash_usable(head_dim: int, mask, sq: int) -> bool:
+def _flash_usable(head_dim: int, mask, sq: int, min_seq: int = _FLASH_CROSSOVER_SEQ) -> bool:
     force = os.environ.get("LUMEN_FLASH")
     if force == "0":
         _log_fallback_once("disabled by LUMEN_FLASH=0")
@@ -418,13 +422,36 @@ def _flash_usable(head_dim: int, mask, sq: int) -> bool:
     if not _on_tpu():
         _log_fallback_once("backend is not TPU (Pallas kernel is TPU-only)")
         return False
-    if sq < _min_flash_seq():
+    if sq < min_seq:
         _log_fallback_once(
-            f"seq {sq} < LUMEN_FLASH_MIN_SEQ ({_min_flash_seq()}): one fused "
-            "XLA einsum beats a degenerate one-block kernel grid"
+            f"seq {sq} < {min_seq}: one fused XLA attention beats a kernel "
+            "grid of a few tiles a head"
         )
         return False
     return True
+
+
+#: calls of :func:`attention` traced so far, by route and query length
+#: (``xla:257``, ``flash:2048``). The route is chosen while a program is
+#: traced, so this counts programs built, not requests served.
+_ROUTES_TRACED: dict[str, int] = {}
+_ROUTES_LOCK = threading.Lock()
+
+
+def _route_gauges() -> dict:
+    with _ROUTES_LOCK:
+        return dict(_ROUTES_TRACED)
+
+
+def _count_route(route: str, sq: int) -> None:
+    """Publish the choice as the ``attention-route`` gauge provider: a
+    hub's /metrics after warm-up says which program its towers run."""
+    from ..utils.metrics import metrics
+
+    key = f"{route}:{sq}"
+    with _ROUTES_LOCK:
+        _ROUTES_TRACED[key] = _ROUTES_TRACED.get(key, 0) + 1
+    metrics.register_gauges("attention-route", _route_gauges)
 
 
 def record_flash_ab(ref_ms: float, flash_ms: float, block: str, platform: str) -> dict:
@@ -458,21 +485,6 @@ def _interpret_mode() -> bool:
     return not _on_tpu()
 
 
-def _flash_blocks() -> tuple[int, int]:
-    """Serving-path flash tile sizes (``LUMEN_FLASH_BLOCK_Q``/``_K``,
-    default 128x128): the bench's on-chip block sweep
-    (``bench.py phase_flash_ab``) picks the winner per chip generation and
-    deployments apply it without a code change."""
-    # Parsed independently: a typo in one variable must not discard a
-    # valid value in the other. A tuning-knob typo (0, negative, huge)
-    # must degrade, not crash the server — clamp to [16, 1024]; above
-    # 1024 the q x k tile alone exceeds VMEM on every current TPU.
-    def _one(name: str) -> int:
-        return env_int(name, 128, minimum=16, maximum=1024)
-
-    return (_one("LUMEN_FLASH_BLOCK_Q"), _one("LUMEN_FLASH_BLOCK_K"))
-
-
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -481,19 +493,20 @@ def attention(
     causal: bool = False,
     scale: float | None = None,
 ) -> jax.Array:
-    """Dispatch: Pallas flash kernel on TPU for unmasked/causal attention on
-    sequences long enough to pay (``LUMEN_FLASH_MIN_SEQ``, default 256 —
-    short-sequence callers like the CLIP towers stay on the fused XLA path,
-    where one batched einsum beats a degenerate one-block kernel grid), XLA
-    reference elsewhere (CPU tests, explicit masks). ``LUMEN_FLASH=0``
-    disables the kernel; ``LUMEN_FLASH=1`` forces it (interpret mode off
-    TPU, for tests)."""
+    """Dispatch by shape: the Pallas flash kernel on TPU for unmasked/causal
+    attention on sequences of ``_FLASH_CROSSOVER_SEQ`` tokens or more, where
+    online softmax saves the memory traffic of the scores; the XLA reference
+    for everything shorter (the CLIP and captioner image towers at 50-257
+    tokens, the text tower at 77), off the TPU and under an explicit mask.
+    ``LUMEN_FLASH=0`` disables the kernel; ``LUMEN_FLASH=1`` forces it
+    (interpret mode off TPU, for tests). The ``attention-route`` gauge
+    counts the choices."""
     if _flash_usable(q.shape[-1], mask, q.shape[2]):
-        bq, bk = _flash_blocks()
+        _count_route("flash", q.shape[2])
         return flash_attention(
-            q, k, v, causal=causal, scale=scale,
-            block_q=bq, block_k=bk, interpret=_interpret_mode(),
+            q, k, v, causal=causal, scale=scale, interpret=_interpret_mode()
         )
+    _count_route("xla", q.shape[2])
     return attention_reference(q, k, v, mask=mask, causal=causal, scale=scale)
 
 
@@ -545,7 +558,7 @@ def attention_cached(
     # kernel's win is streaming those keys without a [B,1,Sq,Sk] HBM
     # mask. min_flash_q still keeps near-decode query blocks on the
     # cheaper masked path.
-    if _flash_usable(q.shape[-1], None, sk) and sq >= min_flash_q:
+    if _flash_usable(q.shape[-1], None, sk, _FLASH_CACHE_MIN_KEYS) and sq >= min_flash_q:
         return flash_attention_cache(
             q, k, v, q_offsets, kv_valid, scale=scale, interpret=_interpret_mode()
         )

@@ -849,7 +849,7 @@ class BaseService(InferenceServicer):
     #: reference ``ml_service.proto:60-73``). Clamped under the 64 MB
     #: gRPC message cap (``server.GRPC_OPTIONS``) with protobuf headroom;
     #: a malformed override degrades to the default instead of crashing
-    #: the import (same policy as LUMEN_FLASH_BLOCK_Q/K).
+    #: the import.
     RESPONSE_CHUNK_BYTES = _response_chunk_bytes()
 
     def _chunked_response(

@@ -277,7 +277,7 @@ class TestPipelinedExecutor:
         b = MicroBatcher(lambda t, n: KillFetch(t), max_batch=1,
                          max_latency_ms=1, inflight=2, name="dead-fetch").start()
         f1 = b.submit(np.zeros(1))  # its fetch kills the worker; entry stranded
-        time.sleep(0.05)
+        b._fetch_thread.join(timeout=5)  # a fixed 50-ms sleep lost this race under load
         f2 = b.submit(np.zeros(1))  # next dispatch detects the dead worker
         # BOTH settle loudly instead of riding out the 300s batch-wait.
         with pytest.raises(RuntimeError, match="fetch worker died"):

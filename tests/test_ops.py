@@ -1,6 +1,8 @@
 """Ops tests: attention (Pallas kernel vs XLA reference), NMS parity,
 CTC decode, sampling distributions, image preprocessing."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -44,6 +46,12 @@ def rand_qkv(rng, b=2, h=4, sq=64, sk=64, d=32, dtype=jnp.float32):
     )
 
 
+# the package re-exports a *function* named ``attention`` that shadows the
+# submodule attribute, so import_module it is
+attn_mod = importlib.import_module("lumen_tpu.ops.attention")
+CROSSOVER = attn_mod._FLASH_CROSSOVER_SEQ
+
+
 class TestAttention:
     def test_reference_softmax_rows_sum(self):
         q, k, v = rand_qkv(jax.random.PRNGKey(0))
@@ -70,34 +78,81 @@ class TestAttention:
         out = attention_reference(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out[:, :, 0]), np.asarray(v[:, :, 0]), atol=1e-5)
 
-    def test_flash_dispatch_seq_gating(self, monkeypatch):
-        # On TPU the kernel only takes sequences long enough to pay; the
-        # CLIP towers (seq 50/77) must stay on the fused XLA path where
-        # one batched einsum beats a degenerate one-block kernel grid.
-        import importlib
+    @pytest.mark.parametrize(
+        "length,mask,head_dim,force,route",
+        [
+            # by shape: what fits a tile or a few goes to the fused XLA attention
+            (77, None, 64, None, "xla"),  # CLIP text tower
+            (256, None, 64, None, "xla"),  # the captioner's image tower
+            (257, None, 64, None, "xla"),  # ViT-L/14 at 224 px
+            (CROSSOVER - 1, None, 64, None, "xla"),
+            (CROSSOVER, None, 64, None, "flash"),
+            (8192, None, 128, None, "flash"),
+            # the kernel's other refusals hold at any length
+            (4096, "mask", 64, None, "xla"),
+            (4096, None, 512, None, "xla"),
+            # LUMEN_FLASH forces either way; a mask still refuses the kernel
+            (77, None, 64, "1", "flash"),
+            (4096, None, 64, "0", "xla"),
+            (4096, "mask", 64, "1", "xla"),
+        ],
+    )
+    def test_flash_dispatch_by_shape(self, monkeypatch, length, mask, head_dim, force, route):
+        """One route a shape on a TPU: (length, mask, head width,
+        ``LUMEN_FLASH``) -> the program :func:`attention` picks."""
+        if force is None:
+            monkeypatch.delenv("LUMEN_FLASH", raising=False)
+        else:
+            monkeypatch.setenv("LUMEN_FLASH", force)
+        monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+        usable = attn_mod._flash_usable(head_dim, object() if mask else None, length)
+        assert ("flash" if usable else "xla") == route
+        if not mask and head_dim <= 256:  # what bench.py stamps its numbers with
+            assert attn_mod.flash_for_seq(length) == usable
 
-        # the package re-exports a *function* named ``attention`` that
-        # shadows the submodule attribute, so import_module it is
-        attn_mod = importlib.import_module("lumen_tpu.ops.attention")
-
+    @pytest.mark.parametrize("keys,route", [(255, "xla"), (256, "flash"), (2048, "flash")])
+    def test_cache_path_keeps_its_own_gate(self, monkeypatch, keys, route):
+        # attention_cached gates on the key length, at the value it had
+        # before the crossover of attention() was measured.
         monkeypatch.delenv("LUMEN_FLASH", raising=False)
         monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
-        assert not attn_mod._flash_usable(64, None, 50)
-        assert not attn_mod._flash_usable(64, None, 77)
-        assert attn_mod._flash_usable(64, None, 256)
-        assert attn_mod._flash_usable(64, None, 1024)
-        # explicit masks and oversized heads always fall back
-        assert not attn_mod._flash_usable(64, object(), 1024)
-        assert not attn_mod._flash_usable(512, None, 1024)
-        # forcing bypasses the gate (CPU interpret-mode tests)
-        monkeypatch.setenv("LUMEN_FLASH", "1")
-        assert attn_mod._flash_usable(64, None, 50)
-        monkeypatch.setenv("LUMEN_FLASH", "0")
-        assert not attn_mod._flash_usable(64, None, 1024)
-        # threshold is env-tunable for on-chip A/B exploration
+        usable = attn_mod._flash_usable(64, None, keys, attn_mod._FLASH_CACHE_MIN_KEYS)
+        assert ("flash" if usable else "xla") == route
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_attention_at_the_tower_shape_against_float32(self, monkeypatch, causal):
+        """``attention()`` at ViT-L/14's shape in the stated precision
+        (bfloat16 operands, float32 scores and softmax) against the same
+        values computed in float32. Tolerance from the dtype: outputs are
+        weighted means of unit-scale values, rounded to bfloat16 (8 bits:
+        2**-9 relative), as are the softmax weights before the second
+        product; 2**-7, absolute plus relative, is twice the widest reading."""
+        from lumen_tpu.ops import attention
+
         monkeypatch.delenv("LUMEN_FLASH", raising=False)
-        monkeypatch.setenv("LUMEN_FLASH_MIN_SEQ", "64")
-        assert attn_mod._flash_usable(64, None, 77)
+        q, k, v = rand_qkv(jax.random.PRNGKey(7), b=2, h=16, sq=257, sk=257, d=64, dtype=jnp.bfloat16)
+        out = attention(q, k, v, causal=causal)
+        assert out.dtype == jnp.bfloat16 and out.shape == q.shape
+        ref = attention_reference(*(x.astype(jnp.float32) for x in (q, k, v)), causal=causal)
+        np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref), atol=2**-7, rtol=2**-7)
+
+    def test_attention_route_gauge_counts_traced_calls(self, monkeypatch):
+        """The route is chosen when a program is traced; the
+        ``attention-route`` gauge provider counts the choices by route and
+        query length."""
+        from lumen_tpu.ops import attention
+        from lumen_tpu.utils.metrics import metrics
+
+        monkeypatch.delenv("LUMEN_FLASH", raising=False)
+        monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)  # trace only: nothing runs
+        long_seq = 2 * CROSSOVER
+        before = dict(metrics.snapshot()["gauges"].get("attention-route", {}))
+        for seq in (257, long_seq):
+            spec = jax.ShapeDtypeStruct((1, 2, seq, 64), jnp.bfloat16)
+            jax.eval_shape(lambda q, k, v: attention(q, k, v), spec, spec, spec)
+        after = metrics.snapshot()["gauges"]["attention-route"]
+        moved = {key: after[key] - before.get(key, 0) for key in after if after[key] != before.get(key, 0)}
+        assert moved == {"xla:257": 1, f"flash:{long_seq}": 1}
 
     def test_repeat_kv(self):
         x = jnp.arange(2 * 2 * 3 * 4).reshape(2, 2, 3, 4)

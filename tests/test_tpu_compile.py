@@ -102,9 +102,18 @@ CASES = {
     "paged_decode": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 128),
     "paged_decode_8k_row": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 512),
     "paged_varq_w5": _paged(att.paged_attention_varq_kernel, (B, 5, HEADS, HEAD_DIM), 128),
-    "flash_prefill": (
+    # attention() hands the kernel nothing shorter than the crossover
+    "flash_prefill_crossover": (
         lambda q, k, v: att.flash_attention(q, k, v, causal=True, interpret=False),
-        [((B, HEADS, 512, HEAD_DIM), BF16)] * 3,
+        [((B, HEADS, att._FLASH_CROSSOVER_SEQ, HEAD_DIM), BF16)] * 3,
+    ),
+    "flash_prefill_8k": (
+        lambda q, k, v: att.flash_attention(q, k, v, causal=True, interpret=False),
+        [((1, HEADS, 8192, HEAD_DIM), BF16)] * 3,
+    ),
+    "flash_unmasked_crossover": (
+        lambda q, k, v: att.flash_attention(q, k, v, interpret=False),
+        [((1, 16, att._FLASH_CROSSOVER_SEQ, HEAD_DIM), BF16)] * 3,
     ),
     "flash_cache_sq1": _flash_cache(1, 2048),
     "flash_cache_sq256": _flash_cache(256, 2048),
@@ -174,3 +183,36 @@ def test_latent_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
     text = jax.jit(fn).lower(*args).compile().as_text()
     kernels = _kernel_names(text)
     assert kernels and all(re.search(pattern, k) for k in kernels), kernels
+
+
+@pytest.mark.parametrize("at_crossover", [False, True], ids=["257_tokens", "crossover"])
+def test_clip_image_tower_route_by_sequence_length(v5e, monkeypatch, at_crossover):
+    """The CLIP ``encode_images`` program at ViT-L/14 widths (1,024 wide, 16
+    heads of 64, 14-px patches, two of the 24 layers): at 224 px, 257
+    tokens, it holds no Mosaic call, the compiler's own attention serves
+    it; a tower whose sequence reaches the crossover holds the kernel."""
+    from lumen_tpu.models.clip.modeling import CLIPConfig, CLIPModel, TowerConfig
+
+    monkeypatch.delenv("LUMEN_FLASH", raising=False)
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)  # default_backend() is the CPU here
+    # patches a side: 16 at 224 px, else the smallest square grid that reaches the crossover
+    side = next(n for n in range(16, 200) if n * n + 1 >= att._FLASH_CROSSOVER_SEQ) if at_crossover else 16
+    cfg = CLIPConfig(
+        embed_dim=768, image_size=14 * side, patch_size=14,
+        vision=TowerConfig(1024, 2, 16), text=TowerConfig(768, 1, 12),
+    )
+    model = CLIPModel(cfg)
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, cfg.image_size, cfg.image_size, 3)), jnp.zeros((1, cfg.context_length), I32),
+    )["params"]
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=v5e), shapes)
+    rows = 1 if at_crossover else 8
+    pixels = jax.ShapeDtypeStruct((rows, cfg.image_size, cfg.image_size, 3), jnp.uint8, sharding=v5e)
+
+    def encode_images(p, pixels_u8):  # as models/clip/manager.py builds it
+        x = pixels_u8.astype(jnp.float32) / 255.0
+        return model.apply({"params": p}, x.astype(BF16), method=lambda m, px: m.encode_image(px))
+
+    text = jax.jit(encode_images).lower(params, pixels).compile().as_text()
+    assert ("tpu_custom_call" in text) == at_crossover
