@@ -19,7 +19,7 @@ from dataclasses import dataclass, field
 class ChipSpec:
     """One TPU generation, keyed by the ``device_kind`` strings JAX
     reports. Peak figures are public per-chip numbers, used for batch
-    sizing here and MFU math in ``bench.py``."""
+    sizing here and for the MFU metrics of ``benchmark/``."""
 
     generation: str
     kind_patterns: tuple[str, ...]  # matched against jax device_kind, lowercased
@@ -35,7 +35,7 @@ class ChipSpec:
 CHIP_SPECS: tuple[ChipSpec, ...] = (
     ChipSpec("v6e", ("v6 lite", "v6e"), 32.0, 918.0, base_batch=64),
     # v5e clip_batch=128: a round-3 on-chip run put the ViT-B/32 embed at
-    # batch 256 / 5322 images/sec (BASELINE.md; provisional provenance,
+    # batch 256 / 5322 images/sec (round 3, git history; provisional provenance,
     # but the implied 23.5% MFU is exactly where this shape lands on a
     # 197-TFLOP chip), and first principles agree — batch-128 ViT-B/32
     # activations are tens of MB against 16 GB HBM, so 32 was simply

@@ -12,7 +12,6 @@
  * @property {number=} max_batch_latency_ms
  * @property {(number[]|null)=} batch_buckets
  * @property {boolean=} warmup
- * @property {"coalesce"|"continuous"=} scheduler
  * @property {number=} decode_block
  * @property {(string|null)=} quantize
  * @property {(number|null)=} max_seq

@@ -165,17 +165,9 @@ class BackendSettings(BaseModel):
     batch_buckets: list[int] | None = None
     # Compile every batch bucket at startup instead of on first request.
     warmup: bool = False
-    # VLM decode scheduling: "continuous" (the default) runs the paged-KV
-    # continuous-batching engine — requests admit/retire at step
-    # granularity into a shared page pool, no queueing behind long
-    # generations; "coalesce" groups same-shape concurrent requests into
-    # one fused-loop program (lowest dispatch overhead, best for
-    # same-shaped bursts). LUMEN_VLM_SCHEDULER overrides either at boot.
-    # Other services ignore this.
-    scheduler: Literal["coalesce", "continuous"] = "continuous"
-    # Continuous scheduler only: decode steps per compiled block (one host
-    # dispatch per block; larger amortizes dispatch, smaller admits and
-    # retires rows sooner). Ignored by "coalesce".
+    # VLM only: decode steps per compiled block of the paged continuous
+    # engine (one host dispatch per block; larger amortizes dispatch,
+    # smaller admits and retires rows sooner). Other services ignore this.
     decode_block: int = Field(8, ge=1)
     # VLM only: weight-only int8 for the decoder's attention + MLP
     # projections (per-channel scales). Halves the dominant HBM traffic of

@@ -159,8 +159,9 @@ class CLIPManager:
         self.model = CLIPModel(self.cfg)
         self.model_id = self.info.name
         # Serving route actually in use ("bf16" | "int8"): int8 is opt-in
-        # via `quantize` AND verified — BENCH_r05 measured q8 at 0.923x
-        # bf16 on v5e, so a warmup-timed A/B may fall the route back.
+        # via `quantize` AND verified — q8 measured 0.923x bf16 on v5e
+        # (round-5 chip run, 2026-08-02, older than the ledger), so a
+        # warmup-timed A/B may fall the route back.
         self.quant_route = "bf16"
         self.quant_speedup: float | None = None  # measured q8/bf16, when timed
         self._initialized = False
@@ -544,7 +545,8 @@ class CLIPManager:
     def _pick_quant_route(self, base_model, params, qparams, place, make_encoders):
         """Decide whether the explicit int8 opt-in actually serves int8.
 
-        BENCH_r05 measured the W8A8 dynamic kernel at 0.923x bf16 on v5e —
+        The W8A8 dynamic kernel measured 0.923x bf16 on v5e (round-5 chip
+        run, 2026-08-02, older than the ledger) —
         a *regression* the operator opting into "int8" almost certainly
         did not want. So when warmup is on, the two routes run a one-shot
         timed A/B at the top serving bucket and the loser's params are

@@ -1,12 +1,10 @@
 """Continuous batching over a paged KV pool for VLM generation.
 
-The coalescing batcher (``manager._GenBatcher``) groups only requests that
-arrive within one small latency window AND share a prompt bucket; once a
-fused generation program launches, everything behind it queues until the
-longest row finishes. The slot-era version of this scheduler removed that
-cliff but still gave every decode row a contiguous ``max_seq`` KV region —
-the pool paid worst-case memory per slot and admission needed a same-shape
-bucket. This engine is the paged rebuild:
+The one engine VLM requests are served by. A batcher that launches one
+fused generation program per group of same-shaped requests makes everything
+behind it queue until the longest row finishes, and a contiguous ``max_seq``
+KV region per decode row pays worst-case memory per slot and needs a
+same-shape bucket at admission. This engine does neither:
 
 - KV lives in a shared POOL OF PAGES (``paged_kv.PagedKVPool`` host
   accounting + ``Generator.init_pool`` device arrays); each row owns a
@@ -43,7 +41,7 @@ Per-step occupancy (active rows / pool pages) is published as gauges and
 each decode block lands a ``batch.device`` span on every active request's
 trace. The reference serves one request at a time per process
 (``packages/lumen-vlm/src/lumen_vlm/backends/onnxrt_backend.py:298-356``);
-neither strategy has an upstream equivalent.
+this has no upstream equivalent.
 """
 
 from __future__ import annotations
@@ -70,7 +68,6 @@ from ...utils.shm_arena import ShmArena
 from ...utils.telemetry import record_event
 from ...utils.trace import current_trace, phase
 from . import migration
-from .manager import _PendingGen
 from .modeling import prefix_ladder
 from .paged_kv import (
     DEFAULT_PAGE_SIZE,
@@ -108,13 +105,29 @@ def _retire(req: "_Request", tokens: list, eos: bool) -> None:
 
 
 @dataclass
-class _Request(_PendingGen):
-    """One continuous-batching request: the batcher's fields plus a
-    per-request rng, an optional stream queue, a cancel flag (set when
-    a stream consumer goes away so the slot stops decoding), and the
-    submitter's trace (decode blocks land ``batch.device`` spans on it)."""
+class _Request:
+    """One continuous-batching request: the prepared prompt and its
+    generation parameters, a per-request rng, an optional stream queue, a
+    cancel flag (set when a stream consumer goes away so the slot stops
+    decoding), and the submitter's trace (decode blocks land
+    ``batch.device`` spans on it)."""
 
+    embeds: object  # [1, L, H]
+    positions: object  # [1, L]
+    length: object  # [1]
+    prompt_ids: object  # [1, S]
+    max_new: int
+    temperature: float
+    top_p: float
+    do_sample: bool
+    repetition_penalty: float
     rng: object = None
+
+    @property
+    def key(self) -> tuple:
+        # Only identically-shaped requests share one admission program.
+        return (self.embeds.shape[1], self.prompt_ids.shape[1])
+
     future: Future = field(default_factory=Future)
     stream_q: "queue_mod.SimpleQueue | None" = None
     cancelled: bool = False

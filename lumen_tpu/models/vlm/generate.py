@@ -1,13 +1,15 @@
-"""Fused autoregressive generation: prefill + ``lax.while_loop`` decode.
+"""Compiled generation programs: the paged pool's admit / block-step /
+chunked-prefill programs the continuous engine serves from, and a fused
+prefill + ``lax.while_loop`` loop over a contiguous cache kept as the
+reference they are held to.
 
-The reference decodes with a host python loop — one onnxruntime session
-call per token, rebuilding the attention mask and renaming ``present.*``
-outputs each step (``packages/lumen-vlm/src/lumen_vlm/backends/
-onnxrt_backend.py:298-356``, ``:480-492``). Here the entire loop — embed,
-decoder forward over the static KV cache, repetition penalty, temperature/
-top-p sampling, EOS check — is ONE compiled XLA program; the host sees only
-the final token buffer. Streaming keeps a host loop for chunk delivery but
-each step is still a single compiled call (no mask rebuilds, no renames).
+The reference implementation decodes with a host python loop — one
+onnxruntime session call per token, rebuilding the attention mask and
+renaming ``present.*`` outputs each step (``packages/lumen-vlm/src/lumen_vlm/
+backends/onnxrt_backend.py:298-356``, ``:480-492``). Here a block of decode
+steps — embed, decoder forward over the paged KV pool, repetition penalty,
+temperature/top-p sampling, EOS check — is ONE compiled XLA program
+(``_step_block_impl``); the host sees a block's tokens at a time.
 
 Sampling semantics follow the reference (``:508-533``): greedy when
 ``do_sample`` is false or temperature ~ 0, else temperature + nucleus.
@@ -69,7 +71,6 @@ class Generator:
         self.seq_buckets = tuple(buckets)
         self._generate = jax.jit(self._generate_impl, static_argnames=("kv_len",))
         self._prefill = jax.jit(self._prefill_impl, static_argnames=("kv_len",))
-        self._step = jax.jit(self._step_impl)
         # The paged KV pool is the dominant buffer; donating it lets XLA
         # update in place instead of holding two copies across every
         # admit/block dispatch. Block tables are host-managed (numpy in
@@ -170,7 +171,7 @@ class Generator:
         last = logits[jnp.arange(b), lengths - 1]  # [B, V] next-token logits
         return caches, last
 
-    # -- fused non-streaming path -------------------------------------------
+    # -- reference loop (contiguous cache; nothing serves from it) -----------
 
     def _generate_impl(
         self,
@@ -272,10 +273,12 @@ class Generator:
         do_sample=False,
         repetition_penalty=1.0,
     ) -> GenerateOutput:
-        """Each generation param may be a python scalar (shared by the whole
-        batch) or a length-B sequence (batched serving with mixed request
-        configs — the capability the reference's one-request-at-a-time
-        backend lacks, ``onnxrt_backend.py:298-356``)."""
+        """The contiguous-cache reference loop: prefill + one fused
+        ``while_loop`` over a ``[B, kv_len]`` cache. Nothing serves from it
+        (the continuous engine does, over pages); parity tests, the arch
+        parity script and the manager's int8 boot A/B compare against it.
+        Each generation param may be a python scalar (shared by the whole
+        batch) or a length-B sequence."""
         cap = np.minimum(np.asarray(max_new_tokens, np.int32), self.max_new_cap)
         # KV bucket: smallest configured size covering prompt + budget.
         # embeds may be right-padded past the live length, and the decode
@@ -299,16 +302,15 @@ class Generator:
         )
         return GenerateOutput(tokens=buf, n_generated=n_gen, stopped_eos=eos)
 
-    # -- streaming path (host loop, one compiled call per step) -------------
+    # -- whole-prompt admission prefill --------------------------------------
 
     def _prefill_impl(
         self, params, embeds, positions, lengths, prompt_ids, rng,
         temperature, top_p, do_sample, repetition_penalty,
         kv_len: int | None = None,  # static KV bucket; None = max_seq.
-        # The streaming path decodes INTO this cache, so it must keep the
-        # full max_seq; continuous admission only needs the prompt span
-        # (decode happens in the pool's own full-size cache) and passes
-        # the smallest bucket covering the prompt.
+        # Continuous admission only needs the prompt span (decode happens
+        # in the pool's own pages) and passes the smallest bucket covering
+        # the prompt.
     ):
         caches, last_logits = self._prefill_core(params, embeds, positions, lengths, kv_len)
         seen = self._seen_from_prompt(prompt_ids, lengths)
@@ -316,22 +318,6 @@ class Generator:
             rng, last_logits, seen, temperature, top_p, do_sample, repetition_penalty
         ).astype(jnp.int32)
         return caches, tok0, seen
-
-    def _step_impl(
-        self, params, caches, cur_tok, cur_len, seen, rng,
-        temperature, top_p, do_sample, repetition_penalty,
-    ):
-        self._no_latent("contiguous-cache decode")
-        b = cur_tok.shape[0]
-        seen = seen.at[jnp.arange(b), cur_tok].max(True)
-        tok_embed = self._embed(params, cur_tok[:, None]).astype(self.cache_dtype)
-        logits, caches = self._decode(
-            params, tok_embed, cur_len[:, None], caches, cur_len, cur_len + 1
-        )
-        nxt = self._sample_next(
-            rng, logits[:, 0], seen, temperature, top_p, do_sample, repetition_penalty
-        ).astype(jnp.int32)
-        return caches, nxt, seen
 
     # -- continuous-batching pool programs (paged KV) ------------------------
     #
@@ -685,40 +671,3 @@ class Generator:
             done=done,
         )
         return new_pool, rng, toks_out
-
-    def stream(
-        self,
-        params,
-        embeds,
-        positions,
-        lengths,
-        prompt_ids,
-        rng,
-        max_new_tokens: int = 256,
-        temperature: float = 0.0,
-        top_p: float = 1.0,
-        do_sample: bool = False,
-        repetition_penalty: float = 1.0,
-    ):
-        """Yield generated token ids one at a time (batch size 1 semantics:
-        yields ints). Stops after EOS or ``max_new_tokens``."""
-        t_ = jnp.asarray(temperature, jnp.float32)
-        p_ = jnp.asarray(top_p, jnp.float32)
-        s_ = jnp.asarray(do_sample, bool)
-        r_ = jnp.asarray(repetition_penalty, jnp.float32)
-        rng, sub = jax.random.split(rng)
-        caches, tok, seen = self._prefill(
-            params, embeds, positions, lengths, prompt_ids, sub, t_, p_, s_, r_
-        )
-        cur_len = lengths.astype(jnp.int32)
-        cap = min(int(max_new_tokens), self.max_new_cap)
-        for _ in range(cap):
-            tok_host = int(tok[0])
-            yield tok_host
-            if tok_host == self.cfg.eos_token_id:
-                return
-            rng, sub = jax.random.split(rng)
-            caches, tok, seen = self._step(
-                params, caches, tok, cur_len, seen, sub, t_, p_, s_, r_
-            )
-            cur_len = cur_len + 1

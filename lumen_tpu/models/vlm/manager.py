@@ -4,9 +4,10 @@ Business-logic layer mirroring the reference ``FastVLMModelManager``
 (``packages/lumen-vlm/src/lumen_vlm/fastvlm/fastvlm_model.py:51-400``) over
 the TPU-native stack: host does image decode + letterbox + tokenize; device
 runs ONE compiled prepare program (normalize -> vision encode -> token embed
--> image-token splice) and ONE compiled generate program (prefill +
-while_loop decode, ``generate.py``). Prompt lengths are padded to static
-buckets so the number of distinct compiles is bounded.
+-> image-token splice) and hands the row to the one serving engine, the
+paged continuous scheduler (``continuous.py``: chunked prefill lane + block
+decode over a page pool). Prompt lengths are padded to static buckets so
+the number of distinct compiles is bounded.
 """
 
 from __future__ import annotations
@@ -59,151 +60,6 @@ class GenerationChunk:
     metadata: dict[str, Any] = field(default_factory=dict)
 
 
-@dataclass
-class _PendingGen:
-    """One queued generation request inside the batcher."""
-
-    embeds: Any  # [1, L, H]
-    positions: Any  # [1, L]
-    length: Any  # [1]
-    prompt_ids: Any  # [1, S]
-    max_new: int
-    temperature: float
-    top_p: float
-    do_sample: bool
-    repetition_penalty: float
-    future: Any = None
-
-    @property
-    def key(self) -> tuple:
-        # Only identically-shaped requests share one compiled program.
-        return (self.embeds.shape[1], self.prompt_ids.shape[1])
-
-
-class _GenBatcher:
-    """Batched decode scheduler: collects concurrent ``generate`` requests
-    with the same prompt-bucket shape and decodes them as one [B>1]
-    program. Replaces the round-1 single-flight lock — the decoder's
-    per-sample cache offsets (``modeling.py``) already support mixed
-    positions, and per-sample sampling params (``ops/sampling.py``) support
-    mixed request configs, so aggregate tokens/sec scales with batch.
-    """
-
-    def __init__(
-        self, runner, max_batch: int = 4, max_latency_ms: float = 6.0,
-        name: str = "vlm",
-    ):
-        from concurrent.futures import Future
-
-        self._Future = Future
-        self._runner = runner
-        # Gauge provider id: per-model-name, matching the batcher's
-        # ``batcher:{name}`` semantics — distinct models coexist; a
-        # same-name replacement takes over the slot (last-writer-wins
-        # register, ownership-guarded unregister).
-        self.name = name
-        self.max_batch = max_batch
-        self.max_latency_s = max_latency_ms / 1e3
-        self.batches_run = 0  # observability: how often we actually batched
-        self.rows_run = 0
-        self._queue: list[_PendingGen] = []
-        self._cond = threading.Condition()
-        self._closed = False
-        self._thread = threading.Thread(target=self._loop, name="vlm-gen-batcher", daemon=True)
-        self._thread.start()
-        ref = weakref.ref(self)  # registry must not pin the runner/params
-
-        def _gauges() -> dict:
-            b = ref()
-            if b is None:
-                return {}
-            return {
-                "batches_run": b.batches_run,
-                "rows_run": b.rows_run,
-                "queue_depth": len(b._queue),
-            }
-
-        self._gauge_fn = _gauges
-        metrics.register_gauges(f"vlm-coalesce:{self.name}", _gauges)
-
-    def submit(self, item: _PendingGen):
-        item.future = self._Future()
-        with self._cond:
-            if self._closed:
-                raise RuntimeError("generation batcher is closed")
-            self._queue.append(item)
-            self._cond.notify()
-        return item.future
-
-    def close(self) -> None:
-        with self._cond:
-            self._closed = True
-            self._cond.notify()
-        self._thread.join(timeout=5)
-        with self._cond:
-            pending, self._queue = self._queue, []
-        for item in pending:
-            item.future.set_exception(RuntimeError("generation batcher closed"))
-        if fn := getattr(self, "_gauge_fn", None):
-            metrics.unregister_gauges(f"vlm-coalesce:{self.name}", fn)
-
-    def _take_batch(self) -> list[_PendingGen]:
-        with self._cond:
-            while not self._queue and not self._closed:
-                self._cond.wait()
-            if not self._queue:
-                return []
-            head = self._queue.pop(0)
-        batch = [head]
-        deadline = time.perf_counter() + self.max_latency_s
-        while len(batch) < self.max_batch:
-            with self._cond:
-                take = [i for i, it in enumerate(self._queue) if it.key == head.key]
-                for offset, i in enumerate(take[: self.max_batch - len(batch)]):
-                    batch.append(self._queue.pop(i - offset))
-            if len(batch) >= self.max_batch:
-                break
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
-            with self._cond:
-                self._cond.wait(timeout=remaining)
-                if self._closed:
-                    break
-        return batch
-
-    def _loop(self) -> None:
-        try:
-            while True:
-                batch = self._take_batch()
-                if not batch:
-                    if self._closed:
-                        return
-                    continue
-                # Count before the futures resolve so a caller that joins
-                # its threads and immediately reads the counters sees this
-                # batch.
-                self.batches_run += 1
-                self.rows_run += len(batch)
-                try:
-                    self._runner(batch)
-                except Exception as e:  # noqa: BLE001 - fan the failure out
-                    for item in batch:
-                        if not item.future.done():
-                            item.future.set_exception(e)
-        finally:
-            # Worker death for ANY reason (incl. BaseException like
-            # KeyboardInterrupt) must not strand callers blocked on
-            # futures: close the queue and fail everything pending.
-            with self._cond:
-                self._closed = True
-                pending, self._queue = self._queue, []
-            err = RuntimeError("generation batcher worker exited")
-            for item in pending:
-                if item.future is not None and not item.future.done():
-                    item.future.set_exception(err)
-
-
 class VLMManager:
     def __init__(
         self,
@@ -213,9 +69,6 @@ class VLMManager:
         max_new_cap: int = 512,
         prefill_buckets: Sequence[int] = DEFAULT_PREFILL_BUCKETS,
         warmup: bool = False,
-        gen_batch_size: int = 4,
-        gen_batch_latency_ms: float = 6.0,
-        scheduler: str = "continuous",  # or "coalesce"
         gen_slots: int = 8,
         gen_block: int = 8,
         quantize: str | None = None,  # None | "int8" (weight-only decoder quant)
@@ -226,45 +79,21 @@ class VLMManager:
         self.quantize = quantize
         # Decode route actually in use ("bf16" | "int8"): finalized at
         # initialize() — a warmup A/B (LUMEN_VLM_Q8_ROUTE=auto) may fall an
-        # int8 opt-in back to bf16 when q8 measures slower (BENCH_r05:
-        # q8 decode at 0.03x bf16 on v5e).
+        # int8 opt-in back to bf16 when q8 measures slower (q8 decode at
+        # 0.03x bf16 on v5e; round-5 chip run, 2026-08-02, older than the
+        # ledger).
         self.quant_route = "int8" if quantize else "bf16"
         self.quant_speedup: float | None = None  # measured q8/bf16 decode ratio
         self.model_dir = model_dir
-        from ...utils.env import env_choice
-
-        # LUMEN_VLM_SCHEDULER overrides the constructor/config choice at
-        # boot (one-shot warning on malformed values) — flipping engines
-        # must not require a config rollout.
-        env_sched = env_choice("LUMEN_VLM_SCHEDULER", None, ("coalesce", "continuous"))
-        if env_sched is not None and env_sched != scheduler:
-            logger.info(
-                "VLM scheduler %r overridden to %r by LUMEN_VLM_SCHEDULER",
-                scheduler, env_sched,
-            )
-            scheduler = env_sched
-        from ...runtime.fleet import plan_replicas, replicas_for
+        from ...runtime.fleet import plan_replicas
 
         # Serving mesh: a ``model`` axis tensor-parallelizes the decoder, an
         # ``expert`` axis shards MoE expert banks (SURVEY §2.8); without
         # either the mesh is the trivial data mesh and weights replicate.
-        # The continuous engine is built PER REPLICA through the fleet
-        # plan (one engine + page pool per device slice, PR 7 semantics);
-        # the coalescing batcher stays a singleton over the full mesh.
-        if scheduler == "continuous":
-            self.fleet_plan = plan_replicas("vlm", mesh_axes)
-            self.mesh = self.fleet_plan.meshes[0]
-        else:
-            from ...runtime.mesh import build_mesh
-
-            self.fleet_plan = None
-            self.mesh = build_mesh(mesh_axes) if mesh_axes else build_mesh()
-            if replicas_for("vlm") != 1:  # includes the "max" sentinel (-1)
-                logger.warning(
-                    "LUMEN_REPLICAS(_VLM) > 1 requested but the coalescing "
-                    "VLM scheduler is not replica-fleeted; serving 1 replica "
-                    "over the full mesh (use scheduler=continuous to fleet)"
-                )
+        # The engine is built PER REPLICA through the fleet plan (one
+        # engine + page pool per device slice, PR 7 semantics).
+        self.fleet_plan = plan_replicas("vlm", mesh_axes)
+        self.mesh = self.fleet_plan.meshes[0]
         from ...ops.quant_matmul import note_mesh_model_axis
 
         # TP x int8: pl.pallas_call has no GSPMD sharding rule, so a
@@ -275,11 +104,6 @@ class VLMManager:
         self.max_seq = max_seq
         self.max_new_cap = max_new_cap
         self.prefill_buckets = sorted(prefill_buckets)
-        self.gen_batch_size = gen_batch_size
-        self.gen_batch_latency_ms = gen_batch_latency_ms
-        if scheduler not in ("coalesce", "continuous"):
-            raise ValueError(f"scheduler must be 'coalesce' or 'continuous', got {scheduler!r}")
-        self.scheduler = scheduler
         self.gen_slots = gen_slots
         self.gen_block = gen_block
         self.info: ModelInfo = load_model_info(model_dir)
@@ -308,11 +132,6 @@ class VLMManager:
         self.vision_tokens = self.cfg.vision.num_tokens
         self._seed_lock = threading.Lock()
         self._seed = 0
-        # Each live stream holds a full [1, max_seq] KV cache in device
-        # memory; without a bound, N concurrent streams allocate N caches
-        # and can exhaust HBM (batched generate() is already bounded by
-        # the single batcher thread).
-        self._stream_slots = threading.Semaphore(max(1, gen_batch_size))
 
     def _build_config(self, model_dir: str) -> VLMConfig:
         cfg_path = os.path.join(model_dir, "config.json")
@@ -400,8 +219,9 @@ class VLMManager:
 
     def _resolve_q8_route(self, converted: dict) -> dict:
         """Decide whether the int8 decode opt-in actually serves int8 —
-        the VLM twin of the CLIP route gate (PR 2). BENCH_r05 measured q8
-        decode at 135 tok/s vs 4,498 bf16 (0.03x) on v5e: an operator who
+        the VLM twin of the CLIP route gate (PR 2). q8 decode measured 135
+        tok/s vs 4,498 bf16 (0.03x) on v5e (round-5 chip run, 2026-08-02,
+        older than the ledger): an operator who
         opted into "int8" for memory almost certainly did not want a 30x
         decode regression. ``LUMEN_VLM_Q8_ROUTE``:
 
@@ -448,7 +268,8 @@ class VLMManager:
         else:
             # Disk-tier verdict cache (next to the weights, keyed by
             # model@revision): the warmup A/B measured q8 decode at 0.03x
-            # bf16 on v5e (BENCH_r05) — re-running the losing probe every
+            # bf16 on v5e (round-5 chip run, 2026-08-02, older than the
+            # ledger) — re-running the losing probe every
             # boot costs two timed decode passes for a known answer. An
             # explicit pin (route != auto) still bypasses the cache, and
             # a cache miss (new revision) re-measures and re-persists.
@@ -560,7 +381,7 @@ class VLMManager:
         program — prefill + while_loop step — at a timing-sized KV), best
         of 2 after a compile pass. The placement is freed before return."""
         prompt_len, new_tokens = 16, 24
-        batch = max(1, min(4, self.gen_batch_size))
+        batch = max(1, min(4, self.gen_slots))
         placed = self._place_params(params, quantized=quantized)
         gen = Generator(
             model, cfg,
@@ -749,85 +570,74 @@ class VLMManager:
 
         self._prepare = prepare
         self._prepare_text = prepare_text
-        self._batcher = None
-        self._continuous = None
-        self._engines = []
         self._engine_fleet = None
-        if self.scheduler == "continuous":
-            from ...runtime.fleet import batcher_name
-            from ...utils.env import env_int
-            from .continuous import ContinuousScheduler
-            from .paged_kv import DEFAULT_PAGE_SIZE, LATENT_PAGE_SIZE, resolve_pool_pages
+        from ...runtime.fleet import batcher_name
+        from ...utils.env import env_int
+        from .continuous import ContinuousScheduler
+        from .paged_kv import DEFAULT_PAGE_SIZE, LATENT_PAGE_SIZE, resolve_pool_pages
 
-            self._page_size = env_int(
-                "LUMEN_VLM_PAGE_SIZE",
-                LATENT_PAGE_SIZE if self.cfg.decoder.latent else DEFAULT_PAGE_SIZE,
-                minimum=8, maximum=256,
+        self._page_size = env_int(
+            "LUMEN_VLM_PAGE_SIZE",
+            LATENT_PAGE_SIZE if self.cfg.decoder.latent else DEFAULT_PAGE_SIZE,
+            minimum=8, maximum=256,
+        )
+        self._pool_pages, self.pool_source = resolve_pool_pages(
+            self.cfg, self._page_size, self.gen_slots, self.max_seq,
+            dtype_bytes=jnp.dtype(compute).itemsize, block=self.gen_block,
+        )
+        plan = self.fleet_plan
+
+        def build_engine(rid: int | None, mesh, placed) -> ContinuousScheduler:
+            """Manager factory for one per-replica decode engine: its
+            own page pool + block tables on the replica's mesh slice,
+            per-replica gauge names (``vlm-continuous:<model>-rN``)."""
+            return ContinuousScheduler(
+                self.generator, placed, slots=self.gen_slots,
+                block=self.gen_block,
+                name=batcher_name(self.info.name, rid),
+                page_size=self._page_size, pages=self._pool_pages,
+                mesh=mesh if plan.replicas > 1 else None,
             )
-            self._pool_pages, self.pool_source = resolve_pool_pages(
-                self.cfg, self._page_size, self.gen_slots, self.max_seq,
-                dtype_bytes=jnp.dtype(compute).itemsize, block=self.gen_block,
+
+        self._engine_factory = build_engine
+        self._engines = [
+            build_engine(None if plan.replicas == 1 else 0, plan.meshes[0], self.params)
+        ]
+        for rid in range(1, plan.replicas):
+            placed = self._place_params(params, mesh=plan.meshes[rid])
+            self._engines.append(build_engine(rid, plan.meshes[rid], placed))
+        self._continuous = self._engines[0]
+        if plan.replicas > 1:
+            from ...runtime.fleet import EngineFleet
+
+            def rebuild_engine(rid: int) -> ContinuousScheduler:
+                """Unpark hook: re-place the (already device-resident)
+                params on the replica's original mesh slice and build
+                a fresh engine there. The migration dispatcher is
+                wired at server boot only, so copy it over from a
+                surviving sibling — a rebuilt engine in a role-tagged
+                fleet must keep exporting rows."""
+                placed = self._place_params(
+                    self.params, mesh=plan.meshes[rid]
+                )
+                eng = build_engine(rid, plan.meshes[rid], placed)
+                fleet = self._engine_fleet
+                if fleet is not None:
+                    for sib in fleet.serving_engines():
+                        if sib.migrator is not None:
+                            eng.migrator = sib.migrator
+                            break
+                return eng
+
+            self._engine_fleet = EngineFleet(
+                self.info.name, list(self._engines),
+                build=rebuild_engine,
+                devices_per_replica=plan.devices_per_replica,
             )
-            plan = self.fleet_plan
-
-            def build_engine(rid: int | None, mesh, placed) -> ContinuousScheduler:
-                """Manager factory for one per-replica decode engine: its
-                own page pool + block tables on the replica's mesh slice,
-                per-replica gauge names (``vlm-continuous:<model>-rN``)."""
-                return ContinuousScheduler(
-                    self.generator, placed, slots=self.gen_slots,
-                    block=self.gen_block,
-                    name=batcher_name(self.info.name, rid),
-                    page_size=self._page_size, pages=self._pool_pages,
-                    mesh=mesh if plan.replicas > 1 else None,
-                )
-
-            self._engine_factory = build_engine
-            self._engines = [
-                build_engine(None if plan.replicas == 1 else 0, plan.meshes[0], self.params)
-            ]
-            for rid in range(1, plan.replicas):
-                placed = self._place_params(params, mesh=plan.meshes[rid])
-                self._engines.append(build_engine(rid, plan.meshes[rid], placed))
-            self._continuous = self._engines[0]
-            if plan.replicas > 1:
-                from ...runtime.fleet import EngineFleet
-
-                def rebuild_engine(rid: int) -> ContinuousScheduler:
-                    """Unpark hook: re-place the (already device-resident)
-                    params on the replica's original mesh slice and build
-                    a fresh engine there. The migration dispatcher is
-                    wired at server boot only, so copy it over from a
-                    surviving sibling — a rebuilt engine in a role-tagged
-                    fleet must keep exporting rows."""
-                    placed = self._place_params(
-                        self.params, mesh=plan.meshes[rid]
-                    )
-                    eng = build_engine(rid, plan.meshes[rid], placed)
-                    fleet = self._engine_fleet
-                    if fleet is not None:
-                        for sib in fleet.serving_engines():
-                            if sib.migrator is not None:
-                                eng.migrator = sib.migrator
-                                break
-                    return eng
-
-                self._engine_fleet = EngineFleet(
-                    self.info.name, list(self._engines),
-                    build=rebuild_engine,
-                    devices_per_replica=plan.devices_per_replica,
-                )
-                logger.info(
-                    "VLM continuous engine fleet: %d replicas x %d slots "
-                    "(%d devices each)",
-                    plan.replicas, self.gen_slots, plan.devices_per_replica,
-                )
-        else:
-            self._batcher = _GenBatcher(
-                self._run_gen_batch,
-                max_batch=self.gen_batch_size,
-                max_latency_ms=self.gen_batch_latency_ms,
-                name=self.info.name,
+            logger.info(
+                "VLM continuous engine fleet: %d replicas x %d slots "
+                "(%d devices each)",
+                plan.replicas, self.gen_slots, plan.devices_per_replica,
             )
         self._initialized = True
         if self.warmup:
@@ -847,17 +657,12 @@ class VLMManager:
 
     def close(self) -> None:
         if self._initialized:
-            if self._batcher is not None:
-                self._batcher.close()
-            fleet = getattr(self, "_engine_fleet", None)
-            if fleet is not None:
+            if self._engine_fleet is not None:
                 # The fleet is authoritative after any unpark rebuilt an
                 # engine the boot-time _engines list has no reference to.
-                fleet.close()
+                self._engine_fleet.close()
             else:
-                for engine in getattr(self, "_engines", []) or (
-                    [self._continuous] if self._continuous is not None else []
-                ):
+                for engine in self._engines:
                     engine.close()
         if fn := getattr(self, "_route_gauge_fn", None):
             metrics.unregister_gauges(f"vlm-quant:{self.model_id}", fn)
@@ -880,19 +685,13 @@ class VLMManager:
     def kv_layout(self) -> str:
         """KV cache layout on the wire (capability ``extra``): operators
         and clients can see whether decode is paged without reading logs."""
-        if self._continuous is not None:
-            kv = self._continuous.kv
-            return (
-                f"paged(page={kv.page_size},pages={kv.pages_total},"
-                f"slots={self.gen_slots})"
-            )
-        return f"contiguous(max_seq={self.max_seq})"
+        kv = self._continuous.kv
+        return f"paged(page={kv.page_size},pages={kv.pages_total},slots={self.gen_slots})"
 
     def topology(self) -> dict[str, str]:
-        """Device topology for the capability ``extra``: the continuous
-        engine fleet reports one replica per device slice (built through
-        the manager factory); coalesce stays one replica over the full
-        mesh."""
+        """Device topology for the capability ``extra``: the engine fleet
+        reports one replica per device slice (built through the manager
+        factory)."""
         from ...runtime.fleet import topology_extra
 
         out = topology_extra(self.mesh)
@@ -958,7 +757,7 @@ class VLMManager:
         None when the cache is unconfigured — no hashing on the hot path."""
         from .prefix_cache import prefix_cache_enabled
 
-        if self._continuous is None or not prefix_cache_enabled():
+        if not prefix_cache_enabled():
             return None
         ids = np.asarray(prompt_ids)[0, :n].astype(np.int64)
         if not image_bytes:
@@ -977,9 +776,12 @@ class VLMManager:
         max_new_tokens, temperature, top_p, do_sample, repetition_penalty,
         prefix_content=None,
     ):
-        """One construction site for both schedulers' request objects —
-        adding a generation parameter means touching exactly here."""
-        common = dict(
+        """One construction site for the engine's request object — adding a
+        generation parameter means touching exactly here."""
+        from ...utils import disagg
+        from .continuous import _Request
+
+        req = _Request(
             embeds=embeds,
             positions=positions,
             length=lengths,
@@ -989,72 +791,22 @@ class VLMManager:
             top_p=float(top_p),
             do_sample=bool(do_sample),
             repetition_penalty=float(repetition_penalty),
+            rng=self._next_rng(),
+            prefix_content=prefix_content,
         )
-        if self._continuous is not None:
-            from ...utils import disagg
-            from .continuous import _Request
-
-            req = _Request(
-                rng=self._next_rng(), prefix_content=prefix_content, **common
-            )
-            owner = disagg.current()
-            if owner:
-                # Disaggregated serving: the front tier pinned this
-                # request's decode to a decode-lane peer; the scheduler
-                # migrates the row there right after prefill.
-                req.migrate_to = owner
-            return req
-        return _PendingGen(**common)
+        owner = disagg.current()
+        if owner:
+            # Disaggregated serving: the front tier pinned this
+            # request's decode to a decode-lane peer; the scheduler
+            # migrates the row there right after prefill.
+            req.migrate_to = owner
+        return req
 
     def _next_rng(self) -> jax.Array:
         with self._seed_lock:
             self._seed += 1
             seed = self._seed
         return jax.random.PRNGKey(seed)
-
-    # -- batched decode ----------------------------------------------------
-
-    def _run_gen_batch(self, items: list) -> None:
-        """Decode a same-shape group of requests as one [B] program and
-        fan the per-row results back out (runs on the batcher thread).
-
-        The batch dim is padded up to a power-of-two bucket (1,2,4,...)
-        so distinct compiled programs per prompt bucket stay bounded at
-        log2(max_batch)+1 instead of one per observed batch size — a
-        serving-time compile on the sole batcher thread stalls every
-        queued request. Padding rows replay row 0 with a zero budget, so
-        they exit the decode loop immediately."""
-        b = len(items)
-        bucket = 1
-        while bucket < b:
-            bucket *= 2
-        pad = bucket - b
-
-        def stack(rows, pad_row):
-            return jnp.concatenate(list(rows) + [pad_row] * pad, axis=0)
-
-        embeds = stack((it.embeds for it in items), items[0].embeds)
-        positions = stack((it.positions for it in items), items[0].positions)
-        lengths = stack((it.length for it in items), items[0].length)
-        prompt_ids = stack((it.prompt_ids for it in items), items[0].prompt_ids)
-        out = self.generator.generate(
-            self.params,
-            embeds,
-            positions,
-            lengths,
-            prompt_ids,
-            self._next_rng(),
-            max_new_tokens=[it.max_new for it in items] + [0] * pad,
-            temperature=[it.temperature for it in items] + [0.0] * pad,
-            top_p=[it.top_p for it in items] + [1.0] * pad,
-            do_sample=[it.do_sample for it in items] + [False] * pad,
-            repetition_penalty=[it.repetition_penalty for it in items] + [1.0] * pad,
-        )
-        tokens = np.asarray(out.tokens)
-        n_gen = np.asarray(out.n_generated)
-        eos = np.asarray(out.stopped_eos)
-        for i, item in enumerate(items):
-            item.future.set_result((tokens[i], int(n_gen[i]), bool(eos[i])))
 
     # -- generation --------------------------------------------------------
 
@@ -1167,11 +919,7 @@ class VLMManager:
             max_new_tokens, temperature, top_p, do_sample, repetition_penalty,
             prefix_content=self._prefix_content(prompt_ids, n_input, image_bytes),
         )
-        if self._continuous is not None:
-            future = self._pick_engine().submit(req)
-        else:
-            future = self._batcher.submit(req)
-        row_tokens, n_gen, stopped_eos = future.result()
+        row_tokens, n_gen, stopped_eos = self._pick_engine().submit(req).result()
         tokens = [int(t) for t in row_tokens[:n_gen]]
         text = self.tokenizer.decode(tokens)
         finish = "eos_token" if stopped_eos else "length"
@@ -1216,34 +964,6 @@ class VLMManager:
         # Hold back enough text that a stop sequence straddling a chunk
         # boundary can still be cut before emission.
         holdback = max((len(s) for s in stop_sequences), default=1) - 1 if stop_sequences else 0
-        # No global lock: the generator's prefill/step programs carry all
-        # state explicitly (caches are per-call values), so concurrent
-        # streams and batched generates interleave safely. The semaphore
-        # only bounds how many per-stream KV caches are live at once; the
-        # continuous scheduler's memory is the fixed slot pool instead, so
-        # its streams need no such bound.
-        if self._continuous is not None:
-            yield from self._stream_locked(
-                messages, image_bytes, max_new_tokens, temperature, top_p,
-                do_sample, repetition_penalty, stop_sequences, holdback, t0,
-                add_generation_prompt,
-            )
-            return
-        self._stream_slots.acquire()
-        try:
-            yield from self._stream_locked(
-                messages, image_bytes, max_new_tokens, temperature, top_p,
-                do_sample, repetition_penalty, stop_sequences, holdback, t0,
-                add_generation_prompt,
-            )
-        finally:
-            self._stream_slots.release()
-
-    def _stream_locked(
-        self, messages, image_bytes, max_new_tokens, temperature, top_p,
-        do_sample, repetition_penalty, stop_sequences, holdback, t0,
-        add_generation_prompt=True,
-    ) -> Iterator[GenerationChunk]:
         embeds, positions, lengths, prompt_ids, n_input = self._prepare_inputs(
             messages, image_bytes, add_generation_prompt
         )
@@ -1262,29 +982,12 @@ class VLMManager:
             if first_emit_s is None:
                 first_emit_s = time.perf_counter()
                 metrics.observe("vlm.ttft", (first_emit_s - t0) * 1e3)
-        req = None
-        if self._continuous is not None:
-            req = self._make_gen_request(
-                embeds, positions, lengths, prompt_ids,
-                max_new_tokens, temperature, top_p, do_sample, repetition_penalty,
-                prefix_content=self._prefix_content(prompt_ids, n_input, image_bytes),
-            )
-            token_iter = self._pick_engine().submit_stream(req)
-        else:
-            token_iter = self.generator.stream(
-                self.params,
-                embeds,
-                positions,
-                lengths,
-                prompt_ids,
-                self._next_rng(),
-                max_new_tokens=max_new_tokens,
-                temperature=temperature,
-                top_p=top_p,
-                do_sample=do_sample,
-                repetition_penalty=repetition_penalty,
-            )
-        for tok in token_iter:
+        req = self._make_gen_request(
+            embeds, positions, lengths, prompt_ids,
+            max_new_tokens, temperature, top_p, do_sample, repetition_penalty,
+            prefix_content=self._prefix_content(prompt_ids, n_input, image_bytes),
+        )
+        for tok in self._pick_engine().submit_stream(req):
             tokens.append(tok)
             if tok == self.cfg.eos_token_id:
                 finish = "eos_token"
@@ -1331,8 +1034,7 @@ class VLMManager:
             meta["tokens_per_second"] = round(tps, 2)
         if first_emit_s is not None:
             meta["ttft_ms"] = round((first_emit_s - t0) * 1e3, 2)
-        if req is not None:
-            meta.update(_reuse_meta(req))
+        meta.update(_reuse_meta(req))
         yield GenerationChunk(text="", tokens=[], is_final=True, metadata=meta)
 
     # -- utils -------------------------------------------------------------

@@ -6,8 +6,6 @@ from .attention import (
     attention_reference,
     flash_attention,
     flash_attention_cache,
-    record_flash_ab,
-    flash_for_seq,
     repeat_kv,
 )
 from .ctc import ctc_collapse, ctc_greedy_device, load_ctc_vocab
@@ -32,8 +30,6 @@ __all__ = [
     "attention_reference",
     "flash_attention",
     "flash_attention_cache",
-    "record_flash_ab",
-    "flash_for_seq",
     "repeat_kv",
     "ctc_greedy_device",
     "ctc_collapse",

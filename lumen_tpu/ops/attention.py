@@ -39,14 +39,6 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def flash_for_seq(sq: int) -> bool:
-    """Would :func:`attention` use the Pallas kernel for THIS query length?
-    (Reported by ``bench.py`` so perf numbers record which attention path
-    produced them: the CLIP towers, 77 text tokens and 50-257 image
-    tokens, sit below the crossover.)"""
-    return _flash_usable(0, None, sq)
-
-
 def attention_reference(
     q: jax.Array,
     k: jax.Array,
@@ -452,31 +444,6 @@ def _count_route(route: str, sq: int) -> None:
     with _ROUTES_LOCK:
         _ROUTES_TRACED[key] = _ROUTES_TRACED.get(key, 0) + 1
     metrics.register_gauges("attention-route", _route_gauges)
-
-
-def record_flash_ab(ref_ms: float, flash_ms: float, block: str, platform: str) -> dict:
-    """Publish a flash-vs-reference A/B verdict as the ``flash-ab`` gauge
-    provider (and return the gauge dict). ``bench.py phase_flash_ab``
-    calls this so the measured verdict lands on /metrics instead of
-    being visible only in the bench JSON tail; a negative verdict
-    (``speedup_pct < 100``) alongside ``flash_attention: false`` in the
-    capability report says the fallback is MEASURED, not an accident."""
-    from ..utils.metrics import metrics
-
-    speedup = ref_ms / flash_ms if flash_ms else 0.0
-    verdict = {
-        "ref_ms": round(ref_ms, 3),
-        "flash_ms": round(flash_ms, 3),
-        "speedup_pct": round(speedup * 100, 1),
-        "flash_wins": 1 if speedup >= 1.0 else 0,
-    }
-    import logging
-
-    logging.getLogger(__name__).info(
-        "flash A/B verdict (%s, block %s): %.3fx reference", platform, block, speedup
-    )
-    metrics.register_gauges("flash-ab", lambda: dict(verdict))
-    return verdict
 
 
 def _interpret_mode() -> bool:

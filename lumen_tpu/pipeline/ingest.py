@@ -302,8 +302,9 @@ class IngestPipeline:
         # item is hashed and looked up in the process-wide cache BEFORE
         # the decode pool — a hit skips decode, preprocess, transfer and
         # every device stage (the host decode lane is the measured ingest
-        # bottleneck, BENCH_r05). Misses are stored after postprocess, so
-        # a warm re-ingest of the same library is pure cache traffic.
+        # bottleneck; round-5 chip run, 2026-08-02, older than the ledger).
+        # Misses are stored after postprocess, so a warm re-ingest of the
+        # same library is pure cache traffic.
         # Non-bytes items pass through untouched. Best-effort within one
         # run: duplicates already in flight compute again (bulk ingest is
         # offline; single-flight coalescing is for the serving path).
@@ -508,7 +509,8 @@ class IngestPipeline:
                     # One sha256 over the RAW bytes serves both pre-decode
                     # gates: the quarantine rejection and the cache lookup
                     # — neither touches the decode pool (the lane
-                    # BENCH_r05 measured as the ingest bottleneck).
+                    # measured as the ingest bottleneck; round-5 chip
+                    # run, 2026-08-02, older than the ledger).
                     key = make_key(self.cache_namespace, self.cache_options, item)
                     reason = quarantine.reason(key)
                     if reason is not None:

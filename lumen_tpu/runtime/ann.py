@@ -123,16 +123,11 @@ def merge_topk(
     — so a sharded merge is bit-reproducible and comparable against a
     sorted oracle. Tolerates empty shards and k larger than any shard's
     contribution (the hypothesis property test exercises both)."""
-    heap: list[tuple[float, str]] = []
-    for ids, scores in parts:
-        for vid, score in zip(ids, scores):
-            item = (float(score), str(vid))
-            if len(heap) < k:
-                heapq.heappush(heap, item)
-            elif item > heap[0]:
-                heapq.heapreplace(heap, item)
-    ordered = sorted(heap, key=lambda t: (-t[0], t[1]))
-    return [vid for _, vid in ordered], [score for score, _ in ordered]
+    ordered = heapq.nsmallest(
+        k,
+        ((-float(score), str(vid)) for ids, scores in parts for vid, score in zip(ids, scores)),
+    )
+    return [vid for _, vid in ordered], [-neg for neg, _ in ordered]
 
 
 class AnnShard:
@@ -537,11 +532,12 @@ def exact_oracle(
     ids: Sequence[str], vecs: np.ndarray, q: np.ndarray, k: int
 ) -> tuple[list[str], list[float]]:
     """Numpy reference: full cosine scoring + the same deterministic
-    tie-break as :func:`merge_topk`. The recall@k arbiter for tests and
-    the bench phase."""
+    tie-break as :func:`merge_topk`. The recall@k arbiter for tests."""
     vecs = normalize(vecs)
     q = normalize(q)[0]
-    scores = vecs @ q
+    # Row by row, not ``vecs @ q``: BLAS rounds a row's product by where the
+    # row sits in the matrix, so equal vectors would not tie exactly.
+    scores = (vecs * q).sum(axis=-1)
     order = sorted(range(len(ids)), key=lambda i: (-float(scores[i]), str(ids[i])))
     top = order[: min(k, len(order))]
     return [str(ids[i]) for i in top], [float(scores[i]) for i in top]

@@ -1,6 +1,7 @@
 """Shared host-decode pool: the serving path's first lane.
 
-BENCH_r05 showed the device ~100x ahead of the serving path (CLIP embeds
+A round-5 chip run (2026-08-02; older than the ledger) showed
+the device ~100x ahead of the serving path (CLIP embeds
 9k images/sec/chip device-only vs 77 rps through gRPC): the gap is host
 serialization, and the first serialized step is image decode. Every gRPC
 handler thread used to decode its own payload inline, so decode

@@ -1,6 +1,7 @@
 """Process-wide content-addressed inference result cache + single-flight.
 
-BENCH_r05 measured the device lane ~100x ahead of the serving path (CLIP
+A round-5 chip run (2026-08-02; older than the ledger) measured the device
+lane ~100x ahead of the serving path (CLIP
 9,083 images/s/chip device-only vs 37.9 images/s end-to-end ingest and 77
 RPS gRPC c10): the binding resource is the *host* — decode (~100
 images/s/core) and per-request serialization. The cheapest throughput
@@ -54,7 +55,7 @@ always the least-recently-used entry of the tenant holding the MOST
 bytes, so a flooding tenant's churn evicts its own backlog while smaller
 tenants' hot sets stay resident. ``cross_tenant_evictions`` counts the
 violations (an under-fair-share tenant losing an entry to another
-tenant's store) — zero by construction, watched by ``bench.py qos``.
+tenant's store) — zero by construction, held by ``tests/test_qos.py``.
 """
 
 from __future__ import annotations

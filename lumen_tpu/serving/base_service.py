@@ -25,8 +25,9 @@ semantics are exactly the unary ones (each item runs the full
 ``_dispatch``: breaker gate, payload limit, deadline, cache/coalesce,
 quarantine, error mapping), and a client disconnect mid-stream cancels the
 not-yet-started remainder of the fan-out. This amortizes stream setup,
-admission and context bookkeeping that BENCH_r05 showed costing more than
-the device call itself (77 rps through gRPC vs 9k images/s on-device).
+admission and context bookkeeping that cost more than the device call
+itself (77 rps through gRPC vs 9k images/s on-device; round-5 chip run,
+2026-08-02, older than the ledger).
 
 **Multi-tenant QoS** (:mod:`lumen_tpu.utils.qos`): every dispatch resolves
 a ``(tenant, lane)`` identity — tenant from the ``lumen-tenant`` gRPC
@@ -422,8 +423,9 @@ class BaseService(InferenceServicer):
         lane = _get_bulk_lane()
         # Request-path trim: the stream's gRPC request metadata (where the
         # tenant id lives) is identical for every item — resolve it ONCE
-        # instead of scanning the metadata tuple per item (BENCH_r05
-        # attribution charges that per-item bookkeeping to rpc overhead).
+        # instead of scanning the metadata tuple per item (the round-5
+        # attribution, 2026-08-02 and older than the ledger, charges that
+        # per-item bookkeeping to rpc overhead).
         stream_tenant = self._invocation_meta(context, request_qos.TENANT_META_KEY)
         # Backpressure: bound items submitted-but-unsettled so a 100k-item
         # stream cannot buffer every payload in the executor queue at once

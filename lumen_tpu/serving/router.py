@@ -465,9 +465,8 @@ class HubRouter(InferenceServicer):
                     code=pb.ERROR_CODE_UNAVAILABLE,
                     message="this host accepts no KV migrations",
                     detail=(
-                        "no continuous-batching VLM engine is attached "
-                        "(front tier, modelless host, or non-continuous "
-                        "scheduler); the prefill host decodes locally"
+                        "no VLM engine is attached (front tier or "
+                        "modelless host); the prefill host decodes locally"
                     ),
                 ),
             )

@@ -71,24 +71,21 @@ class VlmService(BaseService):
             kw["prefill_buckets"] = tuple(bs.batch_buckets)
         if bs.max_seq:
             kw["max_seq"] = bs.max_seq
-        # batch_size here is the decode batch (requests coalesced per
-        # program) and the stream-cache bound — NOT a CLIP-style image
-        # batch. Configs written before per-family sizing may carry the
-        # headline batch (e.g. 256); clamp to a sane decode width instead
-        # of allocating hundreds of KV caches.
-        gen_batch = max(1, min(bs.batch_size, 16))
-        if gen_batch != bs.batch_size:
+        # batch_size here is the decode width (slots of the engine's page
+        # pool) — NOT a CLIP-style image batch. Configs written before
+        # per-family sizing may carry the headline batch (e.g. 256); clamp
+        # to a sane decode width instead of sizing a pool for hundreds of
+        # rows.
+        gen_slots = max(1, min(bs.batch_size, 16))
+        if gen_slots != bs.batch_size:
             logger.warning(
-                "vlm batch_size %d clamped to %d (decode batch)", bs.batch_size, gen_batch
+                "vlm batch_size %d clamped to %d (decode slots)", bs.batch_size, gen_slots
             )
         manager = VLMManager(
             model_dir,
             dtype=bs.dtype,
             warmup=bs.warmup,
-            gen_batch_size=gen_batch,
-            gen_batch_latency_ms=bs.max_batch_latency_ms,
-            scheduler=bs.scheduler,
-            gen_slots=gen_batch,  # pool width = configured decode batch
+            gen_slots=gen_slots,
             gen_block=bs.decode_block,
             quantize=bs.quantize,
             mesh_axes=bs.mesh.axes if bs.mesh else None,
@@ -98,17 +95,12 @@ class VlmService(BaseService):
         return cls(manager)
 
     def capability(self):
-        # Suggested client concurrency = the decode width the scheduler
-        # actually coalesces (slot-pool width x engine replicas for
-        # continuous, batcher width otherwise) — advertising 1 made
-        # clients serialize requests the server batches fine (reference
-        # field semantics: proto Capability.max_concurrency, "Suggested
-        # max concurrency").
-        width = (
-            self.manager.gen_slots * max(1, len(self.manager._engines))
-            if self.manager.scheduler == "continuous"
-            else self.manager.gen_batch_size
-        )
+        # Suggested client concurrency = the decode width the engines
+        # actually batch (slot-pool width x engine replicas) — advertising
+        # 1 made clients serialize requests the server batches fine
+        # (reference field semantics: proto Capability.max_concurrency,
+        # "Suggested max concurrency").
+        width = self.manager.gen_slots * len(self.manager._engines)
         return self.registry.build_capability(
             model_ids=[self.manager.model_id],
             runtime="jax-tpu",
@@ -129,11 +121,10 @@ class VlmService(BaseService):
                 # config (the gRPC-layer gate still applies to it).
                 "qos": qos_service_extra("vlm"),
                 "quant_route": self.manager.quant_route,
-                # Decode scheduling on the wire: which scheduler actually
-                # serves (env knob may have overridden the config) and how
-                # KV is laid out — previously constructor-only and
-                # invisible to clients/dashboards.
-                "scheduler": self.manager.scheduler,
+                # Decode scheduling on the wire: the one engine's name
+                # (a constant clients and chip_smoke.py read) and how KV
+                # is laid out.
+                "scheduler": "continuous",
                 "kv_layout": self.manager.kv_layout(),
                 **self.manager.topology(),
                 # Disaggregation lane only when configured — unconfigured
@@ -282,16 +273,7 @@ class VlmService(BaseService):
                 error=pb.Error(code=code, message=message, detail=detail),
             )
 
-        mgr = self.manager
-        eng = mgr._pick_engine() if mgr._continuous is not None else None
-        if eng is None:
-            yield refuse(
-                pb.ERROR_CODE_UNAVAILABLE,
-                "this host runs no continuous-batching engine",
-                "fed_kv_put needs the paged continuous scheduler "
-                "(scheduler=continuous); the prefill host decodes locally",
-            )
-            return
+        eng = self.manager._pick_engine()
         op = first.meta.get("op", "")
         if op == "offer":
             yield self._kv_offer_answer(eng, first, pb)

@@ -2,8 +2,8 @@
 paths accept — the real weight-load + serve stack without a download.
 
 ``chip_smoke.py`` writes the published architectures with these
-(CLIP ViT-B/32, Qwen2-0.5B with the 1024-px tower); ``bench.py`` writes its
-own cut-down configurations. Nothing written here is ever committed: a
+(CLIP ViT-B/32, Qwen2-0.5B with the 1024-px tower); tests write
+tiny cuts. Nothing written here is ever committed: a
 0.5B-parameter safetensors file in the tree would sink the copy to the
 chip.
 """

@@ -101,30 +101,3 @@ def env_list(name: str, default: tuple[str, ...] = ()) -> tuple[str, ...]:
     if raw is None:
         return default
     return tuple(part for part in (p.strip() for p in raw.split(",")) if part)
-
-
-def env_choice(
-    name: str,
-    default: str | None,
-    choices: tuple[str, ...],
-) -> str | None:
-    """Enum twin of :func:`env_int`: the value must be one of ``choices``
-    (matched case-insensitively, returned in the canonical spelling);
-    unset -> ``default`` silently, anything else -> ``default`` with a
-    one-shot warning naming the knob, the bad value and the legal set."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    lowered = raw.strip().lower()
-    for choice in choices:
-        if lowered == choice.lower():
-            return choice
-    with _warned_lock:
-        first = name not in _warned
-        _warned.add(name)
-    if first:
-        logger.warning(
-            "malformed env knob %s=%r (expected one of %s); using default %r",
-            name, raw, "|".join(choices), default,
-        )
-    return default

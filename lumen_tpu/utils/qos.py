@@ -54,9 +54,9 @@ non-default tenants and the RAM tier evicts fair-share-first (see
 :mod:`lumen_tpu.runtime.result_cache`), so one tenant's churn cannot
 evict another's hot set.
 
-Chaos-tested by ``bench.py --phase qos`` (tenant-A bulk flood vs
-interactive tenants B/C: interactive p95 must stay within 2x of its
-isolated baseline) and the ``tenant_flood`` fault point
+Held by ``tests/test_qos.py`` (a tenant-A bulk flood sheds before it
+reaches the backend and evicts nothing of tenants B/C) and the
+``tenant_flood`` fault point
 (:mod:`lumen_tpu.testing.faults`) which forces a tenant's quota to read
 as exhausted.
 """

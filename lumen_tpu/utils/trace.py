@@ -1,6 +1,7 @@
 """Request-scoped tracing: per-stage latency attribution for the serving path.
 
-BENCH_r05 measured the device sustaining ~9k img/s/chip while gRPC c10
+A round-5 chip run (2026-08-02; older than the ledger) measured the device
+sustaining ~9k img/s/chip while gRPC c10
 delivers 77 rps — ROADMAP item 3 says the remaining ~10x lives in the
 host/request path, but the metrics registry only records ONE end-to-end
 histogram per task. Nobody can say whether a slow request spent its time

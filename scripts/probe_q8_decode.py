@@ -1,7 +1,7 @@
 """On-chip micro-probe: why is int8 VLM decode ~34x slower than bf16?
 
-TPU_SESSION_r05.json measured the fused int8 decode at 119 tok/s vs 4065
-bf16 (hbm_util 0.43% — the device is idle, so some op inside the compiled
+A chip session (round 5, git history) measured the fused int8 decode at
+119 tok/s vs 4065 bf16 (hbm_util 0.43% — the device is idle, so some op inside the compiled
 step lowers catastrophically). This probe times the isolated projection
 formulations at decode shapes (batch rows x [896 -> 4864]) to attribute
 the pathology:

@@ -1,7 +1,7 @@
 """Fourth int8-decode probe: the REAL VLMModel decode step, bisected.
 
 probe_q8_steps showed hand-rolled QDense math is FASTER than bf16 at every
-real decoder shape — so the 34x slowdown (TPU_SESSION_r05.json vlm_q8) must
+real decoder shape — so the 34x slowdown (vlm_q8; round 5, git history) must
 come from the actual model/generate structure. This times the real
 bench-model decode step (same configs as bench.phase_vlm) three ways:
 

@@ -56,3 +56,38 @@ def test_failure_detection(monkeypatch):
     by_name = {name: rc for name, rc, _ in results}
     assert by_name["check_knobs"] == 1
     assert by_name["check_endpoints"] == 0
+
+
+# -- a document names only files the tree has ---------------------------------
+
+import re  # noqa: E402
+
+import pytest  # noqa: E402
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_DOCS = ["README.md", os.path.join(".claude", "skills", "verify", "SKILL.md")] + sorted(
+    os.path.join("docs", f) for f in os.listdir(os.path.join(_ROOT, "docs")) if f.endswith(".md")
+)
+# `name.py`, `name.md`, and `Name.json` (a record at the root), with or without a path or `:line`
+_CITED = re.compile(r"`([A-Za-z0-9_./-]+\.(?:py|md)|(?:[A-Za-z0-9_./-]+/)?[A-Z][A-Za-z0-9_]*\.json)(?::[0-9,:-]+)?`")
+# the reference project's own files, which docs/MIGRATION.md maps from
+_UPSTREAM = {"fastvlm_service.py", "onnxrt_backend.py", "env_checker.py", "compute_bioclip_npy_embeddings.py"}
+
+
+def _tree_basenames() -> set[str]:
+    names = set()
+    for top, dirs, files in os.walk(_ROOT):
+        dirs[:] = [d for d in dirs if not d.startswith(".") and d not in ("chiprun_out", "__pycache__")]
+        names.update(files)
+    return names
+
+
+@pytest.mark.parametrize("doc", _DOCS)
+def test_a_document_names_only_files_the_tree_has(doc):
+    """PR 33 removed a harness and 22 records that 41 places in the documents
+    still cited: a backticked file name in a document is a file of the tree."""
+    have = _tree_basenames() | _UPSTREAM
+    with open(os.path.join(_ROOT, doc), encoding="utf-8") as f:
+        cited = {m.group(1) for m in _CITED.finditer(f.read())}
+    missing = sorted(c for c in cited if os.path.basename(c) not in have)
+    assert not missing, f"{doc} names files the tree does not have: {missing}"

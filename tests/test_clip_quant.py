@@ -120,7 +120,8 @@ class TestQuantizedManager:
 class TestQuantRouteSelection:
     """int8 is opt-in AND verified: without a warmup pass the explicit
     config wins; with warmup, a one-shot A/B may fall the route back to
-    bf16 (BENCH_r05: q8 at 0.923x bf16 on v5e was a regression); the
+    bf16 (q8 at 0.923x bf16 on v5e was a regression: round-5 chip run,
+    2026-08-02, older than the ledger); the
     chosen route lands in a metrics gauge either way."""
 
     def test_explicit_optin_without_warmup_serves_int8(self, tmp_path):

@@ -125,11 +125,22 @@ class TestTelemetry:
         one worker waited at least as long as the first ran."""
         pool = DecodePool(workers=1, name="t-sums")
         try:
-            seen = []
-            for burst in (3, 2):
-                pool.map(lambda x: time.sleep(0.01) or x, range(burst))
-                seen.append(pool.gauges())
-            a, b = seen
+            # All three are queued before the first may finish (it waits
+            # for the gate), so what the other two waited does not hang on
+            # how fast this thread submits under load.
+            gate = threading.Event()
+
+            def work(x):
+                if x == 0:
+                    gate.wait(10)
+                return time.sleep(0.01) or x
+
+            futs = [pool.submit(work, i) for i in range(3)]
+            gate.set()
+            assert [f.result() for f in futs] == [0, 1, 2]
+            a = pool.gauges()
+            pool.map(lambda x: time.sleep(0.01) or x, range(2))
+            b = pool.gauges()
             assert a["wait_count"] == a["tasks"] == 3 and b["wait_count"] == b["tasks"] == 5
             for key in ("wait_ms_sum", "run_ms_sum"):
                 assert 0 < a[key] < b[key]

@@ -79,7 +79,7 @@ def model_dir(tmp_path_factory):
 def _make_mgr(model_dir, **over):
     kwargs = dict(
         dtype="float32", max_seq=128, max_new_cap=16,
-        prefill_buckets=(16, 32), scheduler="continuous",
+        prefill_buckets=(16, 32),
         gen_slots=4, gen_block=4,
     )
     kwargs.update(over)

@@ -1,5 +1,5 @@
 """``lumen_tpu.testing.model_dirs``: the seeded model directories that
-``chip_smoke.py`` and ``bench.py`` serve from must be what the managers'
+``chip_smoke.py`` and the tests serve from must be what the managers'
 load paths accept, at any size — checked here at the tiny cuts without a
 compile (shape gates, manifests, tokenizers)."""
 

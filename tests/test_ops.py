@@ -107,8 +107,6 @@ class TestAttention:
         monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
         usable = attn_mod._flash_usable(head_dim, object() if mask else None, length)
         assert ("flash" if usable else "xla") == route
-        if not mask and head_dim <= 256:  # what bench.py stamps its numbers with
-            assert attn_mod.flash_for_seq(length) == usable
 
     @pytest.mark.parametrize("keys,route", [(255, "xla"), (256, "flash"), (2048, "flash")])
     def test_cache_path_keeps_its_own_gate(self, monkeypatch, keys, route):

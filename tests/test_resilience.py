@@ -414,8 +414,10 @@ class TestDegradedHub:
         from lumen_tpu.serving.server import serve
 
         config = make_hub_config(tmp_path)
-        # The 'bad' service's download fails once (boot), then clears.
-        faults.configure("download", times=1, match="model-bad")
+        # The 'bad' service's download fails at boot and at every recovery
+        # attempt until the degraded state has been looked at: the test, not
+        # a 0.01-s backoff, decides when the fault clears.
+        faults.configure("download", times=10_000, match="model-bad")
         recoveries_before = metrics.counter_value("recoveries")
 
         handle = serve(config)
@@ -443,6 +445,8 @@ class TestDegradedHub:
             assert statuses == {"good": "healthy", "bad": "degraded"}
 
             # Background recovery: fault cleared, service hot-swaps in.
+            assert isinstance(handle.services["bad"], DegradedService)
+            faults.clear("download")
             assert handle.recovery is not None
             assert handle.recovery.wait_idle(timeout=15)
             (r,) = self._infer(stub, "echo2")

@@ -102,6 +102,28 @@ class TestMergeOracle:
         ids, _ = merge_topk([a, b], 2)
         assert ids == ["a", "b"]
 
+    @pytest.mark.parametrize(
+        "parts, k, want",
+        [
+            # a tie across shards: the smaller id wins whichever shard holds it
+            ([(["d", "b"], [0.9, 0.5]), (["c", "a"], [0.9, 0.5])], 3, ["c", "d", "a"]),
+            # a tie inside one shard, listed larger id first
+            ([(["z", "y", "x"], [0.7, 0.7, 0.2])], 2, ["y", "z"]),
+            # k cuts a tie group: the smallest ids of the group stay
+            ([(["e", "c"], [0.4, 0.4]), (["d", "b"], [0.4, 0.4]), (["a"], [0.8])], 3, ["a", "b", "c"]),
+            # every score equal: plain id order
+            ([(["m", "k"], [0.0, 0.0]), (["n", "j", "l"], [0.0, 0.0, 0.0])], 4, ["j", "k", "l", "m"]),
+            # an empty shard, k above every candidate: all of them, none invented
+            ([([], []), (["q", "p"], [0.3, 0.3]), ([], [])], 9, ["p", "q"]),
+        ],
+        ids=["across-shards", "inside-a-shard", "k-cuts-a-group", "all-equal", "empty-shard-large-k"],
+    )
+    def test_tied_scores_keep_ascending_ids(self, parts, k, want):
+        scores = {vid: s for ids, ss in parts for vid, s in zip(ids, ss)}
+        got_ids, got_scores = merge_topk(parts, k)
+        assert got_ids == want
+        assert got_scores == [scores[v] for v in want]
+
 
 try:
     from hypothesis import given, settings, strategies as st

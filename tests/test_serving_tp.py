@@ -54,9 +54,7 @@ def _mgr(model_dir, **kw):
         max_seq=128,
         max_new_cap=16,
         prefill_buckets=(16, 32),
-        gen_batch_size=2,
-        gen_batch_latency_ms=1.0,
-        **kw,
+        **{"gen_slots": 2, **kw},
     )
     mgr.initialize()
     return mgr
@@ -271,7 +269,7 @@ class TestVlmExpertParallel:
 class TestContinuousSchedulerOnTpMesh:
     def test_continuous_tp_decode_matches_replicated(self, model_dir):
         """The slot-pool scheduler composes with TP-sharded weights: same
-        tokens as the replicated coalescing path."""
+        tokens as the replicated engine."""
         repl = _mgr(model_dir)
         try:
             want = repl.generate(PROMPT, max_new_tokens=10)
@@ -280,7 +278,6 @@ class TestContinuousSchedulerOnTpMesh:
         cont_tp = _mgr(
             model_dir,
             mesh_axes={"data": 4, "model": 2},
-            scheduler="continuous",
             gen_slots=2,
             gen_block=4,
         )

@@ -44,8 +44,6 @@ def soak_server(tmp_path_factory):
         max_seq=128,
         max_new_cap=16,
         prefill_buckets=(16, 32),
-        gen_batch_size=4,
-        scheduler="continuous",
         gen_slots=4,
         gen_block=4,
     )

@@ -1,9 +1,9 @@
 """Batched VLM generation tests (round-1 verdict item 6: replace the
 single-flight lock with batched decode).
 
-Covers: per-sample sampling params (ops/sampling), per-sample stop caps in
-the fused loop, the request batcher grouping concurrent generates into one
-[B>1] program, and correctness of batched results vs serial B=1 runs.
+Covers: per-sample sampling params (ops/sampling), per-sample stop caps,
+the engine stepping concurrent generates in shared decode blocks, and
+correctness of batched results vs serial B=1 runs.
 """
 
 from __future__ import annotations
@@ -17,6 +17,7 @@ import pytest
 
 from lumen_tpu.models.vlm import ChatMessage, VLMManager
 from lumen_tpu.ops.sampling import apply_repetition_penalty, sample
+from lumen_tpu.utils.metrics import metrics
 from tests.test_vlm import make_vlm_model_dir
 
 
@@ -73,31 +74,31 @@ def manager(tmp_path_factory):
         max_seq=128,
         max_new_cap=16,
         prefill_buckets=(16, 32),
-        gen_batch_size=4,
-        gen_batch_latency_ms=30.0,
-        # This file tests the coalescing batcher specifically; the
-        # serving default moved to the paged continuous engine.
-        scheduler="coalesce",
+        gen_slots=4,
+        gen_block=4,
     )
     mgr.initialize()
     yield mgr
     mgr.close()
 
 
+def _engine_gauges(mgr) -> dict:
+    return metrics.snapshot()["gauges"][f"vlm-continuous:{mgr.info.name}"]
+
+
 class TestBatchedGeneration:
     def test_concurrent_greedy_matches_serial(self, manager):
         """N concurrent generates return exactly what serial runs return,
-        and the batcher actually coalesced them into fewer programs."""
+        and the engine actually stepped them in shared decode blocks."""
         prompts = ["hello", "the quick brown fox", "a", "count to three"]
         serial = [
             manager.generate(
-                [ChatMessage(role="user", content=p)], max_new_tokens=8
+                [ChatMessage(role="user", content=p)], max_new_tokens=12
             )
             for p in prompts
         ]
 
-        before_batches = manager._batcher.batches_run
-        before_rows = manager._batcher.rows_run
+        before = _engine_gauges(manager)
         results: dict[int, object] = {}
         errors: list[Exception] = []
         barrier = threading.Barrier(len(prompts))
@@ -106,7 +107,7 @@ class TestBatchedGeneration:
             try:
                 barrier.wait()
                 results[i] = manager.generate(
-                    [ChatMessage(role="user", content=p)], max_new_tokens=8
+                    [ChatMessage(role="user", content=p)], max_new_tokens=12
                 )
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
@@ -122,10 +123,11 @@ class TestBatchedGeneration:
         for i, want in enumerate(serial):
             assert results[i].tokens == want.tokens, (i, results[i].text, want.text)
             assert results[i].finish_reason == want.finish_reason
-        rows = manager._batcher.rows_run - before_rows
-        batches = manager._batcher.batches_run - before_batches
-        assert rows == len(prompts)
-        assert batches < rows, "concurrent requests were never coalesced"
+        after = _engine_gauges(manager)
+        assert after["admitted"] - before["admitted"] == len(prompts)
+        rows = after["rows_stepped"] - before["rows_stepped"]
+        blocks = after["blocks_run"] - before["blocks_run"]
+        assert blocks < rows, "concurrent requests never shared a decode block"
 
     def test_mixed_max_new_tokens(self, manager):
         """Batched rows stop at their own budget."""

@@ -1,7 +1,7 @@
 """Continuous-batching VLM scheduler tests.
 
 The slot-pool scheduler (``models/vlm/continuous.py``) must produce
-exactly the tokens the coalescing batcher / fused loop produce, while
+exactly the tokens the contiguous-cache reference loop produces, while
 admitting requests into free slots mid-decode instead of queueing them
 behind running generations.
 """
@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import threading
 import time
+
+import numpy as np
 
 from lumen_tpu.models.vlm import ChatMessage, VLMManager
 from tests.test_vlm import make_vlm_model_dir
@@ -30,7 +32,6 @@ def cont_mgr(model_dir):
         max_seq=128,
         max_new_cap=16,
         prefill_buckets=(16, 32),
-        scheduler="continuous",
         gen_slots=4,
         gen_block=4,
     )
@@ -39,30 +40,40 @@ def cont_mgr(model_dir):
     mgr.close()
 
 
-@pytest.fixture(scope="module")
-def coalesce_mgr(model_dir):
-    mgr = VLMManager(
-        model_dir,
-        dtype="float32",
-        max_seq=128,
-        max_new_cap=16,
-        prefill_buckets=(16, 32),
-        scheduler="coalesce",
-    )
-    mgr.initialize()
-    yield mgr
-    mgr.close()
-
-
 class TestContinuousCorrectness:
-    def test_greedy_matches_coalesce(self, cont_mgr, coalesce_mgr):
-        """Same model dir, same greedy request -> identical tokens through
-        both schedulers (the step-block body mirrors the fused loop)."""
-        msgs = [ChatMessage(role="user", content="the quick brown fox")]
-        a = cont_mgr.generate(msgs, max_new_tokens=8)
-        b = coalesce_mgr.generate(msgs, max_new_tokens=8)
-        assert a.tokens == b.tokens, (a.text, b.text)
-        assert a.finish_reason == b.finish_reason
+    @pytest.mark.parametrize(
+        "content, image, penalty",
+        [
+            ("the quick brown fox", False, 1.0),  # first prompt bucket (16)
+            (" ".join(["word"] * 20), False, 1.0),  # second bucket (32)
+            ("the dog", True, 1.0),  # image tokens spliced into the prompt
+            ("the quick brown fox", False, 1.3),  # greedy under a repetition penalty
+        ],
+        ids=["bucket-16", "bucket-32", "image", "repetition-penalty"],
+    )
+    def test_greedy_matches_the_reference_loop(self, cont_mgr, content, image, penalty):
+        """The engine's tokens are the contiguous-cache reference loop's on
+        the same prepared inputs: the step-block body claims the fused
+        loop's body (sampling, penalty, stop), over pages instead."""
+        import jax
+
+        from tests.test_vlm import png_bytes
+
+        msgs = [ChatMessage(role="user", content=content)]
+        image_bytes = png_bytes() if image else None
+        served = cont_mgr.generate(
+            msgs, image_bytes=image_bytes, max_new_tokens=8, repetition_penalty=penalty
+        )
+        embeds, positions, lengths, prompt_ids, n = cont_mgr._prepare_inputs(msgs, image_bytes)
+        assert prompt_ids.shape[1] == (32 if content.startswith("word") else 16)
+        assert (embeds.shape[1] > prompt_ids.shape[1]) == image  # the vision tokens
+        ref = cont_mgr.generator.generate(
+            cont_mgr.params, embeds, positions, lengths, prompt_ids,
+            jax.random.PRNGKey(0), max_new_tokens=8, repetition_penalty=penalty,
+        )
+        want = [int(t) for t in np.asarray(ref.tokens[0][: int(ref.n_generated[0])])]
+        assert served.tokens == want, (served.text, want)
+        assert (served.finish_reason == "eos_token") == bool(ref.stopped_eos[0])
 
     def test_concurrent_mixed_budgets_match_serial(self, cont_mgr):
         prompts = [("hello", 3), ("the quick brown fox", 8), ("a", 5), ("count", 1)]
@@ -97,15 +108,14 @@ class TestContinuousCorrectness:
 
     def test_late_admission_does_not_wait_for_long_row(self, model_dir):
         """A request arriving while a long generation is mid-decode joins a
-        free slot and finishes first — the coalescing batcher would have
-        queued it until the long row completed."""
+        free slot and finishes first, instead of queueing until the long
+        row completed."""
         mgr = VLMManager(
             model_dir,
             dtype="float32",
             max_seq=128,
             max_new_cap=64,
             prefill_buckets=(16,),
-            scheduler="continuous",
             gen_slots=2,
             gen_block=2,  # 32 blocks for the long row: plenty of admit windows
         )
@@ -165,7 +175,6 @@ class TestContinuousCorrectness:
             max_seq=128,
             max_new_cap=16,
             prefill_buckets=(16,),
-            scheduler="continuous",
             gen_slots=2,
             gen_block=2,
         )
@@ -173,10 +182,6 @@ class TestContinuousCorrectness:
         mgr.close()
         with pytest.raises(RuntimeError):
             mgr._continuous.submit(object())
-
-    def test_bad_scheduler_name_rejected(self, model_dir):
-        with pytest.raises(ValueError, match="scheduler"):
-            VLMManager(model_dir, scheduler="nope")
 
     def test_abandoned_stream_frees_slot(self, cont_mgr):
         """Breaking out of a stream (client disconnect / stop sequence)
@@ -213,7 +218,6 @@ class TestPoolInvalidationEscalation:
             max_seq=128,
             max_new_cap=8,
             prefill_buckets=(16,),
-            scheduler="continuous",
             gen_slots=2,
             gen_block=2,
         )
@@ -287,7 +291,7 @@ class TestPagedPoolBehavior:
         the gauges expose the same balance (the bench asserts this too)."""
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=128, max_new_cap=16,
-            prefill_buckets=(16, 32), scheduler="continuous",
+            prefill_buckets=(16, 32),
             gen_slots=4, gen_block=4,
         )
         mgr.initialize()
@@ -328,7 +332,7 @@ class TestPagedPoolBehavior:
 
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=128, max_new_cap=64,
-            prefill_buckets=(16,), scheduler="continuous",
+            prefill_buckets=(16,),
             gen_slots=2, gen_block=4,
         )
         mgr.initialize()
@@ -411,7 +415,7 @@ class TestPagedPoolBehavior:
 
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=128, max_new_cap=64,
-            prefill_buckets=(16,), scheduler="continuous",
+            prefill_buckets=(16,),
             gen_slots=2, gen_block=4,
         )
         mgr.initialize()
@@ -444,7 +448,7 @@ def _make_lane_mgr(model_dir, chunk: "int | None" = 32, **kw):
     (``chunk`` 32) or, with ``chunk`` None, the one-shot prefill."""
     cfg = dict(
         dtype="float32", max_seq=256, max_new_cap=32,
-        prefill_buckets=(16, 64, 128), scheduler="continuous",
+        prefill_buckets=(16, 64, 128),
         gen_slots=4, gen_block=4,
     )
     cfg.update(kw)
@@ -546,7 +550,7 @@ class TestChunkedPrefillLane:
 
         mgr_direct = VLMManager(
             model_dir, dtype="float32", max_seq=256, max_new_cap=16,
-            prefill_buckets=(64,), scheduler="continuous",
+            prefill_buckets=(64,),
             gen_slots=2, gen_block=4,
         )
         mgr_direct.initialize()
@@ -558,7 +562,7 @@ class TestChunkedPrefillLane:
         monkeypatch.setenv("LUMEN_VLM_PREFILL_CHUNK", "32")
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=256, max_new_cap=16,
-            prefill_buckets=(64,), scheduler="continuous",
+            prefill_buckets=(64,),
             gen_slots=2, gen_block=4,
         )
         mgr.initialize()
@@ -784,20 +788,63 @@ class TestObservabilitySurface:
             f"paged(page={kv.page_size},pages={kv.pages_total},slots={cont_mgr.gen_slots})"
         )
 
-    def test_scheduler_env_knob(self, model_dir, monkeypatch):
-        from lumen_tpu.utils import env as env_mod
+    def test_a_config_that_names_a_scheduler_is_rejected_at_load(self):
+        """One engine: ``backend_settings.scheduler`` is no field any more, and
+        ``extra="forbid"`` answers a config that still asks for another."""
+        import os
 
-        monkeypatch.setenv("LUMEN_VLM_SCHEDULER", "coalesce")
-        mgr = VLMManager(model_dir, dtype="float32", max_seq=128,
-                         max_new_cap=8, prefill_buckets=(16,))
-        assert mgr.scheduler == "coalesce"
-        # Malformed values degrade to the caller's choice with a one-shot
-        # warning (utils/env.py contract).
-        env_mod._reset_warnings()
-        monkeypatch.setenv("LUMEN_VLM_SCHEDULER", "turbo")
-        mgr2 = VLMManager(model_dir, dtype="float32", max_seq=128,
-                          max_new_cap=8, prefill_buckets=(16,))
-        assert mgr2.scheduler == "continuous"
+        import yaml
+
+        from lumen_tpu.core.config import load_config, validate_config_dict
+        from lumen_tpu.core.exceptions import ConfigError
+
+        example = os.path.join(os.path.dirname(__file__), "..", "examples", "lumen-config-tp.yaml")
+        assert load_config(example).services["vlm"].backend_settings.decode_block == 8
+        with open(example, encoding="utf-8") as f:
+            raw = yaml.safe_load(f)
+        for name in ("continuous", "anything"):
+            raw["services"]["vlm"]["backend_settings"]["scheduler"] = name
+            with pytest.raises(ConfigError, match="backend_settings.scheduler"):
+                validate_config_dict(raw)
+
+    def test_max_concurrency_is_slots_times_engines(self, cont_mgr):
+        from lumen_tpu.serving.services.vlm_service import VlmService
+
+        svc = VlmService(cont_mgr)
+        assert svc.capability().max_concurrency == cont_mgr.gen_slots == 4
+        engines = cont_mgr._engines
+        try:
+            cont_mgr._engines = engines * 3  # a three-replica fleet's list
+            assert svc.capability().max_concurrency == 12
+        finally:
+            cont_mgr._engines = engines
+
+    def test_from_config_hands_the_decode_width_as_slots(self, monkeypatch):
+        """A headline ``batch_size`` of 256 reaches the manager as sixteen
+        slots with the configured block, and no argument of the engine that
+        went (``scheduler``, ``gen_batch_*``) is passed."""
+        from lumen_tpu.core.config import BackendSettings
+        from lumen_tpu.serving.services import vlm_service
+
+        seen = {}
+
+        class Manager:
+            def __init__(self, model_dir, **kw):
+                seen.update(kw)
+
+            def initialize(self):
+                pass
+
+        monkeypatch.setattr(vlm_service, "VLMManager", Manager)
+        monkeypatch.setattr(vlm_service, "require_executable_runtime", lambda mc: None)
+
+        class Cfg:
+            models = {"vlm": type("M", (), {"model": "x/y"})()}
+            backend_settings = BackendSettings(batch_size=256, decode_block=4)
+
+        vlm_service.VlmService.from_config(Cfg, "/nowhere")
+        assert seen["gen_slots"] == 16 and seen["gen_block"] == 4
+        assert set(seen) == {"dtype", "warmup", "gen_slots", "gen_block", "quantize", "mesh_axes"}
 
     def test_batch_device_span_lands_on_request_trace(self, cont_mgr):
         from lumen_tpu.utils import trace as trace_mod
@@ -845,7 +892,7 @@ class TestKVSpillTier:
     def _make_mgr(self, model_dir):
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=128, max_new_cap=64,
-            prefill_buckets=(16,), scheduler="continuous",
+            prefill_buckets=(16,),
             gen_slots=2, gen_block=4,
         )
         mgr.initialize()
@@ -1121,7 +1168,6 @@ class TestBatchedAdmission:
             max_seq=128,
             max_new_cap=16,
             prefill_buckets=(16,),
-            scheduler="continuous",
             gen_slots=8,
             gen_block=4,
         )
@@ -1179,7 +1225,7 @@ class TestPrefixReuseAndSpec:
     def _make_mgr(self, model_dir, **kw):
         cfg = dict(
             dtype="float32", max_seq=128, max_new_cap=16,
-            prefill_buckets=(16, 32), scheduler="continuous",
+            prefill_buckets=(16, 32),
             gen_slots=4, gen_block=4,
         )
         cfg.update(kw)
@@ -1462,7 +1508,7 @@ class TestWaitCounters:
         monkeypatch.setenv("LUMEN_VLM_PREFILL_CHUNK", "32")
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=256, max_new_cap=16,
-            prefill_buckets=(64,), scheduler="continuous", gen_slots=2, gen_block=4,
+            prefill_buckets=(64,), gen_slots=2, gen_block=4,
         )
         mgr.initialize()
         try:

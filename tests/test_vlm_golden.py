@@ -10,7 +10,7 @@ and asserts:
 1. prefill logits match HF forward logits (fp32, atol 2e-4), and
 2. greedy generation produces token-for-token identical output to
    ``model.generate(do_sample=False)`` — through the fused while_loop
-   decode AND the streaming step path.
+   reference loop AND a stream out of the continuous engine that serves.
 
 That is the "load a real checkpoint and get the same answers" bar from
 the round-1 verdict, checked at the family's numerical core.
@@ -170,14 +170,28 @@ class TestQwen2GoldenParity:
         n = 8
         want = self._hf_greedy(hf_model, ids, n)
 
+        # The loop that SERVES: a continuous engine over the golden
+        # parameters, the request streamed out of its paged block loop.
+        from lumen_tpu.models.vlm.continuous import ContinuousScheduler, _Request
+
         gen = Generator(model, cfg, max_seq=64, max_new_cap=16, cache_dtype=jnp.float32)
         embeds, positions, lengths = self._prepare_text(cfg, model, params, ids)
-        got = list(
-            gen.stream(
-                params, embeds, positions, lengths, jnp.asarray(ids),
-                jax.random.PRNGKey(0), max_new_tokens=n,
-            )
+        engine = ContinuousScheduler(
+            gen, params, slots=2, block=4, name="golden", page_size=16, pages=9
         )
+        try:
+            got = list(
+                engine.submit_stream(
+                    _Request(
+                        embeds=embeds, positions=positions, length=lengths,
+                        prompt_ids=jnp.asarray(ids), max_new=n, temperature=0.0,
+                        top_p=1.0, do_sample=False, repetition_penalty=1.0,
+                        rng=jax.random.PRNGKey(0),
+                    )
+                )
+            )
+        finally:
+            engine.close()
         # stream yields EOS if hit; HF strips nothing — both keep EOS
         assert got == want
 

@@ -7,6 +7,8 @@ the shares of an expert-parallel deployment add up to the uncut layer."""
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -376,7 +378,7 @@ def latent_mgr(tmp_path_factory):
     name = weights.ensure_model_dir(root, "rehearsal-tiny-dots3", "vlm", entry)
     mgr = VLMManager(
         os.path.join(root, "models", name), dtype="float32", max_seq=2304, max_new_cap=512,
-        scheduler="continuous", gen_slots=2, gen_block=4,
+        gen_slots=2, gen_block=4,
     )
     mgr.initialize()
     yield mgr, entry, os.path.join(root, "models", name)
@@ -583,3 +585,171 @@ def test_a_short_tail_is_padded_and_serves_what_the_unpadded_tail_serves(latent_
         sched.gen._prefill_chunk = inner
     assert shapes == [320, 320, 320, 207]
     assert padded.tokens == short.tokens and len(padded.tokens) == 8
+
+
+# -- what a latent decoder refuses, so that it is never silently wrong ---------------
+
+
+def _new_scheduler(mgr):
+    from lumen_tpu.models.vlm.continuous import ContinuousScheduler
+
+    return ContinuousScheduler(mgr.generator, mgr.params, slots=2, block=4, name="latent-refusals")
+
+
+def _refuse_prefix_cache(mgr, monkeypatch):
+    monkeypatch.setenv("LUMEN_VLM_PREFIX_BYTES", str(1 << 20))
+    _new_scheduler(mgr)
+
+
+def _refuse_speculation(mgr, monkeypatch):
+    monkeypatch.setenv("LUMEN_VLM_SPEC_K", "2")
+    _new_scheduler(mgr)
+
+
+def _spill_tier_is_off(mgr, monkeypatch):
+    monkeypatch.setenv("LUMEN_VLM_SPILL_BYTES", str(1 << 20))
+    sched = _new_scheduler(mgr)
+    try:
+        assert sched._spill_budget == 0 and mgr._continuous._spill_budget == 0
+    finally:
+        sched.close()
+
+
+_ONE = jnp.zeros((1,), jnp.int32)
+
+
+def _refuse_reference_loop(mgr, monkeypatch):
+    hidden = mgr.cfg.decoder.hidden_size
+    mgr.generator.generate(
+        mgr.params, jnp.zeros((1, 4, hidden)), jnp.arange(4)[None], _ONE + 4, jnp.zeros((1, 4), jnp.int32),
+        jax.random.PRNGKey(0), max_new_tokens=2,
+    )
+
+
+def _refuse_export_row(mgr, monkeypatch):
+    mgr.generator._export_row({"caches": _ONE}, 0, _ONE)
+
+
+def _refuse_resume(mgr, monkeypatch):
+    mgr.generator._resume({"caches": _ONE}, 0, _ONE, _ONE, *([_ONE] * 9))
+
+
+def _refuse_seed_prefix(mgr, monkeypatch):
+    mgr.generator._seed_prefix([{"k": _ONE + 0}], [{"k": _ONE}], _ONE)
+
+
+def _refuse_verify(mgr, monkeypatch):
+    mgr.generator._verify(mgr.params, {"cur_tok": _ONE}, _ONE[None], jax.random.PRNGKey(0), _ONE[None], _ONE, width=2)
+
+
+def _refuse_admit_shared(mgr, monkeypatch):
+    mgr._continuous.kv.admit_shared(0, [1], 100)
+
+
+def _refuse_admit_exact(mgr, monkeypatch):
+    mgr._continuous.kv.admit_exact(0, 1)
+
+
+@pytest.mark.parametrize(
+    "attempt, says",
+    [
+        (_refuse_prefix_cache, "LUMEN_VLM_PREFIX_BYTES"),
+        (_refuse_speculation, "LUMEN_VLM_SPEC_K"),
+        (_spill_tier_is_off, None),  # not raised: switched off at boot, a preempted row restarts from its prompt
+        (_refuse_reference_loop, "the fused generate program"),
+        (_refuse_export_row, "the spill tier's export"),
+        (_refuse_resume, "the spill tier's resume"),
+        (_refuse_seed_prefix, "seeding a scratch from a cached prefix"),
+        (_refuse_verify, "speculative verify"),
+        (_refuse_admit_shared, "shared prefix cannot be attached to window layers"),
+        (_refuse_admit_exact, "does not export window layers' pages"),
+    ],
+    ids=["prefix-cache", "spec-k", "spill-off", "reference-loop", "export-row", "resume", "seed-prefix", "verify",
+         "admit-shared", "admit-exact"],
+)
+def test_what_shares_or_exports_a_latent_row_is_refused(latent_mgr, monkeypatch, attempt, says):
+    """Window layers free the pages a shared prefix or an exported row would
+    need, so each of these raises (or, for the spill tier, is switched off at
+    boot) rather than serve a row with holes in it."""
+    mgr, _, _ = latent_mgr
+    if says is None:
+        attempt(mgr, monkeypatch)
+    else:
+        with pytest.raises(NotImplementedError, match=says):
+            attempt(mgr, monkeypatch)
+    assert mgr._continuous.kv.stats().pages_live == 0  # nothing was granted on the way
+
+
+# -- the manager's behaviour with the second kind of row state ------------------------
+
+
+def _ask(mgr, words: str, budget: int):
+    from lumen_tpu.models.vlm import ChatMessage
+
+    return mgr.generate([ChatMessage(role="user", content=words)], max_new_tokens=budget)
+
+
+def _together(*calls):
+    """Run the calls at once, one thread each; their results in order."""
+    barrier, out = threading.Barrier(len(calls)), {}
+
+    def run(i, call):
+        barrier.wait()
+        out[i] = call()
+
+    threads = [threading.Thread(target=run, args=(i, c)) for i, c in enumerate(calls)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(out) == len(calls)
+    return [out[i] for i in range(len(calls))]
+
+
+class TestLatentRowsThroughTheManager:
+    """``test_vlm_batched.TestBatchedGeneration``'s five behaviours, for rows
+    of latent and window pages: the one engine serves both kinds."""
+
+    PROMPTS = ("w11 w12 w13", "w21 w22 w23 w24 w25 w26", "w31", "w41 w42 w43 w44")
+
+    def test_concurrent_greedy_matches_serial(self, latent_mgr):
+        mgr, _, _ = latent_mgr
+        serial = [_ask(mgr, p, 8) for p in self.PROMPTS]
+        before = _gauges(mgr._continuous)
+        got = _together(*[lambda p=p: _ask(mgr, p, 8) for p in self.PROMPTS])
+        after = _gauges(mgr._continuous)
+        assert [g.tokens for g in got] == [s.tokens for s in serial]
+        assert after["admitted"] - before["admitted"] == len(self.PROMPTS)
+        assert after["blocks_run"] - before["blocks_run"] < after["rows_stepped"] - before["rows_stepped"]
+        assert after["pages_live"] == 0 and after["window_pages_live"] == 0
+
+    def test_a_row_stops_at_its_own_budget(self, latent_mgr):
+        mgr, _, _ = latent_mgr
+        long = _ask(mgr, "w11 w12 w13", 8)
+        short, again = _together(lambda: _ask(mgr, "w11 w12 w13", 2), lambda: _ask(mgr, "w11 w12 w13", 8))
+        assert again.tokens == long.tokens
+        assert short.tokens == long.tokens[: len(short.tokens)]
+        assert len(short.tokens) == 2 or short.finish_reason == "eos_token"
+
+    def test_a_zero_budget_row_emits_nothing(self, latent_mgr):
+        mgr, _, _ = latent_mgr
+        none, some = _together(lambda: _ask(mgr, "w11 w12 w13", 0), lambda: _ask(mgr, "w11 w12 w13", 8))
+        assert none.tokens == [] and len(some.tokens) > 0
+
+    def test_two_prompt_buckets_are_both_served(self, latent_mgr):
+        mgr, _, _ = latent_mgr
+        long_prompt = " ".join(f"w{100 + i}" for i in range(90))  # past the 64-token bucket
+        a, b = _together(lambda: _ask(mgr, "w11", 4), lambda: _ask(mgr, long_prompt, 4))
+        assert a.input_tokens <= 64 < b.input_tokens <= 128
+        assert len(a.tokens) > 0 and len(b.tokens) > 0
+
+    def test_a_streams_text_is_generates(self, latent_mgr):
+        from lumen_tpu.models.vlm import ChatMessage
+
+        mgr, _, _ = latent_mgr
+        msgs = [ChatMessage(role="user", content="w11 w12 w13")]
+        chunks, whole = _together(
+            lambda: list(mgr.generate_stream(msgs, max_new_tokens=6)), lambda: _ask(mgr, "w11 w12 w13", 6)
+        )
+        assert chunks[-1].is_final
+        assert "".join(c.text for c in chunks if not c.is_final).strip() == whole.text

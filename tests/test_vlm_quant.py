@@ -238,7 +238,8 @@ class TestQ8RouteGate:
         assert f"vlm-quant:{mgr.model_id}" not in metrics.snapshot().get("gauges", {})
 
     def test_verdict_persists_and_skips_reprobe(self, model_dir, monkeypatch):
-        """BENCH_r05 measured q8 decode at 0.03x bf16, yet every boot
+        """q8 decode measured 0.03x bf16 (round-5 chip run, 2026-08-02,
+        older than the ledger), yet every boot
         re-ran the losing probe: the verdict now lands on disk next to the
         weights (keyed model@revision) and the next auto+warmup boot skips
         the A/B entirely. An explicit pin still bypasses the cache."""
