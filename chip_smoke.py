@@ -79,7 +79,7 @@ class Sizes:
     clip: str  # lumen_tpu.testing.model_dirs.write_clip_dir size
     vlm_tiny: bool
     kernel_batch: int
-    kernel_pages: int  # block-table width (16-token pages a row)
+    kernel_pages: int  # block-table width (pages of the serving default a row)
     kernel_seq: int  # KV length of the flash / ragged cases
     clip_images: int
     clip_streams: int
@@ -90,7 +90,7 @@ class Sizes:
 
 
 REAL = Sizes(
-    clip="vitb32", vlm_tiny=False, kernel_batch=8, kernel_pages=128, kernel_seq=2048,
+    clip="vitb32", vlm_tiny=False, kernel_batch=8, kernel_pages=32, kernel_seq=2048,
     clip_images=64, clip_streams=16, vlm_requests=8, vlm_new_tokens=32, dp_images=256,
     jpeg_edge=256,
 )
@@ -185,11 +185,12 @@ def phase_kernels(sizes: Sizes, rehearse: bool, seed: int) -> None:
     import jax.numpy as jnp
 
     att = importlib.import_module("lumen_tpu.ops.attention")
+    from lumen_tpu.models.vlm.paged_kv import DEFAULT_PAGE_SIZE as page
     from lumen_tpu.ops import quant_matmul
 
     interpret = rehearse
     rng = np.random.default_rng(seed)
-    b, heads, kv_heads, dh, page = sizes.kernel_batch, 14, 2, 64, 16
+    b, heads, kv_heads, dh = sizes.kernel_batch, 14, 2, 64
     maxp, seq = sizes.kernel_pages, sizes.kernel_seq
     bf16 = jnp.bfloat16
 

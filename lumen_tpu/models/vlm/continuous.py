@@ -71,7 +71,6 @@ from . import migration
 from .modeling import prefix_ladder
 from .paged_kv import (
     DEFAULT_PAGE_SIZE,
-    LATENT_PAGE_SIZE,
     PagedKVPool,
     PoolExhausted,
     WindowPages,
@@ -277,8 +276,7 @@ class ContinuousScheduler:
         self.block = block
         dec = generator.cfg.decoder
         self.page_size = page_size or env_int(
-            "LUMEN_VLM_PAGE_SIZE", LATENT_PAGE_SIZE if dec.latent else DEFAULT_PAGE_SIZE,
-            minimum=8, maximum=256,
+            "LUMEN_VLM_PAGE_SIZE", DEFAULT_PAGE_SIZE, minimum=8, maximum=256
         )
         max_pages = -(-generator.max_seq // self.page_size)
         if pages is None:

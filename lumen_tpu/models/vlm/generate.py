@@ -27,6 +27,7 @@ import numpy as np
 
 from ...ops.sampling import apply_repetition_penalty, sample
 from .modeling import VLMConfig, VLMModel, init_kv_cache, init_paged_kv_cache
+from .paged_kv import DEFAULT_PAGE_SIZE
 
 
 @dataclass
@@ -348,7 +349,7 @@ class Generator:
         return first["c"].shape[1] if "c" in first else first["k"].shape[2]
 
     def init_pool(
-        self, slots: int, pages: int | None = None, page_size: int = 16,
+        self, slots: int, pages: int | None = None, page_size: int = DEFAULT_PAGE_SIZE,
         window_pages: int | None = None,
     ) -> dict:
         """Fresh all-slots-free paged pool state (host-callable, device

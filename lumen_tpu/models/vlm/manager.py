@@ -574,12 +574,10 @@ class VLMManager:
         from ...runtime.fleet import batcher_name
         from ...utils.env import env_int
         from .continuous import ContinuousScheduler
-        from .paged_kv import DEFAULT_PAGE_SIZE, LATENT_PAGE_SIZE, resolve_pool_pages
+        from .paged_kv import DEFAULT_PAGE_SIZE, resolve_pool_pages
 
         self._page_size = env_int(
-            "LUMEN_VLM_PAGE_SIZE",
-            LATENT_PAGE_SIZE if self.cfg.decoder.latent else DEFAULT_PAGE_SIZE,
-            minimum=8, maximum=256,
+            "LUMEN_VLM_PAGE_SIZE", DEFAULT_PAGE_SIZE, minimum=8, maximum=256
         )
         self._pool_pages, self.pool_source = resolve_pool_pages(
             self.cfg, self._page_size, self.gen_slots, self.max_seq,

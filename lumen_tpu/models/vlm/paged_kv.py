@@ -48,15 +48,17 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-#: default tokens per KV page. 16 keeps the page's [page, head_dim] tile
-#: bf16-sublane aligned on TPU and the per-page waste (< page tokens per
-#: row) small against prompt lengths in the hundreds.
-DEFAULT_PAGE_SIZE = 16
-
-#: default tokens per page of a latent decoder's cache: a page is one
-#: [page, latent] tile shared by all heads, and the decode kernels visit a
-#: page a grid step, so 16 tokens would be an 18 KB step.
-LATENT_PAGE_SIZE = 64
+#: default tokens per page, for a grouped-query pool ([page, head_dim] tiles
+#: per KV head) and a latent one ([page, latent], shared by all heads) alike.
+#: The decode kernels visit a page a grid step, and a grid step costs
+#: 0.23-0.28 us whether it holds 16 keys or 64: at 16 tokens a Qwen2-1.5B
+#: step moved 4 KB and the kernel's time was its step count (1,024 a layer
+#: call, 0.24 ms, 1.6% of its roofline, a third of the decode step; at 64,
+#: 256 steps and 0.07 ms; PERF.md section 6, PR 35). 64 is a multiple of
+#: the bf16 sublane tile, divides every prompt scratch length the ladder
+#: makes (320 = 5 pages), and wastes under a page per row against prompts
+#: in the hundreds.
+DEFAULT_PAGE_SIZE = 64
 
 #: fraction of free HBM the pool may claim when sized from device stats.
 DEFAULT_HEADROOM_FRACTION = 0.6

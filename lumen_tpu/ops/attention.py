@@ -573,12 +573,15 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
 # is (batch*kv_heads, max_pages): the page axis runs sequentially and each
 # step DMAs ONE page picked by the scalar-prefetched block table — the
 # "ragged" part: row lengths differ, and dead pages (j beyond the row's
-# live count) skip their matmuls entirely. Every live page folds into
+# live count) skip their matmuls entirely. The page is the kernel's tile:
+# the keys a grid step covers are the pool's page size, and small pages
+# make the kernel's time its step count (paged_kv.DEFAULT_PAGE_SIZE says
+# what was measured). Every live page folds into
 # flash-style running statistics (row max, row sum, weighted V) in VMEM
 # scratch whose shapes do not depend on the row capacity. Nothing is
-# stored at a per-page offset: a page of 16 tokens can never land on the
-# 128-lane tile boundary Mosaic requires of a dynamic lane offset, which
-# is what an assembled [Gp, MAXP*page] logits row needed. Online
+# stored at a per-page offset: a page shorter than the 128-lane tile can
+# never land on the tile boundary Mosaic requires of a dynamic lane
+# offset, which is what an assembled [Gp, MAXP*page] logits row needed. Online
 # rescaling sums in a different order than the one-pass XLA reference
 # below, so the two agree to f32 rounding, not bitwise
 # (tests/test_paged_attention.py states the bound).

@@ -832,12 +832,13 @@ class TestEndToEndMigration:
             mgr_a.close()
             mgr_b.close()
 
-    def test_non_power_of_two_page_count_migrates(self, model_dir):
+    def test_non_power_of_two_page_count_migrates(self, model_dir, monkeypatch):
         """Regression: the export gather pads page leaves up to a power
         of two for its compiled shape. The wire must ship only the REAL
         pages — a 3-page prompt (padded to 4) used to be refused by the
         decode host on every commit ("page leaf carries 4 page(s);
         commit declared 3") and silently fall back to local decode."""
+        monkeypatch.setenv("LUMEN_VLM_PAGE_SIZE", "16")  # 46 tokens: three pages
         _reset_migration_counters()
         mgr_a, mgr_b, eng_a, fed, stub_b = self._fleet(
             model_dir, prefill_buckets=(16, 32, 64)

@@ -63,6 +63,8 @@ class TestKernelInterpretExact:
             (4, 4, 4, 16, 8, 3),  # MHA (group of 1: the matvec corner)
             (1, 8, 2, 32, 8, 16),  # single row, long table
             (5, 6, 3, 24, 4, 7),  # odd everything
+            (2, 12, 2, 128, 64, 8),  # Qwen2-1.5B decode shape at the default page
+            (3, 4, 2, 16, 128, 3),  # a page as wide as the lane tile
         ],
     )
     def test_matches_reference_exactly(self, monkeypatch, b, h, kvh, d, page, maxp):
@@ -322,6 +324,8 @@ class TestVarqKernelExact:
             (2, 5, 14, 2, 64, 16, 8),  # Qwen2-0.5B verify shape
             (4, 2, 4, 4, 16, 8, 3),  # MHA (group of 1)
             (1, 8, 8, 2, 32, 8, 16),  # single row, wide window
+            (2, 3, 12, 2, 128, 64, 4),  # Qwen2-1.5B verify shape at the default page
+            (2, 3, 4, 2, 16, 128, 3),  # a page as wide as the lane tile
         ],
     )
     def test_matches_reference_exactly(self, monkeypatch, b, w, h, kvh, d, page, maxp):
@@ -377,6 +381,33 @@ class TestVarqKernelExact:
         out = att_mod.paged_attention_varq_reference(q, kp, vp, bt, kl)
         single = att_mod.paged_attention_reference(q[:, 0], kp, vp, bt, kl)
         np.testing.assert_array_equal(np.asarray(out[:, 0]), np.asarray(single))
+
+    @pytest.mark.parametrize("page", [64, 128])
+    @pytest.mark.parametrize("w", [1, 3], ids=["decode", "verify3"])
+    def test_ragged_rows_at_large_pages(self, monkeypatch, page, w):
+        """What a step of many keys has to get right, row by row: an idle
+        slot (table all dump page, one visible key), a row that ends on a
+        page's last slot, one key into the next page, a last page partly
+        live, and a full table; for the window, its last slot crossing
+        into a page the first slot cannot see."""
+        monkeypatch.setenv("LUMEN_PAGED_KERNEL", "1")
+        maxp = 4
+        lens = [1, page, page + 1, 2 * page + page // 3, page - w + 2, maxp * page - w + 1]
+        q, kp, vp, bt, _ = _vcase(len(lens), w, 4, 2, 16, page, maxp, seed=page + w)
+        bt = bt.at[0].set(0)
+        kl = jnp.asarray(lens, np.int32)
+        ref = att_mod.paged_attention_varq_reference(q, kp, vp, bt, kl)
+        ker = att_mod.paged_attention(q, kp, vp, bt, kl)
+        np.testing.assert_allclose(
+            np.asarray(ker), np.asarray(ref), rtol=F32_BOUND, atol=F32_BOUND
+        )
+        if w == 1:  # and the one-token entry points against each other
+            one = att_mod.paged_attention(q[:, 0], kp, vp, bt, kl)
+            np.testing.assert_allclose(
+                np.asarray(one),
+                np.asarray(att_mod.paged_attention_reference(q[:, 0], kp, vp, bt, kl)),
+                rtol=F32_BOUND, atol=F32_BOUND,
+            )
 
 
 class TestPagedKVPoolSharing:

@@ -7,8 +7,9 @@ Both paged-attention kernels passed every interpret-mode test while the
 compiler refused them outright — these cases are the ones that would
 have caught that, at no chip time: shapes, not arrays, at Qwen2-0.5B
 widths (14 query / 2 KV heads, head_dim 64, hidden 896, MLP 4864,
-16-token pages), ``interpret=False`` passed explicitly because
-``jax.default_backend()`` is the CPU here.
+16-token pages) and, for the paged decode kernels, at the serving
+default's page and Qwen2-1.5B's heads as well, ``interpret=False``
+passed explicitly because ``jax.default_backend()`` is the CPU here.
 """
 
 from __future__ import annotations
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 # ``lumen_tpu.ops`` re-exports the ``attention`` FUNCTION over the submodule.
 att = importlib.import_module("lumen_tpu.ops.attention")
+from lumen_tpu.models.vlm.paged_kv import DEFAULT_PAGE_SIZE
 from lumen_tpu.ops import latent_attention as lat
 from lumen_tpu.ops import quant_matmul
 
@@ -54,11 +56,12 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _paged(fn, q_shape, maxp):
-    pages = (B * maxp + 1, KV_HEADS, PAGE, HEAD_DIM)
+def _paged(fn, q_shape, maxp, page=PAGE):
+    rows, head_dim = q_shape[0], q_shape[-1]
+    pages = (rows * maxp + 1, KV_HEADS, page, head_dim)
     return (
         lambda q, k, v, bt, kl: fn(q, k, v, bt, kl, interpret=False),
-        [(q_shape, BF16), (pages, BF16), (pages, BF16), ((B, maxp), I32), ((B,), I32)],
+        [(q_shape, BF16), (pages, BF16), (pages, BF16), ((rows, maxp), I32), ((rows,), I32)],
     )
 
 
@@ -96,12 +99,23 @@ def _latent(heads, c_dim, span, sel):
     )
 
 
+#: the decode step as the caption cells serve it: sixteen slots of
+#: Qwen2-1.5B (12 query / 2 KV heads of 128), the default page, and the
+#: whole table of a max_seq of 2,048
+DEFAULT_MAXP = 2048 // DEFAULT_PAGE_SIZE
+
 CASES = {
-    # 128 pages a row is the serving default (max_seq 2048); 512 pages is
-    # the 8,192-token row the old kernel capped at.
+    # 16-token pages, stated: 128 pages a row is a max_seq of 2,048; 512
+    # pages is the 8,192-token row the old kernel capped at.
     "paged_decode": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 128),
     "paged_decode_8k_row": _paged(att.paged_attention_kernel, (B, HEADS, HEAD_DIM), 512),
     "paged_varq_w5": _paged(att.paged_attention_varq_kernel, (B, 5, HEADS, HEAD_DIM), 128),
+    "paged_decode_default_page": _paged(
+        att.paged_attention_kernel, (16, 12, 128), DEFAULT_MAXP, DEFAULT_PAGE_SIZE
+    ),
+    "paged_varq_w5_default_page": _paged(
+        att.paged_attention_varq_kernel, (16, 5, 12, 128), DEFAULT_MAXP, DEFAULT_PAGE_SIZE
+    ),
     # attention() hands the kernel nothing shorter than the crossover
     "flash_prefill_crossover": (
         lambda q, k, v: att.flash_attention(q, k, v, causal=True, interpret=False),
@@ -151,7 +165,10 @@ def _kernel_names(hlo: str) -> list[str]:
     ]
 
 
-@pytest.mark.parametrize("name", ["paged_decode", "paged_varq_w5"])
+@pytest.mark.parametrize(
+    "name",
+    ["paged_decode", "paged_varq_w5", "paged_decode_default_page", "paged_varq_w5_default_page"],
+)
 def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
     """The benchmark's ``paged_attn_*`` metrics find the kernel on the
     device's ``XLA Ops`` line by ``^paged_attention`` on the instruction's

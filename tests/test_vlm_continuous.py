@@ -14,7 +14,9 @@ import time
 import numpy as np
 
 from lumen_tpu.models.vlm import ChatMessage, VLMManager
+from lumen_tpu.models.vlm.paged_kv import DEFAULT_PAGE_SIZE
 from tests.test_vlm import make_vlm_model_dir
+from tests.test_vlm_latent import latent_mgr  # noqa: F401 - the fixture, for the gauge test below
 
 import pytest
 
@@ -74,6 +76,34 @@ class TestContinuousCorrectness:
         want = [int(t) for t in np.asarray(ref.tokens[0][: int(ref.n_generated[0])])]
         assert served.tokens == want, (served.text, want)
         assert (served.finish_reason == "eos_token") == bool(ref.stopped_eos[0])
+
+    @pytest.mark.parametrize("page", [16, DEFAULT_PAGE_SIZE], ids=lambda p: f"page-{p}")
+    def test_greedy_is_the_reference_loops_at_either_page(self, model_dir, page):
+        """The served tokens do not depend on the page: at 16 tokens and at
+        the default, a 58-token prompt that decodes across the 64th
+        position (a page boundary at either size) serves what the
+        contiguous-cache reference loop serves, so both serve the same."""
+        import jax
+
+        mgr = _make_lane_mgr(
+            model_dir, chunk=None, page=page, max_seq=128, max_new_cap=16,
+            prefill_buckets=(16, 32, 64),
+        )
+        try:
+            assert mgr._continuous.page_size == page
+            msgs = [ChatMessage(role="user", content=_lane_prompt(7, words=52))]
+            embeds, positions, lengths, prompt_ids, n = mgr._prepare_inputs(msgs, None)
+            assert n == 58 and prompt_ids.shape[1] == 64
+            served = mgr.generate(msgs, max_new_tokens=16)
+            ref = mgr.generator.generate(
+                mgr.params, embeds, positions, lengths, prompt_ids,
+                jax.random.PRNGKey(0), max_new_tokens=16,
+            )
+            want = [int(t) for t in np.asarray(ref.tokens[0][: int(ref.n_generated[0])])]
+            assert len(want) > 64 - n  # the row crossed into the next page
+            assert served.tokens == want, (served.text, want)
+        finally:
+            mgr.close()
 
     def test_concurrent_mixed_budgets_match_serial(self, cont_mgr):
         prompts = [("hello", 3), ("the quick brown fox", 8), ("a", 5), ("count", 1)]
@@ -443,9 +473,11 @@ def _lane_prompt(k: int, words: int = 39) -> str:
     return " ".join(f"w{16 + (37 * k + 5 * j) % 200}" for j in range(words))
 
 
-def _make_lane_mgr(model_dir, chunk: "int | None" = 32, **kw):
+def _make_lane_mgr(model_dir, chunk: "int | None" = 32, page: int = 16, **kw):
     """A continuous engine whose 64 and 128 buckets take the chunk lane
-    (``chunk`` 32) or, with ``chunk`` None, the one-shot prefill."""
+    (``chunk`` 32) or, with ``chunk`` None, the one-shot prefill. The
+    page is stated: a chunk is whole pages, so the lane tests' counts
+    (chunks a prompt, pages a prompt caches) are in 16-token pages."""
     cfg = dict(
         dtype="float32", max_seq=256, max_new_cap=32,
         prefill_buckets=(16, 64, 128),
@@ -453,8 +485,9 @@ def _make_lane_mgr(model_dir, chunk: "int | None" = 32, **kw):
     )
     cfg.update(kw)
     mp = pytest.MonkeyPatch()
+    mp.setenv("LUMEN_VLM_PAGE_SIZE", str(page))  # both read once, at construction
     if chunk is not None:
-        mp.setenv("LUMEN_VLM_PREFILL_CHUNK", str(chunk))  # read once, at construction
+        mp.setenv("LUMEN_VLM_PREFILL_CHUNK", str(chunk))
     try:
         mgr = VLMManager(model_dir, **cfg)
         mgr.initialize()
@@ -560,6 +593,7 @@ class TestChunkedPrefillLane:
             mgr_direct.close()
 
         monkeypatch.setenv("LUMEN_VLM_PREFILL_CHUNK", "32")
+        monkeypatch.setenv("LUMEN_VLM_PAGE_SIZE", "16")  # a chunk is whole pages
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=256, max_new_cap=16,
             prefill_buckets=(64,),
@@ -758,6 +792,23 @@ class TestChunkedPrefillLane:
             assert stats.allocated_total == stats.freed_total
         finally:
             mgr.close()
+
+
+@pytest.mark.parametrize("which", ["cont_mgr", "latent_mgr"], ids=["grouped-query", "latent"])
+def test_the_gauge_reports_the_default_page_with_no_setting(request, which):
+    """One default page for both kinds of pool: with ``LUMEN_VLM_PAGE_SIZE``
+    unset, a grouped-query and a latent manager size their pools by
+    ``DEFAULT_PAGE_SIZE`` and say so in the ``vlm-continuous:*`` gauge."""
+    import os
+
+    assert "LUMEN_VLM_PAGE_SIZE" not in os.environ
+    mgr = request.getfixturevalue(which)
+    mgr = mgr[0] if isinstance(mgr, tuple) else mgr
+    sched = mgr._continuous
+    assert sched.gen.cfg.decoder.latent == (which == "latent_mgr")
+    # the ``vlm-continuous:<name>`` provider itself: the registry's slot for a
+    # name is last-writer-wins, and other tests build engines of the same name
+    assert sched._gauge_fn()["page_size"] == DEFAULT_PAGE_SIZE == sched.kv.page_size == 64
 
 
 class TestObservabilitySurface:
@@ -1221,16 +1272,25 @@ class TestPrefixReuseAndSpec:
     #: cached page (16 tokens), hit coverage 16/20 = 0.8. The repeated
     #: tail also gives the prompt-lookup drafter n-gram matches.
     PROMPT = "the quick brown fox jumps over the lazy dog again and again and again"
+    #: by page; at 64 tokens the same: 80 live tokens in a 96 bucket, one
+    #: full cached page, hit coverage 64/80 = 0.8
+    PROMPTS = {16: PROMPT, 64: " ".join([PROMPT] * 5 + ["the quick brown fox"])}
 
-    def _make_mgr(self, model_dir, **kw):
+    def _make_mgr(self, model_dir, page: int = 16, **kw):
+        """A manager at a stated page: what a prompt shares through the
+        cache, and where a verify window crosses a page, is counted in
+        pages."""
         cfg = dict(
             dtype="float32", max_seq=128, max_new_cap=16,
             prefill_buckets=(16, 32),
             gen_slots=4, gen_block=4,
         )
         cfg.update(kw)
-        mgr = VLMManager(model_dir, **cfg)
-        mgr.initialize()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("LUMEN_VLM_PAGE_SIZE", str(page))  # read once, at construction
+            mgr = VLMManager(model_dir, **cfg)
+            mgr.initialize()
+        assert mgr._continuous.page_size == page
         return mgr
 
     def _count_prefills(self, sched):
@@ -1271,16 +1331,18 @@ class TestPrefixReuseAndSpec:
         for key in ("prefix_entries", "prefix_hits", "spec_k", "spec_accept_rate"):
             assert key not in g, key
 
-    def test_prefix_hit_skips_covered_prefill(self, model_dir, monkeypatch):
+    @pytest.mark.parametrize("page", [16, 64])
+    def test_prefix_hit_skips_covered_prefill(self, model_dir, monkeypatch, page):
         """Second identical prompt admits via the cache: zero full
         prefills, ONE suffix-only chunk, identical tokens, and the final
         metadata reports the covered fraction."""
         monkeypatch.setenv("LUMEN_VLM_PREFIX_BYTES", str(8 << 20))
-        mgr = self._make_mgr(model_dir)
+        mgr = self._make_mgr(model_dir, page=page, prefill_buckets=(16, 32, 96))
         try:
             sched = mgr._continuous
             assert sched.prefix is not None
-            msgs = [ChatMessage(role="user", content=self.PROMPT)]
+            msgs = [ChatMessage(role="user", content=self.PROMPTS[page])]
+            assert mgr._prepare_inputs(msgs, None, True)[4] == 5 * page // 4  # live tokens
             hits0, miss0 = sched.prefix_hits, sched.prefix_misses
             first = mgr.generate(msgs, max_new_tokens=8)
             assert sched.prefix_misses == miss0 + 1
@@ -1300,7 +1362,8 @@ class TestPrefixReuseAndSpec:
             # admission runs no full prefill and exactly one suffix chunk.
             assert full == [], full
             assert len(chunk) == 1, chunk
-            assert second.metadata.get("prefix_hit") == 0.8  # 16/20 tokens
+            assert second.metadata.get("prefix_hit") == 0.8  # 16/20, 64/80 tokens
+            assert sched.prefix_hit_pages == 1
 
             g = sched._gauge_fn()
             assert g["prefix_entries"] >= 1
@@ -1309,14 +1372,15 @@ class TestPrefixReuseAndSpec:
         finally:
             mgr.close()
 
+    @pytest.mark.parametrize("page", [16, 64])
     def test_spec_greedy_token_identical_with_acceptance(
-        self, model_dir, monkeypatch, cont_mgr
+        self, model_dir, monkeypatch, cont_mgr, page
     ):
         """LUMEN_VLM_SPEC_K=4: greedy output matches the non-speculative
         engine token for token, with real proposals AND acceptances (the
         tiny model's repetitive output is ideal prompt-lookup traffic)."""
         monkeypatch.setenv("LUMEN_VLM_SPEC_K", "4")
-        mgr = self._make_mgr(model_dir)
+        mgr = self._make_mgr(model_dir, page=page)
         try:
             sched = mgr._continuous
             assert sched.spec_k == 4 and sched._spec_active()
@@ -1506,6 +1570,7 @@ class TestWaitCounters:
 
     def test_lane_jobs_book_lane_time(self, model_dir, monkeypatch):
         monkeypatch.setenv("LUMEN_VLM_PREFILL_CHUNK", "32")
+        monkeypatch.setenv("LUMEN_VLM_PAGE_SIZE", "16")  # a chunk is whole pages
         mgr = VLMManager(
             model_dir, dtype="float32", max_seq=256, max_new_cap=16,
             prefill_buckets=(64,), gen_slots=2, gen_block=4,
