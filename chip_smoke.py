@@ -209,8 +209,31 @@ def phase_kernels(sizes: Sizes, rehearse: bool, seed: int) -> None:
     w8 = jnp.asarray(rng.integers(-127, 128, size=(hidden, mlp)), jnp.int8)
     w_scale = jnp.asarray(rng.uniform(0.5, 1.5, size=mlp) / 127.0, jnp.float32)
 
+    # the Mamba-2 kernels at granite-4.0-h-small's dimensions (128 heads of 64,
+    # d_state 128): a 320-token prompt's scan from a state already there, a
+    # padded tail that must not move it; sixteen slots of which nine step
+    from lumen_tpu.ops import ssm
+
+    mh, mp, mn, mseq, mslots = (8, 16, 32, 40, 4) if rehearse else (128, 64, 128, 320, 16)
+    dt = jnp.asarray(np.log1p(np.exp(rng.standard_normal((mslots, mseq, mh)) - 3.0)), jnp.float32)
+    dt = dt.at[0, -7:].set(0.0)
+    a_neg = jnp.asarray(-np.exp(rng.standard_normal(mh)), jnp.float32)
+    d_skip = jnp.asarray(rng.standard_normal(mh), jnp.float32)
+    m_state = jnp.asarray(rng.standard_normal((mslots, mn, mh * mp)), jnp.float32)
+    m_x, m_b, m_c = normal(mslots, mseq, mh * mp), normal(mslots, mseq, mn), normal(mslots, mseq, mn)
+    stepping = jnp.asarray(np.arange(mslots) % 16 < 9)
+    flat = lambda y, state: jnp.concatenate([y.astype(jnp.float32).ravel(), state.ravel()])
+
     # (name, kernel, reference, arguments, holds a tpu_custom_call)
     cases = [
+        ("ssd_chunk_scan",
+         lambda *a: flat(*ssm.ssd_chunk_scan_kernel(*a, interpret=interpret)),
+         lambda *a: flat(*ssm.ssd_chunk_scan_reference(*a)),
+         (m_x[:1], dt[:1], a_neg, m_b[:1], m_c[:1], d_skip, m_state[:1]), True),
+        ("ssm_state_update",
+         lambda *a: flat(*ssm.ssm_state_update_kernel(*a, interpret=interpret)),
+         lambda *a: flat(*ssm.ssm_state_update_reference(*a)),
+         (m_x[:, 0], dt[:, 1], a_neg, m_b[:, 0], m_c[:, 0], d_skip, m_state, stepping), True),
         ("paged_decode",
          lambda *a: att.paged_attention_kernel(*a, interpret=interpret),
          att.paged_attention_reference,

@@ -74,7 +74,6 @@ from .paged_kv import (
     PagedKVPool,
     PoolExhausted,
     WindowPages,
-    page_bytes,
     window_pool_pages,
 )
 from .prefix_cache import PrefixCache, chunk_keys, prefix_cache_enabled
@@ -282,16 +281,16 @@ class ContinuousScheduler:
         if pages is None:
             pages = slots * max_pages + 1  # slot-era footprint fallback
         window = window_pages = None
-        if dec.latent:
-            # Window layers keep pages of their own and free those behind
-            # the window; what shares or exports whole rows of pages (prefix
-            # cache, spill tier, speculative verify, migration) has no
-            # latent form yet and is refused or off, never silently wrong.
-            if prefix_cache_enabled() or env_int("LUMEN_VLM_SPEC_K", 0, minimum=0, maximum=15):
-                raise NotImplementedError(
-                    "LUMEN_VLM_PREFIX_BYTES and LUMEN_VLM_SPEC_K are not implemented for a "
-                    "latent decoder (window layers free the pages a shared prefix would need)"
-                )
+        #: what a row keeps in each layer, and what that allows
+        #: (``paged_kv.RowState``). What shares or exports whole rows (prefix
+        #: cache, spill tier, speculative verify, migration) has a form for
+        #: pages of K/V alone: for latent pages and recurrent state it is
+        #: refused or off, never silently wrong.
+        rows = self.rows = generator.rows
+        if prefix_cache_enabled() or env_int("LUMEN_VLM_SPEC_K", 0, minimum=0, maximum=15):
+            rows.refuse("LUMEN_VLM_PREFIX_BYTES / LUMEN_VLM_SPEC_K (a shared prefix, a verify window)")
+        if rows.window_layers:
+            # window layers keep pages of their own and free those behind the window
             window_pages = window_pool_pages(generator.cfg, self.page_size, slots, block)
             window = WindowPages(
                 window_pages, self.page_size, slots, max_pages, dec.sliding_window
@@ -366,8 +365,8 @@ class ContinuousScheduler:
         # degrades exactly as the pre-spill engine did, minus the bare
         # RuntimeError (sampled victims get the typed retryable shed).
         self._spill_budget = env_int("LUMEN_VLM_SPILL_BYTES", 256 << 20, minimum=0)
-        if dec.latent and self._spill_budget:
-            logger.info("VLM spill tier off: a latent decoder's preempted rows restart from the prompt")
+        if not rows.shareable and self._spill_budget:
+            logger.info("VLM spill tier off: this decoder's rows cannot be exported; a preempted row restarts from the prompt")
             self._spill_budget = 0
         self._spill_max = env_int("LUMEN_VLM_SPILL_MAX", 32, minimum=0)
         self._spill_arena: ShmArena | None = None  # created on first spill
@@ -399,9 +398,7 @@ class ContinuousScheduler:
         self.prefix: PrefixCache | None = None
         if prefix_cache_enabled():
             dtype_bytes = jnp.dtype(generator.cache_dtype).itemsize
-            self.prefix = PrefixCache(
-                self.kv, page_bytes(generator.cfg, self.page_size, dtype_bytes)
-            )
+            self.prefix = PrefixCache(self.kv, rows.page_bytes(self.page_size, dtype_bytes))
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_hit_pages = 0  # shared pages attached across all hits
@@ -448,10 +445,19 @@ class ContinuousScheduler:
         # over ``index_topk`` has every causal key scored, in each full
         # layer); the expert layers' come back from the device with each
         # block's tokens (``Generator._apply``).
-        self._indexer_layers = dec.layers_of("full_attention") if dec.latent else 0
+        self._indexer_layers = rows.indexer_layers
         self.indexer_rows = 0
         self.indexer_keys_scored = 0
         self.moe_stats = np.zeros((4,), np.int64)  # routed, held, experts touched, calls
+        # A recurrent decoder's: the state every slot holds whatever its row's
+        # length, rows whose finished state was copied into a slot (inside
+        # ``vlm.admit`` or ``vlm.lane_finish``), and of those the ones that replaced what a
+        # retired row had left there (an install writes the whole state, so
+        # a reused slot starts from its own prompt alone).
+        self.state_bytes = slots * rows.slot_bytes(jnp.dtype(generator.cache_dtype).itemsize)
+        self.state_installs = 0
+        self.state_resets = 0
+        self._slots_used: set[int] = set()
         self._thread = threading.Thread(target=self._loop, name="vlm-continuous", daemon=True)
         self._thread.start()
         ref = weakref.ref(self)  # registry must not pin the pool/params
@@ -512,6 +518,11 @@ class ContinuousScheduler:
                 out["window_pages_total"] = s.kv.window.pages_total
                 out["indexer_rows"] = s.indexer_rows
                 out["indexer_keys_scored"] = s.indexer_keys_scored
+            if s.rows.state_layers:
+                out["state_bytes"] = s.state_bytes
+                out["state_layers"] = s.rows.state_layers
+                out["state_installs"] = s.state_installs
+                out["state_resets"] = s.state_resets
             if s.gen._counts_experts:
                 routed, held, touched, calls = (int(v) for v in s.moe_stats)
                 out["moe_tokens_routed"] = routed
@@ -934,6 +945,10 @@ class ContinuousScheduler:
             self.kv.release(slot)
             raise
         self._admit_seq += 1
+        if self.rows.state_layers:
+            self.state_installs += 1
+            self.state_resets += slot in self._slots_used
+            self._slots_used.add(slot)
         slot_state = _Slot(request=req, prompt_len=n, seq=self._admit_seq)
         if self._spec_active():
             slot_state.text_toks = self._text_toks(req)
@@ -1642,6 +1657,7 @@ class ContinuousScheduler:
         to the loop thread (the prefix cache is loop-owned); a lost
         race fails the request with :class:`migration.ChunksMissing`,
         which the wire handler maps to a retryable refusal."""
+        self.rows.refuse("admitting a migrated row")
         need = rec.cur_len + max(int(req.max_new) - rec.n_gen, 0) + 1
         if not self.kv.fits(need):
             raise ValueError(

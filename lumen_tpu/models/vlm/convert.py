@@ -62,6 +62,20 @@ DECODER_RULES = [
     (r"model\.layers\.(\d+)\.self_attn\.indexer\.k_norm\.bias", r"decoder/layers_\1/attn/index_k_norm/bias", None),
     (r"model\.layers\.(\d+)\.mlp\.gate\.e_score_correction_bias", r"decoder/layers_\1/mlp/select_bias", None),
     (r"model\.layers\.(\d+)\.mlp\.shared_experts\.(gate_proj|up_proj|down_proj)\.weight", r"decoder/layers_\1/mlp/shared/\2/kernel", linear_kernel),
+    # Hybrid decoder (``granitemoehybrid``): the Mamba-2 mixer; the experts as
+    # two stacked tensors a layer (``input_linear`` holds the gate and up
+    # halves of every expert side by side: split by _split_fused below); the
+    # shared expert likewise.
+    (r"model\.layers\.(\d+)\.mamba\.(in_proj|out_proj)\.weight", r"decoder/layers_\1/mamba/\2/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.weight", r"decoder/layers_\1/mamba/conv_kernel", lambda w: np.ascontiguousarray(w[:, 0, :].T)),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.bias", r"decoder/layers_\1/mamba/conv_bias", None),
+    (r"model\.layers\.(\d+)\.mamba\.(dt_bias|A_log|D)", r"decoder/layers_\1/mamba/\2", None),
+    (r"model\.layers\.(\d+)\.mamba\.norm\.weight", r"decoder/layers_\1/mamba/norm/scale", None),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.router\.layer\.weight", r"decoder/layers_\1/mlp/router", linear_kernel),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.input_linear\.weight", r"decoder/layers_\1/mlp/__fused_in__", None),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.output_linear\.weight", r"decoder/layers_\1/mlp/w_down", lambda w: np.ascontiguousarray(w.transpose(0, 2, 1))),
+    (r"model\.layers\.(\d+)\.shared_mlp\.input_linear\.weight", r"decoder/layers_\1/mlp/shared/__fused_in__", None),
+    (r"model\.layers\.(\d+)\.shared_mlp\.output_linear\.weight", r"decoder/layers_\1/mlp/shared/down_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.input_layernorm\.weight", r"decoder/layers_\1/input_norm/scale", None),
     (r"model\.layers\.(\d+)\.post_attention_layernorm\.weight", r"decoder/layers_\1/post_attn_norm/scale", None),
     (r"model\.norm\.weight", r"decoder/final_norm/scale", None),
@@ -152,6 +166,24 @@ def _stack_experts(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
     return out
 
 
+def _split_fused(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+    """``.../__fused_in__`` leaves (HF ``input_linear.weight``: the gate half
+    of the outputs, then the up half) into the two the modules hold: a bank
+    ``[E, 2f, d]`` into ``w_gate`` / ``w_up`` ``[E, d, f]``, a single
+    ``[2f, d]`` into ``gate_proj`` / ``up_proj`` kernels ``[d, f]``."""
+    out: dict[str, np.ndarray] = {}
+    for key, val in flat.items():
+        if not key.endswith("/__fused_in__"):
+            out[key] = val
+            continue
+        prefix = key.removesuffix("/__fused_in__")
+        gate, up = np.split(np.swapaxes(val, -1, -2), 2, axis=-1)
+        names = ("w_gate", "w_up") if val.ndim == 3 else ("gate_proj/kernel", "up_proj/kernel")
+        out[f"{prefix}/{names[0]}"] = np.ascontiguousarray(gate)
+        out[f"{prefix}/{names[1]}"] = np.ascontiguousarray(up)
+    return out
+
+
 #: decoder projections QDense replaces when ``weight_quant="int8"`` — must
 #: stay in lockstep with modeling._dense call sites (attn q/k/v/o, SwiGLU
 #: gate/up/down incl. the MoE shared expert, untied lm_head). MoE expert
@@ -192,7 +224,7 @@ def convert_vlm_checkpoint(
     if tie_word_embeddings:
         drop.append(r"^lm_head\.weight$")
     flat = apply_rules(normalized, DECODER_RULES + VISION_RULES, drop=drop)
-    params = unflatten(_stack_experts(flat))
+    params = unflatten(_stack_experts(_split_fused(flat)))
     if init_params is not None:
         assert_tree_shapes(params, init_params)
     return params

@@ -27,7 +27,7 @@ import numpy as np
 
 from ...ops.sampling import apply_repetition_penalty, sample
 from .modeling import VLMConfig, VLMModel, init_kv_cache, init_paged_kv_cache
-from .paged_kv import DEFAULT_PAGE_SIZE
+from .paged_kv import DEFAULT_PAGE_SIZE, RowState
 
 
 @dataclass
@@ -60,6 +60,9 @@ class Generator:
         self.max_seq = max_seq
         self.max_new_cap = max_new_cap
         self.cache_dtype = cache_dtype
+        #: what a row keeps in each layer (pages of K/V, latent pages, a
+        #: recurrent state) and what that allows: ``paged_kv.RowState``
+        self.rows = RowState(cfg)
         # KV right-sizing (round-4 verdict): the fused path allocates its
         # cache at the smallest bucket >= prompt + budget instead of
         # worst-case max_seq — a 32-token caption request in a
@@ -142,10 +145,6 @@ class Generator:
             caches.append({"moe_stats": jnp.zeros((batch, 4), jnp.int32)})
         return caches
 
-    def _no_latent(self, what: str) -> None:
-        if self.cfg.decoder.latent:
-            raise NotImplementedError(f"{what} is not implemented for a latent decoder")
-
     def _embed(self, params, ids):
         return self.model.apply({"params": params}, ids, method=VLMModel.embed_tokens)
 
@@ -190,7 +189,8 @@ class Generator:
         kv_len: int | None = None,  # static: KV bucket (defaults to max_seq)
     ):
         cfg = self.cfg
-        self._no_latent("the fused generate program")
+        if cfg.decoder.latent:  # LatentAttention has no per-row contiguous step
+            raise NotImplementedError("the fused generate program is not implemented for a latent decoder")
         b = embeds.shape[0]
         caches, last_logits = self._prefill_core(params, embeds, positions, lengths, kv_len)
         seen = self._seen_from_prompt(prompt_ids, lengths)
@@ -343,10 +343,9 @@ class Generator:
             offset, kv_len, valid,
         )
 
-    @staticmethod
-    def _page_size_of(pool: dict) -> int:
-        first = pool["caches"][0]
-        return first["c"].shape[1] if "c" in first else first["k"].shape[2]
+    def _page_size_of(self, pool: dict) -> int:
+        """Tokens a page of ``pool`` holds, by the pool's description."""
+        return self.rows.page_size_of(pool["caches"])
 
     def init_pool(
         self, slots: int, pages: int | None = None, page_size: int = DEFAULT_PAGE_SIZE,
@@ -368,7 +367,9 @@ class Generator:
         extra = {name: jnp.zeros((4,), jnp.int32) for name in names}
         return dict(
             **extra,
-            caches=init_paged_kv_cache(cfg, pages, page_size, self.cache_dtype, window_pages),
+            caches=init_paged_kv_cache(
+                cfg, pages, page_size, self.cache_dtype, window_pages, slots=slots
+            ),
             cur_tok=jnp.zeros((slots,), jnp.int32),
             cur_len=jnp.zeros((slots,), jnp.int32),
             seen=jnp.zeros((slots, cfg.decoder.vocab_size), bool),
@@ -386,47 +387,20 @@ class Generator:
         self, pool, slot, caches1, tok0, seen1, length, bt_row,
         max_new, temperature, top_p, do_sample, rep,
     ):
-        """Write one prefilled request into ``slot``: scatter its prompt
-        KV (contiguous [1, kvh, Lb, dh] prefill scratch, ``Lb`` a page
-        multiple) into the pages ``bt_row`` grants, page by page. Entries
-        past the prompt's live pages point at the dump page 0, so the
-        scatter needs no masking."""
+        """Write one prefilled request into ``slot``, layer by layer as
+        the row state's description installs it (``RowState.install``): a
+        scratch of K/V or latent rows scattered into the pages ``bt_row``
+        grants, a recurrent state copied into the slot's row."""
         z = jnp.zeros((), jnp.int32)
         s = jnp.asarray(slot, jnp.int32)
+        page = self._page_size_of(pool)
+        caches = [
+            self.rows.install(i, dst, pre, s, bt_row, page)
+            for i, (dst, pre) in enumerate(zip(pool["caches"], caches1))
+        ]
         extra = {}
-        if self.cfg.decoder.latent:
-            # Latent rows: [1, Lb, width] scratch -> [nseg, page, width]
-            # pages; ``bt_row`` [2, MAXP] is the full layers' table and the
-            # window layers', whose entries behind the window are the dump
-            # page (``paged_kv.WindowPages.install``).
-            from .modeling import FULL_ATTENTION
-
-            page = self._page_size_of(pool)
-            nseg = caches1[0]["c"].shape[1] // page
-            caches = []
-            for i, (dst_layer, pre) in enumerate(zip(pool["caches"], caches1)):
-                dst = bt_row[0 if self.cfg.decoder.layer_kind(i) == FULL_ATTENTION else 1, :nseg]
-                caches.append({
-                    name: arr.at[dst].set(
-                        pre[name][0].reshape(nseg, page, -1).astype(arr.dtype)
-                    )
-                    for name, arr in dst_layer.items()
-                })
-            if self._counts_experts:
-                extra["moe_stats"] = pool["moe_stats"] + caches1[-1]["moe_stats"].sum(0)
-        else:
-            page = pool["caches"][0]["k"].shape[2]
-            lb = caches1[0]["k"].shape[2]
-            nseg = lb // page
-            kvh = pool["caches"][0]["k"].shape[1]
-            dh = pool["caches"][0]["k"].shape[3]
-            dst = bt_row[:nseg]
-
-            def scatter(pages_arr, pre):
-                seg = pre[0].reshape(kvh, nseg, page, dh).transpose(1, 0, 2, 3)
-                return pages_arr.at[dst].set(seg.astype(pages_arr.dtype))
-
-            caches = jax.tree.map(scatter, pool["caches"], caches1)
+        if self._counts_experts:
+            extra["moe_stats"] = pool["moe_stats"] + caches1[-1]["moe_stats"].sum(0)
         return dict(
             **extra,
             caches=caches,
@@ -451,7 +425,7 @@ class Generator:
         that the resume scatter writes straight back to the dump page.
         The caller ships the result host-side with ONE ``jax.device_get``
         (the spill tier's per-victim transfer budget)."""
-        self._no_latent("the spill tier's export")
+        self.rows.refuse("the spill tier's export")
         s = jnp.asarray(slot, jnp.int32)
         return dict(
             pages=jax.tree.map(lambda c: c[page_ids], pool["caches"]),
@@ -472,7 +446,7 @@ class Generator:
         but not-yet-emitted next token, so a resumed greedy row continues
         token-identically and a resumed sampled row continues its own
         draw without splicing."""
-        self._no_latent("the spill tier's resume")
+        self.rows.refuse("the spill tier's resume")
         s = jnp.asarray(slot, jnp.int32)
         z = jnp.zeros((), jnp.int32)
         caches = jax.tree.map(
@@ -593,7 +567,7 @@ class Generator:
         with the dump page 0; pad segments land on slots the suffix chunks
         overwrite (decode writes K/V before attending) or the valid-length
         mask hides."""
-        self._no_latent("seeding a scratch from a cached prefix")
+        self.rows.refuse("seeding a scratch from a cached prefix")
         nseg = page_ids.shape[0]
 
         def seed(dst, src):
@@ -620,9 +594,9 @@ class Generator:
         slots' KV writes land above the row's final ``cur_len`` where the
         valid-length mask hides them until real tokens overwrite them."""
         cfg = self.cfg
-        self._no_latent("speculative verify")
+        self.rows.refuse("speculative verify")
         b = pool["cur_tok"].shape[0]
-        capacity = block_tables.shape[1] * pool["caches"][0]["k"].shape[2]
+        capacity = block_tables.shape[1] * self._page_size_of(pool)
         toks_in = jnp.asarray(draft, jnp.int32).at[:, 0].set(pool["cur_tok"])
         # Same spirit as _step_block's clamp: the host never dispatches a
         # live row whose window would cross its block table's capacity.

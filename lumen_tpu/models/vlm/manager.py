@@ -13,6 +13,7 @@ the number of distinct compiles is bounded.
 from __future__ import annotations
 
 import hashlib
+import io
 import json
 import logging
 import os
@@ -639,12 +640,19 @@ class VLMManager:
             )
         self._initialized = True
         if self.warmup:
-            # Compile the dominant path up front (smallest prompt bucket:
-            # text embed + prefill + one decode step); the image-prefill
-            # variant still compiles on its first request.
+            # Compile a photo library's dominant path up front: one caption
+            # of an image with the shortest text bucket (the tower, the
+            # prompt's prefill programs, the install, a decode block over
+            # the table width such rows use). A text-only chat compiles its
+            # own three programs on its first request (60 s on a v5e for a
+            # 1.5B decoder, 0.2 s from the second on: PERF.md, PR 36):
+            # warming those instead cost every caption deployment a second
+            # set of programs it never ran (45-54 s of a compiling boot).
             t0 = time.perf_counter()
-            self.generate([ChatMessage(role="user", content="hi")], max_new_tokens=1)
-            logger.info("vlm warmup (text path) in %.1fs", time.perf_counter() - t0)
+            self.generate(
+                [ChatMessage(role="user", content="hi")], self._warmup_image(), max_new_tokens=1
+            )
+            logger.info("vlm warmup (image path) in %.1fs", time.perf_counter() - t0)
         logger.info(
             "VLM ready: %s layers=%d hidden=%d vision_tokens=%d",
             self.model_id,
@@ -652,6 +660,15 @@ class VLMManager:
             self.cfg.decoder.hidden_size,
             self.vision_tokens,
         )
+
+    def _warmup_image(self) -> bytes:
+        """A mid-gray JPEG at the tower's size: what the warm-up captions."""
+        from PIL import Image
+
+        size = self.cfg.vision.image_size
+        buf = io.BytesIO()
+        Image.new("RGB", (size, size), (128, 128, 128)).save(buf, "JPEG")
+        return buf.getvalue()
 
     def close(self) -> None:
         if self._initialized:

@@ -35,6 +35,12 @@ from ...ops.quant import QDense
 
 FULL_ATTENTION = "full_attention"
 WINDOW_ATTENTION = "sliding_attention"
+#: the two kinds of a hybrid decoder (``granitemoehybrid``'s own words): a
+#: Mamba-2 mixer, whose row state is recurrent and holds no page, and a
+#: grouped-query attention layer over K/V pages
+MAMBA = "mamba"
+ATTENTION = "attention"
+LATENT_KINDS = (FULL_ATTENTION, WINDOW_ATTENTION)
 
 
 @dataclass(frozen=True)
@@ -127,6 +133,27 @@ class DecoderConfig:
     moe_routed_scale: float = 1.0
     moe_shared_gated: bool = True
     moe_held: tuple[int, int] | None = None
+    # --- Hybrid decoder (``model_type`` ``granitemoehybrid``): ``layer_types``
+    # of "mamba" and "attention". A mamba layer is a Mamba-2 mixer
+    # (``Mamba2Mixer``: ``mamba_heads`` heads of ``mamba_head_dim``, a state
+    # of ``mamba_state`` a head value, one B and C shared by every head
+    # (``from_hf`` refuses more groups), a causal depthwise convolution of
+    # ``mamba_conv`` taps,
+    # ``mamba_chunk`` the block of the chunked scan); an attention layer is
+    # ``DecoderAttention`` as the three switches below leave it. The four
+    # multipliers are Granite's: token embeddings, each residual branch, the
+    # attention scores (``attn_scale``) and the logits' divisor.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    attn_rope: bool = True  # False: no rotation ("nope")
+    attn_bias: bool = True  # q/k/v bias (Qwen2 has it)
+    attn_scale: float | None = None  # None -> head_dim ** -0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def dim_per_head(self) -> int:
@@ -134,10 +161,21 @@ class DecoderConfig:
 
     @property
     def latent(self) -> bool:
-        return bool(self.layer_types)
+        return any(k in LATENT_KINDS for k in self.layer_types)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C side by side."""
+        return self.mamba_inner + 2 * self.mamba_state
 
     def layer_kind(self, i: int) -> str:
-        return self.layer_types[i]
+        """Layer ``i``'s kind; a decoder that names none is all grouped-query
+        attention (the Qwen2 layout)."""
+        return self.layer_types[i] if self.layer_types else ATTENTION
 
     def layers_of(self, kind: str) -> int:
         return sum(1 for k in self.layer_types if k == kind)
@@ -205,6 +243,8 @@ class VLMConfig:
         vis = cfg.get("vision_config", {})
         if text.get("model_type") == "dots3_note":
             decoder = _dots3_decoder(text)
+        elif text.get("model_type") == "granitemoehybrid":
+            decoder = _granite_decoder(text)
         else:
             decoder = cls._qwen2_decoder(cfg, text)
         return cls._with_tower(cfg, text, vis, decoder)
@@ -312,20 +352,89 @@ def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
     )
 
 
+def _granite_decoder(t: dict[str, Any]) -> DecoderConfig:
+    """``model_type`` ``granitemoehybrid``: Mamba-2 and grouped-query layers
+    by ``layer_types``, no rotation, softmax over the selected experts'
+    logits, an ungated shared expert, the four multipliers.
+    ``num_local_experts`` counts the experts HELD here; ``ep_size`` chips
+    share each layer (default 1: the whole bank), as for ``dots3_note``."""
+    n = t["num_hidden_layers"]
+    kinds = tuple(t.get("layer_types", (ATTENTION,) * n)[:n])
+    if len(kinds) != n or set(kinds) - {MAMBA, ATTENTION}:
+        raise ValueError(f"layer_types must name {n} mamba/attention layers, got {kinds}")
+    if t.get("mamba_n_groups", 1) != 1:
+        raise NotImplementedError("a Mamba-2 mixer with more than one B/C group")
+    if t.get("position_embedding_type", "nope") != "nope":
+        raise NotImplementedError(f"position_embedding_type {t['position_embedding_type']!r} in a hybrid decoder")
+    held, ep, rank = t.get("num_local_experts", 0), t.get("ep_size", 1), t.get("ep_rank", 0)
+    return DecoderConfig(
+        hidden_size=t["hidden_size"],
+        layers=n,
+        heads=t["num_attention_heads"],
+        kv_heads=t.get("num_key_value_heads", t["num_attention_heads"]),
+        intermediate_size=t["intermediate_size"],
+        vocab_size=t["vocab_size"],
+        rope_theta=float(t.get("rope_theta", 10000.0)),
+        rms_norm_eps=t.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=t.get("max_position_embeddings", 131072),
+        tie_word_embeddings=t.get("tie_word_embeddings", True),
+        moe_experts=held * ep,
+        moe_top_k=t.get("num_experts_per_tok", 0),
+        moe_intermediate_size=t["intermediate_size"],
+        moe_shared_intermediate=t.get("shared_intermediate_size", 0),
+        moe_norm_topk=True,  # softmax over the selected logits
+        moe_shared_gated=False,
+        moe_held=(rank * held, (rank + 1) * held) if held else None,
+        layer_types=kinds,
+        mamba_heads=t["mamba_n_heads"],
+        mamba_head_dim=t["mamba_d_head"],
+        mamba_state=t["mamba_d_state"],
+        mamba_conv=t.get("mamba_d_conv", 4),
+        mamba_chunk=t.get("mamba_chunk_size", 256),
+        attn_rope=False,
+        attn_bias=bool(t.get("attention_bias", False)),
+        attn_scale=float(t["attention_multiplier"]) if "attention_multiplier" in t else None,
+        embedding_multiplier=float(t.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(t.get("residual_multiplier", 1.0)),
+        logits_scaling=float(t.get("logits_scaling", 1.0)),
+    )
+
+
 # -- KV cache ---------------------------------------------------------------
+
+
+def _recurrent_cache(d: DecoderConfig, rows: int, dtype) -> dict:
+    """One Mamba layer's row state, led by row (a batch row of a scratch, a
+    slot of the pool), never by page: ``conv`` the last ``mamba_conv - 1``
+    inputs of the convolution, token-major ``[rows, K-1, channels]`` (HF
+    keeps ``[rows, channels, K]``: channels on the lanes tile whole), and
+    ``ssm`` the scan's state ``[rows, d_state, heads * head_dim]`` in
+    float32 (``ops.ssm``)."""
+    return {
+        "conv": jnp.zeros((rows, d.mamba_conv - 1, d.mamba_conv_dim), dtype),
+        "ssm": jnp.zeros((rows, d.mamba_state, d.mamba_inner), jnp.float32),
+    }
+
+
+def _layer_cache(d: DecoderConfig, i: int, lead: tuple[int, int], rows: int, dtype) -> dict:
+    """Layer ``i``'s cache over ``lead`` = (batch, max_seq) or (pages, page)
+    for the kinds that keep a row a token, over ``rows`` (batch rows or
+    slots) for a Mamba layer, which keeps a state a row."""
+    kind = d.layer_kind(i)
+    if kind in LATENT_KINDS:
+        return _latent_cache(d, i, lead, dtype)
+    if kind == MAMBA:
+        return _recurrent_cache(d, rows, dtype)
+    shape = (lead[0], d.kv_heads, lead[1], d.dim_per_head)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def init_kv_cache(cfg: VLMConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -> list[dict]:
     """Preallocated per-layer cache: the reference's zero-length grow-by-
-    concat cache (``onnxrt_backend.py:731-755``) becomes a fixed buffer."""
+    concat cache (``onnxrt_backend.py:731-755``) becomes a fixed buffer.
+    A Mamba layer's entry is its row state (:func:`_recurrent_cache`)."""
     d = cfg.decoder
-    if d.latent:
-        return [_latent_cache(d, i, (batch, max_seq), dtype) for i in range(d.layers)]
-    shape = (batch, d.kv_heads, max_seq, d.dim_per_head)
-    return [
-        {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for _ in range(d.layers)
-    ]
+    return [_layer_cache(d, i, (batch, max_seq), batch, dtype) for i in range(d.layers)]
 
 
 def _latent_cache(d: DecoderConfig, i: int, lead: tuple[int, int], dtype) -> dict:
@@ -345,7 +454,7 @@ def _latent_cache(d: DecoderConfig, i: int, lead: tuple[int, int], dtype) -> dic
 
 def init_paged_kv_cache(
     cfg: VLMConfig, pages: int, page_size: int, dtype=jnp.bfloat16,
-    window_pages: int | None = None,
+    window_pages: int | None = None, slots: int = 0,
 ) -> list[dict]:
     """Per-layer PAGED cache: a pool of ``pages`` fixed-size pages shared
     by every decode row, addressed through per-row block tables
@@ -355,21 +464,15 @@ def init_paged_kv_cache(
     A latent decoder's pages hold latent rows (``[pages, page, width]``), and
     its window layers draw theirs from an id space of their own,
     ``window_pages`` large (``paged_kv.WindowPages``): they free pages behind
-    the window while the full layers keep theirs."""
+    the window while the full layers keep theirs. A Mamba layer holds no
+    page: its entry is one row of state a SLOT (``slots`` of them,
+    :func:`_recurrent_cache`), beside the pages of the attention layers."""
     d = cfg.decoder
-    if d.latent:
-        return [
-            _latent_cache(
-                d, i,
-                (pages if d.layer_kind(i) == FULL_ATTENTION else window_pages, page_size),
-                dtype,
-            )
-            for i in range(d.layers)
-        ]
-    shape = (pages, d.kv_heads, page_size, d.dim_per_head)
     return [
-        {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for _ in range(d.layers)
+        _layer_cache(
+            d, i, (window_pages if d.layer_kind(i) == WINDOW_ATTENTION else pages, page_size), slots, dtype
+        )
+        for i in range(d.layers)
     ]
 
 
@@ -434,14 +537,16 @@ class DecoderAttention(nn.Module):
         c = self.cfg
         b, s, _ = x.shape
         dh = c.dim_per_head
-        q = _dense(c, c.heads * dh, "q_proj", True, x.dtype)(x)
-        k = _dense(c, c.kv_heads * dh, "k_proj", True, x.dtype)(x)
-        v = _dense(c, c.kv_heads * dh, "v_proj", True, x.dtype)(x)
+        q = _dense(c, c.heads * dh, "q_proj", c.attn_bias, x.dtype)(x)
+        k = _dense(c, c.kv_heads * dh, "k_proj", c.attn_bias, x.dtype)(x)
+        v = _dense(c, c.kv_heads * dh, "v_proj", c.attn_bias, x.dtype)(x)
         q = q.reshape(b, s, c.heads, dh).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, c.kv_heads, dh).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, c.kv_heads, dh).transpose(0, 2, 1, 3)
-        q = rope_rotate(q, positions, c.rope_theta)
-        k = rope_rotate(k, positions, c.rope_theta)
+        if c.attn_rope:
+            q = rope_rotate(q, positions, c.rope_theta)
+            k = rope_rotate(k, positions, c.rope_theta)
+        scale = c.attn_scale  # None: the ops' own head_dim ** -0.5
 
         if block_tables is not None:
             page = cache["k"].shape[2]
@@ -467,6 +572,7 @@ class DecoderAttention(nn.Module):
                     new_v.astype(x.dtype),
                     block_tables,
                     kv_valid_len,
+                    scale=scale,
                 )[:, :, None, :]
             else:
                 # [B, W, H, dh] query selects the variable-query-length
@@ -478,6 +584,7 @@ class DecoderAttention(nn.Module):
                     new_v.astype(x.dtype),
                     block_tables,
                     kv_valid_len,
+                    scale=scale,
                 ).transpose(0, 2, 1, 3)
             out = out.transpose(0, 2, 1, 3).reshape(b, s, c.heads * dh)
             return _dense(c, c.hidden_size, "o_proj", False, x.dtype)(out), cache
@@ -517,6 +624,7 @@ class DecoderAttention(nn.Module):
                 repeat_kv(values, n_rep),
                 q_offsets=positions[:, 0],
                 kv_valid=kv_valid_len,
+                scale=scale,
             )
         else:
             keys, values = k, v
@@ -524,7 +632,9 @@ class DecoderAttention(nn.Module):
             # Cacheless forward: positions are arange rows (see
             # ``VLMModel.__call__`` / ``merge_image_embeddings``), so the
             # positions-pairwise mask is exactly the causal triangle.
-            out = attention(q, repeat_kv(keys, n_rep), repeat_kv(values, n_rep), causal=True)
+            out = attention(
+                q, repeat_kv(keys, n_rep), repeat_kv(values, n_rep), causal=True, scale=scale
+            )
 
         out = out.transpose(0, 2, 1, 3).reshape(b, s, c.heads * dh)
         return _dense(c, c.hidden_size, "o_proj", False, x.dtype)(out), cache
@@ -684,6 +794,75 @@ class LatentAttention(nn.Module):
         return dense(c.hidden_size, "o_proj")(out), cache
 
 
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 mixer of a hybrid decoder's "mamba" layer (``ops.ssm`` has
+    the mathematics): ``[z | xBC | dt] = u W_in``; ``xBC`` through a causal
+    depthwise convolution and SiLU, split into ``x`` (heads x head_dim), ``B``
+    and ``C`` (``d_state`` each, shared by every head); ``dt = softplus(dt +
+    dt_bias)``, ``A = -exp(A_log)``; the scan; ``y = RMSNorm(y * silu(z))``
+    over the whole inner width; ``W_out``. The cache is the layer's row
+    state (``_recurrent_cache``): a prefill segment starts from it and
+    leaves it at the segment's last LIVE token (positions at or past
+    ``kv_valid_len`` are right padding: their ``dt`` is 0 and the
+    convolution's tail is taken in front of them); a decode step
+    (``block_tables`` given, or a per-row ``cache_offset``) moves the rows
+    ``token_valid`` marks and leaves a done or free slot's state alone."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(
+        self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None, token_valid=None
+    ):
+        from ...ops import ssm
+
+        c = self.cfg
+        b, s, _ = x.shape
+        h, n, inner, conv_dim = c.mamba_heads, c.mamba_state, c.mamba_inner, c.mamba_conv_dim
+        proj = nn.Dense(inner + conv_dim + h, use_bias=False, name="in_proj", dtype=x.dtype)(x)
+        z, xbc, dt = proj[..., :inner], proj[..., inner : inner + conv_dim], proj[..., inner + conv_dim :]
+        init = nn.initializers.normal(0.02)
+        conv_w = self.param("conv_kernel", init, (c.mamba_conv, conv_dim), jnp.float32)
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h,), jnp.float32)
+        a = -jnp.exp(self.param("A_log", nn.initializers.zeros, (h,), jnp.float32))
+        d_skip = self.param("D", nn.initializers.ones, (h,), jnp.float32)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        stepping = cache is not None and (
+            block_tables is not None or jnp.ndim(cache_offset) == 1
+        )
+        if stepping:
+            if s != 1:
+                raise NotImplementedError("a recurrent layer decodes one token a row")
+            active = jnp.ones((b,), bool) if token_valid is None else token_valid.reshape(b)
+            act, tail = ssm.conv1d_update(xbc[:, 0], cache["conv"], conv_w, conv_b, active)
+            act = act.astype(x.dtype)
+            y, state = ssm.ssm_state_update(
+                act[:, :inner], dt[:, 0], a, act[:, inner : inner + n], act[:, inner + n :],
+                d_skip, cache["ssm"], active,
+            )
+            y, cache = y[:, None], {"conv": tail, "ssm": state}
+        else:
+            live = positions < kv_valid_len[:, None]  # [B, S]
+            if cache is None:
+                tail = jnp.zeros((b, c.mamba_conv - 1, conv_dim), x.dtype)
+                state = jnp.zeros((b, n, inner), jnp.float32)
+            else:
+                tail, state = cache["conv"], cache["ssm"]
+            act, tail = ssm.causal_conv1d(xbc, tail, conv_w, conv_b, live.sum(axis=1))
+            act = act.astype(x.dtype)
+            y, state = ssm.ssd_chunk_scan(
+                act[..., :inner], jnp.where(live[..., None], dt, 0.0), a,
+                act[..., inner : inner + n], act[..., inner + n :], d_skip, state,
+                chunk=c.mamba_chunk,
+            )
+            if cache is not None:
+                cache = {"conv": tail, "ssm": state}
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = RMSNorm(c.rms_norm_eps, name="norm")(gated).astype(x.dtype)
+        return nn.Dense(c.hidden_size, use_bias=False, name="out_proj", dtype=x.dtype)(y), cache
+
+
 class SwiGLU(nn.Module):
     cfg: DecoderConfig
     intermediate: int | None = None  # override cfg.intermediate_size
@@ -774,24 +953,23 @@ class DecoderLayer(nn.Module):
         self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None, token_valid=None
     ):
         c = self.cfg
-        if c.latent:
-            attn = LatentAttention(c, c.layer_kind(self.layer_idx), name="attn")
+        kind = c.layer_kind(self.layer_idx)
+        normed = RMSNorm(c.rms_norm_eps, name="input_norm")(x)
+        if kind == MAMBA:
+            h, cache = Mamba2Mixer(c, name="mamba")(
+                normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+            )
         else:
-            attn = DecoderAttention(c, name="attn")
-        h, cache = attn(
-            RMSNorm(c.rms_norm_eps, name="input_norm")(x),
-            positions,
-            cache,
-            cache_offset,
-            kv_valid_len,
-            block_tables,
-        )
-        x = x + h
+            if kind in LATENT_KINDS:
+                attn = LatentAttention(c, kind, name="attn")
+            else:
+                attn = DecoderAttention(c, name="attn")
+            h, cache = attn(normed, positions, cache, cache_offset, kv_valid_len, block_tables)
+        r = c.residual_multiplier
+        x = x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
         y = RMSNorm(c.rms_norm_eps, name="post_attn_norm")(x)
-        if c.is_moe_layer(self.layer_idx):
-            x = x + MoEFFN(c, name="mlp")(y, token_valid)
-        else:
-            x = x + SwiGLU(c, name="mlp")(y)
+        f = MoEFFN(c, name="mlp")(y, token_valid) if c.is_moe_layer(self.layer_idx) else SwiGLU(c, name="mlp")(y)
+        x = x + (f if r == 1.0 else f * jnp.asarray(r, f.dtype))
         return x, cache
 
 
@@ -815,7 +993,11 @@ class Decoder(nn.Module):
             self.lm_head = _dense(c, c.vocab_size, "lm_head", False, None)
 
     def embed(self, input_ids: jax.Array) -> jax.Array:
-        return self.embed_tokens(input_ids)
+        """Token rows only carry ``embedding_multiplier``: image rows are
+        spliced over them afterwards as the tower gives them."""
+        e = self.embed_tokens(input_ids)
+        m = self.cfg.embedding_multiplier
+        return e if m == 1.0 else e * jnp.asarray(m, e.dtype)
 
     def __call__(
         self,
@@ -840,6 +1022,8 @@ class Decoder(nn.Module):
             logits = x @ self.embed_tokens.embedding.T.astype(x.dtype)
         else:
             logits = self.lm_head(x)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
         return logits, (new_caches if caches is not None else None)
 
 

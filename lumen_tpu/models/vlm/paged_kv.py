@@ -435,28 +435,137 @@ class PagedKVPool:
         )
 
 
-def page_bytes(cfg, page_size: int, dtype_bytes: int) -> int:
-    """HBM cost of ONE page id across every decoder layer that keeps it
-    (each page id indexes a [page_size, head_dim] K and V tile in all
-    layers; in a latent decoder a [page_size, latent + rope (+ index key)]
-    tile in the FULL layers: the window layers' pages are
-    :func:`window_page_bytes` each, in their own id space)."""
-    d = cfg.decoder
-    if d.latent:
-        from .modeling import FULL_ATTENTION
+class RowState:
+    """What one decode row keeps in each layer of a decoder, and what that
+    allows: the one description the pool's sizing, the admit program and the
+    scheduler's refusals ask.
 
-        row = d.latent_full.kv_lora + d.latent_full.rope + d.index_head_dim
-        return d.layers_of(FULL_ATTENTION) * page_size * row * dtype_bytes
-    return 2 * d.layers * d.kv_heads * page_size * d.dim_per_head * dtype_bytes
+    A layer's row state is one of three kinds: **paged K/V** (a grouped-query
+    layer: a ``[page, head_dim]`` K and V tile a KV head under every page id
+    of the row's block table), **latent pages** (a latent layer: one
+    ``[page, latent + rope (+ index key)]`` tile; the full layers share the
+    pool's id space, the window layers keep one of their own), or
+    **recurrent** (a Mamba layer: a convolution tail and a scan state a SLOT,
+    whatever the row's length; no page at all). ``kinds`` names each layer's.
 
+    Only a row of paged K/V alone can be shared or exported whole today
+    (prefix cache, spill tier, speculative verify, migration): window layers
+    free the pages a shared prefix would need, and a recurrent state is not
+    a function of a page-aligned prefix that a later row could attach (it
+    would need a snapshot at every page boundary). :meth:`refuse` is the one
+    place that says so."""
 
-def window_page_bytes(cfg, page_size: int, dtype_bytes: int) -> int:
-    """HBM cost of one window page id across the window layers."""
-    from .modeling import WINDOW_ATTENTION
+    PAGED, LATENT, WINDOW, RECURRENT = "paged_kv", "latent_pages", "latent_window_pages", "recurrent"
 
-    d = cfg.decoder
-    row = d.latent_window.kv_lora + d.latent_window.rope
-    return d.layers_of(WINDOW_ATTENTION) * page_size * row * dtype_bytes
+    def __init__(self, cfg):
+        from .modeling import FULL_ATTENTION, MAMBA, WINDOW_ATTENTION
+
+        d = self.decoder = cfg.decoder
+        by_type = {FULL_ATTENTION: self.LATENT, WINDOW_ATTENTION: self.WINDOW, MAMBA: self.RECURRENT}
+        self.kinds = tuple(by_type.get(d.layer_kind(i), self.PAGED) for i in range(d.layers))
+
+    def layers_of(self, kind: str) -> int:
+        return self.kinds.count(kind)
+
+    @property
+    def window_layers(self) -> int:
+        return self.layers_of(self.WINDOW)
+
+    @property
+    def indexer_layers(self) -> int:
+        """Layers whose queries score every causal key (a full latent layer)."""
+        return self.layers_of(self.LATENT)
+
+    @property
+    def state_layers(self) -> int:
+        return self.layers_of(self.RECURRENT)
+
+    @property
+    def shareable(self) -> bool:
+        """Whether a row's state can be attached to another row or exported."""
+        return all(k == self.PAGED for k in self.kinds)
+
+    def refuse(self, what: str) -> None:
+        """Raise unless rows can be shared or exported (``what`` needs it)."""
+        if self.shareable:
+            return
+        held = "latent" if self.LATENT in self.kinds or self.WINDOW in self.kinds else "recurrent"
+        why = (
+            "window layers free the pages a shared prefix would need"
+            if held == "latent"
+            else "a row's recurrent state is no function of its pages: sharing or exporting it needs a snapshot"
+        )
+        raise NotImplementedError(f"{what} is not implemented for a {held} decoder ({why})")
+
+    def page_bytes(self, page_size: int, dtype_bytes: int) -> int:
+        """HBM cost of ONE page id of the pool's id space across every layer
+        that keeps it: K and V tiles in the grouped-query layers, a latent
+        tile in the FULL layers (the window layers' pages are
+        :meth:`window_page_bytes` each, in their own id space); a recurrent
+        layer keeps none."""
+        d = self.decoder
+        kv = 2 * d.kv_heads * d.dim_per_head
+        row = self.layers_of(self.PAGED) * kv
+        if self.indexer_layers:
+            row += self.indexer_layers * (d.latent_full.kv_lora + d.latent_full.rope + d.index_head_dim)
+        return page_size * row * dtype_bytes
+
+    def window_page_bytes(self, page_size: int, dtype_bytes: int) -> int:
+        """HBM cost of one window page id across the window layers."""
+        if not self.window_layers:
+            return 0
+        d = self.decoder
+        return self.window_layers * page_size * (d.latent_window.kv_lora + d.latent_window.rope) * dtype_bytes
+
+    def slot_bytes(self, dtype_bytes: int) -> int:
+        """HBM cost of one SLOT's recurrent state across the recurrent
+        layers, whatever the row's length: the scan state in float32 and the
+        convolution tail in the cache's type."""
+        d = self.decoder
+        if not self.state_layers:
+            return 0
+        ssm = d.mamba_state * d.mamba_inner * 4
+        conv = (d.mamba_conv - 1) * d.mamba_conv_dim * dtype_bytes
+        return self.state_layers * (ssm + conv)
+
+    def page_size_of(self, caches: list) -> int:
+        """Tokens a page of pool ``caches`` holds, read off the first layer
+        that keeps pages."""
+        for kind, layer in zip(self.kinds, caches):
+            if kind == self.PAGED:
+                return layer["k"].shape[2]
+            if kind in (self.LATENT, self.WINDOW):
+                return layer["c"].shape[1]
+        raise ValueError("no layer of this decoder keeps pages: its block tables have no page to address")
+
+    def install(self, i: int, dst: dict, pre: dict, slot, bt_row, page: int) -> dict:
+        """Layer ``i`` of the pool with one prefilled request's batch-1
+        scratch entry ``pre`` written in: a contiguous ``[1, kvh, Lb, dh]``
+        (or latent ``[1, Lb, width]``) scratch scattered page by page into
+        the ids ``bt_row`` grants (entries past the prompt's live pages are
+        the dump page 0, so the scatter needs no masking; a latent decoder's
+        ``bt_row`` [2, MAXP] is the full layers' table and the window
+        layers'); a recurrent state copied whole into ``slot``'s row, so a
+        reused slot keeps nothing of the row before."""
+        kind = self.kinds[i]
+        if kind == self.RECURRENT:
+            return {name: arr.at[slot].set(pre[name][0].astype(arr.dtype)) for name, arr in dst.items()}
+        if kind == self.PAGED:
+            kvh, lb, dh = pre["k"].shape[1:]
+            nseg = lb // page
+            ids = bt_row[:nseg]
+            return {
+                name: arr.at[ids].set(
+                    pre[name][0].reshape(kvh, nseg, page, dh).transpose(1, 0, 2, 3).astype(arr.dtype)
+                )
+                for name, arr in dst.items()
+            }
+        nseg = pre["c"].shape[1] // page
+        ids = bt_row[0 if kind == self.LATENT else 1, :nseg]
+        return {
+            name: arr.at[ids].set(pre[name][0].reshape(nseg, page, -1).astype(arr.dtype))
+            for name, arr in dst.items()
+        }
 
 
 def window_pool_pages(cfg, page_size: int, slots: int, block: int) -> int:
@@ -500,7 +609,8 @@ def resolve_pool_pages(
     frac = env_float(
         "LUMEN_VLM_KV_HEADROOM", DEFAULT_HEADROOM_FRACTION, minimum=0.05, maximum=0.95
     )
-    per_page = page_bytes(cfg, page_size, dtype_bytes)
+    rows = RowState(cfg)
+    per_page = rows.page_bytes(page_size, dtype_bytes)
     headroom = None
     for dev in jax.local_devices():
         stats = dev.memory_stats() or {}
@@ -523,11 +633,13 @@ def resolve_pool_pages(
         )
         return cap, "no_device_stats"
     budget = int(headroom * frac)
-    if cfg.decoder.latent:
-        # the window layers' space is a fixed size: what is left buys pages
-        budget -= window_pool_pages(cfg, page_size, slots, block) * window_page_bytes(
-            cfg, page_size, dtype_bytes
+    # what does not grow with a row's length is a fixed size (the window
+    # layers' id space, every slot's recurrent state): what is left buys pages
+    if rows.window_layers:
+        budget -= window_pool_pages(cfg, page_size, slots, block) * rows.window_page_bytes(
+            page_size, dtype_bytes
         )
+    budget -= slots * rows.slot_bytes(dtype_bytes)
     pages = max(budget, 0) // max(per_page, 1)
     sized = max(floor, min(pages, cap))
     logger.info(

@@ -543,9 +543,9 @@ class TestResolvePoolPages:
 
     def test_sized_from_tightest_device(self, monkeypatch):
         from lumen_tpu.models.vlm.modeling import VLMConfig
-        from lumen_tpu.models.vlm.paged_kv import page_bytes
+        from lumen_tpu.models.vlm.paged_kv import RowState
 
-        per_page = page_bytes(VLMConfig.tiny(), 16, 2)
+        per_page = RowState(VLMConfig.tiny()).page_bytes(16, 2)
         roomy = _FakeDevice("tpu", {"bytes_limit": 10**9, "bytes_in_use": 0})
         tight = _FakeDevice("tpu", {"bytes_limit": 100 * per_page, "bytes_in_use": 50 * per_page})
         pages, source = self._resolve(monkeypatch, [roomy, tight])

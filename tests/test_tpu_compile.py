@@ -27,7 +27,7 @@ from jax.sharding import SingleDeviceSharding
 att = importlib.import_module("lumen_tpu.ops.attention")
 from lumen_tpu.models.vlm.paged_kv import DEFAULT_PAGE_SIZE
 from lumen_tpu.ops import latent_attention as lat
-from lumen_tpu.ops import quant_matmul
+from lumen_tpu.ops import quant_matmul, ssm
 
 B, HEADS, KV_HEADS, HEAD_DIM, PAGE = 8, 14, 2, 64, 16
 HIDDEN, MLP = 896, 4864
@@ -99,6 +99,20 @@ def _latent(heads, c_dim, span, sel):
     )
 
 
+def _ssm_scan(rows, tokens):
+    """The prefill scan at granite-4.0-h-small's dimensions (128 heads of 64,
+    d_state 128): a lane chunk, its 64-token tail, a whole prompt admitted
+    in a group."""
+    heads, head_dim, state = 128, 64, 128
+    f32 = jnp.float32
+    return (
+        lambda *a: ssm.ssd_chunk_scan_kernel(*a, interpret=False),
+        [((rows, tokens, heads * head_dim), BF16), ((rows, tokens, heads), f32), ((heads,), f32),
+         ((rows, tokens, state), BF16), ((rows, tokens, state), BF16), ((heads,), f32),
+         ((rows, state, heads * head_dim), f32)],
+    )
+
+
 #: the decode step as the caption cells serve it: sixteen slots of
 #: Qwen2-1.5B (12 query / 2 KV heads of 128), the default page, and the
 #: whole table of a max_seq of 2,048
@@ -137,6 +151,15 @@ CASES = {
     "indexer_scores": (
         lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
         [((16, 64, 128), BF16), ((16, 64), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],
+    ),
+    "ssm_scan_chunk": _ssm_scan(1, 256),
+    "ssm_scan_tail": _ssm_scan(1, 64),
+    "ssm_scan_group": _ssm_scan(4, 320),
+    # sixteen slots' states updated in place, the active rows alone
+    "ssm_update": (
+        lambda *a: ssm.ssm_state_update_kernel(*a, interpret=False),
+        [((16, 8192), BF16), ((16, 128), jnp.float32), ((128,), jnp.float32), ((16, 128), BF16),
+         ((16, 128), BF16), ((128,), jnp.float32), ((16, 128, 8192), jnp.float32), ((16,), jnp.bool_)],
     ),
     "w8a16": (
         lambda x, q, s: quant_matmul._w8a16_2d(x, q, s, block_n=256, interpret=False),
@@ -193,6 +216,23 @@ def test_latent_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
     """``latent_attn_roofline`` / ``latent_attn_time_pct`` match
     ``^latent_paged_attention`` and ``indexer_roofline`` matches
     ``^indexer_scores`` on the device's ``XLA Ops`` line, as above."""
+    import re
+
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = _kernel_names(text)
+    assert kernels and all(re.search(pattern, k) for k in kernels), kernels
+
+
+@pytest.mark.parametrize(
+    "name,pattern",
+    [("ssm_scan_chunk", "^ssd_chunk_scan"), ("ssm_scan_tail", "^ssd_chunk_scan"), ("ssm_update", "^ssm_state_update")],
+)
+def test_ssm_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
+    """``ssm_scan_roofline`` / ``ssm_scan_time_pct`` match ``^ssd_chunk_scan``
+    and ``ssm_update_roofline`` / ``ssm_update_time_pct`` match
+    ``^ssm_state_update`` on the device's ``XLA Ops`` line, as above."""
     import re
 
     fn, shapes = CASES[name]

@@ -347,13 +347,13 @@ def test_from_hf_reads_the_catalog_config_of_dots3_note_prev():
 
 
 def test_page_bytes_count_what_each_kind_of_layer_holds():
-    from lumen_tpu.models.vlm.paged_kv import page_bytes, window_page_bytes
+    from lumen_tpu.models.vlm.paged_kv import RowState
 
     cfg = VLMConfig.from_hf(tiny_config())
     # two full layers: (16 + 8 latent and rope, 16 index key) values a token
-    assert page_bytes(cfg, PAGE, 2) == 2 * PAGE * (16 + 8 + 16) * 2
+    assert RowState(cfg).page_bytes(PAGE, 2) == 2 * PAGE * (16 + 8 + 16) * 2
     # two window layers: 32 + 8 values a token, in their own id space
-    assert window_page_bytes(cfg, PAGE, 2) == 2 * PAGE * (32 + 8) * 2
+    assert RowState(cfg).window_page_bytes(PAGE, 2) == 2 * PAGE * (32 + 8) * 2
     # a row holds its window (5) grown by a block (8), at the worst alignment: 4 pages
     assert WindowPages.row_pages(5, PAGE, 8) == 4
     assert window_pool_pages(cfg, PAGE, 4, 8) == 4 * 4 + 1
