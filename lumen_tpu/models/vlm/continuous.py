@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops.sampling import draws
 from ...testing.faults import KV_RESUME, KV_SPILL, faults
 from ...utils.deadline import PreemptionShed
 from ...utils import telemetry
@@ -353,6 +354,9 @@ class ContinuousScheduler:
         self._closed = False
         self._admit_seq = 0
         self.blocks_run = 0  # observability
+        #: of those, blocks in which no live row drew its token (``draws``):
+        #: the compiled sampler then took the argmax and sorted nothing
+        self.blocks_greedy = 0
         self.admitted = 0
         self.preemptions = 0
         self.chunks_run = 0
@@ -469,6 +473,7 @@ class ContinuousScheduler:
             stats = s.kv.stats()
             out = {
                 "blocks_run": s.blocks_run,
+                "blocks_greedy": s.blocks_greedy,
                 "rows_stepped": s._occ_rows,
                 "admitted": s.admitted,
                 "pending_ms_sum": round(s.pending_ms_sum, 3),
@@ -1797,9 +1802,17 @@ class ContinuousScheduler:
                 return
             width, drafts, bucket = self._plan_block()
         active = len(self._slots)
+        # The device decides it anew in every step, over the rows still live
+        # there: a subset of these, so a block counted greedy sorts nothing.
+        live = [s.request for s in self._slots.values()]
+        sampling = bool(
+            draws([r.do_sample for r in live], [r.temperature for r in live], np).any()
+        )
         t0 = time.perf_counter()
         tm0 = time.monotonic()
-        with phase("vlm.block.dispatch", step=step, rows=active, bucket=bucket):
+        with phase(
+            "vlm.block.dispatch", step=step, rows=active, bucket=bucket, sampling=int(sampling)
+        ):
             if width:
                 q = np.zeros((self.n_slots, width), np.int32)
                 ql = np.ones((self.n_slots,), np.int32)
@@ -1822,6 +1835,7 @@ class ContinuousScheduler:
                 if self._indexer_layers:
                     self._count_indexer(self.n_slots * self.block, bucket * self.page_size)
         self.blocks_run += 1
+        self.blocks_greedy += not sampling
         self._occ_rows += active
         self._occ_blocks += 1
         # One fused device->host transfer for everything the bookkeeping

@@ -474,7 +474,15 @@ class Generator:
         EOS, repetition penalty), with free/finished slots masked out. Each
         step's K/V write and attention go through ``block_tables`` — the
         host scheduler guarantees every live row's pages cover
-        ``cur_len + block`` before dispatching."""
+        ``cur_len + block`` before dispatching.
+
+        The sampler sees ``do_sample`` of the rows live in that step only: a
+        freed slot keeps its last request's ``do_sample`` until ``_admit``
+        overwrites it, and ``sample()`` sorts the vocabulary whenever some
+        row draws. What a done row would have drawn is never emitted
+        (``tok`` is the pad id where ``active`` is false, ``done`` is sticky,
+        and ``_admit`` / ``_resume`` set ``cur_tok`` anew), so its argmax
+        serves as well."""
         cfg = self.cfg
         b = pool["cur_tok"].shape[0]
         capacity = block_tables.shape[-1] * self._page_size_of(pool)
@@ -498,7 +506,7 @@ class Generator:
             rng, sub = jax.random.split(rng)
             nxt = self._sample_next(
                 sub, logits[:, 0], seen,
-                pool["temperature"], pool["top_p"], pool["do_sample"], pool["rep"],
+                pool["temperature"], pool["top_p"], pool["do_sample"] & active, pool["rep"],
             ).astype(jnp.int32)
             counted = {} if stats is None else {"moe_stats": pool["moe_stats"] + stats}
             new_pool = dict(
@@ -592,7 +600,10 @@ class Generator:
         attends over exactly the KV a sequential step at that position
         would see (the varq kernel's per-slot causal mask), and rejected
         slots' KV writes land above the row's final ``cur_len`` where the
-        valid-length mask hides them until real tokens overwrite them."""
+        valid-length mask hides them until real tokens overwrite them.
+        As in ``_step_block_impl`` the sampler sees ``do_sample`` of the rows
+        live in a step only (``cur_tok`` takes ``nxt`` where ``step_active``
+        alone)."""
         cfg = self.cfg
         self.rows.refuse("speculative verify")
         b = pool["cur_tok"].shape[0]
@@ -623,7 +634,7 @@ class Generator:
             rng, sub = jax.random.split(rng)
             nxt = self._sample_next(
                 sub, logits[:, t], seen,
-                pool["temperature"], pool["top_p"], pool["do_sample"], pool["rep"],
+                pool["temperature"], pool["top_p"], pool["do_sample"] & step_active, pool["rep"],
             ).astype(jnp.int32)
             cur_len = cur_len + step_active.astype(jnp.int32)
             if t + 1 < width:

@@ -54,6 +54,16 @@ def top_p_filter(logits: jnp.ndarray, top_p: jnp.ndarray | float) -> jnp.ndarray
     return jnp.where(logits >= threshold, logits, -jnp.inf)
 
 
+def draws(do_sample, temperature, xp=jnp):
+    """Where the next token is drawn from the nucleus and not taken as the
+    argmax: ``do_sample`` and a temperature above zero. The one predicate of
+    the compiled sampler (``xp`` = ``jnp``) and of the scheduler's count of
+    greedy blocks (``xp`` = ``numpy``, so the host dispatches nothing): both
+    compare in float32, so the two cannot drift."""
+    hot = xp.asarray(temperature, xp.float32) > xp.float32(1e-6)
+    return xp.asarray(do_sample, bool) & hot
+
+
 def sample(
     rng: jax.Array,
     logits: jnp.ndarray,
@@ -64,12 +74,22 @@ def sample(
     """Temperature + top-p categorical sampling; falls back to greedy when
     ``do_sample`` is False or temperature ~ 0. All args may be traced values
     (scalars, or per-sample [B] vectors for batched mixed-config serving)
-    so one compiled program serves every generation config."""
+    so one compiled program serves every generation config.
+
+    The nucleus (a sort of the whole vocabulary, a softmax, a cumulative sum,
+    Gumbel noise for every entry) runs under a ``lax.cond`` on "some row
+    draws": a batch of greedy rows takes the argmax alone, and a batch with
+    one drawing row runs the straight line it always ran, for every row.
+    ``rng`` is consumed in neither branch's favour: callers split it before
+    they get here."""
     greedy_ids = greedy(logits)
-    scaled = logits.astype(jnp.float32) / jnp.maximum(_per_sample(temperature, logits), 1e-6)
-    filtered = top_p_filter(scaled, top_p)
-    sampled_ids = jax.random.categorical(rng, filtered, axis=-1)
     # [B]-or-scalar shaped, matching the ids
-    hot = jnp.asarray(temperature, jnp.float32) > 1e-6
-    use_sample = jnp.asarray(do_sample) & hot
-    return jnp.where(use_sample, sampled_ids, greedy_ids)
+    use_sample = draws(do_sample, temperature)
+
+    def nucleus():
+        scaled = logits.astype(jnp.float32) / jnp.maximum(_per_sample(temperature, logits), 1e-6)
+        filtered = top_p_filter(scaled, top_p)
+        sampled_ids = jax.random.categorical(rng, filtered, axis=-1)
+        return jnp.where(use_sample, sampled_ids, greedy_ids)
+
+    return jax.lax.cond(jnp.any(use_sample), nucleus, lambda: greedy_ids)

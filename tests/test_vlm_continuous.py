@@ -1682,6 +1682,13 @@ class TestFeederThreadPhases:
         assert steps == list(range(steps[0], steps[0] + len(steps)))  # the numbers batch.device spans carry
         assert all(e[3]["rows"] >= 1 for e in self._named(profiled, "lumen:vlm.block.dispatch"))
 
+    def test_a_dispatch_phase_says_whether_a_live_row_draws(self, profiled, cont_mgr):
+        """Every generation of the session is greedy: ``sampling`` is 0 in
+        the profiler's record, and the gauge counted each block greedy."""
+        assert {e[3]["sampling"] for e in self._named(profiled, "lumen:vlm.block.dispatch")} == {0}
+        sched = cont_mgr._continuous
+        assert 0 < sched.blocks_greedy <= sched.blocks_run
+
     def test_admissions_name_their_requests(self, profiled):
         admits = self._named(profiled, "lumen:vlm.admit")
         assert len(admits) == 2 and all(e[3]["rows"] == 1 and e[3]["kind"] == "group" for e in admits)
