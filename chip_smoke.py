@@ -224,8 +224,23 @@ def phase_kernels(sizes: Sizes, rehearse: bool, seed: int) -> None:
     stepping = jnp.asarray(np.arange(mslots) % 16 < 9)
     flat = lambda y, state: jnp.concatenate([y.astype(jnp.float32).ravel(), state.ravel()])
 
+    # the latent decode kernel as a decoder without indexer or window calls it
+    # (A.X-K1: 64 heads over a 512-value latent and a 64-value position key):
+    # sixteen rows, every page of each row's table, no selection, start 0
+    from lumen_tpu.ops import latent_attention as lat
+
+    lh, lc, lr, lrows, lmaxp = (4, 32, 8, 3, 6) if rehearse else (64, 512, 64, 16, 72)
+    l_pages = lrows * lmaxp + 1
+    l_tables = jnp.asarray(rng.permutation(l_pages - 1)[: lrows * lmaxp].reshape(lrows, lmaxp) + 1, jnp.int32)
+    l_lens = jnp.asarray(rng.integers(1, lmaxp * page, size=lrows), jnp.int32)
+
     # (name, kernel, reference, arguments, holds a tpu_custom_call)
     cases = [
+        ("latent_paged_all_keys",
+         lambda *a: lat.latent_paged_attention_kernel(*a, None, scale=0.13, interpret=interpret),
+         lambda *a: lat.latent_paged_attention_reference(*a, None, scale=0.13),
+         (normal(lrows, lh, lc), normal(lrows, lh, lr), normal(l_pages, page, lc), normal(l_pages, page, lr),
+          l_tables, l_lens, jnp.zeros((lrows,), jnp.int32)), True),
         ("ssd_chunk_scan",
          lambda *a: flat(*ssm.ssd_chunk_scan_kernel(*a, interpret=interpret)),
          lambda *a: flat(*ssm.ssd_chunk_scan_reference(*a)),

@@ -452,6 +452,14 @@ class ContinuousScheduler:
         self._indexer_layers = rows.indexer_layers
         self.indexer_rows = 0
         self.indexer_keys_scored = 0
+        # keys one full latent layer's query attends (its own token among
+        # them) in a block's first step, summed over the rows the block starts
+        # with (``rows_stepped``'s own count): a row's length, or
+        # ``index_topk`` where an indexer cuts it
+        self._latent_keys_cap = (
+            (dec.index_topk if dec.indexer else generator.max_seq) if rows.full_latent_layers else 0
+        )
+        self.latent_keys_sum = 0
         self.moe_stats = np.zeros((4,), np.int64)  # routed, held, experts touched, calls
         # A recurrent decoder's: the state every slot holds whatever its row's
         # length, rows whose finished state was copied into a slot (inside
@@ -523,6 +531,8 @@ class ContinuousScheduler:
                 out["window_pages_total"] = s.kv.window.pages_total
                 out["indexer_rows"] = s.indexer_rows
                 out["indexer_keys_scored"] = s.indexer_keys_scored
+            if s._latent_keys_cap:
+                out["latent_keys_sum"] = s.latent_keys_sum
             if s.rows.state_layers:
                 out["state_bytes"] = s.state_bytes
                 out["state_layers"] = s.rows.state_layers
@@ -1838,6 +1848,10 @@ class ContinuousScheduler:
         self.blocks_greedy += not sampling
         self._occ_rows += active
         self._occ_blocks += 1
+        if self._latent_keys_cap:
+            self.latent_keys_sum += sum(
+                min(s.prompt_len + len(s.tokens) + 1, self._latent_keys_cap) for s in self._slots.values()
+            )
         # One fused device->host transfer for everything the bookkeeping
         # below needs (four separate np.asarray calls = four round trips
         # on the per-block hot path). cur_tok rides along ONLY when

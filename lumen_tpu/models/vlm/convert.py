@@ -46,9 +46,10 @@ DECODER_RULES = [
     (r"model\.layers\.(\d+)\.mlp\.shared_expert\.up_proj\.weight", r"decoder/layers_\1/mlp/shared/up_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.mlp\.shared_expert\.down_proj\.weight", r"decoder/layers_\1/mlp/shared/down_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.mlp\.shared_expert_gate\.weight", r"decoder/layers_\1/mlp/shared_gate/kernel", linear_kernel),
-    # Latent attention (``dots3_note``): low-rank q and kv projections, one
-    # up-projection folded into the query at run time (a bare kernel, not a
-    # Dense), a gate a head, the indexer, and the router's selection bias.
+    # Latent attention (``DeepseekV3``-style names; ``dots3_note``, ``axk1``):
+    # low-rank q and kv projections, one up-projection folded into the query
+    # at run time (a bare kernel, not a Dense), the router's selection bias,
+    # and, where the checkpoint has them, a gate a head and the indexer.
     (r"model\.layers\.(\d+)\.self_attn\.(q_a_proj|q_b_proj)\.weight", r"decoder/layers_\1/attn/\2/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.self_attn\.kv_a_proj_with_mqa\.weight", r"decoder/layers_\1/attn/kv_a_proj/kernel", linear_kernel),
     (r"model\.layers\.(\d+)\.self_attn\.kv_b_proj\.weight", r"decoder/layers_\1/attn/kv_b_proj", linear_kernel),
@@ -142,9 +143,11 @@ _EXPERT_BANKS = {
 
 def _stack_experts(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
     """Collapse ``.../mlp/__expert_gate__/<i>`` leaves into one stacked
-    ``.../mlp/w_gate`` bank per layer (``[E, ...]``, expert index 0..E-1
-    on the leading dim — the layout ``MoEFFN`` and the ``expert``-axis
-    sharding rules expect)."""
+    ``.../mlp/w_gate`` bank per layer (``[E, ...]``, in expert order on the
+    leading dim — the layout ``MoEFFN`` and the ``expert``-axis sharding
+    rules expect). A chip's share of an expert-parallel checkpoint names its
+    experts by their ids in the whole bank (``lo..hi-1``): any contiguous
+    range stacks, and the configuration's held range says which it is."""
     groups: dict[tuple[str, str], dict[int, np.ndarray]] = {}
     out: dict[str, np.ndarray] = {}
     for key, val in flat.items():
@@ -155,14 +158,10 @@ def _stack_experts(flat: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
         else:
             out[key] = val
     for (prefix, marker), members in groups.items():
-        n = len(members)
-        if sorted(members) != list(range(n)):
-            raise ValueError(
-                f"{prefix}/{marker}: non-contiguous expert indices {sorted(members)}"
-            )
-        out[f"{prefix}/{_EXPERT_BANKS[marker]}"] = np.stack(
-            [members[i] for i in range(n)], axis=0
-        )
+        ids = sorted(members)
+        if ids != list(range(ids[0], ids[0] + len(ids))):
+            raise ValueError(f"{prefix}/{marker}: non-contiguous expert indices {ids}")
+        out[f"{prefix}/{_EXPERT_BANKS[marker]}"] = np.stack([members[i] for i in ids], axis=0)
     return out
 
 

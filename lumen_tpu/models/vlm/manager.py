@@ -650,7 +650,8 @@ class VLMManager:
             # set of programs it never ran (45-54 s of a compiling boot).
             t0 = time.perf_counter()
             self.generate(
-                [ChatMessage(role="user", content="hi")], self._warmup_image(), max_new_tokens=1
+                [ChatMessage(role="user", content=self._warmup_text())], self._warmup_image(),
+                max_new_tokens=1,
             )
             logger.info("vlm warmup (image path) in %.1fs", time.perf_counter() - t0)
         logger.info(
@@ -660,6 +661,29 @@ class VLMManager:
             self.cfg.decoder.hidden_size,
             self.vision_tokens,
         )
+
+    def _warmup_text(self) -> str:
+        """What the warm-up asks beside its image. "hi" where rows have the
+        default length. A ``max_seq`` configured past the default says the
+        deployment's rows are long, so there the prompt fills the longest
+        bucket: the boot then compiles the lane's chunk program, the
+        install from the longest scratch and the decode block over the whole
+        table, which every such row runs, in place of a short caption's
+        one-shot prefill, small install and narrow block, which it may never
+        run (a short request compiles those on its first use; 45 and 23 s
+        less of a compiling run of the two long-context caption cells on a
+        v5e: PERF.md, PR 39)."""
+        if self.max_seq <= 2048 or len(self.prefill_buckets) < 2:
+            return "hi"
+        low, top = self.prefill_buckets[-2:]
+
+        def tokens(words: int) -> int:
+            text = " ".join(["hi"] * words)
+            return len(self._encode_prompt([ChatMessage(role="user", content=text)], True, True))
+
+        one, many = tokens(1), tokens(65)
+        words = 1 + ((low + top) // 2 - one) * 64 // max(many - one, 1)
+        return " ".join(["hi"] * words) if low < tokens(words) <= top else "hi"
 
     def _warmup_image(self) -> bytes:
         """A mid-gray JPEG at the tower's size: what the warm-up captions."""

@@ -23,14 +23,16 @@ Architecture notes (TPU-first, not a translation):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ...ops.attention import attention, attention_cached, paged_attention, repeat_kv
+from ...ops.attention import _count_route, attention, attention_cached, paged_attention, repeat_kv
 from ...ops.quant import QDense
 
 FULL_ATTENTION = "full_attention"
@@ -44,10 +46,69 @@ LATENT_KINDS = (FULL_ATTENTION, WINDOW_ATTENTION)
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN (``rope_scaling`` of ``type`` ``yarn``, the DeepSeek-V3 family's
+    reading): each rotary frequency is a blend of ``theta^(-2i/d)`` and the
+    same over ``factor``, by a linear ramp in ``i`` between the two
+    correction dimensions (the pairs that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original_max`` positions); cos and sin are
+    multiplied by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``
+    and the softmax scale by ``mscale(factor, mscale_all_dim) ** 2``, with
+    ``mscale(s, m) = 0.1 m ln s + 1``."""
+
+    factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @classmethod
+    def from_hf(cls, r: dict | None) -> "YarnScaling | None":
+        if not r:
+            return None
+        kind = r.get("type", r.get("rope_type"))
+        if kind != "yarn":
+            raise NotImplementedError(f"rope_scaling of type {kind!r}")
+        return cls(
+            factor=float(r["factor"]), beta_fast=float(r.get("beta_fast", 32)),
+            beta_slow=float(r.get("beta_slow", 1)),
+            original_max=int(r["original_max_position_embeddings"]),
+            mscale=float(r.get("mscale", 1)), mscale_all_dim=float(r.get("mscale_all_dim", 0)),
+        )
+
+    @staticmethod
+    def get_mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """The ``dim // 2`` blended frequencies, float64."""
+        def correction(turns: float) -> float:
+            return dim * math.log(self.original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction(self.beta_fast)), 0)
+        high = min(math.ceil(correction(self.beta_slow)), dim - 1)
+        plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
+
+    @property
+    def rotation_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self.get_mscale(self.factor, self.mscale) / self.get_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the score scale is multiplied by."""
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2 if self.mscale_all_dim else 1.0
+
+
+@dataclass(frozen=True)
 class LatentDims:
     """Sizes of one kind of latent attention layer: low-rank query and
     key/value projections, ``heads`` x (``nope`` no-position + ``rope``
-    RoPE) query/key values a head, ``v_dim`` value values a head."""
+    RoPE) query/key values a head, ``v_dim`` value values a head; the
+    rotation's base and, where the model scales it, its YaRN settings."""
 
     heads: int
     q_lora: int
@@ -56,10 +117,12 @@ class LatentDims:
     rope: int
     v_dim: int
     rope_theta: float
+    rope_scaling: YarnScaling | None = None
 
     @property
     def scale(self) -> float:
-        return 1.0 / float(self.nope + self.rope) ** 0.5
+        base = 1.0 / float(self.nope + self.rope) ** 0.5
+        return base if self.rope_scaling is None else base * self.rope_scaling.softmax_scale
 
 
 @dataclass(frozen=True)
@@ -105,16 +168,20 @@ class DecoderConfig:
     # (20 tok/s vs 3896 bf16 — the convert lowered to non-vectorized
     # code), so both formulations ship and the bench A/Bs them.
     weight_quant_kernel: str = "dequant"  # "dequant" | "dynamic"
-    # --- Latent attention decoder (``model_type`` ``dots3_note``). Empty
-    # ``layer_types`` = the Qwen2 layout above, untouched. Otherwise one
-    # kind a layer: "full_attention" layers use ``latent_full`` and a
-    # learned indexer that keeps the ``index_topk`` causal keys of largest
-    # index score; "sliding_attention" layers use ``latent_window`` and
-    # see the last ``sliding_window`` keys, the token itself included.
-    # Every head's output passes a sigmoid gate computed from the layer's
-    # normed input. ``latent_rescale`` multiplies the normed latents by
-    # sqrt(hidden / rank). The cache holds one latent row a token
-    # (``init_paged_kv_cache``), never K/V per head.
+    # --- Latent attention decoder (``model_type`` ``dots3_note``, ``axk1``).
+    # Empty ``layer_types`` = the Qwen2 layout above, untouched. Otherwise
+    # one kind a layer: "full_attention" layers use ``latent_full`` and see
+    # every causal key, or, where the decoder has an indexer
+    # (``index_heads`` > 0), the ``index_topk`` causal keys of largest
+    # learned index score; "sliding_attention" layers use ``latent_window``
+    # and see the last ``sliding_window`` keys, the token itself included.
+    # What a layer has beside the projections is said here, not by a model's
+    # name: ``latent_gate`` passes every head's output through a sigmoid
+    # gate computed from the layer's normed input; ``latent_rescale``
+    # multiplies the normed latents by sqrt(hidden / rank); a
+    # ``LatentDims.rope_scaling`` scales the rotation (YaRN). The cache
+    # holds one latent row a token (``init_paged_kv_cache``), never K/V per
+    # head.
     layer_types: tuple[str, ...] = ()
     latent_full: LatentDims | None = None
     latent_window: LatentDims | None = None
@@ -123,16 +190,21 @@ class DecoderConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     latent_rescale: bool = False
+    latent_gate: bool = False
     # --- Routing rule and the held share of the bank (``parallel.moe``):
     # "sigmoid" scores with a learned selection bias, gates renormalised
     # over the selected and scaled; an ungated shared expert. ``moe_held``
     # = (lo, hi): this chip holds experts lo..hi-1 of ``moe_experts`` (the
     # router's width) and computes their part of the layer alone.
+    # ``moe_n_group`` groups of consecutive experts, of which a token keeps
+    # the ``moe_topk_group`` best before it selects (one group: no limit).
     moe_scoring: str = "softmax"
     moe_select_bias: bool = False
     moe_routed_scale: float = 1.0
     moe_shared_gated: bool = True
     moe_held: tuple[int, int] | None = None
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # --- Hybrid decoder (``model_type`` ``granitemoehybrid``): ``layer_types``
     # of "mamba" and "attention". A mamba layer is a Mamba-2 mixer
     # (``Mamba2Mixer``: ``mamba_heads`` heads of ``mamba_head_dim``, a state
@@ -162,6 +234,11 @@ class DecoderConfig:
     @property
     def latent(self) -> bool:
         return any(k in LATENT_KINDS for k in self.layer_types)
+
+    @property
+    def indexer(self) -> bool:
+        """Whether the full latent layers pick their keys with an indexer."""
+        return self.index_heads > 0
 
     @property
     def mamba_inner(self) -> int:
@@ -243,6 +320,8 @@ class VLMConfig:
         vis = cfg.get("vision_config", {})
         if text.get("model_type") == "dots3_note":
             decoder = _dots3_decoder(text)
+        elif text.get("model_type") == "axk1":
+            decoder = _axk1_decoder(text)
         elif text.get("model_type") == "granitemoehybrid":
             decoder = _granite_decoder(text)
         else:
@@ -296,29 +375,23 @@ class VLMConfig:
         )
 
 
-def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
-    """``model_type`` ``dots3_note``: latent attention of two kinds by
-    ``layer_types``, leading dense layers, sigmoid-routed experts with one
-    ungated shared expert. ``n_routed_experts`` counts the experts HELD
-    here; ``ep_size`` chips share each layer (default 1: the whole bank),
-    so the router is ``n_routed_experts * ep_size`` wide and this chip,
-    ``ep_rank``, holds the range ``[rank * n, (rank + 1) * n)``."""
-    n = t["num_hidden_layers"]
-    kinds = tuple(t["layer_types"][:n])
-    if len(kinds) != n or set(kinds) - {FULL_ATTENTION, WINDOW_ATTENTION}:
-        raise ValueError(f"layer_types must name {n} full_attention/sliding_attention layers, got {kinds}")
+def _latent_moe_decoder(t: dict[str, Any], **fields) -> DecoderConfig:
+    """What the latent decoders' configurations share (the DeepSeek-V3
+    family's keys): the full layers' ``LatentDims``, leading dense layers,
+    routed experts with one ungated shared expert. ``n_routed_experts``
+    counts the experts HELD here; ``ep_size`` chips share each layer
+    (default 1: the whole bank), so the router is ``n_routed_experts *
+    ep_size`` wide and this chip, ``ep_rank``, holds the range ``[rank * n,
+    (rank + 1) * n)``. ``fields`` are the caller's own."""
     held, ep, rank = t["n_routed_experts"], t.get("ep_size", 1), t.get("ep_rank", 0)
-    dense = t.get("first_k_dense_replace", 0)
     return DecoderConfig(
         hidden_size=t["hidden_size"],
-        layers=n,
+        layers=t["num_hidden_layers"],
         heads=t["num_attention_heads"],
         kv_heads=t.get("num_key_value_heads", t["num_attention_heads"]),
         intermediate_size=t["intermediate_size"],
         vocab_size=t["vocab_size"],
         rope_theta=float(t["rope_theta"]),
-        rms_norm_eps=t.get("rms_norm_eps", 1e-5),
-        max_position_embeddings=t.get("max_position_embeddings", 32768),
         tie_word_embeddings=t.get("tie_word_embeddings", False),
         moe_experts=held * ep,
         moe_top_k=t["num_experts_per_tok"],
@@ -326,13 +399,33 @@ def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
         moe_shared_intermediate=t.get("n_shared_experts", 0) * t["moe_intermediate_size"],
         moe_every=t.get("moe_layer_freq", 1),
         moe_norm_topk=t.get("norm_topk_prob", True),
-        moe_dense_layers=tuple(range(dense)),
-        layer_types=kinds,
+        moe_dense_layers=tuple(range(t.get("first_k_dense_replace", 0))),
         latent_full=LatentDims(
             heads=t["num_attention_heads"], q_lora=t["q_lora_rank"], kv_lora=t["kv_lora_rank"],
             nope=t["qk_nope_head_dim"], rope=t["qk_rope_head_dim"], v_dim=t["v_head_dim"],
-            rope_theta=float(t["rope_theta"]),
+            rope_theta=float(t["rope_theta"]), rope_scaling=YarnScaling.from_hf(t.get("rope_scaling")),
         ),
+        moe_routed_scale=float(t.get("routed_scaling_factor", 1.0)),
+        moe_shared_gated=False,
+        moe_held=(rank * held, (rank + 1) * held),
+        **fields,
+    )
+
+
+def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
+    """``model_type`` ``dots3_note``: latent attention of two kinds by
+    ``layer_types``, an indexer in the full layers, a gate a head, leading
+    dense layers, sigmoid-routed experts with one ungated shared expert
+    (:func:`_latent_moe_decoder`)."""
+    n = t["num_hidden_layers"]
+    kinds = tuple(t["layer_types"][:n])
+    if len(kinds) != n or set(kinds) - {FULL_ATTENTION, WINDOW_ATTENTION}:
+        raise ValueError(f"layer_types must name {n} full_attention/sliding_attention layers, got {kinds}")
+    return _latent_moe_decoder(
+        t,
+        rms_norm_eps=t.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=t.get("max_position_embeddings", 32768),
+        layer_types=kinds,
         latent_window=LatentDims(
             heads=t["swa_num_attention_heads"], q_lora=t["swa_q_lora_rank"],
             kv_lora=t["swa_kv_lora_rank"], nope=t["swa_qk_nope_head_dim"],
@@ -344,11 +437,34 @@ def _dots3_decoder(t: dict[str, Any]) -> DecoderConfig:
         index_head_dim=t["index_head_dim"],
         index_topk=t["index_topk"],
         latent_rescale=bool(t.get("apply_mla_qkv_lora_rescale", False)),
+        latent_gate=True,
         moe_scoring=t.get("scoring_func", "softmax"),
         moe_select_bias=t.get("topk_method") == "noaux_tc",
-        moe_routed_scale=float(t.get("routed_scaling_factor", 1.0)),
-        moe_shared_gated=False,
-        moe_held=(rank * held, (rank + 1) * held),
+    )
+
+
+def _axk1_decoder(t: dict[str, Any]) -> DecoderConfig:
+    """``model_type`` ``axk1`` (the DeepSeek-V3 layer): every layer latent
+    attention over every causal key (no indexer, no window, no gate) under a
+    YaRN-scaled rotation, leading dense layers, sigmoid-routed experts
+    selected within the ``topk_group`` best of ``n_group`` groups, one
+    ungated shared expert (:func:`_latent_moe_decoder`). The router adds
+    ``e_score_correction_bias`` for selection whatever ``topk_method`` says
+    (``transformers``' ``DeepseekV3TopkRouter`` reads no such key; a zero
+    bias is the rule without one)."""
+    groups = t.get("n_group", 1)
+    width = t["n_routed_experts"] * t.get("ep_size", 1)
+    if width % groups:
+        raise ValueError(f"{width} routed experts do not divide into n_group {groups}")
+    return _latent_moe_decoder(
+        t,
+        rms_norm_eps=t.get("rms_norm_eps", 1e-6),
+        max_position_embeddings=t.get("max_position_embeddings", 131072),
+        layer_types=(FULL_ATTENTION,) * t["num_hidden_layers"],
+        moe_scoring=t.get("scoring_func", "sigmoid"),
+        moe_select_bias=True,
+        moe_n_group=groups,
+        moe_topk_group=t.get("topk_group", groups),
     )
 
 
@@ -440,14 +556,14 @@ def init_kv_cache(cfg: VLMConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) 
 def _latent_cache(d: DecoderConfig, i: int, lead: tuple[int, int], dtype) -> dict:
     """One latent layer's cache over ``lead`` = (pages, page) or (batch,
     max_seq): the normed latent row ``c`` and the rotated position key ``r``
-    a token; a full layer also keeps its indexer's key ``ik``."""
+    a token; a full layer with an indexer also keeps its index key ``ik``."""
     full = d.layer_kind(i) == FULL_ATTENTION
     dims = d.latent_full if full else d.latent_window
     cache = {
         "c": jnp.zeros((*lead, dims.kv_lora), dtype),
         "r": jnp.zeros((*lead, dims.rope), dtype),
     }
-    if full:
+    if full and d.indexer:
         cache["ik"] = jnp.zeros((*lead, d.index_head_dim), dtype)
     return cache
 
@@ -499,13 +615,21 @@ class RMSNorm(nn.Module):
         return (x32 * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope_rotate(
+    x: jax.Array, positions: jax.Array, theta: float, scaling: YarnScaling | None = None
+) -> jax.Array:
     """Rotary embedding, HF half-split convention. ``x``: [B, H, S, D],
-    ``positions``: [B, S] absolute token positions."""
+    ``positions``: [B, S] absolute token positions. ``scaling``: the YaRN
+    frequencies and cos/sin factor in place of the plain ones."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv_freq = jnp.asarray(scaling.inv_freq(d, theta), jnp.float32)
     angles = positions[:, None, :, None].astype(jnp.float32) * inv_freq  # [B,1,S,D/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None and scaling.rotation_scale != 1.0:
+        cos, sin = cos * scaling.rotation_scale, sin * scaling.rotation_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)
@@ -652,11 +776,15 @@ class LatentAttention(nn.Module):
     """Latent attention in absorbed form (``ops.latent_attention``), of the
     kind ``kind`` names: low-rank query (``q_a_proj`` -> norm -> ``q_b_proj``)
     and key/value (``kv_a_proj`` -> norm; ``kv_b_proj`` folded into the query
-    and applied to the weighted latents), one RoPE key shared by all heads, a
-    sigmoid gate a head from the layer's normed input, and in a full layer
-    the indexer (``index_q`` from the query latent, ``index_k`` with a
-    LayerNorm, ``index_w``) that keeps the ``index_topk`` causal keys of
-    largest score. The cache is this layer's ``_latent_cache``."""
+    and applied to the weighted latents), one RoPE key shared by all heads,
+    rotated as the kind's ``LatentDims`` says (YaRN where it is scaled, the
+    score scale with it). What else the layer has, the configuration says:
+    a sigmoid gate a head from the layer's normed input (``latent_gate``);
+    in a full layer an indexer (``index_q`` from the query latent,
+    ``index_k`` with a LayerNorm, ``index_w``) that keeps the ``index_topk``
+    causal keys of largest score (``index_heads`` > 0). A full layer without
+    one attends every causal key. The cache is this layer's
+    ``_latent_cache``."""
 
     cfg: DecoderConfig
     kind: str
@@ -680,17 +808,20 @@ class LatentAttention(nn.Module):
         if c.latent_rescale:
             cq = cq * (c.hidden_size / d.q_lora) ** 0.5
             ckv = ckv * (c.hidden_size / d.kv_lora) ** 0.5
-        k_r = rope_rotate(kv[:, None, :, d.kv_lora :], positions, d.rope_theta)[:, 0]  # [B, S, R]
+        rotate = lambda t: rope_rotate(t, positions, d.rope_theta, d.rope_scaling)
+        k_r = rotate(kv[:, None, :, d.kv_lora :])[:, 0]  # [B, S, R]
         q = dense(h * (d.nope + d.rope), "q_b_proj")(cq)
         q = q.reshape(b, s, h, d.nope + d.rope).transpose(0, 2, 1, 3)
-        q_r = rope_rotate(q[..., d.nope :], positions, d.rope_theta)  # [B, H, S, R]
+        q_r = rotate(q[..., d.nope :])  # [B, H, S, R]
         w_kvb = self.param(
             "kv_b_proj", nn.initializers.normal(0.02), (d.kv_lora, h * (d.nope + d.v_dim))
         ).astype(x.dtype).reshape(d.kv_lora, h, d.nope + d.v_dim)
         q_c = jnp.einsum("bhsn,chn->bhsc", q[..., : d.nope], w_kvb[..., : d.nope])  # absorbed
-        gate = jax.nn.sigmoid(dense(h, "attn_gate")(x).astype(jnp.float32))  # [B, S, H]
+        if c.latent_gate:
+            gate = jax.nn.sigmoid(dense(h, "attn_gate")(x).astype(jnp.float32))  # [B, S, H]
         new = {"c": ckv, "r": k_r}
-        if not windowed:
+        indexed = not windowed and c.indexer
+        if indexed:
             j, di = c.index_heads, c.index_head_dim
             q_i = dense(j * di, "index_q")(cq).reshape(b, s, j, di).transpose(0, 2, 1, 3)
             q_i = jnp.concatenate(
@@ -709,7 +840,9 @@ class LatentAttention(nn.Module):
         if block_tables is not None:
             if s != 1:
                 raise NotImplementedError("latent paged decode takes one token a row")
-            table = block_tables[:, 1 if windowed else 0]  # [B, MAXP] of this kind's id space
+            # [B, MAXP] of this kind's id space: one table a row, or, beside
+            # window layers, the full layers' and theirs
+            table = block_tables if block_tables.ndim == 2 else block_tables[:, 1 if windowed else 0]
             page = cache["c"].shape[1]
             off = jnp.asarray(cache_offset, jnp.int32)
             page_idx = table[jnp.arange(b), off // page]
@@ -725,7 +858,9 @@ class LatentAttention(nn.Module):
             else:
                 kv_start = jnp.zeros_like(kv_valid_len)
                 slots = table.shape[1] * page
-                if slots > c.index_topk:
+                if not indexed:
+                    _count_route("latent-all", slots)
+                elif slots > c.index_topk:
                     scores = la.indexer_scores(q_i[:, 0], w_i[:, 0], cache["ik"], table)
                     ok = jnp.arange(slots, dtype=jnp.int32)[None, :] < kv_valid_len[:, None]
                     sel = la.topk_select(scores, ok, c.index_topk)
@@ -758,7 +893,7 @@ class LatentAttention(nn.Module):
                 )
                 if windowed:
                     see &= positions[:, :, None] - k_pos[None, None, :] < c.sliding_window
-                elif k_pos.shape[0] > c.index_topk:
+                elif indexed and k_pos.shape[0] > c.index_topk:
                     scores = la.indexer_scores_dense(q_i, w_i, keys["ik"].astype(x.dtype))
                     see = la.topk_select(scores, see, c.index_topk)
                 return la.latent_prefill_attention(
@@ -790,8 +925,9 @@ class LatentAttention(nn.Module):
                     idx = jnp.searchsorted(jnp.asarray(ladder), off + s, side="left")
                     out = jax.lax.switch(idx, [branch(n) for n in ladder], keys)
         out = jnp.einsum("bhsc,chv->bshv", out, w_kvb[..., d.nope :])  # [B, S, H, V]
-        out = (out * gate[..., None].astype(out.dtype)).reshape(b, s, h * d.v_dim)
-        return dense(c.hidden_size, "o_proj")(out), cache
+        if c.latent_gate:
+            out = out * gate[..., None].astype(out.dtype)
+        return dense(c.hidden_size, "o_proj")(out.reshape(b, s, h * d.v_dim)), cache
 
 
 class Mamba2Mixer(nn.Module):
@@ -929,6 +1065,7 @@ class MoEFFN(nn.Module):
                 norm_topk=c.moe_norm_topk, scoring=c.moe_scoring, select_bias=bias,
                 routed_scale=c.moe_routed_scale, held=(lo, hi), n_experts=e,
                 token_valid=token_valid, with_stats=True,
+                n_group=c.moe_n_group, topk_group=c.moe_topk_group,
             )
             self.sow(
                 "moe_stats", "counts", stats,

@@ -450,10 +450,14 @@ class RowState:
 
     Only a row of paged K/V alone can be shared or exported whole today
     (prefix cache, spill tier, speculative verify, migration): window layers
-    free the pages a shared prefix would need, and a recurrent state is not
-    a function of a page-aligned prefix that a later row could attach (it
-    would need a snapshot at every page boundary). :meth:`refuse` is the one
-    place that says so."""
+    free the pages a shared prefix would need, a recurrent state is not a
+    function of a page-aligned prefix that a later row could attach (it
+    would need a snapshot at every page boundary), and a latent decoder
+    without window layers keeps every page of a row, so nothing of principle
+    stands in its way: its ``c`` / ``r`` (/ ``ik``) leaves are simply not
+    what the prefix cache's seeding, the spill tier's arena or the
+    migration wire format read and write yet. :meth:`refuse` is the one place
+    that says so."""
 
     PAGED, LATENT, WINDOW, RECURRENT = "paged_kv", "latent_pages", "latent_window_pages", "recurrent"
 
@@ -472,9 +476,15 @@ class RowState:
         return self.layers_of(self.WINDOW)
 
     @property
-    def indexer_layers(self) -> int:
-        """Layers whose queries score every causal key (a full latent layer)."""
+    def full_latent_layers(self) -> int:
+        """Latent layers that keep every page of a row."""
         return self.layers_of(self.LATENT)
+
+    @property
+    def indexer_layers(self) -> int:
+        """Full latent layers whose indexer scores every causal key and keeps
+        an index key a token (none where the decoder has no indexer)."""
+        return self.full_latent_layers if self.decoder.indexer else 0
 
     @property
     def state_layers(self) -> int:
@@ -489,25 +499,32 @@ class RowState:
         """Raise unless rows can be shared or exported (``what`` needs it)."""
         if self.shareable:
             return
-        held = "latent" if self.LATENT in self.kinds or self.WINDOW in self.kinds else "recurrent"
-        why = (
-            "window layers free the pages a shared prefix would need"
-            if held == "latent"
-            else "a row's recurrent state is no function of its pages: sharing or exporting it needs a snapshot"
-        )
+        if self.window_layers:
+            held, why = "latent", "window layers free the pages a shared prefix would need"
+        elif self.full_latent_layers:
+            held, why = "latent", (
+                "a row keeps all its latent pages, but the prefix cache, the spill tier and the "
+                "migration wire format do not read or write latent leaves yet"
+            )
+        else:
+            held, why = "recurrent", (
+                "a row's recurrent state is no function of its pages: sharing or exporting it needs a snapshot"
+            )
         raise NotImplementedError(f"{what} is not implemented for a {held} decoder ({why})")
 
     def page_bytes(self, page_size: int, dtype_bytes: int) -> int:
         """HBM cost of ONE page id of the pool's id space across every layer
         that keeps it: K and V tiles in the grouped-query layers, a latent
-        tile in the FULL layers (the window layers' pages are
+        tile (with its index key, where there is an indexer) in the FULL
+        layers (the window layers' pages are
         :meth:`window_page_bytes` each, in their own id space); a recurrent
         layer keeps none."""
         d = self.decoder
         kv = 2 * d.kv_heads * d.dim_per_head
         row = self.layers_of(self.PAGED) * kv
-        if self.indexer_layers:
-            row += self.indexer_layers * (d.latent_full.kv_lora + d.latent_full.rope + d.index_head_dim)
+        if self.full_latent_layers:
+            index_key = d.index_head_dim if d.indexer else 0
+            row += self.full_latent_layers * (d.latent_full.kv_lora + d.latent_full.rope + index_key)
         return page_size * row * dtype_bytes
 
     def window_page_bytes(self, page_size: int, dtype_bytes: int) -> int:
@@ -543,9 +560,9 @@ class RowState:
         scratch entry ``pre`` written in: a contiguous ``[1, kvh, Lb, dh]``
         (or latent ``[1, Lb, width]``) scratch scattered page by page into
         the ids ``bt_row`` grants (entries past the prompt's live pages are
-        the dump page 0, so the scatter needs no masking; a latent decoder's
-        ``bt_row`` [2, MAXP] is the full layers' table and the window
-        layers'); a recurrent state copied whole into ``slot``'s row, so a
+        the dump page 0, so the scatter needs no masking; a latent decoder
+        with window layers gives ``bt_row`` [2, MAXP], the full layers' table
+        and the window layers'); a recurrent state copied whole into ``slot``'s row, so a
         reused slot keeps nothing of the row before."""
         kind = self.kinds[i]
         if kind == self.RECURRENT:
@@ -561,7 +578,8 @@ class RowState:
                 for name, arr in dst.items()
             }
         nseg = pre["c"].shape[1] // page
-        ids = bt_row[0 if kind == self.LATENT else 1, :nseg]
+        table = bt_row if bt_row.ndim == 1 else bt_row[0 if kind == self.LATENT else 1]
+        ids = table[:nseg]
         return {
             name: arr.at[ids].set(pre[name][0].reshape(nseg, page, -1).astype(arr.dtype))
             for name, arr in dst.items()

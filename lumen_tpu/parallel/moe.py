@@ -77,20 +77,31 @@ def moe_sharding(mesh: Mesh, axis_name: str = EXPERT_AXIS) -> MoEParams:
 def _topk_gates(
     x: jnp.ndarray, router: jnp.ndarray, k: int, norm_topk: bool,
     scoring: str = "softmax", select_bias: jnp.ndarray | None = None,
-    routed_scale: float = 1.0,
+    routed_scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
 ):
     """Top-k routing: ``[T, k]`` gate values + expert ids, in float32.
 
     ``scoring="softmax"``: softmax over the experts, then the k largest.
     ``scoring="sigmoid"``: a sigmoid score an expert; the k largest of
     ``score + select_bias`` are selected (the bias steers selection only)
-    and the gate is the selected expert's own score. ``norm_topk``
+    and the gate is the selected expert's own score. With ``n_group`` > 1
+    the experts form that many groups of consecutive ids, a group's score is
+    the sum of its two largest ``score + select_bias``, and selection is
+    among the experts of the ``topk_group`` best groups alone. ``norm_topk``
     renormalises the gates over the selected; ``routed_scale`` multiplies
     them."""
     logits = x.astype(jnp.float32) @ router.astype(jnp.float32)  # [T, E]
+    if n_group > 1 and scoring != "sigmoid":
+        raise NotImplementedError("group-limited selection is defined for sigmoid scores")
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         ranked = scores if select_bias is None else scores + select_bias.astype(jnp.float32)
+        if n_group > 1:
+            t, e = ranked.shape
+            group_scores = lax.top_k(ranked.reshape(t, n_group, e // n_group), 2)[0].sum(-1)
+            _, kept = lax.top_k(group_scores, topk_group)  # [T, topk_group]
+            keep = jax.nn.one_hot(kept, n_group, dtype=jnp.bool_).any(axis=1)  # [T, G]
+            ranked = jnp.where(jnp.repeat(keep, e // n_group, axis=1), ranked, -jnp.inf)
         _, gate_idx = lax.top_k(ranked, k)
         gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
     elif scoring == "softmax":
@@ -109,6 +120,7 @@ def _moe_exact_local(
     scoring: str = "softmax", select_bias: jnp.ndarray | None = None,
     routed_scale: float = 1.0, held: tuple[int, int] | None = None,
     token_valid: jnp.ndarray | None = None, with_stats: bool = False,
+    n_group: int = 1, topk_group: int = 1,
 ):
     """Exact (zero-drop) single-device MoE via grouped GEMM.
 
@@ -129,7 +141,7 @@ def _moe_exact_local(
     """
     t, d = x.shape
     gate_vals, gate_idx = _topk_gates(
-        x, params.router, k, norm_topk, scoring, select_bias, routed_scale
+        x, params.router, k, norm_topk, scoring, select_bias, routed_scale, n_group, topk_group
     )
     lo, hi = held if held is not None else (0, n_experts)
     n_held = hi - lo
@@ -249,6 +261,8 @@ def moe_ffn(
     n_experts: int | None = None,
     token_valid: jax.Array | None = None,
     with_stats: bool = False,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> jax.Array:
     """Apply the routed expert FFN to ``x: [T, D]`` (flatten [B, S, D]
     upstream).
@@ -266,8 +280,8 @@ def moe_ffn(
     top-k choices are distinct experts — at an ``[E, T_local, D]`` buffer
     memory cost, so prefer a finite factor at scale.
 
-    ``scoring`` / ``select_bias`` / ``routed_scale``: the routing rule (see
-    :func:`_topk_gates`). ``held=(lo, hi)`` with ``n_experts`` (the router's
+    ``scoring`` / ``select_bias`` / ``routed_scale`` / ``n_group`` /
+    ``topk_group``: the routing rule (see :func:`_topk_gates`). ``held=(lo, hi)`` with ``n_experts`` (the router's
     width): ``params`` is one chip's share of the bank, experts ``lo..hi-1``;
     the layer routes over all ``n_experts`` and computes its own experts'
     part of the result, exactly and without an exchange (single device,
@@ -276,19 +290,22 @@ def moe_ffn(
     returns ``(y, [routed, held, experts touched, 1])``.
     """
     n_experts = n_experts or params.w_gate.shape[0]
-    plain = scoring == "softmax" and select_bias is None and held is None and not with_stats
+    plain = (
+        scoring == "softmax" and select_bias is None and held is None and not with_stats
+        and n_group == 1
+    )
     if not plain:
         if capacity_factor is not None or not (
             mesh is None or axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1
         ):
             raise NotImplementedError(
-                "sigmoid scoring, a selection bias, a held range and routing stats run the "
+                "sigmoid scoring, a selection bias, groups, a held range and routing stats run the "
                 "exact single-device path only (capacity_factor=None, no expert mesh axis)"
             )
         return _moe_exact_local(
             params, x, n_experts=n_experts, k=k, norm_topk=norm_topk, scoring=scoring,
             select_bias=select_bias, routed_scale=routed_scale, held=held,
-            token_valid=token_valid, with_stats=with_stats,
+            token_valid=token_valid, with_stats=with_stats, n_group=n_group, topk_group=topk_group,
         )
     if mesh is None or axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1:
         if capacity_factor is None:

@@ -148,6 +148,9 @@ CASES = {
     "latent_full": _latent(128, 512, None, sel=False),
     "latent_full_selected": _latent(128, 512, None, sel=True),
     "latent_window": _latent(64, 1024, 9, sel=False),
+    # a full layer without an indexer (A.X-K1: 64 heads over a 512-value latent):
+    # every page of the row's table, no selection
+    "latent_all_keys": _latent(64, 512, None, sel=False),
     "indexer_scores": (
         lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
         [((16, 64, 128), BF16), ((16, 64), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],
@@ -210,10 +213,11 @@ def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
 @pytest.mark.parametrize(
     "name,pattern",
     [("latent_full_selected", "^latent_paged_attention"), ("latent_window", "^latent_paged_attention"),
-     ("indexer_scores", "^indexer_scores")],
+     ("latent_all_keys", "^latent_paged_attention"), ("indexer_scores", "^indexer_scores")],
 )
 def test_latent_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
-    """``latent_attn_roofline`` / ``latent_attn_time_pct`` match
+    """``latent_attn_roofline`` / ``latent_attn_time_pct`` (and their
+    ``.full`` twins, for a decoder that attends every key) match
     ``^latent_paged_attention`` and ``indexer_roofline`` matches
     ``^indexer_scores`` on the device's ``XLA Ops`` line, as above."""
     import re
@@ -273,3 +277,57 @@ def test_clip_image_tower_route_by_sequence_length(v5e, monkeypatch, at_crossove
 
     text = jax.jit(encode_images).lower(params, pixels).compile().as_text()
     assert ("tpu_custom_call" in text) == at_crossover
+
+
+def test_a_latent_decoder_without_indexer_compiles_its_step_and_chunk_programs(v5e, monkeypatch):
+    """The decode block and the 512-token lane chunk of the benchmark's
+    A.X-K1 configuration at its published widths (hidden 7,168, 64 heads over
+    a 512-value latent, 12 held experts of 2,048, 16 slots, ``max_seq`` 4,608),
+    cut to the dense layer and one expert layer: the step holds the latent
+    paged kernel under the name the ``latent_attn_*.full`` metrics match and
+    nothing of an indexer; the chunk holds no kernel of the repo's (the prefix
+    ladder's branches over the causal mask are XLA's)."""
+    import json
+
+    from lumen_tpu.models.vlm.generate import Generator
+    from lumen_tpu.models.vlm.modeling import VLMConfig, VLMModel
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)  # default_backend() is the CPU here
+    monkeypatch.delenv("LUMEN_PAGED_KERNEL", raising=False)
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "hub-vitl14-axk1-ep16.json")
+    with open(path) as f:
+        entry = json.load(f)
+    hf = entry["models"]["vlm"]["config"]
+    hf = {**hf, "text_config": {**hf["text_config"], "num_hidden_layers": 2}}
+    vcfg = VLMConfig.from_hf(hf)
+    model = VLMModel(vcfg)
+    slots, max_seq, page = 16, entry["backend_settings"]["vlm"]["max_seq"], DEFAULT_PAGE_SIZE
+    gen = Generator(model, vcfg, max_seq=max_seq, max_new_cap=512, cache_dtype=BF16)
+
+    def on_chip(tree, floats=None):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, floats if floats and jnp.issubdtype(a.dtype, jnp.floating) else a.dtype, sharding=v5e
+            ), tree,
+        )
+
+    size = vcfg.vision.image_size
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), I32), jnp.zeros((1, size, size, 3)))
+    )["params"], BF16)
+    maxp = max_seq // page
+    pool = on_chip(jax.eval_shape(lambda: gen.init_pool(slots, pages=slots * maxp + 1, page_size=page)))
+    shape = lambda *s, dtype=I32: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
+    step = jax.jit(gen._step_block_impl, static_argnames=("block",)).lower(
+        params, pool, shape(slots, maxp), shape(2, dtype=jnp.uint32), block=8
+    ).compile().as_text()
+    kernels = _kernel_names(step)
+    # the compiler's own grouped multiplications are custom calls too (``moe_ffn_roofline`` matches ``^ragged-dot``)
+    assert {k for k in kernels if not k.startswith("ragged-dot")} == {"latent_paged_attention_kernel"}, kernels
+    assert any(k.startswith("ragged-dot") for k in kernels)
+    scratch = on_chip(jax.eval_shape(lambda: gen.new_prefill_cache(max_seq)))
+    assert [sorted(layer) for layer in scratch[:2]] == [["c", "r"]] * 2  # no index key kept
+    chunk = jax.jit(gen._prefill_chunk_impl).lower(
+        params, scratch, shape(1, 512, vcfg.decoder.hidden_size, dtype=BF16), shape(1, 512), shape(), shape(1)
+    ).compile().as_text()
+    assert all(k.startswith("ragged-dot") for k in _kernel_names(chunk)), _kernel_names(chunk)

@@ -399,6 +399,20 @@ def test_a_max_seq_over_2048_continues_the_prompt_ladder(latent_mgr):
     assert mgr._continuous.page_size == 64 and mgr._continuous.kv.window is not None
 
 
+def test_a_long_max_seq_warms_the_longest_bucket_and_the_default_a_short_caption(latent_mgr, monkeypatch):
+    """``max_seq`` 2,304 was configured past the default: the warm-up's
+    prompt lands in the last prefill bucket (1,024 < n <= 1,664), so the boot
+    compiles what the longest rows run; at the default length it says "hi"."""
+    from lumen_tpu.models.vlm import ChatMessage
+
+    mgr, _, _ = latent_mgr
+    text = mgr._warmup_text()
+    n = len(mgr._encode_prompt([ChatMessage(role="user", content=text)], True, True))
+    assert 1024 < n <= 1664 and mgr._bucket_len(n) == 1664
+    monkeypatch.setattr(mgr, "max_seq", 2048)
+    assert mgr._warmup_text() == "hi"
+
+
 def test_the_served_tokens_are_the_references_and_the_counters_count(latent_mgr):
     """A 300-word prompt through the chunked-prefill lane (the lane's chunk
     is 320, an eighth of ``max_seq`` 2,304 in whole pages; the 512-token
@@ -678,6 +692,38 @@ def test_what_shares_or_exports_a_latent_row_is_refused(latent_mgr, monkeypatch,
         with pytest.raises(NotImplementedError, match=says):
             attempt(mgr, monkeypatch)
     assert mgr._continuous.kv.stats().pages_live == 0  # nothing was granted on the way
+
+
+def _no_window_config() -> dict:
+    """A latent decoder whose every layer is full and has no indexer: it
+    keeps every page of a row."""
+    from tests.test_vlm_axk1 import tiny_config as no_window
+
+    return no_window()
+
+
+@pytest.mark.parametrize(
+    "make, indexer_layers, reason",
+    [
+        (tiny_config, 2, "window layers free the pages a shared prefix would need"),
+        (_no_window_config, 0, "do not read or write latent leaves yet"),
+    ],
+    ids=["window-and-indexer", "every-key-every-layer"],
+)
+def test_each_latent_decoder_is_refused_for_a_reason_that_is_true_of_it(make, indexer_layers, reason):
+    """``RowState.refuse`` names window layers only where the decoder has
+    them; a latent decoder without any keeps all its pages and is refused
+    because nothing that shares or exports rows handles latent leaves yet.
+    ``indexer_layers`` counts only layers that have an indexer."""
+    from lumen_tpu.models.vlm.paged_kv import RowState
+
+    rows = RowState(VLMConfig.from_hf(make()))
+    assert rows.indexer_layers == indexer_layers and rows.full_latent_layers >= 2 and not rows.shareable
+    assert (rows.window_layers > 0) == ("window" in reason)
+    for what in ("LUMEN_VLM_PREFIX_BYTES", "the spill tier's export", "admitting a migrated row", "speculative verify"):
+        with pytest.raises(NotImplementedError, match=reason) as err:
+            rows.refuse(what)
+        assert what in str(err.value) and "latent decoder" in str(err.value)
 
 
 # -- the manager's behaviour with the second kind of row state ------------------------
