@@ -496,8 +496,8 @@ class Generator:
             eos = pool["eos"] | (active & (pool["cur_tok"] == cfg.eos_token_id))
             done = pool["done"] | eos | (n_gen >= pool["max_new"])
             tok_embed = self._embed(params, pool["cur_tok"][:, None]).astype(self.cache_dtype)
-            # Free slots hold cur_len=0 and done rows stop advancing, so the
-            # clamp only guards a full slot writing past its block table.
+            # Done rows stop advancing and a freed slot keeps its last row's
+            # cur_len, so the clamp only guards a full slot past its table.
             pos = jnp.minimum(pool["cur_len"], capacity - 1)
             logits, caches, stats = self._decode_paged(
                 params, tok_embed, pos[:, None], pool["caches"], block_tables, pos, pos + 1,

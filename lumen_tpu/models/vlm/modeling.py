@@ -784,13 +784,16 @@ class LatentAttention(nn.Module):
     ``index_k`` with a LayerNorm, ``index_w``) that keeps the ``index_topk``
     causal keys of largest score (``index_heads`` > 0). A full layer without
     one attends every causal key. The cache is this layer's
-    ``_latent_cache``."""
+    ``_latent_cache``. In paged decode, a row ``token_valid`` leaves out (a
+    done or free slot, which keeps its last row's length) reads no key."""
 
     cfg: DecoderConfig
     kind: str
 
     @nn.compact
-    def __call__(self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None):
+    def __call__(
+        self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None, token_valid=None
+    ):
         from ...ops import latent_attention as la
 
         c = self.cfg
@@ -851,6 +854,8 @@ class LatentAttention(nn.Module):
                 name: cache[name].at[page_idx, slot].set(new[name][:, 0].astype(cache[name].dtype))
                 for name in cache
             }
+            if token_valid is not None:  # its output is thrown away
+                kv_valid_len = jnp.where(token_valid.reshape(b), kv_valid_len, 0)
             sel, span = None, None
             if windowed:
                 kv_start = jnp.maximum(kv_valid_len - c.sliding_window, 0)
@@ -1098,10 +1103,13 @@ class DecoderLayer(nn.Module):
             )
         else:
             if kind in LATENT_KINDS:
-                attn = LatentAttention(c, kind, name="attn")
+                h, cache = LatentAttention(c, kind, name="attn")(
+                    normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+                )
             else:
-                attn = DecoderAttention(c, name="attn")
-            h, cache = attn(normed, positions, cache, cache_offset, kv_valid_len, block_tables)
+                h, cache = DecoderAttention(c, name="attn")(
+                    normed, positions, cache, cache_offset, kv_valid_len, block_tables
+                )
         r = c.residual_multiplier
         x = x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
         y = RMSNorm(c.rms_norm_eps, name="post_attn_norm")(x)

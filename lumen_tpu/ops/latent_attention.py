@@ -15,6 +15,8 @@ Three callers, one mathematics:
   against its pages ``{"c": [P, page, C], "r": [P, page, R]}``. A window
   layer passes ``kv_starts`` and visits only the ``span`` pages its window
   can touch; a full layer may pass ``sel``, the keys its indexer picked.
+  A grid step covers ``GRID_KEYS`` keys of the row's pages and copies only
+  those that hold a key the row may see.
   Pallas kernel ``latent_paged_attention_kernel`` on TPU, the exact XLA
   gather elsewhere (the rule of ``ops.attention.paged_attention``:
   ``LUMEN_PAGED_KERNEL=0`` disables, ``=1`` forces interpret mode);
@@ -41,6 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import (
     _LANES,
     NEG_INF,
+    _count_route,
     _interpret_mode,
     _paged_kernel_usable,
     _q_group_pad,
@@ -67,58 +70,123 @@ def topk_select(scores: jax.Array, ok: jax.Array, k: int) -> jax.Array:
 # -- paged decode ------------------------------------------------------------
 
 
+#: keys one grid step of the latent decode kernel covers: its pages are
+#: copied from the pool that many keys at a time (timed on the chip at 256,
+#: 512 and 1,024, the last fastest at every shape the cells run: ``PERF.md``
+#: section 6, PR 40)
+GRID_KEYS = 1024
+
+
+def grid_pages(page: int, steps: int) -> int:
+    """Pages one grid step of :func:`latent_paged_attention_kernel` covers:
+    ``GRID_KEYS`` keys' worth, and no more than the ``steps`` pages a row may
+    visit (a window layer's span caps it)."""
+    return max(1, min(GRID_KEYS // page, steps))
+
+
 def _latent_paged_kernel(
     bt_ref,  # [B, MAXP] int32 block table (SMEM, prefetched)
     len_ref,  # [B] int32 keys visible to the row (its own token included)
     start_ref,  # [B] int32 first visible key (0 in a full layer)
     qc_ref,  # [1, Hp, C] query folded through W_uk
     qr_ref,  # [1, Hp, R] rotated position query
-    c_ref,  # [1, page, C] one page of latent rows
-    r_ref,  # [1, page, R] one page of position keys
-    *rest,  # [sel_ref [1, 1, 1, page] f32], o_ref, acc, m, l
+    c_hbm,  # [P, page, C] the pool's latent rows, left in HBM
+    *rest,  # k x r_ref [1, page, R], [sel_ref [1, 1, 1, k*page] f32], o_ref, c_buf, sems, flags, acc, m, l
     sm_scale: float,
     page: int,
     steps: int,
     maxp: int,
+    k: int,
     has_sel: bool,
 ):
-    del bt_ref  # consumed by the index maps
+    r_refs, rest = rest[:k], rest[k:]
     if has_sel:
-        sel_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        sel_ref, o_ref, c_buf, sems, flags, acc_ref, m_ref, l_ref = rest
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, c_buf, sems, flags, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    g = pl.program_id(1)
+    rows = pl.num_programs(0)
+    groups = pl.num_programs(1)
     kv_len = len_ref[b]
     start = start_ref[b]
-    slot = start // page + j  # logical page of the row this step visits
+    first, last = _row_slots(len_ref, start_ref, b, page, steps, maxp)
 
-    @pl.when(j == 0)
+    def copy(row, grp, buf, what):
+        """Start or wait for the copies of ``row``'s group ``grp`` into
+        buffer ``buf``: one a page of the row's table that holds a key it
+        may see."""
+        lo, hi = _row_slots(len_ref, start_ref, row, page, steps, maxp)
+        for i in range(k):
+            slot = lo + grp * k + i
+            cp = pltpu.make_async_copy(
+                c_hbm.at[bt_ref[row, jnp.minimum(slot, maxp - 1)]],
+                c_buf.at[buf, pl.ds(i * page, page)],
+                sems.at[buf],
+            )
+            pl.when(slot <= hi)(getattr(cp, what))
+
+    @pl.when((b == 0) & (g == 0))
+    def _first():
+        # Slots of a group past the row's last page are never copied: give
+        # them zeros once, so that a masked weight (zero) never meets a NaN.
+        c_buf[...] = jnp.zeros_like(c_buf)
+        flags[0] = 0  # the buffer this step's pages are in
+        flags[1] = -1  # the step (b * groups + g) whose copies are in flight
+
+    @pl.when(g == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when((slot < maxp) & (slot * page < kv_len))
+    @pl.when(g < _row_groups(first, last, k))
     def _update():
-        c = c_ref[0]
+        buf = flags[0]
+
+        @pl.when(flags[1] != b * groups + g)
+        def _own():  # nothing was prefetched for it: the call's first, or after a row with no key
+            copy(b, g, buf, "start")
+
+        # the next step with pages: this row's next group, else the next row's first
+        more = g + 1 < _row_groups(first, last, k)
+        nb = jnp.where(more, b, b + 1)
+        ng = jnp.where(more, g + 1, 0)
+        nxt = jnp.minimum(nb, rows - 1)
+        n_first, n_last = _row_slots(len_ref, start_ref, nxt, page, steps, maxp)
+        prefetch = (nb < rows) & (ng < _row_groups(n_first, n_last, k))
+
+        @pl.when(prefetch)
+        def _next():
+            copy(nxt, ng, 1 - buf, "start")
+            flags[0] = 1 - buf
+            flags[1] = nb * groups + ng
+
+        @pl.when(~prefetch)
+        def _none():
+            flags[1] = -1
+
+        copy(b, g, buf, "wait")
+        c = c_buf[buf]  # [k*page, C]
+        r = jnp.concatenate([r_ref[0] for r_ref in r_refs], axis=0)  # [k*page, R]
         s = jax.lax.dot_general(
             qc_ref[0], c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) + jax.lax.dot_general(
-            qr_ref[0], r_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            qr_ref[0], r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        s = s * sm_scale  # [Hp, page]
-        pos = slot * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (pos >= start) & (pos < kv_len)
+        s = s * sm_scale  # [Hp, k*page]
+        pos = (first + g * k) * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # keys of slots past the last page copied are not the row's: masked too
+        ok = (pos >= start) & (pos < jnp.minimum(kv_len, (last + 1) * page))
         if has_sel:
             ok = ok & (sel_ref[0, 0] > 0.0)
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        # A page may hold no key this row may see (all behind the window's
-        # start or none picked by the indexer): masked weights are set to
-        # zero outright, since exp(NEG_INF - NEG_INF) is one.
+        # A group may hold no key this row may see (none picked by the
+        # indexer): masked weights are set to zero outright, since
+        # exp(NEG_INF - NEG_INF) is one.
         p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + p.sum(axis=-1)
@@ -128,10 +196,24 @@ def _latent_paged_kernel(
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
 
-    @pl.when(j == steps - 1)
+    @pl.when(g == groups - 1)
     def _finalize():
         l = l_ref[:, 0]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+
+
+def _row_slots(len_ref, start_ref, row, page: int, steps: int, maxp: int):
+    """First and last slot of ``row``'s table that hold a key it may see,
+    within the ``steps`` slots visited from its start's page (last < first:
+    none)."""
+    first = start_ref[row] // page
+    end = jnp.minimum((len_ref[row] + page - 1) // page, jnp.minimum(first + steps, maxp))
+    return first, end - 1
+
+
+def _row_groups(first, last, k: int):
+    """Grid steps of ``k`` pages that hold the slots ``first..last``."""
+    return jnp.where(last >= first, (last - first) // k + 1, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "span", "interpret"))
@@ -143,67 +225,94 @@ def latent_paged_attention_kernel(
     block_tables: jax.Array,  # [B, MAXP] int32 (dead entries: 0)
     kv_lens: jax.Array,  # [B] int32
     kv_starts: jax.Array,  # [B] int32
-    sel: jax.Array | None = None,  # [B, MAXP*page] bool: keys the indexer picked
+    sel: jax.Array | None = None,  # [B, MAXP*page] bool: keys the indexer picked (kv_starts 0)
     *,
     scale: float,
     span: int | None = None,  # window layer: pages visited from the start's page
     interpret: bool = False,
 ) -> jax.Array:
     """Pallas absorbed latent decode attention: ``[B, H, C]`` weighted
-    latent rows. Grid ``(B, steps)``; each step DMAs one page picked by the
-    prefetched block table, ``steps`` being the whole table for a full layer
-    and ``span`` pages from the window's first for a window layer."""
+    latent rows. Grid ``(B, ceil(steps / k))``: a step covers ``k`` pages of
+    the row (:func:`grid_pages`), ``steps`` being the whole table for a full
+    layer and ``span`` pages from the window's first for a window layer. A
+    step reads only the pages that hold a key the row may see (from
+    ``kv_start // page`` to the page of its last key), and the next step's
+    are copied while it computes: ``c`` by copies the kernel starts from the
+    pool left in HBM, by the prefetched block table; ``r`` by ``k`` block
+    specs, one a page, whose index stays where it was for a slot past the
+    row's last page, so nothing is copied for it (Mosaic refuses a copy of
+    one page of a 64-wide pool)."""
     b, h, c_dim = qc.shape
     page = c_pages.shape[1]
     maxp = block_tables.shape[1]
     steps = maxp if span is None else min(span, maxp)
+    k = grid_pages(page, steps)
+    groups = -(-steps // k)
     hp = _q_group_pad(h)
     if hp != h:
         qc = jnp.pad(qc, ((0, 0), (0, hp - h), (0, 0)))
         qr = jnp.pad(qr, ((0, 0), (0, hp - h), (0, 0)))
     r_dim = qr.shape[-1]
 
-    def slot_of(i, j, ks):
-        return jnp.minimum(ks[i] // page + j, maxp - 1)
+    def at(kl, ks, i, j):
+        """The row's group for grid step ``j``: past its last, its last."""
+        first, last = _row_slots(kl, ks, i, page, steps, maxp)
+        return first, last, jnp.clip(j, 0, jnp.maximum(_row_groups(first, last, k) - 1, 0))
 
     def q_map(i, j, bt, kl, ks):
         return (i, 0, 0)
 
-    def page_map(i, j, bt, kl, ks):
-        return (bt[i, slot_of(i, j, ks)], 0, 0)
+    def r_map(n):
+        def index(i, j, bt, kl, ks):
+            first, last, grp = at(kl, ks, i, j)
+            slot = first + grp * k + n
+            # a slot past the row's last page takes the block this input
+            # held a group before (no copy), or in a row's first group its
+            # last page (a copy already made by another input); a row with
+            # no key to see takes the dump page, as the row before it did
+            slot = jnp.where(slot <= last, slot, jnp.where(grp > 0, slot - k, last))
+            return (jnp.where(last >= first, bt[i, jnp.clip(slot, 0, maxp - 1)], 0), 0, 0)
+
+        return index
 
     def sel_map(i, j, bt, kl, ks):
-        return (i, slot_of(i, j, ks), 0, 0)
+        return (i, at(kl, ks, i, j)[2], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, hp, c_dim), q_map),
         pl.BlockSpec((1, hp, r_dim), q_map),
-        pl.BlockSpec((1, page, c_dim), page_map),
-        pl.BlockSpec((1, page, r_dim), page_map),
+        pl.BlockSpec(memory_space=pl.ANY),
+        *(pl.BlockSpec((1, page, r_dim), r_map(n)) for n in range(k)),
     ]
-    operands = [qc, qr, c_pages, r_pages]
+    operands = [qc, qr, c_pages, *([r_pages] * k)]
     if sel is not None:
-        in_specs.append(pl.BlockSpec((1, 1, 1, page), sel_map))
-        operands.append(sel.astype(jnp.float32).reshape(b, maxp, 1, page))
+        keep = sel.astype(jnp.float32)
+        keep = jnp.pad(keep, ((0, 0), (0, groups * k * page - keep.shape[1])))
+        in_specs.append(pl.BlockSpec((1, 1, 1, k * page), sel_map))
+        operands.append(keep.reshape(b, groups, 1, k * page))
     kernel = functools.partial(
-        _latent_paged_kernel, sm_scale=scale, page=page, steps=steps, maxp=maxp,
+        _latent_paged_kernel, sm_scale=scale, page=page, steps=steps, maxp=maxp, k=k,
         has_sel=sel is not None,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, steps),
+            grid=(b, groups),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, hp, c_dim), q_map),
             scratch_shapes=[
+                pltpu.VMEM((2, k * page, c_dim), c_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((hp, c_dim), jnp.float32),
                 pltpu.VMEM((hp, _LANES), jnp.float32),
                 pltpu.VMEM((hp, _LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hp, c_dim), qc.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        # one sequence over rows too: a step copies the next row's first group
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
@@ -246,7 +355,12 @@ def latent_paged_attention(
     qc, qr, c_pages, r_pages, block_tables, kv_lens, kv_starts, sel=None,
     *, scale: float, span: int | None = None,
 ) -> jax.Array:
-    """Dispatch: the Pallas kernel on TPU, the XLA reference elsewhere."""
+    """Dispatch: the Pallas kernel on TPU, the XLA reference elsewhere. The
+    ``attention-route`` gauge counts ``latent-grid:{keys a grid step}`` for
+    each layer traced, on either path (the grid is decided by the shapes)."""
+    page = c_pages.shape[1]
+    maxp = block_tables.shape[1]
+    _count_route("latent-grid", grid_pages(page, maxp if span is None else min(span, maxp)) * page)
     if _paged_kernel_usable(0):
         return latent_paged_attention_kernel(
             qc, qr, c_pages, r_pages, block_tables, kv_lens, kv_starts, sel,

@@ -80,10 +80,12 @@ def _flash_cache(sq, sk):
     )
 
 
-def _latent(heads, c_dim, span, sel):
+def _latent(heads, c_dim, span, sel, maxp=72):
     """The latent decode kernel at the published ``dots3_note`` widths: 16
-    rows, 64-token pages, 72 pages a row (max_seq 4,608)."""
-    rows, maxp, page, rope = 16, 72, 64, 64
+    rows, 64-token pages, 72 pages a row (max_seq 4,608). A grid step holds
+    1,024 keys: double-buffered ``[1024, C]`` latent rows beside sixteen
+    one-page position blocks."""
+    rows, page, rope = 16, 64, 64
     shapes = [
         ((rows, heads, c_dim), BF16), ((rows, heads, rope), BF16),
         ((rows * maxp + 1, page, c_dim), BF16), ((rows * maxp + 1, page, rope), BF16),
@@ -151,6 +153,8 @@ CASES = {
     # a full layer without an indexer (A.X-K1: 64 heads over a 512-value latent):
     # every page of the row's table, no selection
     "latent_all_keys": _latent(64, 512, None, sel=False),
+    # the same over a row of 8,192 tokens: 128 pages, 8 grid steps a row
+    "latent_all_keys_8k_row": _latent(64, 512, None, sel=False, maxp=128),
     "indexer_scores": (
         lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
         [((16, 64, 128), BF16), ((16, 64), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],

@@ -195,7 +195,7 @@ def test_pages_behind_the_window_are_freed_and_never_read(built):
 # -- the kernels against their XLA references (interpret mode) -----------------
 
 
-def _paged_case(rng, rows=3, heads=4, c_dim=32, r_dim=8, maxp=6, lens=(22, 9, 17)):
+def _paged_case(rng, rows=3, heads=4, c_dim=32, r_dim=8, maxp=6, lens=(22, 9, 17), page=PAGE):
     from lumen_tpu.ops import latent_attention as la
 
     pages = rows * maxp + 1
@@ -203,29 +203,52 @@ def _paged_case(rng, rows=3, heads=4, c_dim=32, r_dim=8, maxp=6, lens=(22, 9, 17
     bt = np.zeros((rows, maxp), np.int32)
     ids = iter(rng.permutation(np.arange(1, pages)))
     for b, n in enumerate(lens):
-        for j in range(-(-n // PAGE)):
+        for j in range(-(-n // page)):
             bt[b, j] = next(ids)
     return la, dict(
-        qc=f(rows, heads, c_dim), qr=f(rows, heads, r_dim), c_pages=f(pages, PAGE, c_dim),
-        r_pages=f(pages, PAGE, r_dim), block_tables=jnp.asarray(bt), kv_lens=jnp.asarray(lens, jnp.int32),
+        qc=f(rows, heads, c_dim), qr=f(rows, heads, r_dim), c_pages=f(pages, page, c_dim),
+        r_pages=f(pages, page, r_dim), block_tables=jnp.asarray(bt), kv_lens=jnp.asarray(lens, jnp.int32),
     )
 
 
-@pytest.mark.parametrize("case", ["full", "selected", "window"])
-def test_latent_paged_kernel_matches_its_reference(case):
+#: name -> (case, _paged_case's shapes, the window or the pages row 0 loses)
+PAGED_CASES = {
+    "full": ("full", {}, None),
+    "selected": ("selected", {}, None),
+    "window": ("window", {}, 5),
+    # 64-token pages, 16 a grid step: rows of 20, 18 and 5 pages whose last
+    # group is partial, an idle row (kv_len 1) that copies one page, and done
+    # or free rows (kv_len 0: LatentAttention's for a row token_valid leaves
+    # out) that copy none
+    "full_partial_groups": ("full", dict(rows=6, maxp=20, lens=(1250, 1, 0, 300, 1100, 0), page=64), None),
+    # a window of 1,100 keys over 19 pages, two steps: a row's groups begin
+    # at the page of its window's start, which falls mid-page (900 in row 2)
+    "window_start_mid_page": ("window", dict(rows=4, maxp=32, lens=(100, 1300, 2000, 1), page=64), 1100),
+    # the indexer picks nothing in row 0's second group (pages 16-31)
+    "selected_empty_group": ("selected", dict(rows=3, maxp=34, lens=(2100, 300, 1), page=64), (16, 32)),
+}
+
+
+@pytest.mark.parametrize("name", list(PAGED_CASES))
+def test_latent_paged_kernel_matches_its_reference(name):
+    case, shapes, extra = PAGED_CASES[name]
     rng = np.random.default_rng(3)
-    la, a = _paged_case(rng)
-    starts = jnp.zeros((3,), jnp.int32)
+    la, a = _paged_case(rng, **shapes)
+    rows, maxp = a["block_tables"].shape
+    page = a["c_pages"].shape[1]
+    starts = jnp.zeros((rows,), jnp.int32)
     sel, span = None, None
     if case == "selected":
         # row 0 loses its whole first page and every third key after it
-        keep = np.ones((3, 6 * PAGE), bool)
-        keep[0, :PAGE] = False
+        keep = np.ones((rows, maxp * page), bool)
+        keep[0, :page] = False
         keep[:, 5::3] = False
+        if extra:
+            keep[0, extra[0] * page : extra[1] * page] = False
         sel = jnp.asarray(keep)
     if case == "window":
-        starts = jnp.maximum(a["kv_lens"] - 5, 0)
-        span = la.window_span_pages(5, PAGE)
+        starts = jnp.maximum(a["kv_lens"] - extra, 0)
+        span = la.window_span_pages(extra, page)
     args = (a["qc"], a["qr"], a["c_pages"], a["r_pages"], a["block_tables"], a["kv_lens"], starts, sel)
     want = la.latent_paged_attention_reference(*args, scale=0.3, span=span)
     got = la.latent_paged_attention_kernel(*args, scale=0.3, span=span, interpret=True)
@@ -233,6 +256,40 @@ def test_latent_paged_kernel_matches_its_reference(case):
     if case == "window":  # and both are what a full pass under the same bounds gives
         whole = la.latent_paged_attention_reference(*args, scale=0.3, span=None)
         np.testing.assert_allclose(np.asarray(want), np.asarray(whole), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["full", "selected", "window"])
+def test_the_route_gauge_counts_the_keys_of_a_latent_grid_step(case):
+    """``attention-route`` counts ``latent-grid:{keys a grid step}`` once for
+    each latent decode layer traced, whichever kind (the grid follows the
+    shapes: a whole table of 6 pages of 4 is one step of 24 keys, a window
+    of 5 keys two pages of 4), on the CPU's XLA path as on the chip's."""
+    from lumen_tpu.utils.metrics import metrics
+
+    la, a = _paged_case(np.random.default_rng(5))
+    starts, sel, span = jnp.zeros((3,), jnp.int32), None, None
+    if case == "selected":
+        sel = jnp.ones((3, 6 * PAGE), bool)
+    if case == "window":
+        starts, span = jnp.maximum(a["kv_lens"] - 5, 0), la.window_span_pages(5, PAGE)
+    name = f"latent-grid:{8 if case == 'window' else 24}"
+    before = metrics.snapshot().get("gauges", {}).get("attention-route", {}).get(name, 0)
+    la.latent_paged_attention(
+        a["qc"], a["qr"], a["c_pages"], a["r_pages"], a["block_tables"], a["kv_lens"], starts, sel,
+        scale=0.3, span=span,
+    )
+    assert metrics.snapshot()["gauges"]["attention-route"][name] == before + 1
+
+
+def test_a_latent_grid_step_covers_1024_keys_of_the_pool_page():
+    """A.X-K1's whole table (72 pages of 64) takes 5 grid steps a row, and
+    dots3's window layer (span 9) one; a short table is one step."""
+    from lumen_tpu.ops import latent_attention as la
+
+    assert la.grid_pages(64, 72) * 64 == la.GRID_KEYS == 1024
+    assert -(-72 // la.grid_pages(64, 72)) == 5
+    assert la.grid_pages(64, la.window_span_pages(513, 64)) == 9
+    assert la.grid_pages(4, 6) == 6 and la.grid_pages(2048, 6) == 1
 
 
 def test_indexer_kernel_matches_its_reference_and_the_selection_keeps_k():
