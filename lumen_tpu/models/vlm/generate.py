@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops.sampling import apply_repetition_penalty, sample
+from ...utils.trace import SCOPE_DECODE, SCOPE_EMBED, SCOPE_INSTALL, SCOPE_PREFILL, SCOPE_SAMPLE, SCOPE_STATE
 from .modeling import VLMConfig, VLMModel, init_kv_cache, init_paged_kv_cache
 from .paged_kv import DEFAULT_PAGE_SIZE, RowState
 
@@ -383,6 +384,7 @@ class Generator:
             rep=jnp.ones((slots,), jnp.float32),
         )
 
+    @jax.named_scope(SCOPE_INSTALL)
     def _admit_impl(
         self, pool, slot, caches1, tok0, seen1, length, bt_row,
         max_new, temperature, top_p, do_sample, rep,
@@ -435,6 +437,7 @@ class Generator:
             n_gen=pool["n_gen"][s],
         )
 
+    @jax.named_scope(SCOPE_INSTALL)
     def _resume_impl(
         self, pool, slot, pages, page_ids, seen1, cur_tok, cur_len, n_gen,
         max_new, temperature, top_p, do_sample, rep,
@@ -489,37 +492,42 @@ class Generator:
 
         def body(carry, _):
             pool, rng = carry
-            active = ~pool["done"]
-            tok = jnp.where(active, pool["cur_tok"], cfg.pad_token_id)
-            n_gen = pool["n_gen"] + active.astype(jnp.int32)
-            seen = pool["seen"].at[jnp.arange(b), pool["cur_tok"]].max(active)
-            eos = pool["eos"] | (active & (pool["cur_tok"] == cfg.eos_token_id))
-            done = pool["done"] | eos | (n_gen >= pool["max_new"])
-            tok_embed = self._embed(params, pool["cur_tok"][:, None]).astype(self.cache_dtype)
-            # Done rows stop advancing and a freed slot keeps its last row's
-            # cur_len, so the clamp only guards a full slot past its table.
-            pos = jnp.minimum(pool["cur_len"], capacity - 1)
-            logits, caches, stats = self._decode_paged(
-                params, tok_embed, pos[:, None], pool["caches"], block_tables, pos, pos + 1,
-                active,
-            )
-            rng, sub = jax.random.split(rng)
-            nxt = self._sample_next(
-                sub, logits[:, 0], seen,
-                pool["temperature"], pool["top_p"], pool["do_sample"] & active, pool["rep"],
-            ).astype(jnp.int32)
-            counted = {} if stats is None else {"moe_stats": pool["moe_stats"] + stats}
-            new_pool = dict(
-                pool,
-                **counted,
-                caches=caches,
-                cur_tok=nxt,
-                cur_len=pool["cur_len"] + active.astype(jnp.int32),
-                seen=seen,
-                n_gen=n_gen,
-                eos=eos,
-                done=done,
-            )
+            with jax.named_scope(SCOPE_STATE):
+                active = ~pool["done"]
+                tok = jnp.where(active, pool["cur_tok"], cfg.pad_token_id)
+                n_gen = pool["n_gen"] + active.astype(jnp.int32)
+                seen = pool["seen"].at[jnp.arange(b), pool["cur_tok"]].max(active)
+                eos = pool["eos"] | (active & (pool["cur_tok"] == cfg.eos_token_id))
+                done = pool["done"] | eos | (n_gen >= pool["max_new"])
+            with jax.named_scope(SCOPE_EMBED):
+                tok_embed = self._embed(params, pool["cur_tok"][:, None]).astype(self.cache_dtype)
+            with jax.named_scope(SCOPE_DECODE):
+                # Done rows stop advancing and a freed slot keeps its last row's
+                # cur_len, so the clamp only guards a full slot past its table.
+                pos = jnp.minimum(pool["cur_len"], capacity - 1)
+                logits, caches, stats = self._decode_paged(
+                    params, tok_embed, pos[:, None], pool["caches"], block_tables, pos, pos + 1,
+                    active,
+                )
+            with jax.named_scope(SCOPE_SAMPLE):
+                rng, sub = jax.random.split(rng)
+                nxt = self._sample_next(
+                    sub, logits[:, 0], seen,
+                    pool["temperature"], pool["top_p"], pool["do_sample"] & active, pool["rep"],
+                ).astype(jnp.int32)
+            with jax.named_scope(SCOPE_STATE):
+                counted = {} if stats is None else {"moe_stats": pool["moe_stats"] + stats}
+                new_pool = dict(
+                    pool,
+                    **counted,
+                    caches=caches,
+                    cur_tok=nxt,
+                    cur_len=pool["cur_len"] + active.astype(jnp.int32),
+                    seen=seen,
+                    n_gen=n_gen,
+                    eos=eos,
+                    done=done,
+                )
             return (new_pool, rng), tok
 
         (pool, rng), toks = jax.lax.scan(body, (pool, rng), None, length=block)
@@ -543,11 +551,13 @@ class Generator:
         """Contiguous batch-1 scratch cache for one chunked prefill."""
         return self._scratch(1, kv_len)
 
+    @jax.named_scope(SCOPE_PREFILL)
     def _prefill_chunk_impl(self, params, caches, embeds, positions, offset, valid_len):
         """One prompt chunk through the decoder: writes K/V at ``offset``
         into the donated scratch, returns this chunk's logits."""
         return self._decode(params, embeds, positions, caches, offset, valid_len)
 
+    @jax.named_scope(SCOPE_SAMPLE)
     def _chunk_finish_impl(
         self, chunk_logits, idx, prompt_ids, lengths, rng,
         temperature, top_p, do_sample, repetition_penalty,

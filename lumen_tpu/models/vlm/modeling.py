@@ -34,6 +34,7 @@ import numpy as np
 
 from ...ops.attention import _count_route, attention, attention_cached, paged_attention, repeat_kv
 from ...ops.quant import QDense
+from ...utils.trace import SCOPE_KV_WRITE
 
 FULL_ATTENTION = "full_attention"
 WINDOW_ATTENTION = "sliding_attention"
@@ -682,12 +683,13 @@ class DecoderAttention(nn.Module):
             # dump into page 0. s > 1 is the speculative verify window:
             # token t of every row lands at its row's position off+t
             # (static unroll — W is small and fixed per program).
-            for t in range(s):
-                off_t = off + t
-                page_idx = block_tables[bidx, off_t // page]  # [B] page ids
-                slot = off_t % page
-                new_k = new_k.at[page_idx, :, slot].set(k[:, :, t].astype(new_k.dtype))
-                new_v = new_v.at[page_idx, :, slot].set(v[:, :, t].astype(new_v.dtype))
+            with jax.named_scope(SCOPE_KV_WRITE):
+                for t in range(s):
+                    off_t = off + t
+                    page_idx = block_tables[bidx, off_t // page]  # [B] page ids
+                    slot = off_t % page
+                    new_k = new_k.at[page_idx, :, slot].set(k[:, :, t].astype(new_k.dtype))
+                    new_v = new_v.at[page_idx, :, slot].set(v[:, :, t].astype(new_v.dtype))
             cache = {"k": new_k, "v": new_v}
             if s == 1:
                 out = paged_attention(
@@ -715,22 +717,23 @@ class DecoderAttention(nn.Module):
 
         if cache is not None:
             off = jnp.asarray(cache_offset, jnp.int32)
-            if off.ndim == 0:
-                # Prefill: one contiguous segment at a shared offset.
-                zero = jnp.zeros((), jnp.int32)
-                new_k = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (zero, zero, off, zero)
-                )
-                new_v = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (zero, zero, off, zero)
-                )
-            else:
-                # Decode: one token per sample at a per-sample slot (prompts
-                # in a batch have different lengths).
-                assert s == 1, "per-sample cache offsets require a single-token segment"
-                bidx = jnp.arange(b)
-                new_k = cache["k"].at[bidx, :, off].set(k[:, :, 0].astype(cache["k"].dtype))
-                new_v = cache["v"].at[bidx, :, off].set(v[:, :, 0].astype(cache["v"].dtype))
+            with jax.named_scope(SCOPE_KV_WRITE):
+                if off.ndim == 0:
+                    # Prefill: one contiguous segment at a shared offset.
+                    zero = jnp.zeros((), jnp.int32)
+                    new_k = jax.lax.dynamic_update_slice(
+                        cache["k"], k.astype(cache["k"].dtype), (zero, zero, off, zero)
+                    )
+                    new_v = jax.lax.dynamic_update_slice(
+                        cache["v"], v.astype(cache["v"].dtype), (zero, zero, off, zero)
+                    )
+                else:
+                    # Decode: one token per sample at a per-sample slot (prompts
+                    # in a batch have different lengths).
+                    assert s == 1, "per-sample cache offsets require a single-token segment"
+                    bidx = jnp.arange(b)
+                    new_k = cache["k"].at[bidx, :, off].set(k[:, :, 0].astype(cache["k"].dtype))
+                    new_v = cache["v"].at[bidx, :, off].set(v[:, :, 0].astype(cache["v"].dtype))
             cache = {"k": new_k, "v": new_v}
             keys, values = new_k.astype(x.dtype), new_v.astype(x.dtype)
             n_rep = c.heads // c.kv_heads
@@ -848,12 +851,13 @@ class LatentAttention(nn.Module):
             table = block_tables if block_tables.ndim == 2 else block_tables[:, 1 if windowed else 0]
             page = cache["c"].shape[1]
             off = jnp.asarray(cache_offset, jnp.int32)
-            page_idx = table[jnp.arange(b), off // page]
-            slot = off % page
-            cache = {
-                name: cache[name].at[page_idx, slot].set(new[name][:, 0].astype(cache[name].dtype))
-                for name in cache
-            }
+            with jax.named_scope(SCOPE_KV_WRITE):
+                page_idx = table[jnp.arange(b), off // page]
+                slot = off % page
+                cache = {
+                    name: cache[name].at[page_idx, slot].set(new[name][:, 0].astype(cache[name].dtype))
+                    for name in cache
+                }
             if token_valid is not None:  # its output is thrown away
                 kv_valid_len = jnp.where(token_valid.reshape(b), kv_valid_len, 0)
             sel, span = None, None
@@ -881,12 +885,13 @@ class LatentAttention(nn.Module):
                         "a latent decoder decodes through the paged pool (continuous scheduler) only"
                     )
                 zero = jnp.zeros((), jnp.int32)
-                cache = {
-                    name: jax.lax.dynamic_update_slice(
-                        cache[name], new[name].astype(cache[name].dtype), (zero, off, zero)
-                    )
-                    for name in cache
-                }
+                with jax.named_scope(SCOPE_KV_WRITE):
+                    cache = {
+                        name: jax.lax.dynamic_update_slice(
+                            cache[name], new[name].astype(cache[name].dtype), (zero, off, zero)
+                        )
+                        for name in cache
+                    }
                 length = cache["c"].shape[1]
             else:
                 off, length = jnp.zeros((), jnp.int32), s
@@ -976,12 +981,15 @@ class Mamba2Mixer(nn.Module):
             if s != 1:
                 raise NotImplementedError("a recurrent layer decodes one token a row")
             active = jnp.ones((b,), bool) if token_valid is None else token_valid.reshape(b)
-            act, tail = ssm.conv1d_update(xbc[:, 0], cache["conv"], conv_w, conv_b, active)
-            act = act.astype(x.dtype)
-            y, state = ssm.ssm_state_update(
-                act[:, :inner], dt[:, 0], a, act[:, inner : inner + n], act[:, inner + n :],
-                d_skip, cache["ssm"], active,
-            )
+            # The slot's tail and state are rewritten in place by the
+            # one-token update itself, so the write's scope holds the update.
+            with jax.named_scope(SCOPE_KV_WRITE):
+                act, tail = ssm.conv1d_update(xbc[:, 0], cache["conv"], conv_w, conv_b, active)
+                act = act.astype(x.dtype)
+                y, state = ssm.ssm_state_update(
+                    act[:, :inner], dt[:, 0], a, act[:, inner : inner + n], act[:, inner + n :],
+                    d_skip, cache["ssm"], active,
+                )
             y, cache = y[:, None], {"conv": tail, "ssm": state}
         else:
             live = positions < kv_valid_len[:, None]  # [B, S]

@@ -329,6 +329,33 @@ def phase(name: str, **args: Any):
     return ann("lumen:" + name, **args)
 
 
+# -- device scopes (op metadata of the compiled programs) ---------------------
+#
+# ``jax.named_scope`` names the step and chunk programs give their parts.
+# A scope is op metadata: it lands in each operation's ``op_name`` and in a
+# profile's HLO protos, and changes no instruction, fusion or buffer of the
+# compiled program, so it costs nothing whether a profiler runs or not.
+# docs/OBSERVABILITY.md ("Device scopes") says where each is placed and which
+# benchmark metric reads it.
+
+#: a decode step's bookkeeping: live rows, ``tok``, ``n_gen``, the ``seen``
+#: scatter, ``eos`` / ``done``, the carry's new dict
+SCOPE_STATE = "lumen.state"
+#: a decode step's token embedding
+SCOPE_EMBED = "lumen.embed"
+#: a decode step's decoder pass (the Flax module path lies beneath it)
+SCOPE_DECODE = "lumen.decode"
+#: the repetition penalty's select, the argmax, the nucleus under its cond
+SCOPE_SAMPLE = "lumen.sample"
+#: the writes of a step's or a chunk's keys, values, latents or recurrent
+#: state into the pool, the scratch or the slot
+SCOPE_KV_WRITE = "lumen.kv_write"
+#: one prompt chunk through the decoder
+SCOPE_PREFILL = "lumen.prefill"
+#: a prefilled or resumed row written into its slot and pages
+SCOPE_INSTALL = "lumen.install"
+
+
 # -- contextvar propagation --------------------------------------------------
 
 _current: contextvars.ContextVar[Trace | None] = contextvars.ContextVar(
