@@ -198,7 +198,8 @@ def phase_kernels(sizes: Sizes, rehearse: bool, seed: int) -> None:
         return jnp.asarray(rng.standard_normal(shape), bf16)
 
     n_pages = b * maxp + 1
-    k_pages, v_pages = normal(n_pages, kv_heads, page, dh), normal(n_pages, kv_heads, page, dh)
+    # token-major pages, as the pool holds them: a token's heads one row
+    k_pages, v_pages = normal(n_pages, page, kv_heads * dh), normal(n_pages, page, kv_heads * dh)
     tables = jnp.asarray(rng.permutation(n_pages - 1)[: b * maxp].reshape(b, maxp) + 1, jnp.int32)
     window = 5
     kv_lens = jnp.asarray(rng.integers(1, maxp * page - window, size=b), jnp.int32)

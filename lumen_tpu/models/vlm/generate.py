@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops.attention import gather_pages
 from ...ops.sampling import apply_repetition_penalty, sample
 from ...utils.trace import SCOPE_DECODE, SCOPE_EMBED, SCOPE_INSTALL, SCOPE_PREFILL, SCOPE_SAMPLE, SCOPE_STATE
 from .modeling import VLMConfig, VLMModel, init_kv_cache, init_paged_kv_cache
@@ -326,8 +327,8 @@ class Generator:
     # A fixed pool of B decode slots advances together in k-step blocks;
     # requests are admitted into free slots between blocks and retired on
     # EOS/cap without stopping the others. KV lives in a shared PAGED pool
-    # ([pages, kv_heads, page_size, dh] per layer) addressed through
-    # host-managed per-row block tables (``paged_kv.PagedKVPool``):
+    # ([pages, page_size, kv_heads * dh] per layer, token-major) addressed
+    # through host-managed per-row block tables (``paged_kv.PagedKVPool``):
     # admission scatters the prompt's prefill cache into freshly granted
     # pages, decode writes one slot per step through the row's table, and
     # retire returns the pages — long and short generations share the pool
@@ -586,14 +587,9 @@ class Generator:
         overwrite (decode writes K/V before attending) or the valid-length
         mask hides."""
         self.rows.refuse("seeding a scratch from a cached prefix")
-        nseg = page_ids.shape[0]
 
-        def seed(dst, src):
-            seg = src[page_ids]  # [nseg, kvh, page, dh]
-            flat = seg.transpose(1, 0, 2, 3).reshape(
-                1, seg.shape[1], nseg * seg.shape[2], seg.shape[3]
-            )
-            return flat.astype(dst.dtype)
+        def seed(dst, src):  # token-major pages -> the head-major scratch [1, kvh, Lb, dh]
+            return gather_pages(src, page_ids[None], dst.shape[-1]).astype(dst.dtype)
 
         return jax.tree.map(seed, caches, pool_caches)
 

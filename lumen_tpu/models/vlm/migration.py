@@ -41,7 +41,7 @@ from ...utils.tensorwire import pack_bundle, unpack_bundle
 
 #: wire format version — bumped on any layout change; a mismatch is an
 #: INVALID_ARGUMENT on the decode host, never a silent misparse.
-MIGRATE_VERSION = "1"
+MIGRATE_VERSION = "2"
 
 
 class ChunksMissing(ValueError):

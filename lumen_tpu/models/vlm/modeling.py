@@ -533,16 +533,25 @@ def _recurrent_cache(d: DecoderConfig, rows: int, dtype) -> dict:
     }
 
 
-def _layer_cache(d: DecoderConfig, i: int, lead: tuple[int, int], rows: int, dtype) -> dict:
-    """Layer ``i``'s cache over ``lead`` = (batch, max_seq) or (pages, page)
-    for the kinds that keep a row a token, over ``rows`` (batch rows or
-    slots) for a Mamba layer, which keeps a state a row."""
+def _layer_cache(
+    d: DecoderConfig, i: int, lead: tuple[int, int], rows: int, dtype, paged: bool = False
+) -> dict:
+    """Layer ``i``'s cache over ``lead`` = (batch, max_seq) or, ``paged``,
+    (pages, page) for the kinds that keep a row a token, over ``rows`` (batch
+    rows or slots) for a Mamba layer, which keeps a state a row. A
+    grouped-query layer's scratch is head-major ``[batch, kv_heads, max_seq,
+    dh]`` (what prefill's cached attention reads), its pages token-major
+    ``[pages, page, kv_heads * dh]`` (what the step's one-token write and the
+    paged kernel both read, as a latent page)."""
     kind = d.layer_kind(i)
     if kind in LATENT_KINDS:
         return _latent_cache(d, i, lead, dtype)
     if kind == MAMBA:
         return _recurrent_cache(d, rows, dtype)
-    shape = (lead[0], d.kv_heads, lead[1], d.dim_per_head)
+    if paged:
+        shape = (*lead, d.kv_heads * d.dim_per_head)
+    else:
+        shape = (lead[0], d.kv_heads, lead[1], d.dim_per_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -576,7 +585,8 @@ def init_paged_kv_cache(
     """Per-layer PAGED cache: a pool of ``pages`` fixed-size pages shared
     by every decode row, addressed through per-row block tables
     (``models/vlm/paged_kv.py``) instead of one contiguous ``max_seq``
-    region per slot. Page 0 is the reserved dump page.
+    region per slot. Page 0 is the reserved dump page. A page is
+    token-major: a grouped-query layer's ``[pages, page, kv_heads * dh]``.
 
     A latent decoder's pages hold latent rows (``[pages, page, width]``), and
     its window layers draw theirs from an id space of their own,
@@ -587,7 +597,8 @@ def init_paged_kv_cache(
     d = cfg.decoder
     return [
         _layer_cache(
-            d, i, (window_pages if d.layer_kind(i) == WINDOW_ATTENTION else pages, page_size), slots, dtype
+            d, i, (window_pages if d.layer_kind(i) == WINDOW_ATTENTION else pages, page_size), slots, dtype,
+            paged=True,
         )
         for i in range(d.layers)
     ]
@@ -655,7 +666,7 @@ class DecoderAttention(nn.Module):
         masked to ``kv_valid_len`` [B] live slots.
 
         With ``block_tables`` [B, max_pages], the cache is PAGED
-        (``{"k"/"v": [num_pages, kv_heads, page, dh]}``): the single new
+        (``{"k"/"v": [num_pages, page, kv_heads * dh]}``): the single new
         token's K/V lands in the page+slot its row's table maps
         ``cache_offset`` to, and attention runs the ragged paged kernel
         (exact XLA gather reference off-TPU) over the row's pages only."""
@@ -674,7 +685,7 @@ class DecoderAttention(nn.Module):
         scale = c.attn_scale  # None: the ops' own head_dim ** -0.5
 
         if block_tables is not None:
-            page = cache["k"].shape[2]
+            page = cache["k"].shape[1]
             off = jnp.asarray(cache_offset, jnp.int32)  # [B] write position
             bidx = jnp.arange(b)
             new_k, new_v = cache["k"], cache["v"]
@@ -688,8 +699,8 @@ class DecoderAttention(nn.Module):
                     off_t = off + t
                     page_idx = block_tables[bidx, off_t // page]  # [B] page ids
                     slot = off_t % page
-                    new_k = new_k.at[page_idx, :, slot].set(k[:, :, t].astype(new_k.dtype))
-                    new_v = new_v.at[page_idx, :, slot].set(v[:, :, t].astype(new_v.dtype))
+                    new_k = new_k.at[page_idx, slot].set(k[:, :, t].reshape(b, -1).astype(new_k.dtype))
+                    new_v = new_v.at[page_idx, slot].set(v[:, :, t].reshape(b, -1).astype(new_v.dtype))
             cache = {"k": new_k, "v": new_v}
             if s == 1:
                 out = paged_attention(

@@ -4,7 +4,7 @@ The slot-era continuous scheduler gave every decode row a contiguous
 ``max_seq`` KV region, so an 8-slot pool paid ``8 x max_seq`` of HBM no
 matter how short the generations were, and admission needed a same-shape
 bucket. Here KV lives in fixed-size PAGES drawn from one shared pool
-(device arrays: ``[num_pages, kv_heads, page_size, head_dim]`` per layer,
+(device arrays: ``[num_pages, page_size, kv_heads * head_dim]`` per layer,
 see ``generate.Generator.init_pool``); each sequence owns a BLOCK TABLE of
 page ids that grows a page at a time as decode crosses page boundaries and
 is returned to the free list at retire. Long and short generations share
@@ -549,39 +549,31 @@ class RowState:
         """Tokens a page of pool ``caches`` holds, read off the first layer
         that keeps pages."""
         for kind, layer in zip(self.kinds, caches):
-            if kind == self.PAGED:
-                return layer["k"].shape[2]
-            if kind in (self.LATENT, self.WINDOW):
-                return layer["c"].shape[1]
+            if kind != self.RECURRENT:  # every page is token-major: [pages, page, width]
+                return next(iter(layer.values())).shape[1]
         raise ValueError("no layer of this decoder keeps pages: its block tables have no page to address")
 
     def install(self, i: int, dst: dict, pre: dict, slot, bt_row, page: int) -> dict:
         """Layer ``i`` of the pool with one prefilled request's batch-1
         scratch entry ``pre`` written in: a contiguous ``[1, kvh, Lb, dh]``
         (or latent ``[1, Lb, width]``) scratch scattered page by page into
-        the ids ``bt_row`` grants (entries past the prompt's live pages are
-        the dump page 0, so the scatter needs no masking; a latent decoder
+        the ids ``bt_row`` grants, token-major (entries past the prompt's live
+        pages are the dump page 0, so the scatter needs no masking; a latent decoder
         with window layers gives ``bt_row`` [2, MAXP], the full layers' table
         and the window layers'); a recurrent state copied whole into ``slot``'s row, so a
         reused slot keeps nothing of the row before."""
         kind = self.kinds[i]
         if kind == self.RECURRENT:
             return {name: arr.at[slot].set(pre[name][0].astype(arr.dtype)) for name, arr in dst.items()}
-        if kind == self.PAGED:
-            kvh, lb, dh = pre["k"].shape[1:]
-            nseg = lb // page
-            ids = bt_row[:nseg]
-            return {
-                name: arr.at[ids].set(
-                    pre[name][0].reshape(kvh, nseg, page, dh).transpose(1, 0, 2, 3).astype(arr.dtype)
-                )
-                for name, arr in dst.items()
-            }
-        nseg = pre["c"].shape[1] // page
+        if kind == self.PAGED:  # [1, kvh, Lb, dh] -> [Lb, kvh * dh], a token's heads one row
+            rows = {name: pre[name][0].transpose(1, 0, 2).reshape(pre[name].shape[2], -1) for name in dst}
+        else:
+            rows = {name: pre[name][0] for name in dst}
+        nseg = next(iter(rows.values())).shape[0] // page
         table = bt_row if bt_row.ndim == 1 else bt_row[0 if kind == self.LATENT else 1]
         ids = table[:nseg]
         return {
-            name: arr.at[ids].set(pre[name][0].reshape(nseg, page, -1).astype(arr.dtype))
+            name: arr.at[ids].set(rows[name].reshape(nseg, page, -1).astype(arr.dtype))
             for name, arr in dst.items()
         }
 

@@ -566,19 +566,25 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
 
 # -- ragged paged-attention decode (paged KV pool path) ---------------------
 #
-# The continuous VLM engine keeps KV in a pool of fixed-size pages
-# ([num_pages, kv_heads, page_size, head_dim] per layer) with a per-row
-# block table instead of one contiguous max_seq region per slot, so a
-# decode step streams only the pages a row actually owns. The kernel grid
-# is (batch*kv_heads, max_pages): the page axis runs sequentially and each
-# step DMAs ONE page picked by the scalar-prefetched block table — the
-# "ragged" part: row lengths differ, and dead pages (j beyond the row's
-# live count) skip their matmuls entirely. The page is the kernel's tile:
-# the keys a grid step covers are the pool's page size, and small pages
-# make the kernel's time its step count (paged_kv.DEFAULT_PAGE_SIZE says
-# what was measured). Every live page folds into
-# flash-style running statistics (row max, row sum, weighted V) in VMEM
-# scratch whose shapes do not depend on the row capacity. Nothing is
+# The continuous VLM engine keeps KV in a pool of fixed-size pages with a
+# per-row block table instead of one contiguous max_seq region per slot, so
+# a decode step streams only the pages a row actually owns. A page is
+# TOKEN-MAJOR ([num_pages, page_size, kv_heads * head_dim] per layer, the
+# latent pool's [pages, page, width] form): a token's K (or V) for every
+# head is one contiguous row, which is the layout both the decode step's
+# one-token scatter and this kernel's page DMA want, so XLA carries the pool
+# through the step's loop without relaying it out. The kernel grid is
+# (batch, max_pages): the page axis runs sequentially and each step DMAs ONE
+# whole page, all KV heads, picked by the scalar-prefetched block table —
+# the "ragged" part: row lengths differ, and dead pages (j beyond the row's
+# live count) skip their matmuls entirely. The heads are a static loop over
+# lane slices of that page (a head's own lane block is refused by Mosaic at
+# head_dim 64). The page is the kernel's tile: the keys a grid step covers
+# are the pool's page size, and small pages make the kernel's time its step
+# count (paged_kv.DEFAULT_PAGE_SIZE says what was measured). Every live page
+# folds into flash-style running statistics (row max, row sum, weighted V),
+# a set per KV head, in VMEM scratch whose shapes do not depend on the row
+# capacity. Nothing is
 # stored at a per-page offset: a page shorter than the 128-lane tile can
 # never land on the tile boundary Mosaic requires of a dynamic lane
 # offset, which is what an assembled [Gp, MAXP*page] logits row needed. Online
@@ -596,6 +602,16 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
 # the W == 1 case.
 
 
+def gather_pages(pages: jax.Array, block_tables: jax.Array, head_dim: int) -> jax.Array:
+    """A row's keys (or values) head-major: token-major pages
+    [P, page, kv_heads * dh] gathered by ``block_tables`` [B, MAXP] ->
+    [B, kv_heads, MAXP * page, dh]."""
+    b, maxp = block_tables.shape
+    page = pages.shape[1]
+    rows = pages[block_tables].reshape(b, maxp * page, -1, head_dim)
+    return rows.transpose(0, 2, 1, 3)
+
+
 def _q_group_pad(g: int) -> int:
     """Query-head group size padded to the f32 sublane (8) so the
     [Gp, ...] VMEM tiles are well-formed on real TPUs. The REFERENCE pads
@@ -608,15 +624,16 @@ def _q_group_pad(g: int) -> int:
 def _paged_kernel(
     bt_ref,  # [B, MAXP] int32 block table (SMEM, prefetched)
     kv_len_ref,  # [B] int32 t=0 visibility per row (SMEM, prefetched)
-    q_ref,  # [1, 1, W*Gp, dh] window-folded query heads for this (b, kv_head)
-    k_ref,  # [1, 1, page, dh] one K page
-    v_ref,  # [1, 1, page, dh] one V page
-    o_ref,  # [1, 1, W*Gp, dh]
-    acc_ref,  # VMEM [W*Gp, dh] f32 running weighted V
-    m_ref,  # VMEM [W*Gp, 128] f32 running row max (lane-broadcast)
-    l_ref,  # VMEM [W*Gp, 128] f32 running row sum (lane-broadcast)
+    q_ref,  # [1, kv_heads, W*Gp, dh] window-folded query heads of row b
+    k_ref,  # [1, page, kv_heads*dh] one K page, every head
+    v_ref,  # [1, page, kv_heads*dh] one V page, every head
+    o_ref,  # [1, kv_heads, W*Gp, dh]
+    acc_ref,  # VMEM [kv_heads, W*Gp, dh] f32 running weighted V
+    m_ref,  # VMEM [kv_heads, W*Gp, 128] f32 running row max (lane-broadcast)
+    l_ref,  # VMEM [kv_heads, W*Gp, 128] f32 running row sum (lane-broadcast)
     *,
     kv_heads: int,
+    head_dim: int,
     sm_scale: float,
     page: int,
     num_pages: int,
@@ -625,7 +642,7 @@ def _paged_kernel(
 ):
     del bt_ref  # consumed by the index maps
     j = pl.program_id(1)  # page slot within the row's block table
-    kv_len = kv_len_ref[pl.program_id(0) // kv_heads]
+    kv_len = kv_len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -638,48 +655,51 @@ def _paged_kernel(
     # partially live page are masked below.
     @pl.when(j * page < kv_len + (window - 1))
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)  # [W*Gp, dh]
-        k = k_ref[0, 0].astype(jnp.float32)  # [page, dh]
-        v = v_ref[0, 0].astype(jnp.float32)
-        # Same (rd, sd -> rs) contraction and scale-after-dot as the
-        # reference einsum, so the logits themselves round alike.
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [W*Gp, page]
+        shape = (q_ref.shape[2], page)  # [W*Gp, page] logits of one head
         visible = kv_len - j * page  # keys of this page slot 0 may see
         if window > 1:
             # row r belongs to window slot t = r // Gp and sees t more keys
-            visible = visible + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
-        s = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < visible, s, NEG_INF
-        )
+            visible = visible + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // gp
+        live = jax.lax.broadcasted_iota(jnp.int32, shape, 1) < visible
+        for h in range(kv_heads):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[0, h].astype(jnp.float32)  # [W*Gp, dh]
+            k = k_ref[0, :, lanes].astype(jnp.float32)  # [page, dh]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            # Same (rd, sd -> rs) contraction and scale-after-dot as the
+            # reference einsum, so the logits themselves round alike.
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * sm_scale
+            s = jnp.where(live, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        # NEG_INF is finite: a row with no visible key in this page keeps
-        # m_prev and adds exp(NEG_INF - m_prev) == 0. Page 0 always holds a
-        # visible key (kv_lens >= 1), so m_prev is real from then on.
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+            m_prev = m_ref[h, :, 0]
+            l_prev = l_ref[h, :, 0]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            # NEG_INF is finite: a row with no visible key in this page keeps
+            # m_prev and adds exp(NEG_INF - m_prev) == 0. Page 0 always holds a
+            # visible key (kv_lens >= 1), so m_prev is real from then on.
+            p = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + p.sum(axis=-1)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
+                p, v, preferred_element_type=jnp.float32
+            )
+            m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
 
     @pl.when(j == num_pages - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            l = l_ref[h, :, 0]
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention_varq_kernel(
     q: jax.Array,  # [B, W, H, dh] verify window, position-ordered
-    k_pages: jax.Array,  # [P, kv_heads, page, dh]
-    v_pages: jax.Array,  # [P, kv_heads, page, dh]
+    k_pages: jax.Array,  # [P, page, kv_heads*dh]
+    v_pages: jax.Array,  # [P, page, kv_heads*dh]
     block_tables: jax.Array,  # [B, MAXP] int32 page ids (dead entries: 0)
     kv_lens: jax.Array,  # [B] int32 t=0 visibility (cur token included)
     scale: float | None = None,
@@ -688,7 +708,8 @@ def paged_attention_varq_kernel(
     """Pallas ragged paged-attention over a W-token window per row (see
     block comment above)."""
     b, w, h, d = q.shape
-    _, kv_heads, page, _ = k_pages.shape
+    _, page, lanes = k_pages.shape
+    kv_heads = lanes // d
     maxp = block_tables.shape[1]
     g = h // kv_heads
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -703,6 +724,7 @@ def paged_attention_varq_kernel(
     kernel = functools.partial(
         _paged_kernel,
         kv_heads=kv_heads,
+        head_dim=d,
         sm_scale=sm_scale,
         page=page,
         num_pages=maxp,
@@ -711,26 +733,26 @@ def paged_attention_varq_kernel(
     )
 
     def q_map(i, j, bt, kl):
-        return (i // kv_heads, i % kv_heads, 0, 0)
+        return (i, 0, 0, 0)
 
     def page_map(i, j, bt, kl):
         # The block table picks which page the DMA fetches — the ragged
         # indirection lives in the index map, not the kernel body.
-        return (bt[i // kv_heads, j], i % kv_heads, 0, 0)
+        return (bt[i, j], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * kv_heads, maxp),
+        grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, rows, d), q_map),
-            pl.BlockSpec((1, 1, page, d), page_map),
-            pl.BlockSpec((1, 1, page, d), page_map),
+            pl.BlockSpec((1, kv_heads, rows, d), q_map),
+            pl.BlockSpec((1, page, lanes), page_map),
+            pl.BlockSpec((1, page, lanes), page_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), q_map),
+        out_specs=pl.BlockSpec((1, kv_heads, rows, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, d), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, _LANES), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -752,8 +774,8 @@ def paged_attention_varq_kernel(
 
 def paged_attention_kernel(
     q: jax.Array,  # [B, H, dh] one decode token per row
-    k_pages: jax.Array,  # [P, kv_heads, page, dh]
-    v_pages: jax.Array,  # [P, kv_heads, page, dh]
+    k_pages: jax.Array,  # [P, page, kv_heads*dh]
+    v_pages: jax.Array,  # [P, page, kv_heads*dh]
     block_tables: jax.Array,  # [B, MAXP] int32 page ids (dead entries: 0)
     kv_lens: jax.Array,  # [B] int32 live tokens (current token included)
     scale: float | None = None,
@@ -768,8 +790,8 @@ def paged_attention_kernel(
 
 def paged_attention_reference(
     q: jax.Array,  # [B, H, dh]
-    k_pages: jax.Array,  # [P, kv_heads, page, dh]
-    v_pages: jax.Array,  # [P, kv_heads, page, dh]
+    k_pages: jax.Array,  # [P, page, kv_heads*dh]
+    v_pages: jax.Array,  # [P, page, kv_heads*dh]
     block_tables: jax.Array,  # [B, MAXP] int32
     kv_lens: jax.Array,  # [B] int32
     scale: float | None = None,
@@ -783,14 +805,14 @@ def paged_attention_reference(
     always counts the just-written token; an all-dead row's output is
     unspecified on both paths)."""
     b, h, d = q.shape
-    _, kv_heads, page, _ = k_pages.shape
+    _, page, lanes = k_pages.shape
+    kv_heads = lanes // d
     maxp = block_tables.shape[1]
     g = h // kv_heads
     gp = _q_group_pad(g)
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    # [B, MAXP, kv_heads, page, dh] -> [B, kv_heads, MAXP*page, dh]
-    k = k_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(b, kv_heads, maxp * page, d)
-    v = v_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(b, kv_heads, maxp * page, d)
+    k = gather_pages(k_pages, block_tables, d)  # [B, kv_heads, MAXP*page, dh]
+    v = gather_pages(v_pages, block_tables, d)
     qg = q.reshape(b, kv_heads, g, d).astype(jnp.float32)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
@@ -810,8 +832,8 @@ def paged_attention_reference(
 
 def paged_attention_varq_reference(
     q: jax.Array,  # [B, W, H, dh]
-    k_pages: jax.Array,  # [P, kv_heads, page, dh]
-    v_pages: jax.Array,  # [P, kv_heads, page, dh]
+    k_pages: jax.Array,  # [P, page, kv_heads*dh]
+    v_pages: jax.Array,  # [P, page, kv_heads*dh]
     block_tables: jax.Array,  # [B, MAXP] int32
     kv_lens: jax.Array,  # [B] int32 t=0 visibility
     scale: float | None = None,
@@ -822,13 +844,14 @@ def paged_attention_varq_reference(
     at ``kv_lens + t`` bitwise (tests pin it), which is what keeps verified
     drafts token-identical to sequential decode on the reference path."""
     b, w, h, d = q.shape
-    _, kv_heads, page, _ = k_pages.shape
+    _, page, lanes = k_pages.shape
+    kv_heads = lanes // d
     maxp = block_tables.shape[1]
     g = h // kv_heads
     gp = _q_group_pad(g)
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    k = k_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(b, kv_heads, maxp * page, d)
-    v = v_pages[block_tables].transpose(0, 2, 1, 3, 4).reshape(b, kv_heads, maxp * page, d)
+    k = gather_pages(k_pages, block_tables, d)
+    v = gather_pages(v_pages, block_tables, d)
     qg = q.reshape(b, w, kv_heads, g, d).astype(jnp.float32)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, gp - g), (0, 0)))
@@ -884,7 +907,10 @@ def paged_attention(
     (interpret mode off TPU, for tests). A 4-D ``q`` ([B, W, H, dh])
     selects the variable-query-length verify-window path (speculative
     decoding); ``kv_lens`` is then the t=0 visibility and slot t sees
-    ``kv_lens + t`` keys."""
+    ``kv_lens + t`` keys. The ``attention-route`` gauge counts
+    ``paged-page:{kv_heads * dh}``, the lanes of the one whole page a grid
+    step reads, for each layer traced, on either path."""
+    _count_route("paged-page", k_pages.shape[-1])
     if _paged_kernel_usable(q.shape[-1]):
         kernel = paged_attention_varq_kernel if q.ndim == 4 else paged_attention_kernel
         return kernel(

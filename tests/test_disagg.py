@@ -36,6 +36,7 @@ import pytest
 
 from lumen_tpu.models.vlm import ChatMessage, VLMManager, migration
 from lumen_tpu.models.vlm.migration import (
+    MIGRATE_VERSION,
     commit_meta,
     manifest_csv,
     manifest_from_csv,
@@ -678,7 +679,7 @@ class TestKvPutService:
         assert "chunk" in resp.error.message
 
     def test_bad_crc_refused(self, kv_service):
-        blob, crc = pack_payload([np.zeros((1, 2, 1, 16, 4), np.float32),
+        blob, crc = pack_payload([np.zeros((1, 16, 8), np.float32),
                                   np.zeros((1, 8), np.bool_)])
         meta = dict(commit_meta(
             crc=crc ^ 0xDEAD, n_page_leaves=1, n_pages=1, n_shared=0,
@@ -709,6 +710,26 @@ class TestKvPutService:
         (resp,) = self._run(kv_service, first)
         assert resp.meta["fed_kv"] == "refused"
         assert resp.error.code == pb.ERROR_CODE_INVALID_ARGUMENT
+
+    def test_a_version_1_payload_is_refused(self, kv_service):
+        """Version 1 shipped head-major pages; this host's pool is
+        token-major, so an old peer's commit is refused by version before
+        a byte of it is read."""
+        blob, crc = pack_payload([np.zeros((1, 16, 8), np.float32),
+                                  np.zeros((1, 8), np.bool_)])
+        meta = dict(commit_meta(
+            crc=crc, n_page_leaves=1, n_pages=1, n_shared=0, page_size=16,
+            cur_tok=1, cur_len=17, n_gen=0, prompt_len=16, max_new=4,
+            temperature=0.0, top_p=1.0, do_sample=False,
+            repetition_penalty=1.0, manifest=[],
+        ), ver="1")
+        assert MIGRATE_VERSION == "2"
+        first = _kv_req(meta, payload=blob, payload_mime=BUNDLE_MIME,
+                        seq=0, total=1)
+        (resp,) = self._run(kv_service, first)
+        assert resp.meta["fed_kv"] == "refused"
+        assert resp.error.code == pb.ERROR_CODE_INVALID_ARGUMENT
+        assert "version '1'" in resp.error.message
 
     def test_rejections_count(self, kv_service):
         _reset_migration_counters()

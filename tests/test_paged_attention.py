@@ -41,12 +41,19 @@ F32_BOUND = 1e-5
 BF16_BOUND = 2.0**-7
 
 
+def _token_major(pages):
+    """Head-major pages [P, kv_heads, page, d] laid out as the pool holds
+    them: token-major [P, page, kv_heads * d], a token's heads one row."""
+    n, kvh, page, d = pages.shape
+    return pages.transpose(0, 2, 1, 3).reshape(n, page, kvh * d)
+
+
 def _case(b, h, kvh, d, page, maxp, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     n_pages = maxp * b + 1
     q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype)
+    kp = _token_major(jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype))
+    vp = _token_major(jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype))
     bt = jnp.asarray(rng.integers(0, n_pages, size=(b, maxp)), np.int32)
     kl = jnp.asarray(rng.integers(1, maxp * page + 1, size=(b,)), np.int32)
     return q, kp, vp, bt, kl
@@ -129,10 +136,10 @@ class TestKernelInterpretExact:
             tail = n % page
             if tail:
                 pid = bt_np[row, n_live - 1]
-                kp2 = kp2.at[pid, :, tail:].set(0)
-                vp2 = vp2.at[pid, :, tail:].set(0)
-                kp = kp.at[pid, :, tail:].set(0)
-                vp = vp.at[pid, :, tail:].set(0)
+                kp2 = kp2.at[pid, tail:].set(0)
+                vp2 = vp2.at[pid, tail:].set(0)
+                kp = kp.at[pid, tail:].set(0)
+                vp = vp.at[pid, tail:].set(0)
         out1 = att_mod.paged_attention_reference(q, kp, vp, bt, kl)
         out2 = att_mod.paged_attention_reference(q, kp2, vp2, bt, kl)
         np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
@@ -268,7 +275,7 @@ class TestPagedKVPool:
                 assert pool.grow(row, step)
                 grown[row] = step
         # Fill each row's live KV with per-row content through its table.
-        k_pages = np.zeros((pool.pages_total, kvh, page, d), np.float32)
+        k_pages = np.zeros((pool.pages_total, page, kvh * d), np.float32)
         v_pages = np.zeros_like(k_pages)
         own_k = [rng.standard_normal((kvh, n, d)).astype(np.float32) for n in kv_lens]
         own_v = [rng.standard_normal((kvh, n, d)).astype(np.float32) for n in kv_lens]
@@ -276,8 +283,8 @@ class TestPagedKVPool:
             for t in range(kv_lens[row]):
                 pid = pool.block_tables[row, t // page]
                 assert pid != 0
-                k_pages[pid, :, t % page] = own_k[row][:, t]
-                v_pages[pid, :, t % page] = own_v[row][:, t]
+                k_pages[pid, t % page] = own_k[row][:, t].reshape(-1)
+                v_pages[pid, t % page] = own_v[row][:, t].reshape(-1)
         q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
         out = att_mod.paged_attention_reference(
             jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
@@ -302,8 +309,8 @@ def _vcase(b, w, h, kvh, d, page, maxp, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     n_pages = maxp * b + 1
     q = jnp.asarray(rng.standard_normal((b, w, h, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype)
+    kp = _token_major(jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype))
+    vp = _token_major(jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), dtype))
     bt = jnp.asarray(rng.integers(0, n_pages, size=(b, maxp)), np.int32)
     kl = jnp.asarray(rng.integers(1, maxp * page - w + 1, size=(b,)), np.int32)
     return q, kp, vp, bt, kl
@@ -408,6 +415,80 @@ class TestVarqKernelExact:
                 np.asarray(att_mod.paged_attention_reference(q[:, 0], kp, vp, bt, kl)),
                 rtol=F32_BOUND, atol=F32_BOUND,
             )
+
+
+def _head_major_reference(q, k_heads, v_heads, bt, kl):
+    """The one-token reference as it read a head-major pool
+    [P, kv_heads, page, d], kept to pin the token-major one against."""
+    b, h, d = q.shape
+    _, kvh, page, _ = k_heads.shape
+    maxp = bt.shape[1]
+    g = h // kvh
+    gp = att_mod._q_group_pad(g)
+    k = k_heads[bt].transpose(0, 2, 1, 3, 4).reshape(b, kvh, maxp * page, d)
+    v = v_heads[bt].transpose(0, 2, 1, 3, 4).reshape(b, kvh, maxp * page, d)
+    qg = jnp.pad(q.reshape(b, kvh, g, d).astype(jnp.float32), ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k.astype(jnp.float32), preferred_element_type=jnp.float32)
+    s = s * (1.0 / np.sqrt(d))
+    live = jnp.arange(maxp * page)[None, :] < kl[:, None]
+    s = jnp.where(live[:, None, None, :], s, att_mod.NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    w = p / jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bkgs,bksd->bkgd", w, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+    return out[:, :, :g].reshape(b, h, d).astype(q.dtype)
+
+
+class TestTokenMajorPages:
+    """The pool's pages are token-major, ``[P, page, kv_heads * d]``: one grid
+    step of the kernel reads a whole page, every KV head, and slices each
+    head's lanes. At the widths the served decoders have (Qwen2-0.5B's
+    (2, 64), Qwen2-1.5B's (2, 128), Granite's attention layer's (8, 128)),
+    for one token and for a verify window, the kernel is its reference."""
+
+    @pytest.mark.parametrize("w", [1, 4], ids=["decode", "verify4"])
+    @pytest.mark.parametrize("h,kvh,d", [(14, 2, 64), (12, 2, 128), (32, 8, 128)],
+                             ids=["qwen2_0p5b", "qwen2_1p5b", "granite_attn"])
+    def test_kernel_matches_reference_at_served_widths(self, monkeypatch, w, h, kvh, d):
+        monkeypatch.setenv("LUMEN_PAGED_KERNEL", "1")
+        page, maxp = 64, 3
+        lens = [1, page - w + 1, page + 5, maxp * page - w + 1]
+        q, kp, vp, bt, _ = _vcase(len(lens), w, h, kvh, d, page, maxp, seed=h + kvh + w)
+        assert kp.shape == (len(lens) * maxp + 1, page, kvh * d)
+        bt = bt.at[0].set(0)  # an idle slot: the dump page, one key
+        kl = jnp.asarray(lens, np.int32)
+        ref = att_mod.paged_attention_varq_reference(q, kp, vp, bt, kl)
+        ker = att_mod.paged_attention(q, kp, vp, bt, kl)
+        assert ker.shape == (len(lens), w, h, d)
+        np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), rtol=F32_BOUND, atol=F32_BOUND)
+
+    @pytest.mark.parametrize("b,h,kvh,d,page,maxp", [(3, 4, 2, 8, 4, 5), (2, 12, 2, 128, 64, 4), (2, 32, 8, 128, 16, 3)])
+    def test_reference_is_the_head_major_formula_on_the_transposed_pool(self, b, h, kvh, d, page, maxp):
+        """The layout moves no number: the reference over token-major pages
+        is the head-major reference over the same pages transposed."""
+        rng = np.random.default_rng(b * h + page)
+        n_pages = b * maxp + 1
+        k_heads = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), jnp.float32)
+        v_heads = jnp.asarray(rng.standard_normal((n_pages, kvh, page, d)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+        bt = jnp.asarray(rng.integers(0, n_pages, size=(b, maxp)), np.int32)
+        kl = jnp.asarray(rng.integers(1, maxp * page + 1, size=(b,)), np.int32)
+        got = att_mod.paged_attention_reference(q, _token_major(k_heads), _token_major(v_heads), bt, kl)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(_head_major_reference(q, k_heads, v_heads, bt, kl)))
+
+    def test_the_route_gauge_counts_the_lanes_of_a_page(self, monkeypatch):
+        """``attention-route`` counts ``paged-page:{kv_heads * d}`` once for
+        each layer traced, on the reference path as on the kernel's."""
+        from lumen_tpu.utils.metrics import metrics
+
+        def count():
+            return metrics.snapshot().get("gauges", {}).get("attention-route", {}).get("paged-page:48", 0)
+
+        q, kp, vp, bt, kl = _case(2, 6, 3, 16, 4, 3, seed=8)
+        before = count()
+        att_mod.paged_attention(q, kp, vp, bt, kl)
+        monkeypatch.setenv("LUMEN_PAGED_KERNEL", "1")
+        att_mod.paged_attention(q, kp, vp, bt, kl)
+        assert count() == before + 2
 
 
 class TestPagedKVPoolSharing:

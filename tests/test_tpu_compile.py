@@ -56,9 +56,11 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _paged(fn, q_shape, maxp, page=PAGE):
+def _paged(fn, q_shape, maxp, page=PAGE, kv_heads=KV_HEADS):
+    """A paged decode kernel over token-major pages ``[P, page, kv_heads *
+    head_dim]``: a grid step's block is one whole page, every KV head."""
     rows, head_dim = q_shape[0], q_shape[-1]
-    pages = (rows * maxp + 1, KV_HEADS, page, head_dim)
+    pages = (rows * maxp + 1, page, kv_heads * head_dim)
     return (
         lambda q, k, v, bt, kl: fn(q, k, v, bt, kl, interpret=False),
         [(q_shape, BF16), (pages, BF16), (pages, BF16), ((rows, maxp), I32), ((rows,), I32)],
@@ -132,6 +134,11 @@ CASES = {
     "paged_varq_w5_default_page": _paged(
         att.paged_attention_varq_kernel, (16, 5, 12, 128), DEFAULT_MAXP, DEFAULT_PAGE_SIZE
     ),
+    # granite-4.0-h-small's attention layer: 32 query / 8 KV heads of 128, a
+    # page of 1,024 lanes
+    "paged_decode_eight_kv_heads": _paged(
+        att.paged_attention_kernel, (16, 32, 128), DEFAULT_MAXP, DEFAULT_PAGE_SIZE, kv_heads=8
+    ),
     # attention() hands the kernel nothing shorter than the crossover
     "flash_prefill_crossover": (
         lambda q, k, v: att.flash_attention(q, k, v, causal=True, interpret=False),
@@ -197,7 +204,8 @@ def _kernel_names(hlo: str) -> list[str]:
 
 @pytest.mark.parametrize(
     "name",
-    ["paged_decode", "paged_varq_w5", "paged_decode_default_page", "paged_varq_w5_default_page"],
+    ["paged_decode", "paged_varq_w5", "paged_decode_default_page", "paged_varq_w5_default_page",
+     "paged_decode_eight_kv_heads"],
 )
 def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
     """The benchmark's ``paged_attn_*`` metrics find the kernel on the
@@ -335,3 +343,57 @@ def test_a_latent_decoder_without_indexer_compiles_its_step_and_chunk_programs(v
         params, scratch, shape(1, 512, vcfg.decoder.hidden_size, dtype=BF16), shape(1, 512), shape(), shape(1)
     ).compile().as_text()
     assert all(k.startswith("ragged-dot") for k in _kernel_names(chunk)), _kernel_names(chunk)
+
+
+def test_a_grouped_query_step_moves_no_pool_sized_copy(v5e, monkeypatch):
+    """The decode block of a grouped-query decoder at Qwen2-0.5B's attention
+    widths (14 query / 2 KV heads of 64; two layers, a cut feed-forward and
+    vocabulary), sixteen slots, 64-token pages: the one-token page write and
+    the paged kernel read the pool's token-major layout, so the compiler
+    carries the pool through the block's loop as it is. No ``copy``,
+    ``copy-start``/``-done`` or ``slice-start``/``-done`` of the step has a
+    pool leaf's shape or a run of its pages (a head-major pool relaid out
+    every leaf every step, and the whole pool once a block)."""
+    import re
+
+    from lumen_tpu.models.vlm.generate import Generator
+    from lumen_tpu.models.vlm.modeling import DecoderConfig, VLMConfig, VLMModel
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)  # default_backend() is the CPU here
+    monkeypatch.delenv("LUMEN_PAGED_KERNEL", raising=False)
+    tiny = VLMConfig.tiny()
+    vcfg = VLMConfig(
+        decoder=DecoderConfig(
+            hidden_size=HIDDEN, layers=2, heads=HEADS, kv_heads=KV_HEADS, intermediate_size=512,
+            vocab_size=1024, rope_theta=1e6, max_position_embeddings=4096,
+        ),
+        vision=tiny.vision, image_token_id=1000, bos_token_id=1, eos_token_id=2, pad_token_id=0,
+    )
+    model = VLMModel(vcfg)
+    slots, max_seq, page = 16, 2048, DEFAULT_PAGE_SIZE
+    gen = Generator(model, vcfg, max_seq=max_seq, max_new_cap=512, cache_dtype=BF16)
+
+    def on_chip(tree, floats=None):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, floats if floats and jnp.issubdtype(a.dtype, jnp.floating) else a.dtype, sharding=v5e
+            ), tree,
+        )
+
+    size = vcfg.vision.image_size
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), I32), jnp.zeros((1, size, size, 3)))
+    )["params"], BF16)
+    maxp = max_seq // page
+    pool = on_chip(jax.eval_shape(lambda: gen.init_pool(slots, pages=slots * maxp + 1, page_size=page)))
+    leaf = pool["caches"][0]["k"].shape
+    assert leaf == (slots * maxp + 1, page, KV_HEADS * HEAD_DIM)
+    shape = lambda *s, dtype=I32: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
+    step = gen._step_block.lower(  # through the jit that donates the pool, as served
+        params, pool, shape(slots, maxp), shape(2, dtype=jnp.uint32), block=8
+    ).compile().as_text()
+    assert {k for k in _kernel_names(step)} == {"paged_attention_varq_kernel"}, _kernel_names(step)
+    pages = re.compile(r"bf16\[\d+," + ",".join(map(str, leaf[1:])) + r"\]")  # any run of pages
+    move = re.compile(r"^\s*(?:ROOT )?\S+ = (.*?) (copy|copy-start|copy-done|slice-start|slice-done)\(")
+    moves = [line.strip() for line in step.splitlines() if (m := move.match(line)) and pages.search(m.group(1))]
+    assert not moves, moves[:4]
