@@ -59,6 +59,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ...ops.sampling import draws
 from ...testing.faults import KV_RESUME, KV_SPILL, faults
@@ -147,6 +148,9 @@ class _Request:
     #: per-request speculative decoding tally (stream metadata).
     spec_proposed: int = 0
     spec_accepted: int = 0
+    #: a drafting decoder's drafts for this request, by the index among its
+    #: emitted tokens each stood for (stream metadata: ``mtp_drafts``)
+    mtp_drafts: "dict | None" = None
     #: decode-lane peer address for disaggregated serving: the row is
     #: exported right after prefill and its decode migrates there.
     #: Cleared after one attempt — any failure decodes locally.
@@ -240,6 +244,14 @@ class _SpillRecord:
     shared_pages: list = field(default_factory=list)
 
 
+def _weights_mesh(params):
+    """The mesh the weights are placed on, or None where they are not on one
+    (plain arrays: tests, tools)."""
+    leaves = jax.tree.leaves(params)
+    sharding = getattr(leaves[0], "sharding", None) if leaves else None
+    return sharding.mesh if isinstance(sharding, NamedSharding) else None
+
+
 class ContinuousScheduler:
     """Paged continuous-batching decode loop on a dedicated thread.
 
@@ -248,6 +260,10 @@ class ContinuousScheduler:
     token ids into ``stream_q`` as blocks complete (``_STREAM_END``
     sentinel on retirement, exposed via :meth:`submit_stream`).
     """
+
+    #: tokens a block may write a row past its length, where that is not
+    #: the block's length: a drafting decoder's steps write two positions
+    _horizon: "int | None" = None
 
     def __init__(
         self, generator, params, slots: int = 8, block: int = 8,
@@ -297,13 +313,22 @@ class ContinuousScheduler:
                 window_pages, self.page_size, slots, max_pages, dec.sliding_window
             )
         self.kv = PagedKVPool(pages, self.page_size, slots, max_pages, window=window)
-        self.pool = generator.init_pool(
-            slots, pages=pages, page_size=self.page_size, window_pages=window_pages
-        )
-        if mesh is not None:
-            from ...parallel.sharding import replicate
-
-            self.pool = replicate(self.pool, mesh)
+        # The pool, each fresh prefill scratch and the sampling key are made
+        # where the programs' outputs land (the replica's mesh, else the
+        # weights', replicated), so that a program compiles once: its first
+        # call on arrays made elsewhere would compile a second variant of it
+        # beside the one every later call runs.
+        self._home = mesh if mesh is not None else _weights_mesh(params)
+        made_home = {} if self._home is None else {
+            "out_shardings": NamedSharding(self._home, PartitionSpec())
+        }
+        self.pool = jax.jit(
+            lambda: generator.init_pool(
+                slots, pages=pages, page_size=self.page_size, window_pages=window_pages
+            ),
+            **made_home,
+        )()
+        self._new_scratch = jax.jit(generator.new_prefill_cache, static_argnums=0, **made_home)
         # Prompts longer than this (padded length) prefill through the
         # chunk lane, one chunk per job per scheduler turn; the chunk is
         # rounded to a page multiple so scratch caches scatter cleanly
@@ -347,6 +372,8 @@ class ContinuousScheduler:
         # generate under one key). Greedy requests are unaffected; a
         # sampled request's draw depends on its admission group.
         self._rng = jax.random.PRNGKey(int.from_bytes(os.urandom(4), "big"))
+        if self._home is not None:
+            self._rng = jax.device_put(self._rng, made_home["out_shardings"])
         self._slots: dict[int, _Slot] = {}  # slot idx -> live request
         self._pending: list[_Request] = []
         self._prefill_jobs: deque[_PrefillJob] = deque()
@@ -460,7 +487,15 @@ class ContinuousScheduler:
             (dec.index_topk if dec.indexer else generator.max_seq) if rows.full_latent_layers else 0
         )
         self.latent_keys_sum = 0
+        # A drafting decoder (a multi-token prediction layer) verifies a draft
+        # a row in every step, so a block emits up to twice its length and a
+        # row's pages must cover two positions a step.
+        if generator.mtp:
+            self._horizon = 2 * block
+        self.mtp_drafted = 0  # drafts checked against the token the row went on to emit
+        self.mtp_accepted = 0  # of them, emitted
         self.moe_stats = np.zeros((4,), np.int64)  # routed, held, experts touched, calls
+        self.moe_block = np.zeros((4,), np.int32)  # the last block's, as the device counted them
         # A recurrent decoder's: the state every slot holds whatever its row's
         # length, rows whose finished state was copied into a slot (inside
         # ``vlm.admit`` or ``vlm.lane_finish``), and of those the ones that replaced what a
@@ -533,6 +568,9 @@ class ContinuousScheduler:
                 out["indexer_keys_scored"] = s.indexer_keys_scored
             if s._latent_keys_cap:
                 out["latent_keys_sum"] = s.latent_keys_sum
+            if s.gen.mtp:
+                out["mtp_drafted"] = s.mtp_drafted
+                out["mtp_accepted"] = s.mtp_accepted
             if s.rows.state_layers:
                 out["state_bytes"] = s.state_bytes
                 out["state_layers"] = s.rows.state_layers
@@ -951,10 +989,12 @@ class ContinuousScheduler:
             if self.kv.window is not None:
                 bt_dev = np.stack([bt_row, self.kv.window.tables[slot]])
         try:
+            # a drafting decoder's install makes the row's first draft
+            drafter = {"params": self.params} if self.gen.mtp else {}
             self.pool = self.gen._admit(
                 self.pool, slot, caches1, tok0, seen1, length,
                 jnp.asarray(bt_dev), req.max_new, req.temperature,
-                req.top_p, req.do_sample, req.repetition_penalty,
+                req.top_p, req.do_sample, req.repetition_penalty, **drafter,
             )
         except Exception:
             self.kv.release(slot)
@@ -1004,7 +1044,7 @@ class ContinuousScheduler:
         nseg = scratch_len // self.page_size
         ids = np.zeros((nseg,), np.int32)
         ids[: len(pages)] = pages
-        caches = self.gen.new_prefill_cache(scratch_len)
+        caches = self._new_scratch(scratch_len)
         caches = self.gen._seed_prefix(caches, self.pool["caches"], jnp.asarray(ids))
         c = span - covered
         chunk = req.embeds[:, covered:span]
@@ -1096,17 +1136,10 @@ class ContinuousScheduler:
 
     def _start_chunk_job(self, req: _Request) -> _PrefillJob:
         n = int(np.asarray(req.length)[0])
-        span = int(req.embeds.shape[1])
-        # Sized to the padded span only (tail chunks shrink to fit): the
-        # scratch must stay within what a block-table row can address.
-        scratch_len = self._admit_kv_len(span)
-        chunk = self.prefill_chunk
-        if self._even_chunks:
-            share = -(-span // -(-span // chunk))  # span over its chunk count
-            chunk = min(chunk, -(-share // self.page_size) * self.page_size)
+        scratch_len, chunk = self._lane_shape(int(req.embeds.shape[1]))
         job = _PrefillJob(
             request=req,
-            caches=self.gen.new_prefill_cache(scratch_len),
+            caches=self._new_scratch(scratch_len),
             scratch_len=scratch_len,
             length=n,
             chunk=chunk,
@@ -1128,6 +1161,71 @@ class ContinuousScheduler:
             )
             job.offset = len(hit) * self.page_size
         return job
+
+    def _lane_shape(self, span: int) -> tuple[int, int]:
+        """``(scratch length, chunk)`` of a lane job over a prompt padded to
+        ``span`` positions. The scratch is sized to the padded span only
+        (tail chunks shrink to fit): it must stay within what a block-table
+        row can address."""
+        chunk = self.prefill_chunk
+        if self._even_chunks:
+            share = -(-span // -(-span // chunk))  # span over its chunk count
+            chunk = min(chunk, -(-share // self.page_size) * self.page_size)
+        return self._admit_kv_len(span), chunk
+
+    def precompile(self, span: int, width: int, length: int, max_new: int, workers) -> list:
+        """Start compiling, on ``workers``, what a prompt padded to ``span``
+        merged positions (its ids to ``width``; ``length`` of them live)
+        runs through the chunk lane up to its first decode block: its chunk
+        shapes, the finish, the install and the block over the table such a
+        row uses. Each is lowered for what its call site passes (shapes,
+        types, placement), so that those calls find it compiled; side by
+        side they take about as long as the longest alone, where one after
+        another they take the sum. Returns the futures, or none where the
+        prompt would not take the lane."""
+        if span <= self.prefill_chunk:
+            return []
+        gen, params = self.gen, self.params
+        home = None if self._home is None else NamedSharding(self._home, PartitionSpec())
+        mine = None if self.mesh is None else NamedSharding(self.mesh, PartitionSpec())
+
+        def out(x, sharding=home):  # what a program hands the next: placed where its outputs land
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        scratch_len, chunk = self._lane_shape(span)
+        caches = jax.tree.map(out, jax.eval_shape(lambda: gen.new_prefill_cache(scratch_len)))
+        sizes = [chunk if self._even_chunks else min(chunk, span - off) for off in range(0, span, chunk)]
+        hidden = gen.cfg.decoder.hidden_size
+        programs, logits = [], None
+        for c in dict.fromkeys(sizes):
+            traced = gen._prefill_chunk.trace(
+                params, caches, jax.ShapeDtypeStruct((1, c, hidden), gen.cache_dtype, sharding=home),
+                jnp.broadcast_to(jnp.arange(c)[None, :], (1, c)), jnp.asarray(0, jnp.int32),
+                jnp.asarray([c], jnp.int32),
+            )
+            programs.append(traced)
+            if c == sizes[-1]:
+                logits = out(traced.out_info[0])
+        ids = jax.ShapeDtypeStruct((1, width), jnp.int32, sharding=mine)
+        n = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=home)
+        finish = gen._chunk_finish.trace(
+            logits, jnp.asarray([0], jnp.int32), ids, n, jax.random.fold_in(jax.random.PRNGKey(0), 0),
+            jnp.asarray([0.0], jnp.float32), jnp.asarray([1.0], jnp.float32), jnp.asarray([False]),
+            jnp.asarray([1.0], jnp.float32),
+        )
+        tok0, seen = (out(x) for x in finish.out_info)
+        table = jnp.asarray(np.zeros_like(self.kv.device_tables(self.kv.max_pages)[0]))
+        drafter = {"params": params} if gen.mtp else {}
+        programs.append(finish)
+        programs.append(gen._admit.trace(
+            self.pool, 0, caches, tok0, seen, n, table, int(max_new), 0.0, 1.0, False, 1.0, **drafter
+        ))
+        need = min(length + (self._horizon or self.block), length + max_new + 1, self.kv.row_capacity())
+        bucket = self._table_bucket(self.kv.pages_for(need))
+        programs.append(gen._step_block.trace(
+            params, self.pool, jnp.asarray(self.kv.device_tables(bucket)), self._rng, block=self.block
+        ))
+        return [workers.submit(lambda t=t: t.lower().compile()) for t in programs]
 
     def _drop_job_hold(self, job: _PrefillJob) -> None:
         """Release a lane job's prefix-page hold (idempotent)."""
@@ -1746,7 +1844,7 @@ class ContinuousScheduler:
             slot = self._slots.get(idx)
             if slot is None:
                 continue
-            need = self._row_need(slot, horizon)
+            need = self._row_need(slot, horizon or self._horizon)
             while not self.kv.grow(idx, need, cow):
                 if self.prefix is not None and self.prefix.reclaim(1):
                     continue
@@ -1820,6 +1918,7 @@ class ContinuousScheduler:
         )
         t0 = time.perf_counter()
         tm0 = time.monotonic()
+        mtp = None  # a drafting decoder's block: the drafts its steps checked
         with phase(
             "vlm.block.dispatch", step=step, rows=active, bucket=bucket, sampling=int(sampling)
         ):
@@ -1837,13 +1936,17 @@ class ContinuousScheduler:
                 self.spec_turns += 1
             else:
                 ql = None
-                self.pool, self._rng, toks = self.gen._step_block(
+                out = self.gen._step_block(
                     self.params, self.pool,
                     jnp.asarray(self.kv.device_tables(bucket)),
                     self._rng, block=self.block,
                 )
+                self.pool, self._rng, toks = out[:3]
+                mtp = out[3] if self.gen.mtp else None
                 if self._indexer_layers:
-                    self._count_indexer(self.n_slots * self.block, bucket * self.page_size)
+                    # a drafting decoder's step verifies two positions a row
+                    queries = self.n_slots * self.block * (2 if mtp is not None else 1)
+                    self._count_indexer(queries, bucket * self.page_size)
         self.blocks_run += 1
         self.blocks_greedy += not sampling
         self._occ_rows += active
@@ -1858,7 +1961,16 @@ class ContinuousScheduler:
         # speculation is configured — the unconfigured transfer is
         # byte-identical to the non-speculative build.
         with phase("vlm.block.fetch", step=step):
-            if self.spec_k > 0:
+            if mtp is not None:
+                cur_tok = None
+                toks_np, n_gen, done, eos, moe_block, mtp = jax.device_get(
+                    (toks, self.pool["n_gen"], self.pool["done"], self.pool["eos"],
+                     self.pool.pop("moe_block", None), mtp)
+                )
+                if moe_block is not None:
+                    self.moe_block = moe_block
+                    self.moe_stats += moe_block
+            elif self.spec_k > 0:
                 toks_np, n_gen, done, eos, cur_tok = jax.device_get(
                     (
                         toks, self.pool["n_gen"], self.pool["done"],
@@ -1867,11 +1979,11 @@ class ContinuousScheduler:
                 )
             elif self.gen._counts_experts:
                 cur_tok = None
-                toks_np, n_gen, done, eos, moe_block = jax.device_get(
+                toks_np, n_gen, done, eos, self.moe_block = jax.device_get(
                     (toks, self.pool["n_gen"], self.pool["done"], self.pool["eos"],
-                     self.pool["moe_block"])
+                     self.pool.pop("moe_block"))
                 )
-                self.moe_stats += moe_block  # int64 here; the device's int32 is one block's
+                self.moe_stats += self.moe_block  # int64 here; the device's int32 is one block's
             else:
                 cur_tok = None
                 toks_np, n_gen, done, eos = jax.device_get(
@@ -1896,9 +2008,30 @@ class ContinuousScheduler:
         # slower chip, and the duty meter should describe that chip.
         telemetry.busy(f"device:{self.name}", tm0, time.monotonic())
         with phase("vlm.block.emit", step=step, rows=active):
+            if mtp is not None:
+                self._tally_drafts(mtp)
             self._emit_block(
                 step, active, t0, t1, width, ql, toks_np, n_gen, done, eos, cur_tok
             )
+
+    def _tally_drafts(self, drafts: dict) -> None:
+        """A drafting decoder's block: the drafts each live row checked, by
+        the index among its emitted tokens each stood for (kept on the
+        request for its stream's final metadata), and the engine's tallies."""
+        for idx, slot in self._slots.items():
+            checked = drafts["draft"][idx] >= 0
+            if not checked.any():
+                continue
+            req = slot.request
+            if req.mtp_drafts is None:
+                req.mtp_drafts = {}
+            for d, at in zip(drafts["draft"][idx][checked], drafts["draft_at"][idx][checked]):
+                req.mtp_drafts[int(at)] = int(d)
+            taken = int(drafts["accepted"][idx].sum())
+            self.mtp_drafted += int(checked.sum())
+            self.mtp_accepted += taken
+            metrics.count("vlm_mtp_drafted", int(checked.sum()))
+            metrics.count("vlm_mtp_accepted", taken)
 
     def _retire_cancelled(self) -> bool:
         """Retire the rows whose stream consumer went away; True when no
@@ -1955,20 +2088,25 @@ class ContinuousScheduler:
         # bucketing keeps compiled step shapes at log2(max_pages).
         maxp_live = max(
             (
-                self.kv.pages_for(self._row_need(s, width or None))
+                self.kv.pages_for(self._row_need(s, width or self._horizon))
                 for s in self._slots.values()
             ),
             default=1,
         )
+        return width, drafts, self._table_bucket(maxp_live)
+
+    def _table_bucket(self, pages: int) -> int:
+        """The block-table width a block whose longest row needs ``pages``
+        pages runs at: the power of two over it, or the whole table."""
         bucket = 1
-        while bucket < maxp_live:
+        while bucket < pages:
             bucket *= 2
         if bucket * 2 > self.kv.max_pages:
             # the ladder's last rung is the whole table: where max_pages is
             # no power of two (4,608 tokens of 64: 72) the rung under it
             # would be a second program for the same rows
             bucket = self.kv.max_pages
-        return width, drafts, bucket
+        return bucket
 
     def _emit_block(
         self, step, active, t0, t1, width, ql, toks_np, n_gen, done, eos, cur_tok

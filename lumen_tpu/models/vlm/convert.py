@@ -77,6 +77,13 @@ DECODER_RULES = [
     (r"model\.layers\.(\d+)\.block_sparse_moe\.output_linear\.weight", r"decoder/layers_\1/mlp/w_down", lambda w: np.ascontiguousarray(w.transpose(0, 2, 1))),
     (r"model\.layers\.(\d+)\.shared_mlp\.input_linear\.weight", r"decoder/layers_\1/mlp/shared/__fused_in__", None),
     (r"model\.layers\.(\d+)\.shared_mlp\.output_linear\.weight", r"decoder/layers_\1/mlp/shared/down_proj/kernel", linear_kernel),
+    # A multi-token prediction layer (``num_nextn_predict_layers``; index
+    # ``num_hidden_layers``, one past the decoder's last): its two input
+    # norms, the projection of their concatenation, the norm before the
+    # head; its decoder block takes the rules above.
+    (r"model\.layers\.(\d+)\.(enorm|hnorm)\.weight", r"decoder/layers_\1/\2/scale", None),
+    (r"model\.layers\.(\d+)\.eh_proj\.weight", r"decoder/layers_\1/eh_proj/kernel", linear_kernel),
+    (r"model\.layers\.(\d+)\.shared_head\.norm\.weight", r"decoder/layers_\1/head_norm/scale", None),
     (r"model\.layers\.(\d+)\.input_layernorm\.weight", r"decoder/layers_\1/input_norm/scale", None),
     (r"model\.layers\.(\d+)\.post_attention_layernorm\.weight", r"decoder/layers_\1/post_attn_norm/scale", None),
     (r"model\.norm\.weight", r"decoder/final_norm/scale", None),
@@ -131,6 +138,10 @@ DROP = [
     r"position_ids$",
     r"vision_tower\.vision_model\.embeddings\.class_embedding",
     r"vision_tower\.vision_model\.pre_layrnorm\.",
+    # a multi-token prediction layer's copies of the embedding and the head:
+    # it shares the decoder's own
+    r"model\.layers\.\d+\.embed_tokens\.weight$",
+    r"model\.layers\.\d+\.shared_head\.head\.weight$",
 ]
 
 

@@ -26,8 +26,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops.attention import gather_pages
-from ...ops.sampling import apply_repetition_penalty, sample
-from ...utils.trace import SCOPE_DECODE, SCOPE_EMBED, SCOPE_INSTALL, SCOPE_PREFILL, SCOPE_SAMPLE, SCOPE_STATE
+from ...ops.sampling import apply_repetition_penalty, draws, greedy, sample
+from ...utils.trace import (
+    SCOPE_DECODE,
+    SCOPE_EMBED,
+    SCOPE_INSTALL,
+    SCOPE_MTP,
+    SCOPE_PREFILL,
+    SCOPE_SAMPLE,
+    SCOPE_STATE,
+)
 from .modeling import VLMConfig, VLMModel, init_kv_cache, init_paged_kv_cache
 from .paged_kv import DEFAULT_PAGE_SIZE, RowState
 
@@ -110,26 +118,41 @@ class Generator:
     # -- shared pieces ------------------------------------------------------
 
     @property
+    def mtp(self) -> bool:
+        """Whether the decoder drafts with a multi-token prediction layer:
+        its decode step then verifies the draft at every step, its prefill
+        programs fill the layer's cache and its install makes a row's first
+        draft."""
+        return self.cfg.decoder.mtp_layers > 0
+
+    @property
     def _counts_experts(self) -> bool:
         """Whether the decoder's expert layers count what they route
         (``moe_stats``: a held share of the bank): the programs below then
         carry the sums to the pool, where the scheduler reads them."""
         return self.cfg.decoder.moe_held is not None
 
+    def _run(self, params, method, *args, **kwargs):
+        """``(the method's outputs, moe stats [4] int32 or None)``."""
+        if not self._counts_experts:
+            return self.model.apply({"params": params}, *args, method=method, **kwargs), None
+        out, state = self.model.apply(
+            {"params": params}, *args, method=method, mutable=["moe_stats"], **kwargs
+        )
+        return out, sum(jax.tree.leaves(state), jnp.zeros((4,), jnp.int32))
+
     def _apply(self, params, method, *args):
         """``(logits, caches, moe stats [4] int32 or None)`` of one decoder
         pass."""
-        if not self._counts_experts:
-            return (*self.model.apply({"params": params}, *args, method=method), None)
-        (logits, caches), state = self.model.apply(
-            {"params": params}, *args, method=method, mutable=["moe_stats"]
-        )
-        stats = sum(jax.tree.leaves(state), jnp.zeros((4,), jnp.int32))
+        (logits, caches), stats = self._run(params, method, *args)
         return logits, caches, stats
 
     def _decode(self, params, embeds, positions, caches, offset, kv_valid_len):
         """A prefill segment into contiguous ``caches`` (a latent decoder's
-        carry one more entry, the expert layers' counts so far)."""
+        carry one more entry, the expert layers' counts so far; a drafting
+        decoder's, :meth:`_prefill_mtp`)."""
+        if self.mtp:
+            return self._prefill_mtp(params, embeds, positions, caches, offset, kv_valid_len)
         if not self._counts_experts:
             return self._apply(
                 params, VLMModel.decode, embeds, positions, caches, offset, kv_valid_len
@@ -142,10 +165,64 @@ class Generator:
         return logits, [*new, {"moe_stats": counts.at[0].add(stats)}]
 
     def _scratch(self, batch: int, kv_len: int) -> list[dict]:
+        """Contiguous prefill caches, one a layer (a multi-token prediction
+        layer's after the decoder's), and a last entry of what a prefill
+        carries beside them: the expert layers' counts; for a decoder that
+        drafts, the last hidden state of the chunk before (``mtp_carry``)
+        and at the prompt's last position (``mtp_last``)."""
         caches = init_kv_cache(self.cfg, batch, kv_len, self.cache_dtype)
+        extra = {}
         if self._counts_experts:
-            caches.append({"moe_stats": jnp.zeros((batch, 4), jnp.int32)})
+            extra["moe_stats"] = jnp.zeros((batch, 4), jnp.int32)
+        if self.mtp:
+            hidden = jnp.zeros((batch, 1, self.cfg.decoder.hidden_size), jnp.float32)
+            extra.update(mtp_carry=hidden, mtp_last=hidden)
+        if extra:
+            caches.append(extra)
         return caches
+
+    def _mtp_pass(self, params, hidden, next_embeds, positions, cache, offset, kv_len, block_tables=None, valid=None):
+        """The multi-token prediction layer: ``(draft logits, its cache,
+        moe stats or None)``."""
+        with jax.named_scope(SCOPE_MTP):
+            (logits, cache), stats = self._run(
+                params, VLMModel.mtp, hidden, next_embeds.astype(hidden.dtype), positions, cache, offset,
+                kv_len, block_tables, valid,
+            )
+        return logits, cache, stats
+
+    def _prefill_mtp(self, params, embeds, positions, caches, offset, valid_len):
+        """A prefill segment of a drafting decoder into contiguous
+        ``caches``: the decoder over it, then the multi-token prediction
+        layer ONE POSITION BEHIND, at ``offset - 1 ..`` over the hidden
+        state there and the embedding after it (this segment's own: what the
+        last position needs comes with the next segment, and the prompt's
+        last live position's is the first token, with which the row's
+        install runs it: :meth:`_admit_impl`). The first
+        segment (``offset`` 0) runs at ``0 ..``, its last position left for
+        the next. Returns ``(logits, caches)``."""
+        n = self.cfg.decoder.layers
+        extra = caches[-1]
+        valid = positions < valid_len[:, None]
+        (logits, main, hidden), stats = self._run(
+            params, VLMModel.decode, embeds, positions, caches[:n], offset, valid_len, valid, with_hidden=True
+        )
+        first = offset == 0
+        h_in = jnp.where(first, hidden, jnp.concatenate([extra["mtp_carry"].astype(hidden.dtype), hidden[:, :-1]], 1))
+        e_in = jnp.where(first, jnp.roll(embeds, -1, axis=1), embeds)
+        at = positions - jnp.where(first, 0, 1)
+        _, mtp_cache, mtp_stats = self._mtp_pass(
+            params, h_in, e_in, at, caches[n], jnp.maximum(offset - 1, 0), valid_len, None, at < valid_len[:, None]
+        )
+        last = jnp.clip(valid_len - 1 - offset, 0, hidden.shape[1] - 1)  # [B]
+        extra = dict(
+            extra,
+            mtp_carry=hidden[:, -1:].astype(extra["mtp_carry"].dtype),
+            mtp_last=jnp.take_along_axis(hidden, last[:, None, None], axis=1).astype(extra["mtp_last"].dtype),
+        )
+        if stats is not None:
+            extra["moe_stats"] = extra["moe_stats"].at[0].add(stats + mtp_stats)
+        return logits, [*main, mtp_cache, extra]
 
     def _embed(self, params, ids):
         return self.model.apply({"params": params}, ids, method=VLMModel.embed_tokens)
@@ -163,6 +240,11 @@ class Generator:
         logits = logits.astype(jnp.float32)
         logits = apply_repetition_penalty(logits, seen, rep_penalty)
         return sample(rng, logits, temperature, top_p, do_sample)
+
+    def _greedy_next(self, logits, seen, rep_penalty):
+        """What :meth:`_sample_next` gives a row that does not draw: the
+        argmax after the repetition penalty."""
+        return greedy(apply_repetition_penalty(logits.astype(jnp.float32), seen, rep_penalty)).astype(jnp.int32)
 
     def _prefill_core(self, params, embeds, positions, lengths, kv_len: int | None = None):
         b = embeds.shape[0]
@@ -364,9 +446,15 @@ class Generator:
         # ``moe_stats``: the expert layers' counts since the last decode block
         # (admissions add theirs); a block hands the sum over as ``moe_block``
         # and starts the next from zero, so the int32 sums never grow past one
-        # block's work and the scheduler keeps the running totals in int64.
-        names = ("moe_stats", "moe_block") if self._counts_experts else ()
-        extra = {name: jnp.zeros((4,), jnp.int32) for name in names}
+        # block's work and the scheduler keeps the running totals in int64. The
+        # scheduler takes ``moe_block`` out of the pool as it reads it, so the
+        # pool has one structure whichever program made it last, and the install
+        # and the step compile one program each.
+        extra = {"moe_stats": jnp.zeros((4,), jnp.int32)} if self._counts_experts else {}
+        if self.mtp:
+            # the multi-token prediction layer's draft of the token after
+            # ``cur_tok``: made by the row's install, then by each step
+            extra["draft"] = jnp.zeros((slots,), jnp.int32)
         return dict(
             **extra,
             caches=init_paged_kv_cache(
@@ -388,12 +476,17 @@ class Generator:
     @jax.named_scope(SCOPE_INSTALL)
     def _admit_impl(
         self, pool, slot, caches1, tok0, seen1, length, bt_row,
-        max_new, temperature, top_p, do_sample, rep,
+        max_new, temperature, top_p, do_sample, rep, params=None,
     ):
         """Write one prefilled request into ``slot``, layer by layer as
         the row state's description installs it (``RowState.install``): a
         scratch of K/V or latent rows scattered into the pages ``bt_row``
-        grants, a recurrent state copied into the slot's row."""
+        grants, a recurrent state copied into the slot's row. A drafting
+        decoder's install (``params`` given) also makes the row's first
+        draft: the multi-token prediction layer at the prompt's last
+        position, over the hidden state there and the first token, which
+        writes that position's entry of the layer's cache through the row's
+        pages."""
         z = jnp.zeros((), jnp.int32)
         s = jnp.asarray(slot, jnp.int32)
         page = self._page_size_of(pool)
@@ -404,6 +497,17 @@ class Generator:
         extra = {}
         if self._counts_experts:
             extra["moe_stats"] = pool["moe_stats"] + caches1[-1]["moe_stats"].sum(0)
+        if self.mtp:
+            n = self.cfg.decoder.layers
+            at = length.astype(jnp.int32) - 1  # [1]: the prompt's last position
+            draft_logits, caches[n], stats = self._mtp_pass(
+                params, caches1[-1]["mtp_last"].astype(self.cache_dtype), self._embed(params, tok0[:, None]),
+                at[:, None], caches[n], at, at + 1, bt_row[None], jnp.ones((1, 1), bool),
+            )
+            with jax.named_scope(SCOPE_MTP):
+                extra["draft"] = pool["draft"].at[s].set(jnp.argmax(draft_logits[0, 0]).astype(jnp.int32))
+            if stats is not None:
+                extra["moe_stats"] = extra["moe_stats"] + stats
         return dict(
             **extra,
             caches=caches,
@@ -486,7 +590,10 @@ class Generator:
         row draws. What a done row would have drawn is never emitted
         (``tok`` is the pad id where ``active`` is false, ``done`` is sticky,
         and ``_admit`` / ``_resume`` set ``cur_tok`` anew), so its argmax
-        serves as well."""
+        serves as well. A drafting decoder's step verifies a draft
+        (:meth:`_step_block_drafting`)."""
+        if self.mtp:
+            return self._step_block_drafting(params, pool, block_tables, rng, block)
         cfg = self.cfg
         b = pool["cur_tok"].shape[0]
         capacity = block_tables.shape[-1] * self._page_size_of(pool)
@@ -537,6 +644,118 @@ class Generator:
             pool = dict(pool, moe_block=counts, moe_stats=jnp.zeros_like(counts))
         return pool, rng, toks.T  # [B, block]
 
+    def _step_block_drafting(self, params, pool, block_tables, rng, block: int):
+        """``_step_block_impl`` of a decoder with a multi-token prediction
+        layer: every step verifies the row's draft. The decoder runs over two
+        positions a row, the pending ``cur_tok`` and its draft. The first is
+        emitted as a plain step emits it and the next token is sampled from
+        its logits; where the row takes the argmax (it does not draw) and the
+        argmax is the draft, the draft is emitted too, and the token after it
+        is the second position's argmax. So a step emits 1 or 2 tokens, the
+        very tokens plain steps would, and a row that draws accepts no draft.
+        A rejected draft's keys lie past the row's length, where the next
+        step writes over them. Then the multi-token prediction layer runs over
+        the same two positions (the hidden state there and the token that
+        follows each) and drafts the token after the new ``cur_tok`` from the
+        position behind it. A row's first draft is its install's
+        (:meth:`_admit_impl`), so every step of every row checks one.
+
+        Returns ``(pool, rng, toks, drafts)``: ``toks`` [B, 2 * block] holds
+        each row's emitted tokens first (``n_gen`` says how many), ``drafts``
+        [B, block] arrays a step: the draft a row checked (``draft``; -1 where
+        none was: a done row, or one that finished on the token before it),
+        the index among the row's emitted tokens it
+        stood for (``draft_at``) and whether it was emitted (``accepted``)."""
+        cfg = self.cfg
+        n = cfg.decoder.layers
+        b = pool["cur_tok"].shape[0]
+        capacity = block_tables.shape[-1] * self._page_size_of(pool)
+        pad = cfg.pad_token_id
+        rows = jnp.arange(b)
+        pair = jnp.arange(2, dtype=jnp.int32)
+
+        def body(carry, _):
+            pool, rng = carry
+            with jax.named_scope(SCOPE_STATE):
+                active = ~pool["done"]
+                both = jnp.broadcast_to(active[:, None], (b, 2))
+                tok = jnp.where(active, pool["cur_tok"], pad)
+                n_gen = pool["n_gen"] + active.astype(jnp.int32)
+                seen = pool["seen"].at[rows, pool["cur_tok"]].max(active)
+                eos = pool["eos"] | (active & (pool["cur_tok"] == cfg.eos_token_id))
+                done = pool["done"] | eos | (n_gen >= pool["max_new"])
+                checked = active & ~done  # the draft stands for a token the row goes on to emit
+            with jax.named_scope(SCOPE_EMBED):
+                embeds = self._embed(params, jnp.stack([pool["cur_tok"], pool["draft"]], axis=1))
+            with jax.named_scope(SCOPE_DECODE):
+                pos = jnp.minimum(pool["cur_len"], capacity - 2)
+                (logits, main, hidden), stats = self._run(
+                    params, VLMModel.decode_paged, embeds.astype(self.cache_dtype), pos[:, None] + pair,
+                    pool["caches"][:n], block_tables, pos, pos + 1, both, with_hidden=True,
+                )
+            with jax.named_scope(SCOPE_SAMPLE):
+                rng, sub = jax.random.split(rng)
+                nxt0 = self._sample_next(
+                    sub, logits[:, 0], seen,
+                    pool["temperature"], pool["top_p"], pool["do_sample"] & active, pool["rep"],
+                ).astype(jnp.int32)
+            with jax.named_scope(SCOPE_STATE):
+                greedy = ~draws(pool["do_sample"], pool["temperature"])
+                accepted = checked & greedy & (nxt0 == pool["draft"])
+                tok2 = jnp.where(accepted, nxt0, pad)
+                n_gen = n_gen + accepted.astype(jnp.int32)
+                seen = seen.at[rows, nxt0].max(accepted)
+                eos = eos | (accepted & (nxt0 == cfg.eos_token_id))
+                done = done | eos | (n_gen >= pool["max_new"])
+            with jax.named_scope(SCOPE_SAMPLE):  # read only where the row took its draft: greedy
+                nxt1 = self._greedy_next(logits[:, 1], seen, pool["rep"])
+            with jax.named_scope(SCOPE_MTP):
+                follow = self._embed(params, jnp.stack([nxt0, nxt1], axis=1))
+            draft_logits, mtp_cache, mtp_stats = self._mtp_pass(
+                params, hidden, follow, pos[:, None] + pair, pool["caches"][n], pos, pos + 1, block_tables, both,
+            )
+            with jax.named_scope(SCOPE_MTP):
+                behind = accepted.astype(jnp.int32)  # the position of the new ``cur_tok``
+                draft = jnp.argmax(draft_logits[rows, behind], axis=-1).astype(jnp.int32)
+            with jax.named_scope(SCOPE_STATE):
+                counted = {}
+                if stats is not None:
+                    counted["moe_stats"] = pool["moe_stats"] + stats + mtp_stats
+                new_pool = dict(
+                    pool,
+                    **counted,
+                    caches=[*main, mtp_cache],
+                    cur_tok=jnp.where(accepted, nxt1, jnp.where(active, nxt0, pool["cur_tok"])),
+                    cur_len=pool["cur_len"] + active.astype(jnp.int32) + accepted.astype(jnp.int32),
+                    seen=seen,
+                    n_gen=n_gen,
+                    eos=eos,
+                    done=done,
+                    draft=jnp.where(active, draft, pool["draft"]),
+                )
+                out = dict(
+                    toks=jnp.stack([tok, tok2], axis=1),
+                    emitted=jnp.stack([active, accepted], axis=1),
+                    draft=jnp.where(checked, pool["draft"], -1),
+                    draft_at=pool["n_gen"] + 1,
+                    accepted=accepted,
+                )
+            return (new_pool, rng), out
+
+        (pool, rng), out = jax.lax.scan(body, (pool, rng), None, length=block)
+        with jax.named_scope(SCOPE_STATE):
+            toks = out["toks"].transpose(1, 0, 2).reshape(b, 2 * block)
+            emitted = out["emitted"].transpose(1, 0, 2).reshape(b, 2 * block)
+            first = jnp.argsort(~emitted, axis=1, stable=True)  # each row's emitted tokens, in order, first
+            toks = jnp.where(
+                jnp.take_along_axis(emitted, first, axis=1), jnp.take_along_axis(toks, first, axis=1), pad
+            )
+        if self._counts_experts:
+            counts = pool["moe_stats"]
+            pool = dict(pool, moe_block=counts, moe_stats=jnp.zeros_like(counts))
+        drafts = {name: out[name].T for name in ("draft", "draft_at", "accepted")}
+        return pool, rng, toks, drafts
+
     # -- chunked prefill lane ------------------------------------------------
     #
     # A long prompt prefilled in one shot would hold the scheduler loop
@@ -555,7 +774,9 @@ class Generator:
     @jax.named_scope(SCOPE_PREFILL)
     def _prefill_chunk_impl(self, params, caches, embeds, positions, offset, valid_len):
         """One prompt chunk through the decoder: writes K/V at ``offset``
-        into the donated scratch, returns this chunk's logits."""
+        into the donated scratch, returns this chunk's logits (a drafting
+        decoder's multi-token prediction layer runs one position behind:
+        :meth:`_prefill_mtp`)."""
         return self._decode(params, embeds, positions, caches, offset, valid_len)
 
     @jax.named_scope(SCOPE_SAMPLE)

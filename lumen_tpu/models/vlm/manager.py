@@ -639,6 +639,10 @@ class VLMManager:
                 plan.replicas, self.gen_slots, plan.devices_per_replica,
             )
         self._initialized = True
+        # The host's copies of the weights (as read, converted, cast) are
+        # placed on the device by now: let them go before the warm-up's
+        # compiles take their own host memory.
+        del state, converted, params
         if self.warmup:
             # Compile a photo library's dominant path up front: one caption
             # of an image with the shortest text bucket (the tower, the
@@ -649,10 +653,9 @@ class VLMManager:
             # warming those instead cost every caption deployment a second
             # set of programs it never ran (45-54 s of a compiling boot).
             t0 = time.perf_counter()
-            self.generate(
-                [ChatMessage(role="user", content=self._warmup_text())], self._warmup_image(),
-                max_new_tokens=1,
-            )
+            messages, image = [ChatMessage(role="user", content=self._warmup_text())], self._warmup_image()
+            self._compile_warmup(messages)
+            self.generate(messages, image, max_new_tokens=1)
             logger.info("vlm warmup (image path) in %.1fs", time.perf_counter() - t0)
         logger.info(
             "VLM ready: %s layers=%d hidden=%d vision_tokens=%d",
@@ -661,6 +664,38 @@ class VLMManager:
             self.cfg.decoder.hidden_size,
             self.vision_tokens,
         )
+
+    def _compile_warmup(self, messages) -> None:
+        """Compile what the warm-up caption is about to run side by side:
+        the tower and splice, and the engine's lane programs up to its first
+        decode block (``ContinuousScheduler.precompile``), each lowered for
+        the arguments it will be called with. One after another these
+        compile in about the sum of their times, side by side in about the
+        longest's. Where something is left out, or a compile here fails, the
+        warm-up compiles it as it runs."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        if len(self._engines) != 1 or not hasattr(self._prepare, "trace"):
+            return
+        ids = self._encode_prompt(messages, True)
+        width = self._bucket_len(len(ids))
+        size = self.cfg.vision.image_size
+        padded = np.full((1, width), self.cfg.pad_token_id, np.int32)
+        prepare = self._prepare.trace(
+            self.params, jnp.asarray(np.zeros((1, size, size, 3), np.uint8)), jnp.asarray(padded),
+            jnp.asarray([len(ids)], jnp.int32),
+        )
+        span = prepare.out_info[0].shape[1]
+        with ThreadPoolExecutor(8) as workers:
+            futures = [workers.submit(lambda: prepare.lower().compile())]
+            futures += self._continuous.precompile(
+                span, width, len(ids) - 1 + self.vision_tokens, 1, workers
+            )
+            for f in futures:
+                try:
+                    f.result()
+                except Exception:  # noqa: BLE001 - the warm-up compiles it instead
+                    logger.warning("vlm warm-up: a program failed to compile ahead", exc_info=True)
 
     def _warmup_text(self) -> str:
         """What the warm-up asks beside its image. "hi" where rows have the
@@ -1113,6 +1148,13 @@ def _reuse_meta(req) -> dict:
     proposed = getattr(req, "spec_proposed", 0)
     if proposed > 0:
         out["spec_accept_rate"] = round(req.spec_accepted / proposed, 3)
+    drafts = getattr(req, "mtp_drafts", None)
+    if drafts:
+        # a drafting decoder's drafts, by the index of the emitted token each
+        # stood for, as space-separated ids (metadata values are scalars):
+        # -1 where none did (the first token; one after an accepted draft,
+        # which the step sampled itself)
+        out["mtp_drafts"] = " ".join(str(drafts.get(i, -1)) for i in range(max(drafts) + 1))
     return out
 
 

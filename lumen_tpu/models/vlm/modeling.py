@@ -109,7 +109,9 @@ class LatentDims:
     """Sizes of one kind of latent attention layer: low-rank query and
     key/value projections, ``heads`` x (``nope`` no-position + ``rope``
     RoPE) query/key values a head, ``v_dim`` value values a head; the
-    rotation's base and, where the model scales it, its YaRN settings."""
+    rotation's base, where the model scales it its YaRN settings, and
+    whether it turns channels in interleaved pairs ``(2i, 2i + 1)``
+    (``rope_interleave``) or pairs ``i`` with ``i + rope / 2``."""
 
     heads: int
     q_lora: int
@@ -119,6 +121,7 @@ class LatentDims:
     v_dim: int
     rope_theta: float
     rope_scaling: YarnScaling | None = None
+    rope_interleave: bool = False
 
     @property
     def scale(self) -> float:
@@ -190,8 +193,15 @@ class DecoderConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    index_rope_interleave: bool = False
     latent_rescale: bool = False
     latent_gate: bool = False
+    # --- Multi-token prediction (``num_nextn_predict_layers``, DeepSeek-V3's
+    # form): ``mtp_layers`` layers after the last, each a full latent layer
+    # of the decoder's own kind with a cache of its own, which drafts the
+    # token after next from the last hidden state and the next token
+    # (``MTPLayer``). The decode step then verifies a draft at every step.
+    mtp_layers: int = 0
     # --- Routing rule and the held share of the bank (``parallel.moe``):
     # "sigmoid" scores with a learned selection bias, gates renormalised
     # over the selected and scaled; an ungated shared expert. ``moe_held``
@@ -250,9 +260,18 @@ class DecoderConfig:
         """Channels the convolution runs over: x, B and C side by side."""
         return self.mamba_inner + 2 * self.mamba_state
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers that keep a cache: the decoder's and its multi-token
+        prediction layers, which follow them."""
+        return self.layers + self.mtp_layers
+
     def layer_kind(self, i: int) -> str:
         """Layer ``i``'s kind; a decoder that names none is all grouped-query
-        attention (the Qwen2 layout)."""
+        attention (the Qwen2 layout). A multi-token prediction layer (``i``
+        past the decoder's own) is a full latent layer."""
+        if i >= self.layers:
+            return FULL_ATTENTION
         return self.layer_types[i] if self.layer_types else ATTENTION
 
     def layers_of(self, kind: str) -> int:
@@ -323,6 +342,8 @@ class VLMConfig:
             decoder = _dots3_decoder(text)
         elif text.get("model_type") == "axk1":
             decoder = _axk1_decoder(text)
+        elif text.get("model_type") == "glm_moe_dsa":
+            decoder = _glm5_decoder(text)
         elif text.get("model_type") == "granitemoehybrid":
             decoder = _granite_decoder(text)
         else:
@@ -405,6 +426,7 @@ def _latent_moe_decoder(t: dict[str, Any], **fields) -> DecoderConfig:
             heads=t["num_attention_heads"], q_lora=t["q_lora_rank"], kv_lora=t["kv_lora_rank"],
             nope=t["qk_nope_head_dim"], rope=t["qk_rope_head_dim"], v_dim=t["v_head_dim"],
             rope_theta=float(t["rope_theta"]), rope_scaling=YarnScaling.from_hf(t.get("rope_scaling")),
+            rope_interleave=bool(t.get("rope_interleave", False)),
         ),
         moe_routed_scale=float(t.get("routed_scaling_factor", 1.0)),
         moe_shared_gated=False,
@@ -466,6 +488,40 @@ def _axk1_decoder(t: dict[str, Any]) -> DecoderConfig:
         moe_select_bias=True,
         moe_n_group=groups,
         moe_topk_group=t.get("topk_group", groups),
+    )
+
+
+def _glm5_decoder(t: dict[str, Any]) -> DecoderConfig:
+    """``model_type`` ``glm_moe_dsa`` (GLM-5): every layer a full latent
+    layer with an indexer (no window, no gate, no rescale), RoPE without
+    scaling turned in interleaved pairs where ``rope_interleave`` /
+    ``indexer_rope_interleave`` say so, leading dense layers, sigmoid-routed
+    experts selected by score + bias (``noaux_tc``) within the ``topk_group``
+    best of ``n_group`` groups, one ungated shared expert
+    (:func:`_latent_moe_decoder`), and ``num_nextn_predict_layers``
+    multi-token prediction layers of the same kind after the last."""
+    n, mtp = t["num_hidden_layers"], t.get("num_nextn_predict_layers", 0)
+    if mtp > 1:
+        raise NotImplementedError(f"{mtp} multi-token prediction layers (one drafts inside the decode step)")
+    rope = t.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" or t.get("rope_scaling"):
+        raise NotImplementedError("a scaled rotation in a glm_moe_dsa decoder")
+    theta = float(t.get("rope_theta", rope.get("rope_theta", 10000.0)))
+    groups = t.get("n_group", 1)
+    return _latent_moe_decoder(
+        {**t, "rope_theta": theta},
+        rms_norm_eps=t.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=t.get("max_position_embeddings", 202752),
+        layer_types=(FULL_ATTENTION,) * n,
+        index_heads=t["index_n_heads"],
+        index_head_dim=t["index_head_dim"],
+        index_topk=t["index_topk"],
+        index_rope_interleave=bool(t.get("indexer_rope_interleave", False)),
+        moe_scoring=t.get("scoring_func", "sigmoid"),
+        moe_select_bias=t.get("topk_method") == "noaux_tc",
+        moe_n_group=groups,
+        moe_topk_group=t.get("topk_group", groups),
+        mtp_layers=mtp,
     )
 
 
@@ -560,7 +616,7 @@ def init_kv_cache(cfg: VLMConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) 
     concat cache (``onnxrt_backend.py:731-755``) becomes a fixed buffer.
     A Mamba layer's entry is its row state (:func:`_recurrent_cache`)."""
     d = cfg.decoder
-    return [_layer_cache(d, i, (batch, max_seq), batch, dtype) for i in range(d.layers)]
+    return [_layer_cache(d, i, (batch, max_seq), batch, dtype) for i in range(d.cache_layers)]
 
 
 def _latent_cache(d: DecoderConfig, i: int, lead: tuple[int, int], dtype) -> dict:
@@ -600,7 +656,7 @@ def init_paged_kv_cache(
             d, i, (window_pages if d.layer_kind(i) == WINDOW_ATTENTION else pages, page_size), slots, dtype,
             paged=True,
         )
-        for i in range(d.layers)
+        for i in range(d.cache_layers)
     ]
 
 
@@ -628,11 +684,18 @@ class RMSNorm(nn.Module):
 
 
 def rope_rotate(
-    x: jax.Array, positions: jax.Array, theta: float, scaling: YarnScaling | None = None
+    x: jax.Array, positions: jax.Array, theta: float, scaling: YarnScaling | None = None,
+    interleave: bool = False,
 ) -> jax.Array:
     """Rotary embedding, HF half-split convention. ``x``: [B, H, S, D],
     ``positions``: [B, S] absolute token positions. ``scaling``: the YaRN
-    frequencies and cos/sin factor in place of the plain ones."""
+    frequencies and cos/sin factor in place of the plain ones. ``interleave``:
+    channels ``(2i, 2i + 1)`` are the pair that turns at frequency ``i``;
+    they are gathered into the two halves first (the even channels, then
+    the odd), so the result is laid out half-split: a permutation that a
+    query and a key rotated alike leave out of their product."""
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     d = x.shape[-1]
     if scaling is None:
         inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
@@ -799,7 +862,10 @@ class LatentAttention(nn.Module):
     causal keys of largest score (``index_heads`` > 0). A full layer without
     one attends every causal key. The cache is this layer's
     ``_latent_cache``. In paged decode, a row ``token_valid`` leaves out (a
-    done or free slot, which keeps its last row's length) reads no key."""
+    done or free slot, which keeps its last row's length) reads no key; a
+    full layer decodes a verify window of ``S`` tokens a row too (token
+    ``t`` at ``cache_offset + t``, seeing ``kv_valid_len + t`` keys, its
+    indexer choosing its own keys)."""
 
     cfg: DecoderConfig
     kind: str
@@ -825,7 +891,7 @@ class LatentAttention(nn.Module):
         if c.latent_rescale:
             cq = cq * (c.hidden_size / d.q_lora) ** 0.5
             ckv = ckv * (c.hidden_size / d.kv_lora) ** 0.5
-        rotate = lambda t: rope_rotate(t, positions, d.rope_theta, d.rope_scaling)
+        rotate = lambda t: rope_rotate(t, positions, d.rope_theta, d.rope_scaling, d.rope_interleave)
         k_r = rotate(kv[:, None, :, d.kv_lora :])[:, 0]  # [B, S, R]
         q = dense(h * (d.nope + d.rope), "q_b_proj")(cq)
         q = q.reshape(b, s, h, d.nope + d.rope).transpose(0, 2, 1, 3)
@@ -841,36 +907,43 @@ class LatentAttention(nn.Module):
         if indexed:
             j, di = c.index_heads, c.index_head_dim
             q_i = dense(j * di, "index_q")(cq).reshape(b, s, j, di).transpose(0, 2, 1, 3)
+            spin = c.index_rope_interleave
             q_i = jnp.concatenate(
-                [rope_rotate(q_i[..., : d.rope], positions, d.rope_theta), q_i[..., d.rope :]], -1
+                [rope_rotate(q_i[..., : d.rope], positions, d.rope_theta, interleave=spin), q_i[..., d.rope :]], -1
             ).transpose(0, 2, 1, 3)  # [B, S, J, Di]
             k_i = nn.LayerNorm(epsilon=c.rms_norm_eps, name="index_k_norm", dtype=x.dtype)(
                 dense(di, "index_k")(x)
             )
             k_i = jnp.concatenate(
-                [rope_rotate(k_i[:, None, :, : d.rope], positions, d.rope_theta)[:, 0], k_i[..., d.rope :]],
+                [
+                    rope_rotate(k_i[:, None, :, : d.rope], positions, d.rope_theta, interleave=spin)[:, 0],
+                    k_i[..., d.rope :],
+                ],
                 -1,
             )  # [B, S, Di]
             w_i = dense(j, "index_w")(x).astype(jnp.float32) * (j**-0.5 * di**-0.5)  # [B, S, J]
             new["ik"] = k_i
 
         if block_tables is not None:
-            if s != 1:
-                raise NotImplementedError("latent paged decode takes one token a row")
+            if s != 1 and windowed:
+                raise NotImplementedError("a window layer decodes one token a row")
             # [B, MAXP] of this kind's id space: one table a row, or, beside
             # window layers, the full layers' and theirs
             table = block_tables if block_tables.ndim == 2 else block_tables[:, 1 if windowed else 0]
             page = cache["c"].shape[1]
             off = jnp.asarray(cache_offset, jnp.int32)
             with jax.named_scope(SCOPE_KV_WRITE):
-                page_idx = table[jnp.arange(b), off // page]
-                slot = off % page
-                cache = {
-                    name: cache[name].at[page_idx, slot].set(new[name][:, 0].astype(cache[name].dtype))
-                    for name in cache
-                }
+                for t in range(s):  # a verify window: token t at its row's off + t
+                    at = off if t == 0 else off + t
+                    page_idx = table[jnp.arange(b), at // page]
+                    slot = at % page
+                    cache = {
+                        name: cache[name].at[page_idx, slot].set(new[name][:, t].astype(cache[name].dtype))
+                        for name in cache
+                    }
             if token_valid is not None:  # its output is thrown away
-                kv_valid_len = jnp.where(token_valid.reshape(b), kv_valid_len, 0)
+                live = token_valid.reshape(b) if s == 1 else token_valid[:, 0]
+                kv_valid_len = jnp.where(live, kv_valid_len, 0)
             sel, span = None, None
             if windowed:
                 kv_start = jnp.maximum(kv_valid_len - c.sliding_window, 0)
@@ -881,13 +954,26 @@ class LatentAttention(nn.Module):
                 if not indexed:
                     _count_route("latent-all", slots)
                 elif slots > c.index_topk:
-                    scores = la.indexer_scores(q_i[:, 0], w_i[:, 0], cache["ik"], table)
-                    ok = jnp.arange(slots, dtype=jnp.int32)[None, :] < kv_valid_len[:, None]
+                    if s == 1:
+                        scores = la.indexer_scores(q_i[:, 0], w_i[:, 0], cache["ik"], table)
+                        ok = jnp.arange(slots, dtype=jnp.int32)[None, :] < kv_valid_len[:, None]
+                    else:  # every query of the window against one read of the index keys
+                        scores = la.indexer_scores(q_i, w_i, cache["ik"], table)  # [B, S, slots]
+                        seen = kv_valid_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+                        ok = jnp.arange(slots, dtype=jnp.int32)[None, None, :] < seen[:, :, None]
                     sel = la.topk_select(scores, ok, c.index_topk)
-            out = la.latent_paged_attention(
-                q_c[:, :, 0], q_r[:, :, 0], cache["c"], cache["r"], table,
-                kv_valid_len, kv_start, sel, scale=d.scale, span=span,
-            )[:, :, None]  # [B, H, 1, C]
+            if s > 1:
+                _count_route("verify_width", s)
+            if s == 1:
+                out = la.latent_paged_attention(
+                    q_c[:, :, 0], q_r[:, :, 0], cache["c"], cache["r"], table,
+                    kv_valid_len, kv_start, sel, scale=d.scale, span=span,
+                )[:, :, None]  # [B, H, 1, C]
+            else:
+                out = la.latent_paged_attention(
+                    q_c.transpose(0, 2, 1, 3), q_r.transpose(0, 2, 1, 3), cache["c"], cache["r"], table,
+                    kv_valid_len, kv_start, sel, scale=d.scale,
+                ).transpose(0, 2, 1, 3)  # [B, H, S, C]
         else:
             if cache is not None:
                 off = jnp.asarray(cache_offset, jnp.int32)
@@ -1105,6 +1191,34 @@ class MoEFFN(nn.Module):
         return y
 
 
+def _layer(c: DecoderConfig, idx: int, x, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid):
+    """Layer ``idx``'s mixer and feed-forward, each behind its norm and
+    around its residual. Called from the compact method of the module that
+    holds the layer's parameters (:class:`DecoderLayer`, :class:`MTPLayer`),
+    whose children the modules made here become."""
+    kind = c.layer_kind(idx)
+    normed = RMSNorm(c.rms_norm_eps, name="input_norm")(x)
+    if kind == MAMBA:
+        h, cache = Mamba2Mixer(c, name="mamba")(
+            normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+        )
+    else:
+        if kind in LATENT_KINDS:
+            h, cache = LatentAttention(c, kind, name="attn")(
+                normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+            )
+        else:
+            h, cache = DecoderAttention(c, name="attn")(
+                normed, positions, cache, cache_offset, kv_valid_len, block_tables
+            )
+    r = c.residual_multiplier
+    x = x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
+    y = RMSNorm(c.rms_norm_eps, name="post_attn_norm")(x)
+    f = MoEFFN(c, name="mlp")(y, token_valid) if c.is_moe_layer(idx) else SwiGLU(c, name="mlp")(y)
+    x = x + (f if r == 1.0 else f * jnp.asarray(r, f.dtype))
+    return x, cache
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     layer_idx: int = 0
@@ -1113,28 +1227,39 @@ class DecoderLayer(nn.Module):
     def __call__(
         self, x, positions, cache, cache_offset, kv_valid_len, block_tables=None, token_valid=None
     ):
+        return _layer(
+            self.cfg, self.layer_idx, x, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+        )
+
+
+class MTPLayer(nn.Module):
+    """A multi-token prediction layer (DeepSeek-V3's form; GLM-5's
+    ``num_nextn_predict_layers``): at position ``i``, ``h'_i = Layer(W_eh
+    [RMSNorm_e(e_{i+1}) ; RMSNorm_h(h_i)])``, with ``h_i`` the decoder's last
+    hidden state before its final norm and ``e_{i+1}`` the embedding of the
+    token that follows; ``Layer`` is layer ``layer_idx`` (one past the
+    decoder's last) of the decoder's own kind, with a cache of its own and
+    experts of its own. Returns ``RMSNorm_s(h'_i)``, which the decoder's
+    head turns into the logits of the token after next (the draft)."""
+
+    cfg: DecoderConfig
+    layer_idx: int
+
+    @nn.compact
+    def __call__(
+        self, hidden, next_embeds, positions, cache, cache_offset, kv_valid_len, block_tables=None,
+        token_valid=None,
+    ):
         c = self.cfg
-        kind = c.layer_kind(self.layer_idx)
-        normed = RMSNorm(c.rms_norm_eps, name="input_norm")(x)
-        if kind == MAMBA:
-            h, cache = Mamba2Mixer(c, name="mamba")(
-                normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
-            )
-        else:
-            if kind in LATENT_KINDS:
-                h, cache = LatentAttention(c, kind, name="attn")(
-                    normed, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
-                )
-            else:
-                h, cache = DecoderAttention(c, name="attn")(
-                    normed, positions, cache, cache_offset, kv_valid_len, block_tables
-                )
-        r = c.residual_multiplier
-        x = x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
-        y = RMSNorm(c.rms_norm_eps, name="post_attn_norm")(x)
-        f = MoEFFN(c, name="mlp")(y, token_valid) if c.is_moe_layer(self.layer_idx) else SwiGLU(c, name="mlp")(y)
-        x = x + (f if r == 1.0 else f * jnp.asarray(r, f.dtype))
-        return x, cache
+        e = RMSNorm(c.rms_norm_eps, name="enorm")(next_embeds.astype(hidden.dtype))
+        h = RMSNorm(c.rms_norm_eps, name="hnorm")(hidden)
+        x = nn.Dense(c.hidden_size, use_bias=False, name="eh_proj", dtype=hidden.dtype)(
+            jnp.concatenate([e, h], axis=-1)
+        )
+        x, cache = _layer(
+            c, self.layer_idx, x, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+        )
+        return RMSNorm(c.rms_norm_eps, name="head_norm")(x), cache
 
 
 class Decoder(nn.Module):
@@ -1155,6 +1280,8 @@ class Decoder(nn.Module):
             # _dense so weight_quant="int8" applies to the untied lm_head
             # (convert.quantize_decoder_int8 rewrites its kernel to q+scale).
             self.lm_head = _dense(c, c.vocab_size, "lm_head", False, None)
+        if c.mtp_layers:
+            self.mtp_layer = MTPLayer(c, layer_idx=c.layers, name=f"layers_{c.layers}")
 
     def embed(self, input_ids: jax.Array) -> jax.Array:
         """Token rows only carry ``embedding_multiplier``: image rows are
@@ -1172,7 +1299,12 @@ class Decoder(nn.Module):
         kv_valid_len: jax.Array,
         block_tables: jax.Array | None = None,
         token_valid: jax.Array | None = None,
+        with_hidden: bool = False,
     ) -> tuple[jax.Array, list[dict] | None]:
+        """``(logits, caches)``, and with ``with_hidden`` the last layer's
+        output before the final norm (what a multi-token prediction layer
+        reads). ``caches`` may hold entries past the decoder's own layers
+        (the multi-token prediction layers'): they pass through untouched."""
         x = embeds
         new_caches: list[dict] = []
         for i, block in enumerate(self.blocks):
@@ -1181,14 +1313,33 @@ class Decoder(nn.Module):
                 x, positions, layer_cache, cache_offset, kv_valid_len, block_tables, token_valid
             )
             new_caches.append(layer_cache)
-        x = self.final_norm(x)
+        if caches is not None:
+            new_caches.extend(caches[len(self.blocks) :])
+        logits = self.head(self.final_norm(x))
+        if with_hidden:
+            return logits, (new_caches if caches is not None else None), x
+        return logits, (new_caches if caches is not None else None)
+
+    def head(self, x: jax.Array) -> jax.Array:
         if self.cfg.tie_word_embeddings:
             logits = x @ self.embed_tokens.embedding.T.astype(x.dtype)
         else:
             logits = self.lm_head(x)
         if self.cfg.logits_scaling != 1.0:
             logits = logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
-        return logits, (new_caches if caches is not None else None)
+        return logits
+
+    def mtp(
+        self, hidden, next_embeds, positions, cache, cache_offset, kv_valid_len, block_tables=None,
+        token_valid=None,
+    ):
+        """The multi-token prediction layer over ``hidden`` [B, S, H] (the
+        decoder's, before its final norm) and the next tokens' embeddings:
+        ``(draft logits, the layer's cache)``."""
+        x, cache = self.mtp_layer(
+            hidden, next_embeds, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+        )
+        return self.head(x), cache
 
 
 class VisionEncoder(nn.Module):
@@ -1231,13 +1382,16 @@ class VLMModel(nn.Module):
     def embed_tokens(self, input_ids: jax.Array) -> jax.Array:
         return self.decoder.embed(input_ids)
 
-    def decode(self, embeds, positions, caches, cache_offset, kv_valid_len, token_valid=None):
+    def decode(
+        self, embeds, positions, caches, cache_offset, kv_valid_len, token_valid=None, with_hidden=False
+    ):
         return self.decoder(
-            embeds, positions, caches, cache_offset, kv_valid_len, None, token_valid
+            embeds, positions, caches, cache_offset, kv_valid_len, None, token_valid, with_hidden
         )
 
     def decode_paged(
-        self, embeds, positions, caches, block_tables, cache_offset, kv_valid_len, token_valid=None
+        self, embeds, positions, caches, block_tables, cache_offset, kv_valid_len, token_valid=None,
+        with_hidden=False,
     ):
         """Single-token decode against the paged KV pool (continuous
         engine): ``caches`` from :func:`init_paged_kv_cache`,
@@ -1245,12 +1399,25 @@ class VLMModel(nn.Module):
         for a latent decoder: the full layers' table and the window
         layers')."""
         return self.decoder(
-            embeds, positions, caches, cache_offset, kv_valid_len, block_tables, token_valid
+            embeds, positions, caches, cache_offset, kv_valid_len, block_tables, token_valid, with_hidden
         )
 
-    def __call__(self, input_ids: jax.Array, pixel_values: jax.Array | None = None):
+    def mtp(
+        self, hidden, next_embeds, positions, cache, cache_offset, kv_valid_len, block_tables=None,
+        token_valid=None,
+    ):
+        return self.decoder.mtp(
+            hidden, next_embeds, positions, cache, cache_offset, kv_valid_len, block_tables, token_valid
+        )
+
+    def __call__(self, input_ids: jax.Array, pixel_values: jax.Array | None = None, with_drafts: bool = False):
         """Cacheless forward (tests / loss): embeds ids, optionally splices
-        one image per sample at the image-token position, returns logits."""
+        one image per sample at the image-token position, returns logits. A
+        decoder with a multi-token prediction layer runs it too (so that
+        ``init`` makes its parameters): at position ``i`` over the hidden
+        state there and the embedding at ``i + 1`` (the last position's is
+        the first's, rolled round: its draft is no prediction); ``with_drafts``
+        returns ``(logits, draft logits)``."""
         embeds = self.decoder.embed(input_ids)
         if pixel_values is not None:
             vis = self.vision(pixel_values)
@@ -1260,10 +1427,13 @@ class VLMModel(nn.Module):
         else:
             b, s = input_ids.shape
             positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        logits, _ = self.decoder(
-            embeds, positions, None, None, jnp.full((embeds.shape[0],), embeds.shape[1])
-        )
-        return logits
+        lengths = jnp.full((embeds.shape[0],), embeds.shape[1])
+        if not self.cfg.decoder.mtp_layers:
+            logits, _ = self.decoder(embeds, positions, None, None, lengths)
+            return logits
+        logits, _, hidden = self.decoder(embeds, positions, None, None, lengths, with_hidden=True)
+        drafts, _ = self.decoder.mtp(hidden, jnp.roll(embeds, -1, axis=1), positions, None, None, lengths)
+        return (logits, drafts) if with_drafts else logits
 
 
 def merge_image_embeddings(

@@ -449,15 +449,20 @@ class RowState:
     whatever the row's length; no page at all). ``kinds`` names each layer's.
 
     Only a row of paged K/V alone can be shared or exported whole today
-    (prefix cache, spill tier, speculative verify, migration): window layers
-    free the pages a shared prefix would need, a recurrent state is not a
-    function of a page-aligned prefix that a later row could attach (it
-    would need a snapshot at every page boundary), and a latent decoder
-    without window layers keeps every page of a row, so nothing of principle
-    stands in its way: its ``c`` / ``r`` (/ ``ik``) leaves are simply not
-    what the prefix cache's seeding, the spill tier's arena or the
-    migration wire format read and write yet. :meth:`refuse` is the one place
-    that says so."""
+    (prefix cache, spill tier, the host's n-gram verify turns, migration):
+    window layers free the pages a shared prefix would need, a recurrent
+    state is not a function of a page-aligned prefix that a later row could
+    attach (it would need a snapshot at every page boundary), and a latent
+    decoder without window layers keeps every page of a row, so nothing of
+    principle stands in its way: its ``c`` / ``r`` (/ ``ik``) leaves are
+    simply not what the prefix cache's seeding, the spill tier's arena, the
+    n-gram verify program or the migration wire format read and write yet.
+    Such a decoder does verify its own multi-token prediction drafts, inside
+    the decode step (``Generator._step_block_impl``): a rejected draft's
+    slot lies past the row's length, so nothing is rolled back. A
+    multi-token prediction layer is one more full latent layer here (its
+    pages under the row's block table). :meth:`refuse` is the one place that
+    says what is refused."""
 
     PAGED, LATENT, WINDOW, RECURRENT = "paged_kv", "latent_pages", "latent_window_pages", "recurrent"
 
@@ -466,7 +471,7 @@ class RowState:
 
         d = self.decoder = cfg.decoder
         by_type = {FULL_ATTENTION: self.LATENT, WINDOW_ATTENTION: self.WINDOW, MAMBA: self.RECURRENT}
-        self.kinds = tuple(by_type.get(d.layer_kind(i), self.PAGED) for i in range(d.layers))
+        self.kinds = tuple(by_type.get(d.layer_kind(i), self.PAGED) for i in range(d.cache_layers))
 
     def layers_of(self, kind: str) -> int:
         return self.kinds.count(kind)
@@ -503,8 +508,9 @@ class RowState:
             held, why = "latent", "window layers free the pages a shared prefix would need"
         elif self.full_latent_layers:
             held, why = "latent", (
-                "a row keeps all its latent pages, but the prefix cache, the spill tier and the "
-                "migration wire format do not read or write latent leaves yet"
+                "a row keeps all its latent pages, but the prefix cache, the spill tier, the host's n-gram "
+                "verify turns and the migration wire format do not read or write latent leaves yet; "
+                "the decoder's own multi-token prediction drafts are verified inside the decode step"
             )
         else:
             held, why = "recurrent", (

@@ -12,16 +12,20 @@ result afterwards; nothing per head is ever expanded from the cache.
 Three callers, one mathematics:
 
 - paged decode (:func:`latent_paged_attention`): one query token a row
-  against its pages ``{"c": [P, page, C], "r": [P, page, R]}``. A window
+  against its pages ``{"c": [P, page, C], "r": [P, page, R]}``, or a verify
+  window of ``W`` consecutive tokens a row (query ``w`` sees ``w`` keys more
+  than the first: a drafted token checked in the same step). A window
   layer passes ``kv_starts`` and visits only the ``span`` pages its window
-  can touch; a full layer may pass ``sel``, the keys its indexer picked.
+  can touch; a full layer may pass ``sel``, the keys its indexer picked
+  (one set a query).
   A grid step covers ``GRID_KEYS`` keys of the row's pages and copies only
   those that hold a key the row may see.
   Pallas kernel ``latent_paged_attention_kernel`` on TPU, the exact XLA
   gather elsewhere (the rule of ``ops.attention.paged_attention``:
   ``LUMEN_PAGED_KERNEL=0`` disables, ``=1`` forces interpret mode);
-- the indexer's scoring pass for such a row (:func:`indexer_scores`,
-  kernel ``indexer_scores_kernel``) and the selection (:func:`topk_select`);
+- the indexer's scoring pass for such a row, every query of its window
+  against one read of its index keys (:func:`indexer_scores`, kernel
+  ``indexer_scores_kernel``), and the selection (:func:`topk_select`);
 - prefill chunks and the cacheless forward (:func:`latent_prefill_attention`,
   :func:`indexer_scores_dense`), dense XLA under the same masks.
 
@@ -86,18 +90,20 @@ def grid_pages(page: int, steps: int) -> int:
 
 def _latent_paged_kernel(
     bt_ref,  # [B, MAXP] int32 block table (SMEM, prefetched)
-    len_ref,  # [B] int32 keys visible to the row (its own token included)
+    len_ref,  # [B] int32 keys visible to the row's last query (its own token included)
     start_ref,  # [B] int32 first visible key (0 in a full layer)
-    qc_ref,  # [1, Hp, C] query folded through W_uk
-    qr_ref,  # [1, Hp, R] rotated position query
+    qc_ref,  # [1, nq*Hp, C] query folded through W_uk, the window's queries one after another
+    qr_ref,  # [1, nq*Hp, R] rotated position query
     c_hbm,  # [P, page, C] the pool's latent rows, left in HBM
-    *rest,  # k x r_ref [1, page, R], [sel_ref [1, 1, 1, k*page] f32], o_ref, c_buf, sems, flags, acc, m, l
+    *rest,  # k x r_ref [1, page, R], [sel_ref [1, 1, nq, k*page] f32], o_ref, c_buf, sems, flags, acc, m, l
     sm_scale: float,
     page: int,
     steps: int,
     maxp: int,
     k: int,
     has_sel: bool,
+    nq: int,
+    hp: int,
 ):
     r_refs, rest = rest[:k], rest[k:]
     if has_sel:
@@ -174,12 +180,22 @@ def _latent_paged_kernel(
         ) + jax.lax.dot_general(
             qr_ref[0], r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        s = s * sm_scale  # [Hp, k*page]
+        s = s * sm_scale  # [nq*Hp, k*page]
         pos = (first + g * k) * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # keys of slots past the last page copied are not the row's: masked too
         ok = (pos >= start) & (pos < jnp.minimum(kv_len, (last + 1) * page))
+        if nq > 1:
+            # query w of the window sees its own token and those before it
+            query = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // hp
+            ok = ok & (pos < kv_len - (nq - 1) + query)
         if has_sel:
-            ok = ok & (sel_ref[0, 0] > 0.0)
+            if nq > 1:
+                picked = jnp.zeros(s.shape, jnp.float32)
+                for w in range(nq):
+                    picked = jnp.where(query == w, sel_ref[0, 0, w : w + 1], picked)
+                ok = ok & (picked > 0.0)
+            else:
+                ok = ok & (sel_ref[0, 0] > 0.0)
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
@@ -218,21 +234,24 @@ def _row_groups(first, last, k: int):
 
 @functools.partial(jax.jit, static_argnames=("scale", "span", "interpret"))
 def latent_paged_attention_kernel(
-    qc: jax.Array,  # [B, H, C]
-    qr: jax.Array,  # [B, H, R]
+    qc: jax.Array,  # [B, H, C], or [B, W, H, C]: a verify window of W queries
+    qr: jax.Array,  # [B, H, R] / [B, W, H, R]
     c_pages: jax.Array,  # [P, page, C]
     r_pages: jax.Array,  # [P, page, R]
     block_tables: jax.Array,  # [B, MAXP] int32 (dead entries: 0)
-    kv_lens: jax.Array,  # [B] int32
+    kv_lens: jax.Array,  # [B] int32 keys the window's FIRST query sees (0: the row reads none)
     kv_starts: jax.Array,  # [B] int32
-    sel: jax.Array | None = None,  # [B, MAXP*page] bool: keys the indexer picked (kv_starts 0)
+    sel: jax.Array | None = None,  # [B, MAXP*page] / [B, W, MAXP*page] bool: keys the indexer picked (kv_starts 0)
     *,
     scale: float,
     span: int | None = None,  # window layer: pages visited from the start's page
     interpret: bool = False,
 ) -> jax.Array:
-    """Pallas absorbed latent decode attention: ``[B, H, C]`` weighted
-    latent rows. Grid ``(B, ceil(steps / k))``: a step covers ``k`` pages of
+    """Pallas absorbed latent decode attention: ``[B, H, C]`` (``[B, W, H,
+    C]``) weighted latent rows. A window's queries are rows of one grid step
+    (``W * Hp`` of them, each query's own causal length and own selection
+    masked in the step), so a page the window reads is copied once for all
+    of them. Grid ``(B, ceil(steps / k))``: a step covers ``k`` pages of
     the row (:func:`grid_pages`), ``steps`` being the whole table for a full
     layer and ``span`` pages from the window's first for a window layer. A
     step reads only the pages that hold a key the row may see (from
@@ -242,7 +261,8 @@ def latent_paged_attention_kernel(
     specs, one a page, whose index stays where it was for a slot past the
     row's last page, so nothing is copied for it (Mosaic refuses a copy of
     one page of a 64-wide pool)."""
-    b, h, c_dim = qc.shape
+    nq = qc.shape[1] if qc.ndim == 4 else 1
+    b, h, c_dim = qc.shape[0], qc.shape[-2], qc.shape[-1]
     page = c_pages.shape[1]
     maxp = block_tables.shape[1]
     steps = maxp if span is None else min(span, maxp)
@@ -250,9 +270,12 @@ def latent_paged_attention_kernel(
     groups = -(-steps // k)
     hp = _q_group_pad(h)
     if hp != h:
-        qc = jnp.pad(qc, ((0, 0), (0, hp - h), (0, 0)))
-        qr = jnp.pad(qr, ((0, 0), (0, hp - h), (0, 0)))
+        pad = ((0, 0),) * (qc.ndim - 2) + ((0, hp - h), (0, 0))
+        qc, qr = jnp.pad(qc, pad), jnp.pad(qr, pad)
     r_dim = qr.shape[-1]
+    if nq > 1:  # a window's queries are rows of one step; the pages to read are the last query's
+        qc, qr = qc.reshape(b, nq * hp, c_dim), qr.reshape(b, nq * hp, r_dim)
+        kv_lens = jnp.where(kv_lens > 0, kv_lens + nq - 1, 0)
 
     def at(kl, ks, i, j):
         """The row's group for grid step ``j``: past its last, its last."""
@@ -279,20 +302,23 @@ def latent_paged_attention_kernel(
         return (i, at(kl, ks, i, j)[2], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, hp, c_dim), q_map),
-        pl.BlockSpec((1, hp, r_dim), q_map),
+        pl.BlockSpec((1, nq * hp, c_dim), q_map),
+        pl.BlockSpec((1, nq * hp, r_dim), q_map),
         pl.BlockSpec(memory_space=pl.ANY),
         *(pl.BlockSpec((1, page, r_dim), r_map(n)) for n in range(k)),
     ]
     operands = [qc, qr, c_pages, *([r_pages] * k)]
     if sel is not None:
         keep = sel.astype(jnp.float32)
-        keep = jnp.pad(keep, ((0, 0), (0, groups * k * page - keep.shape[1])))
-        in_specs.append(pl.BlockSpec((1, 1, 1, k * page), sel_map))
-        operands.append(keep.reshape(b, groups, 1, k * page))
+        keep = jnp.pad(keep, ((0, 0),) * (keep.ndim - 1) + ((0, groups * k * page - keep.shape[-1]),))
+        in_specs.append(pl.BlockSpec((1, 1, nq, k * page), sel_map))
+        if nq > 1:
+            operands.append(keep.reshape(b, nq, groups, k * page).transpose(0, 2, 1, 3))
+        else:
+            operands.append(keep.reshape(b, groups, 1, k * page))
     kernel = functools.partial(
         _latent_paged_kernel, sm_scale=scale, page=page, steps=steps, maxp=maxp, k=k,
-        has_sel=sel is not None,
+        has_sel=sel is not None, nq=nq, hp=hp,
     )
     out = pl.pallas_call(
         kernel,
@@ -300,17 +326,17 @@ def latent_paged_attention_kernel(
             num_scalar_prefetch=3,
             grid=(b, groups),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hp, c_dim), q_map),
+            out_specs=pl.BlockSpec((1, nq * hp, c_dim), q_map),
             scratch_shapes=[
                 pltpu.VMEM((2, k * page, c_dim), c_pages.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((2,), jnp.int32),
-                pltpu.VMEM((hp, c_dim), jnp.float32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((nq * hp, c_dim), jnp.float32),
+                pltpu.VMEM((nq * hp, _LANES), jnp.float32),
+                pltpu.VMEM((nq * hp, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hp, c_dim), qc.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nq * hp, c_dim), qc.dtype),
         # one sequence over rows too: a step copies the next row's first group
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
@@ -318,6 +344,8 @@ def latent_paged_attention_kernel(
         block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
         kv_starts.astype(jnp.int32), *operands,
     )
+    if nq > 1:
+        return out.reshape(b, nq, hp, c_dim)[:, :, :h]
     return out[:, :h]
 
 
@@ -327,7 +355,16 @@ def latent_paged_attention_reference(
 ) -> jax.Array:
     """Exact XLA reference of the kernel above (the CPU / tier-1 serving
     path): gather the pages the kernel would visit, mask, one-pass softmax
-    in float32."""
+    in float32. A window (``qc`` [B, W, H, C]) is its queries one by one."""
+    if qc.ndim == 4:
+        return jnp.stack([
+            latent_paged_attention_reference(
+                qc[:, w], qr[:, w], c_pages, r_pages, block_tables,
+                jnp.where(kv_lens > 0, kv_lens + w, 0), kv_starts, None if sel is None else sel[:, w],
+                scale=scale, span=span,
+            )
+            for w in range(qc.shape[1])
+        ], axis=1)
     b = qc.shape[0]
     page = c_pages.shape[1]
     maxp = block_tables.shape[1]
@@ -375,54 +412,69 @@ def latent_paged_attention(
 # -- the indexer's scores for a decode row ------------------------------------
 
 
-def _indexer_kernel(bt_ref, q_ref, w_ref, k_ref, o_ref):
+def _indexer_kernel(bt_ref, q_ref, w_ref, k_ref, o_ref, *, nq: int, jp: int):
     del bt_ref
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [J, page]
-    o_ref[0, 0] = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+    )  # [nq*J, page]
+    weighted = jnp.maximum(s, 0.0) * w_ref[0]
+    if nq == 1:
+        o_ref[0, 0] = jnp.sum(weighted, axis=0, keepdims=True)
+    else:
+        for w in range(nq):
+            o_ref[0, 0, w : w + 1] = jnp.sum(weighted[w * jp : (w + 1) * jp], axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def indexer_scores_kernel(
-    qi: jax.Array,  # [B, J, Di] index queries of the row's token
-    w: jax.Array,  # [B, J] float32 head weights, scaled
+    qi: jax.Array,  # [B, J, Di] index queries of the row's token, or [B, W, J, Di] of a window's
+    w: jax.Array,  # [B, J] / [B, W, J] float32 head weights, scaled
     ik_pages: jax.Array,  # [P, page, Di] index keys
     block_tables: jax.Array,  # [B, MAXP]
     interpret: bool = False,
 ) -> jax.Array:
     """``I(s) = sum_j w_j relu(q_j . k_s)`` for every slot of the row's block
-    table: ``[B, MAXP*page]`` float32 (slots past the row's length hold what
-    the dump page holds: the caller masks by length)."""
-    b, j, di = qi.shape
+    table: ``[B, MAXP*page]`` (``[B, W, MAXP*page]``) float32 (slots past the
+    row's length hold what the dump page holds: the caller masks by length).
+    A window's queries are rows of one grid step: each index key is read
+    once for all of them."""
+    nq = qi.shape[1] if qi.ndim == 4 else 1
+    b, j, di = qi.shape[0], qi.shape[-2], qi.shape[-1]
     page = ik_pages.shape[1]
     maxp = block_tables.shape[1]
     jp = _q_group_pad(j)
     if jp != j:
-        qi = jnp.pad(qi, ((0, 0), (0, jp - j), (0, 0)))
-        w = jnp.pad(w, ((0, 0), (0, jp - j)))
+        qi = jnp.pad(qi, ((0, 0),) * (qi.ndim - 2) + ((0, jp - j), (0, 0)))
+        w = jnp.pad(w, ((0, 0),) * (w.ndim - 1) + ((0, jp - j),))
+    if nq > 1:
+        qi, w = qi.reshape(b, nq * jp, di), w.reshape(b, nq * jp)
     out = pl.pallas_call(
-        _indexer_kernel,
+        functools.partial(_indexer_kernel, nq=nq, jp=jp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, maxp),
             in_specs=[
-                pl.BlockSpec((1, jp, di), lambda i, p, bt: (i, 0, 0)),
-                pl.BlockSpec((1, jp, 1), lambda i, p, bt: (i, 0, 0)),
+                pl.BlockSpec((1, nq * jp, di), lambda i, p, bt: (i, 0, 0)),
+                pl.BlockSpec((1, nq * jp, 1), lambda i, p, bt: (i, 0, 0)),
                 pl.BlockSpec((1, page, di), lambda i, p, bt: (bt[i, p], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, 1, page), lambda i, p, bt: (i, p, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, nq, page), lambda i, p, bt: (i, p, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, maxp, 1, page), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, maxp, nq, page), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), qi, w.astype(jnp.float32)[:, :, None], ik_pages)
+    if nq > 1:
+        return out.transpose(0, 2, 1, 3).reshape(b, nq, maxp * page)
     return out.reshape(b, maxp * page)
 
 
 def indexer_scores_reference(qi, w, ik_pages, block_tables) -> jax.Array:
     b = qi.shape[0]
     k = ik_pages[block_tables].reshape(b, -1, ik_pages.shape[-1]).astype(jnp.float32)
+    if qi.ndim == 4:  # a window's queries: [B, W, J, Di]
+        s = jnp.einsum("bwjd,bsd->bwjs", qi.astype(jnp.float32), k)
+        return jnp.einsum("bwj,bwjs->bws", w.astype(jnp.float32), jnp.maximum(s, 0.0))
     s = jnp.einsum("bjd,bsd->bjs", qi.astype(jnp.float32), k)
     return jnp.einsum("bj,bjs->bs", w.astype(jnp.float32), jnp.maximum(s, 0.0))
 

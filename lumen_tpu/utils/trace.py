@@ -354,6 +354,9 @@ SCOPE_KV_WRITE = "lumen.kv_write"
 SCOPE_PREFILL = "lumen.prefill"
 #: a prefilled or resumed row written into its slot and pages
 SCOPE_INSTALL = "lumen.install"
+#: a multi-token prediction layer's pass in the step, chunk and admission
+#: programs: its input norms and projection, its layer, its head, the draft
+SCOPE_MTP = "lumen.mtp"
 
 
 # -- contextvar propagation --------------------------------------------------

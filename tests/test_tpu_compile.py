@@ -82,19 +82,21 @@ def _flash_cache(sq, sk):
     )
 
 
-def _latent(heads, c_dim, span, sel, maxp=72):
+def _latent(heads, c_dim, span, sel, maxp=72, window=0):
     """The latent decode kernel at the published ``dots3_note`` widths: 16
     rows, 64-token pages, 72 pages a row (max_seq 4,608). A grid step holds
     1,024 keys: double-buffered ``[1024, C]`` latent rows beside sixteen
-    one-page position blocks."""
+    one-page position blocks. ``window``: a verify window of that many
+    queries a row, each with its own selection."""
     rows, page, rope = 16, 64, 64
+    q = (rows, window) if window else (rows,)
     shapes = [
-        ((rows, heads, c_dim), BF16), ((rows, heads, rope), BF16),
+        ((*q, heads, c_dim), BF16), ((*q, heads, rope), BF16),
         ((rows * maxp + 1, page, c_dim), BF16), ((rows * maxp + 1, page, rope), BF16),
         ((rows, maxp), I32), ((rows,), I32), ((rows,), I32),
     ]
     if sel:
-        shapes.append(((rows, maxp * page), jnp.bool_))
+        shapes.append(((*q, maxp * page), jnp.bool_))
     return (
         lambda *a: lat.latent_paged_attention_kernel(
             *a, scale=0.07, span=span, interpret=False
@@ -166,6 +168,14 @@ CASES = {
         lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
         [((16, 64, 128), BF16), ((16, 64), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],
     ),
+    # GLM-5's verify window of two queries a row (the pending token and its
+    # draft): 64 heads over a 512-value latent, each query its own top-k; the
+    # indexer's 32 heads of 128 for both queries against one read of a page
+    "latent_verify_selected": _latent(64, 512, None, sel=True, window=2),
+    "indexer_scores_verify": (
+        lambda q, w, k, bt: lat.indexer_scores_kernel(q, w, k, bt, interpret=False),
+        [((16, 2, 32, 128), BF16), ((16, 2, 32), jnp.float32), ((16 * 72 + 1, 64, 128), BF16), ((16, 72), I32)],
+    ),
     "ssm_scan_chunk": _ssm_scan(1, 256),
     "ssm_scan_tail": _ssm_scan(1, 64),
     "ssm_scan_group": _ssm_scan(4, 320),
@@ -225,7 +235,8 @@ def test_paged_kernels_keep_the_name_the_benchmark_reads(v5e, name):
 @pytest.mark.parametrize(
     "name,pattern",
     [("latent_full_selected", "^latent_paged_attention"), ("latent_window", "^latent_paged_attention"),
-     ("latent_all_keys", "^latent_paged_attention"), ("indexer_scores", "^indexer_scores")],
+     ("latent_all_keys", "^latent_paged_attention"), ("indexer_scores", "^indexer_scores"),
+     ("latent_verify_selected", "^latent_paged_attention"), ("indexer_scores_verify", "^indexer_scores")],
 )
 def test_latent_kernels_keep_the_names_the_benchmark_reads(v5e, name, pattern):
     """``latent_attn_roofline`` / ``latent_attn_time_pct`` (and their
@@ -343,6 +354,63 @@ def test_a_latent_decoder_without_indexer_compiles_its_step_and_chunk_programs(v
         params, scratch, shape(1, 512, vcfg.decoder.hidden_size, dtype=BF16), shape(1, 512), shape(), shape(1)
     ).compile().as_text()
     assert all(k.startswith("ragged-dot") for k in _kernel_names(chunk)), _kernel_names(chunk)
+
+
+def test_a_drafting_decoder_compiles_its_verify_step_and_chunk_programs(v5e, monkeypatch):
+    """The decode block and the 512-token lane chunk of the benchmark's
+    GLM-5 configuration at its published widths
+    (hidden 6,144, 64 heads over a 512-value latent, an indexer of 32 heads
+    of 128 keeping 2,048 keys in every layer, 8 held experts of 2,048, 16
+    slots, ``max_seq`` 4,608), cut to the dense layer and one expert layer
+    beside the multi-token prediction layer: the step verifies two positions
+    a row through the latent and indexer kernels, under the names the
+    ``latent_attn_*.verify`` and ``indexer_roofline.verify`` metrics match,
+    and the multi-token prediction layer's operations carry ``lumen.mtp``."""
+    import json
+
+    from lumen_tpu.models.vlm.generate import Generator
+    from lumen_tpu.models.vlm.modeling import VLMConfig, VLMModel
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)  # default_backend() is the CPU here
+    monkeypatch.delenv("LUMEN_PAGED_KERNEL", raising=False)
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "hub-vitl14-glm5-ep32.json")
+    with open(path) as f:
+        entry = json.load(f)
+    hf = entry["models"]["vlm"]["config"]
+    hf = {**hf, "text_config": {**hf["text_config"], "num_hidden_layers": 2}}
+    vcfg = VLMConfig.from_hf(hf)
+    model = VLMModel(vcfg)
+    slots, max_seq, page = 16, entry["backend_settings"]["vlm"]["max_seq"], DEFAULT_PAGE_SIZE
+    gen = Generator(model, vcfg, max_seq=max_seq, max_new_cap=512, cache_dtype=BF16)
+
+    def on_chip(tree, floats=None):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, floats if floats and jnp.issubdtype(a.dtype, jnp.floating) else a.dtype, sharding=v5e
+            ), tree,
+        )
+
+    size = vcfg.vision.image_size
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), I32), jnp.zeros((1, size, size, 3)))
+    )["params"], BF16)
+    maxp = max_seq // page
+    pool = on_chip(jax.eval_shape(lambda: gen.init_pool(slots, pages=slots * maxp + 1, page_size=page)))
+    assert len(pool["caches"]) == 3 and sorted(pool["caches"][2]) == ["c", "ik", "r"]
+    shape = lambda *s, dtype=I32: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
+    step = jax.jit(gen._step_block_impl, static_argnames=("block",)).lower(
+        params, pool, shape(slots, maxp), shape(2, dtype=jnp.uint32), block=8
+    ).compile().as_text()
+    kernels = _kernel_names(step)
+    assert {k for k in kernels if not k.startswith("ragged-dot")} == {
+        "latent_paged_attention_kernel", "indexer_scores_kernel"}, kernels
+    assert "lumen.mtp" in step
+    scratch = on_chip(jax.eval_shape(lambda: gen.new_prefill_cache(max_seq)))
+    chunk = jax.jit(gen._prefill_chunk_impl).lower(
+        params, scratch, shape(1, 512, vcfg.decoder.hidden_size, dtype=BF16), shape(1, 512), shape(), shape(1)
+    ).compile().as_text()
+    assert all(k.startswith("ragged-dot") for k in _kernel_names(chunk)), _kernel_names(chunk)
+    assert "lumen.mtp" in chunk
 
 
 def test_a_grouped_query_step_moves_no_pool_sized_copy(v5e, monkeypatch):

@@ -529,7 +529,7 @@ def test_the_device_counts_one_block_of_expert_calls_and_the_host_keeps_the_tota
     # one-shot prefill, then two blocks of four steps, four expert layers each
     assert _gauges(sched)["moe_layer_calls"] - before == 4 * (1 + 8)
     assert np.asarray(sched.pool["moe_stats"]).tolist() == [0, 0, 0, 0]
-    assert int(sched.pool["moe_block"][3]) == 4 * 4  # the last block's calls alone
+    assert int(sched.moe_block[3]) == 4 * 4  # the last block's calls alone
 
 
 def test_backend_settings_carry_max_seq_to_the_manager(monkeypatch):
@@ -759,19 +759,33 @@ def _no_window_config() -> dict:
     return no_window()
 
 
+def _drafting_config() -> dict:
+    """A latent decoder with an indexer in every layer and a multi-token
+    prediction layer (GLM-5's kind): it keeps every page of a row and
+    verifies its own drafts inside the decode step."""
+    from tests.test_vlm_glm5 import tiny_config as drafting
+
+    return drafting()
+
+
 @pytest.mark.parametrize(
     "make, indexer_layers, reason",
     [
         (tiny_config, 2, "window layers free the pages a shared prefix would need"),
         (_no_window_config, 0, "do not read or write latent leaves yet"),
+        (_drafting_config, 4, "do not read or write latent leaves yet"),
     ],
-    ids=["window-and-indexer", "every-key-every-layer"],
+    ids=["window-and-indexer", "every-key-every-layer", "indexer-every-layer-drafting"],
 )
 def test_each_latent_decoder_is_refused_for_a_reason_that_is_true_of_it(make, indexer_layers, reason):
     """``RowState.refuse`` names window layers only where the decoder has
     them; a latent decoder without any keeps all its pages and is refused
-    because nothing that shares or exports rows handles latent leaves yet.
-    ``indexer_layers`` counts only layers that have an indexer."""
+    because nothing that shares or exports rows handles latent leaves yet:
+    the prefix cache, the spill tier, the host's n-gram verify turns and
+    migration, each named; what such a decoder does verify, its own
+    multi-token prediction drafts inside the decode step, is said beside
+    them. ``indexer_layers`` counts only layers that have an indexer (a
+    multi-token prediction layer among them)."""
     from lumen_tpu.models.vlm.paged_kv import RowState
 
     rows = RowState(VLMConfig.from_hf(make()))
@@ -781,6 +795,11 @@ def test_each_latent_decoder_is_refused_for_a_reason_that_is_true_of_it(make, in
         with pytest.raises(NotImplementedError, match=reason) as err:
             rows.refuse(what)
         assert what in str(err.value) and "latent decoder" in str(err.value)
+        if not rows.window_layers:
+            said = str(err.value)
+            for refused in ("prefix cache", "spill tier", "n-gram verify turns", "migration wire format"):
+                assert refused in said, said
+            assert "multi-token prediction drafts are verified inside the decode step" in said
 
 
 # -- the manager's behaviour with the second kind of row state ------------------------
