@@ -325,6 +325,12 @@ class BaseService(InferenceServicer):
     def healthy(self) -> bool:
         return True
 
+    def decode_slots(self) -> int:
+        """Decode slots over this service's generation engines: each holds
+        one streaming request, and its gRPC handler thread, from admission
+        to its last token. The server sizes its handler pool from these."""
+        return 0
+
     def replica_states(self) -> dict:
         """Per-replica health states keyed by dispatcher name, e.g.
         ``{"clip-image": {"r0": "serving", "r1": "down"}}``. Populated by

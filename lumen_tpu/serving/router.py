@@ -163,25 +163,99 @@ def advertised_fed_role() -> str | None:
     return raw
 
 
-def _fed_wait_slots() -> threading.Semaphore:
-    """Process-wide cap on CONCURRENTLY-PARKED cache-lookup waits — the
-    per-RPC deadline clamp bounds each wait, this bounds the aggregate:
-    with the default 10-thread gRPC pool, a handful of slow flights each
-    attracting one waiting lookup per non-owner peer could otherwise park
-    every handler thread and starve this host's own Health probes into a
-    fleet-wide ejection. Over the cap, lookups degrade to an immediate
-    peek (miss if not cached) — the requester computes, which is always
-    correct. Sized to half the handler pool, floor 1."""
-    global _FED_WAIT_SLOTS
-    if _FED_WAIT_SLOTS is None:
-        from ..utils.env import env_int
-
-        workers = env_int("LUMEN_GRPC_WORKERS", 10, minimum=1)
-        _FED_WAIT_SLOTS = threading.Semaphore(max(1, workers // 2))
-    return _FED_WAIT_SLOTS
+#: gRPC handler threads a hub keeps for unary embeds, Health and
+#: capabilities: the reference's ``ThreadPoolExecutor(10)``, and the whole
+#: pool of a hub that holds no caption engine and of a front tier.
+BASE_HANDLERS = 10
 
 
-_FED_WAIT_SLOTS: threading.Semaphore | None = None
+def grpc_pool_size(decode_slots: int) -> int:
+    """Handler threads for a server whose caption engines hold
+    ``decode_slots`` decode slots in all. A streaming caption holds its
+    handler thread for its whole life, so every slot gets a thread of its
+    own, on top of the :data:`BASE_HANDLERS` that serve everything else
+    (a client that keeps ``max_concurrency`` = the slots in flight never
+    queues in front of the server). ``LUMEN_GRPC_WORKERS``, where set, is
+    the size instead."""
+    from ..utils.env import env_int
+
+    return env_int("LUMEN_GRPC_WORKERS", BASE_HANDLERS + decode_slots, minimum=1)
+
+
+class HandlerPool:
+    """The gRPC handler pool a server runs on: its size, and how many
+    Infer RPCs are inside a handler (``HubRouter.Infer`` and the front
+    tier's, entered and left). Exported as the ``grpc-pool`` gauge; over a
+    window, the delta of ``busy_ms_sum`` over the delta of ``wall_ms`` is
+    the mean number of RPCs in a handler.
+
+    It also holds the cap on CONCURRENTLY-PARKED cache-lookup waits — the
+    per-RPC deadline clamp bounds each wait, this bounds the aggregate: a
+    handful of slow flights each attracting one waiting lookup per
+    non-owner peer could otherwise park every handler thread and starve
+    this host's own Health probes into a fleet-wide ejection. Over the
+    cap, lookups degrade to an immediate peek (miss if not cached) — the
+    requester computes, which is always correct. Sized to half the pool,
+    floor 1."""
+
+    def __init__(self, size: int) -> None:
+        self.size = size
+        self.wait_slots = threading.Semaphore(max(1, size // 2))
+        self._lock = threading.Lock()
+        self._busy = 0
+        self._busy_peak = 0
+        self._busy_ms_sum = 0.0
+        self._t0 = self._t = time.perf_counter()
+
+    def _advance(self) -> None:
+        """Bring the busy integral up to now (under the lock)."""
+        now = time.perf_counter()
+        self._busy_ms_sum += self._busy * (now - self._t) * 1e3
+        self._t = now
+
+    def enter(self) -> None:
+        with self._lock:
+            self._advance()
+            self._busy += 1
+            self._busy_peak = max(self._busy_peak, self._busy)
+
+    def leave(self) -> None:
+        with self._lock:
+            self._advance()
+            self._busy -= 1
+
+    def gauges(self) -> dict:
+        with self._lock:
+            self._advance()
+            return {
+                "size": self.size,
+                "busy": self._busy,
+                "busy_peak": self._busy_peak,
+                "busy_ms_sum": round(self._busy_ms_sum, 3),
+                "wall_ms": round((self._t - self._t0) * 1e3, 3),
+            }
+
+
+_HANDLERS: HandlerPool | None = None
+
+
+def install_handler_pool(size: int) -> HandlerPool:
+    """Make the pool a server is about to run on the process's own, and
+    publish its gauge (``serve()`` calls this once per boot)."""
+    global _HANDLERS
+    _HANDLERS = HandlerPool(size)
+    metrics.register_gauges("grpc-pool", _HANDLERS.gauges)
+    return _HANDLERS
+
+
+def handler_pool() -> HandlerPool:
+    """The pool of the server this process runs. A router driven without
+    ``serve()`` counts into one of the size a hub with no caption engine
+    gets, unpublished."""
+    global _HANDLERS
+    if _HANDLERS is None:
+        _HANDLERS = HandlerPool(grpc_pool_size(0))
+    return _HANDLERS
 
 
 class HubRouter(InferenceServicer):
@@ -417,7 +491,7 @@ class HubRouter(InferenceServicer):
                 if rem is not None:
                     wait_s = max(0.0, min(wait_s, rem - 0.1))
             key = bytes(first.payload).decode("utf-8", "replace")
-            slots = _fed_wait_slots()
+            slots = handler_pool().wait_slots
             parked = wait_s > 0 and slots.acquire(blocking=False)
             if wait_s > 0 and not parked:
                 wait_s = 0.0  # wait budget spent: peek-only, never park
@@ -486,6 +560,16 @@ class HubRouter(InferenceServicer):
             )
 
     def Infer(self, request_iterator: Iterable[pb.InferRequest], context) -> Iterator[pb.InferResponse]:
+        # Every Infer RPC, the front tier's too, is counted while it holds
+        # a handler thread: the grpc-pool gauge's busy fields.
+        pool = handler_pool()
+        pool.enter()
+        try:
+            yield from self._infer(request_iterator, context)
+        finally:
+            pool.leave()
+
+    def _infer(self, request_iterator: Iterable[pb.InferRequest], context) -> Iterator[pb.InferResponse]:
         try:
             first = next(iter(request_iterator))
         except StopIteration:
@@ -916,7 +1000,7 @@ class FederationRouter(HubRouter):
 
     # -- rpcs --------------------------------------------------------------
 
-    def Infer(self, request_iterator: Iterable[pb.InferRequest], context) -> Iterator[pb.InferResponse]:
+    def _infer(self, request_iterator: Iterable[pb.InferRequest], context) -> Iterator[pb.InferResponse]:
         try:
             first = next(iter(request_iterator))
         except StopIteration:

@@ -48,7 +48,13 @@ from .breaker import CircuitBreaker, breaker_failures
 from .loader import resolve
 from .mdns import MdnsAdvertiser
 from .resilience import DegradedService, RecoveryManager, expected_tasks_for
-from .router import FederationRouter, HubRouter
+from .router import (
+    FederationRouter,
+    HubRouter,
+    grpc_pool_size,
+    handler_pool,
+    install_handler_pool,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -67,13 +73,19 @@ _DRAIN_WATCHER_STALE_S = 15.0
 
 
 def grpc_workers() -> int:
-    """``LUMEN_GRPC_WORKERS``: gRPC handler threads (default 10, the
-    reference's ThreadPoolExecutor size). A federation front tier wants
-    more — each forwarded stream parks one handler thread on a peer RPC,
-    so the front's concurrency ceiling is exactly this number."""
-    from ..utils.env import env_int
+    """gRPC handler threads of the server this process runs: ten, plus one
+    for every decode slot of the caption engines its services hold
+    (:func:`~lumen_tpu.serving.router.grpc_pool_size`), unless
+    ``LUMEN_GRPC_WORKERS`` sets it. A federation front tier holds no
+    engine and wants more — each forwarded stream parks one handler thread
+    on a peer RPC, so the front's concurrency ceiling is exactly this
+    number."""
+    return handler_pool().size
 
-    return env_int("LUMEN_GRPC_WORKERS", 10, minimum=1)
+
+def decode_slots(services: dict[str, BaseService]) -> int:
+    """Decode slots over every caption engine the services report."""
+    return sum(svc.decode_slots() for svc in services.values())
 
 
 def drain_budget_s() -> float:
@@ -473,9 +485,13 @@ def serve(
     if federation is not None:
         federation.start()  # the one background health-poll thread
 
+    # Sized from what the services hold: a stream per decode slot and the
+    # base ten (a degraded service that recovers later finds the pool as
+    # its boot left it).
+    pool = install_handler_pool(grpc_pool_size(decode_slots(services)))
     server = grpc.server(
         futures.ThreadPoolExecutor(
-            max_workers=grpc_workers(), thread_name_prefix="grpc"
+            max_workers=pool.size, thread_name_prefix="grpc"
         ),
         options=GRPC_OPTIONS,
     )
@@ -550,7 +566,10 @@ def serve(
 
     autopilot = maybe_start_autopilot()
 
-    logger.info("serving %d service(s) on %s:%d: %s", len(services), host, bound, sorted(services))
+    logger.info(
+        "serving %d service(s) on %s:%d with %d handler threads: %s",
+        len(services), host, bound, pool.size, sorted(services),
+    )
     for name, svc in services.items():
         logger.info("  %s [%s] tasks: %s", name, svc.status(), svc.registry.task_names())
 

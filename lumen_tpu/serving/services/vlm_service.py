@@ -94,17 +94,19 @@ class VlmService(BaseService):
         manager.initialize()
         return cls(manager)
 
+    def decode_slots(self) -> int:
+        return sum(engine.n_slots for engine in self.manager._engines)
+
     def capability(self):
         # Suggested client concurrency = the decode width the engines
         # actually batch (slot-pool width x engine replicas) — advertising
         # 1 made clients serialize requests the server batches fine
         # (reference field semantics: proto Capability.max_concurrency,
         # "Suggested max concurrency").
-        width = self.manager.gen_slots * len(self.manager._engines)
         return self.registry.build_capability(
             model_ids=[self.manager.model_id],
             runtime="jax-tpu",
-            max_concurrency=max(1, width),
+            max_concurrency=max(1, self.decode_slots()),
             # Routes reflect what initialize() actually chose — a manager
             # that opted into int8 but fell back to bf16 (warmup A/B
             # showed a decode regression) must not advertise int8.
